@@ -8,16 +8,31 @@
 //
 //   [Hdr | state[capacity] | size[capacity] | slots (aligned)...]
 //
-// with PROCESS_SHARED pthread mutex/condvars in the header, so worker
+// with a PROCESS_SHARED mutex and a futex word in the header, so worker
 // PROCESSES serialize numpy batches straight into shared slots — no pickle,
 // no pipe — and the consumer maps them zero-copy. Slots are acquired by
 // SEQUENCE NUMBER (pring_acquire_write_seq), so batch order is preserved
 // end-to-end even with racing workers. All blocking waits run in C with the
 // GIL released by ctypes.
+//
+// A participant can be SIGKILLed at any instruction (OOM killer, preemption,
+// the fault-injection tests), so nothing here may depend on a dead process:
+// the mutex is ROBUST (the next locker inherits it, EOWNERDEAD), and waiting
+// is a bare futex on a generation counter — the kernel keeps no record of a
+// waiter that died. A process-shared pthread condvar does: a waiter killed
+// inside pthread_cond_wait never leaves its group, and a later
+// signal/broadcast blocks forever waiting for it (that hung
+// ProcessWorkerPool.shutdown, and with it the whole test run).
 
+#include <errno.h>
+#include <limits.h>
+#include <linux/futex.h>
 #include <pthread.h>
 #include <stdint.h>
 #include <string.h>
+#include <sys/syscall.h>
+#include <time.h>
+#include <unistd.h>
 
 namespace {
 
@@ -31,9 +46,8 @@ struct Hdr {
   int64_t next_write_seq;  // next sequence number allowed to acquire
   int64_t read_seq;        // next sequence number the consumer will read
   int32_t closed;
-  int32_t _pad;
+  uint32_t gen;            // futex word: bumped (under mu) on every change
   pthread_mutex_t mu;
-  pthread_cond_t cv;
 };
 
 constexpr uint64_t kMagic = 0x70616464726e6701ULL;  // "paddrng\1"
@@ -48,6 +62,32 @@ inline int64_t* sizes(Hdr* h) {
 }
 inline char* slot(Hdr* h, int64_t idx) {
   return reinterpret_cast<char*>(h) + h->slots_offset + idx * h->slot_bytes;
+}
+
+inline void lock(Hdr* h) {
+  if (pthread_mutex_lock(&h->mu) == EOWNERDEAD) {
+    // the previous owner died inside a critical section: every field it
+    // guards is a single word, so the state is usable as it stands
+    pthread_mutex_consistent(&h->mu);
+  }
+}
+
+// Publish a change made under mu: unlock, then wake every waiter.
+inline void unlock_and_wake(Hdr* h) {
+  __atomic_add_fetch(&h->gen, 1, __ATOMIC_SEQ_CST);
+  pthread_mutex_unlock(&h->mu);
+  syscall(SYS_futex, &h->gen, FUTEX_WAKE, INT_MAX, nullptr, nullptr, 0);
+}
+
+// Called with mu held: release it, sleep until the generation moves on from
+// what it was under the lock (or `ts` elapses; nullptr = no limit), retake
+// mu. A change between the unlock and the wait is not lost: FUTEX_WAIT
+// returns at once when the word no longer holds `seen`.
+inline void wait_change(Hdr* h, const struct timespec* ts) {
+  uint32_t seen = __atomic_load_n(&h->gen, __ATOMIC_SEQ_CST);
+  pthread_mutex_unlock(&h->mu);
+  syscall(SYS_futex, &h->gen, FUTEX_WAIT, seen, ts, nullptr, 0);
+  lock(h);
 }
 
 }  // namespace
@@ -74,6 +114,7 @@ int pring_init(void* mem, int64_t capacity, int64_t slot_bytes) {
   h->next_write_seq = 0;
   h->read_seq = 0;
   h->closed = 0;
+  h->gen = 0;
   for (int64_t i = 0; i < capacity; ++i) {
     states(h)[i] = FREE;
     sizes(h)[i] = 0;
@@ -81,11 +122,8 @@ int pring_init(void* mem, int64_t capacity, int64_t slot_bytes) {
   pthread_mutexattr_t ma;
   pthread_mutexattr_init(&ma);
   pthread_mutexattr_setpshared(&ma, PTHREAD_PROCESS_SHARED);
+  pthread_mutexattr_setrobust(&ma, PTHREAD_MUTEX_ROBUST);
   if (pthread_mutex_init(&h->mu, &ma) != 0) return -2;
-  pthread_condattr_t ca;
-  pthread_condattr_init(&ca);
-  pthread_condattr_setpshared(&ca, PTHREAD_PROCESS_SHARED);
-  if (pthread_cond_init(&h->cv, &ca) != 0) return -3;
   h->magic = kMagic;
   return 0;
 }
@@ -103,11 +141,11 @@ int64_t pring_slot_bytes(void* mem) {
 // index, or -1 if closed.
 int64_t pring_acquire_write_seq(void* mem, int64_t seq) {
   Hdr* h = static_cast<Hdr*>(mem);
-  pthread_mutex_lock(&h->mu);
+  lock(h);
   int64_t idx = seq % h->capacity;
   while (!h->closed &&
          (h->next_write_seq != seq || states(h)[idx] != FREE)) {
-    pthread_cond_wait(&h->cv, &h->mu);
+    wait_change(h, nullptr);
   }
   if (h->closed) {
     pthread_mutex_unlock(&h->mu);
@@ -115,8 +153,7 @@ int64_t pring_acquire_write_seq(void* mem, int64_t seq) {
   }
   h->next_write_seq = seq + 1;
   states(h)[idx] = WRITING;
-  pthread_mutex_unlock(&h->mu);
-  pthread_cond_broadcast(&h->cv);
+  unlock_and_wake(h);
   return idx;
 }
 
@@ -126,11 +163,10 @@ void* pring_slot_ptr(void* mem, int64_t idx) {
 
 void pring_commit_write(void* mem, int64_t idx, int64_t size) {
   Hdr* h = static_cast<Hdr*>(mem);
-  pthread_mutex_lock(&h->mu);
+  lock(h);
   sizes(h)[idx] = size;
   states(h)[idx] = READY;
-  pthread_mutex_unlock(&h->mu);
-  pthread_cond_broadcast(&h->cv);
+  unlock_and_wake(h);
 }
 
 // Abort = commit an empty (size 0) payload: the consumer skips it. Marking
@@ -138,11 +174,10 @@ void pring_commit_write(void* mem, int64_t idx, int64_t size) {
 // aborted sequence number.
 void pring_abort_write(void* mem, int64_t idx) {
   Hdr* h = static_cast<Hdr*>(mem);
-  pthread_mutex_lock(&h->mu);
+  lock(h);
   sizes(h)[idx] = 0;
   states(h)[idx] = READY;
-  pthread_mutex_unlock(&h->mu);
-  pthread_cond_broadcast(&h->cv);
+  unlock_and_wake(h);
 }
 
 // Block until the next-in-order batch is READY; returns slot index and
@@ -152,7 +187,17 @@ void pring_abort_write(void* mem, int64_t idx) {
 int64_t pring_acquire_read_timeout(void* mem, int64_t* size,
                                    int64_t timeout_ms) {
   Hdr* h = static_cast<Hdr*>(mem);
-  pthread_mutex_lock(&h->mu);
+  struct timespec deadline;
+  if (timeout_ms >= 0) {
+    clock_gettime(CLOCK_MONOTONIC, &deadline);
+    deadline.tv_sec += timeout_ms / 1000;
+    deadline.tv_nsec += (timeout_ms % 1000) * 1000000L;
+    if (deadline.tv_nsec >= 1000000000L) {
+      deadline.tv_sec += 1;
+      deadline.tv_nsec -= 1000000000L;
+    }
+  }
+  lock(h);
   int64_t idx = h->read_seq % h->capacity;
   while (true) {
     if (states(h)[idx] == READY) break;
@@ -163,21 +208,22 @@ int64_t pring_acquire_read_timeout(void* mem, int64_t* size,
       return -1;
     }
     if (timeout_ms < 0) {
-      pthread_cond_wait(&h->cv, &h->mu);
+      wait_change(h, nullptr);
     } else {
-      struct timespec ts;
-      clock_gettime(CLOCK_REALTIME, &ts);
-      ts.tv_sec += timeout_ms / 1000;
-      ts.tv_nsec += (timeout_ms % 1000) * 1000000L;
-      if (ts.tv_nsec >= 1000000000L) {
-        ts.tv_sec += 1;
-        ts.tv_nsec -= 1000000000L;
+      // FUTEX_WAIT takes a RELATIVE timeout: what is left of the deadline
+      struct timespec now, left;
+      clock_gettime(CLOCK_MONOTONIC, &now);
+      left.tv_sec = deadline.tv_sec - now.tv_sec;
+      left.tv_nsec = deadline.tv_nsec - now.tv_nsec;
+      if (left.tv_nsec < 0) {
+        left.tv_sec -= 1;
+        left.tv_nsec += 1000000000L;
       }
-      if (pthread_cond_timedwait(&h->cv, &h->mu, &ts) != 0 &&
-          states(h)[idx] != READY) {
+      if (left.tv_sec < 0) {
         pthread_mutex_unlock(&h->mu);
         return -2;
       }
+      wait_change(h, &left);
     }
   }
   h->read_seq += 1;
@@ -193,18 +239,16 @@ int64_t pring_acquire_read(void* mem, int64_t* size) {
 
 void pring_release_read(void* mem, int64_t idx) {
   Hdr* h = static_cast<Hdr*>(mem);
-  pthread_mutex_lock(&h->mu);
+  lock(h);
   states(h)[idx] = FREE;
-  pthread_mutex_unlock(&h->mu);
-  pthread_cond_broadcast(&h->cv);
+  unlock_and_wake(h);
 }
 
 void pring_close(void* mem) {
   Hdr* h = static_cast<Hdr*>(mem);
-  pthread_mutex_lock(&h->mu);
+  lock(h);
   h->closed = 1;
-  pthread_mutex_unlock(&h->mu);
-  pthread_cond_broadcast(&h->cv);
+  unlock_and_wake(h);
 }
 
 }  // extern "C"

@@ -4,13 +4,16 @@ Parity: the reference's C++ runtime around the compute path — reader
 BlockingQueues/buffered readers and data_feed text processing
 (paddle/fluid/operators/reader/, paddle/fluid/framework/data_feed.cc).
 The library is compiled from csrc/ on first use (g++, cached as
-libpaddle_tpu_native.so next to this file); every consumer has a pure-Python
-fallback so the framework works without a toolchain.
+libpaddle_tpu_native.so next to this file, never committed); every consumer
+has a pure-Python fallback so the framework works without a toolchain — but
+a build that was tried and failed says so (RuntimeWarning with the
+compiler's output), once.
 """
 import ctypes
 import os
 import subprocess
 import threading
+import warnings
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _CSRC = os.path.normpath(os.path.join(_HERE, '..', '..', 'csrc'))
@@ -32,7 +35,14 @@ def _build():
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         return True
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, 'stderr', b'') or b''
+        warnings.warn(
+            "paddle_tpu._native: building %s from csrc/ failed (%r); the "
+            "pure-Python paths are used instead.\n%s"
+            % (os.path.basename(_LIB_PATH), e,
+               detail.decode('utf-8', 'replace')[-2000:]),
+            RuntimeWarning)
         return False
 
 

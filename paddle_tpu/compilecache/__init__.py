@@ -55,7 +55,8 @@ from ..resilience.atomic_io import atomic_write, crc32_bytes, crc32_file
 __all__ = ['CompileCache', 'CachedJit', 'enable', 'disable', 'active',
            'use', 'cache_dir', 'fetch_or_compile', 'note_bypass',
            'note_incompat', 'signature', 'make_key', 'stats', 'hit_rate',
-           'reset_stats', 'ENV_VAR', 'MANIFEST_NAME', 'ENTRY_SUFFIX']
+           'reset_stats', 'executable_device_count', 'ENV_VAR',
+           'MANIFEST_NAME', 'ENTRY_SUFFIX']
 
 ENV_VAR = 'PADDLE_TPU_COMPILE_CACHE'
 MANIFEST_NAME = 'manifest.json'
@@ -125,6 +126,19 @@ def signature(args):
 def _backend_tag():
     import jax
     return (jax.default_backend(), jax.__version__, len(jax.devices()))
+
+
+def executable_device_count(compiled):
+    """Devices the executable itself spans (from its input and output
+    shardings) — NOT the process's device count: a one-device program
+    compiled on a four-chip host must reload as a one-device program."""
+    import jax
+    shardings = jax.tree_util.tree_leaves(
+        (compiled.input_shardings, compiled.output_shardings))
+    devices = set()
+    for s in shardings:
+        devices |= s.device_set
+    return len(devices) or 1
 
 
 def make_key(label, sig, sharding=''):
@@ -218,20 +232,12 @@ class CompileCache:
                 blob = pickle.load(f)
             serialized, in_tree, out_tree = blob['payload']
             from jax.experimental import serialize_executable as se
-            import inspect
-            kwargs = {}
-            # deserialize onto exactly the compiled device count (see
-            # inference.AOTCompiledFunction.load for the feature-detect
-            # rationale)
-            try:
-                if 'execution_devices' in inspect.signature(
-                        se.deserialize_and_load).parameters:
-                    kwargs['execution_devices'] = \
-                        jax.devices()[:int(ent.get('n_devices', 1))]
-            except (TypeError, ValueError):
-                pass
-            compiled = se.deserialize_and_load(serialized, in_tree,
-                                               out_tree, **kwargs)
+            # onto exactly the executable's own device count: the default
+            # maps onto every local device and then rejects the args
+            compiled = se.deserialize_and_load(
+                serialized, in_tree, out_tree,
+                execution_devices=jax.devices()[
+                    :int(ent.get('n_devices', 1))])
         except Exception as e:
             _note('incompat', label, reason=repr(e)[:200])
             return None
@@ -254,7 +260,8 @@ class CompileCache:
         except Exception as e:
             _note('bypass', label, reason='unserializable: %r' % (e,))
             return False
-        backend, jax_version, n_devices = _backend_tag()
+        backend, jax_version, _ = _backend_tag()
+        n_devices = executable_device_count(compiled)
         fname = key + ENTRY_SUFFIX
         try:
             atomic_write(os.path.join(self.root, fname), blob)

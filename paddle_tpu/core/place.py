@@ -33,7 +33,11 @@ class CPUPlace(Place):
 class TPUPlace(Place):
     def jax_device(self):
         devs = jax.devices()
-        return devs[self._device_id % len(devs)]
+        if not 0 <= self._device_id < len(devs):
+            raise ValueError(
+                "%r: this process has %d device(s) (%s)"
+                % (self, len(devs), devs[0].device_kind))
+        return devs[self._device_id]
 
 
 # Aliases so reference-era scripts run unmodified on TPU.
@@ -78,11 +82,7 @@ def get_place():
 
 
 def default_jax_device():
-    p = get_place()
-    try:
-        return p.jax_device()
-    except Exception:
-        return None
+    return get_place().jax_device()
 
 
 def is_compiled_with_cuda():

@@ -25,12 +25,14 @@ def synchronize(device=None):
 
 
 def memory_stats(device=None):
-    """Live/peak HBM bytes (parity: fluid/memory stats)."""
-    try:
-        d = jax.devices()[0]
-        return d.memory_stats() or {}
-    except Exception:
-        return {}
+    """Live/peak HBM bytes of ``device`` — a ``jax.Device``, a Place, or
+    None for the current place's device (parity: fluid/memory stats). The
+    CPU backend reports none: ``{}``."""
+    if device is None:
+        device = get_place()
+    if hasattr(device, 'jax_device'):
+        device = device.jax_device()
+    return device.memory_stats() or {}
 
 
 class cuda:

@@ -1,14 +1,8 @@
-"""jax.shard_map compatibility (check_rep was renamed check_vma in jax 0.8)."""
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+"""shard_map with this repo's historical ``check=`` keyword (JAX calls it
+``check_vma``)."""
+from jax import shard_map as _shard_map
 
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None, check=True):
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check)
+    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                      check_vma=check)

@@ -24,6 +24,7 @@ rollback snapshot (which donation would invalidate). Host bookkeeping
 (`NanGuard` counters/NanStepError, `GradScaler` state) is reconciled at
 the caller's log cadence through :meth:`TrainStep.sync`.
 """
+import contextlib
 import functools
 import itertools
 import os
@@ -33,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import observability as _obs
+from ..kernels._common import kernel_mesh
 
 # distinguishes the default cost-ledger labels of multiple TrainSteps
 # built in one process (frontends that care set .cost_label explicitly)
@@ -60,10 +62,7 @@ def donation_supported(backend=None):
     if env == '1':
         return True
     if backend is None:
-        try:
-            backend = jax.default_backend()
-        except Exception:
-            return False
+        backend = jax.default_backend()
     return backend in _DONATING_BACKENDS
 
 
@@ -76,10 +75,7 @@ def matmul_preference(backend=None):
     if env is not None:
         return env or None
     if backend is None:
-        try:
-            backend = jax.default_backend()
-        except Exception:
-            return None
+        backend = jax.default_backend()
     return 'bfloat16' if backend == 'tpu' else None
 
 
@@ -330,6 +326,26 @@ def build_train_step(loss_fn=None, optimizer=None, *, net=None, loss=None,
                      in_shardings=in_shardings, sharding=sharding)
 
 
+def kernel_mesh_of(sharding, in_shardings):
+    """The ``kernel_mesh`` scope of a partitioned step — the mesh, the
+    axes its batch is split over, the axes its attention heads are — or
+    a null scope for a one-device step. ``sharding=`` names them; with raw
+    ``in_shardings=`` (the Executor's data-parallel steps) they are read
+    off the batch's NamedSharding."""
+    if sharding is not None:
+        if sharding.num_devices <= 1:
+            return contextlib.nullcontext()
+        heads = (sharding.model_axis,) \
+            if sharding.tensor_parallel_degree > 1 else ()
+        return kernel_mesh(sharding.mesh, (sharding.data_axis,), heads)
+    for sh in jax.tree_util.tree_leaves(tuple(in_shardings or ())[1:]):
+        for entry in getattr(sh, 'spec', ()):
+            if entry is not None and sh.mesh.size > 1:
+                axes = (entry,) if isinstance(entry, str) else tuple(entry)
+                return kernel_mesh(sh.mesh, axes)
+    return contextlib.nullcontext()
+
+
 class TrainStep:
     """A compiled train step: call it with (state, batch[, key])."""
 
@@ -364,6 +380,8 @@ class TrainStep:
         self._state_shardings = None
         self._batch_sharding = None
         self._collective_bytes_est = 0
+        self._kernel_mesh = functools.partial(kernel_mesh_of, sharding,
+                                              in_shardings)
         if sharding is not None:
             # the jit program needs the state pytree's shardings: built
             # lazily by init_state (which every frontend goes through)
@@ -576,18 +594,25 @@ class TrainStep:
             new_state, losses = jax.lax.scan(body, state, xs)
             return new_state, losses, None
 
+        scope = self._kernel_mesh
+
+        def traced(state, batch, keys):
+            with contextlib.ExitStack() as scopes:
+                if precision:
+                    scopes.enter_context(
+                        jax.default_matmul_precision(precision))
+                # a partitioned program: the Pallas kernel sites split
+                # themselves over the mesh (shard_map), since the TPU
+                # compiler will not do it for them
+                scopes.enter_context(scope())
+                return run(state, batch, keys)
+
         if with_key:
             def step(state, batch, keys):
-                if precision:
-                    with jax.default_matmul_precision(precision):
-                        return run(state, batch, keys)
-                return run(state, batch, keys)
+                return traced(state, batch, keys)
         else:
             def step(state, batch):
-                if precision:
-                    with jax.default_matmul_precision(precision):
-                        return run(state, batch, None)
-                return run(state, batch, None)
+                return traced(state, batch, None)
         return step
 
     def _one_step(self, state, batch, key):

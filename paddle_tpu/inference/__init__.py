@@ -5,10 +5,11 @@ serialized program/optimization caches; paddle/fluid/inference/api). On
 TPU the expensive artifact is not an optimized subgraph but the XLA
 executable, so the cache layer works at that level:
 
-- ``enable_compilation_cache(dir)`` — turns on XLA's persistent
+- ``enable_compilation_cache(dir=None)`` — turns on XLA's persistent
   compilation cache (every jit in the process, keyed by HLO fingerprint;
   survives process restarts, the analogue of the reference's
-  serialized-program cache directory).
+  serialized-program cache directory). ``JAX_COMPILATION_CACHE_DIR``
+  places it from outside; unset, it defaults to ``<checkout>/.jax_cache``.
 - ``AOTCompiledFunction`` — explicit ahead-of-time lower+compile of one
   function for fixed shapes, serializable to a single file with
   ``jax.experimental.serialize_executable`` (the analogue of shipping a
@@ -23,43 +24,58 @@ executable, so the cache layer works at that level:
 """
 import os
 import pickle
+import warnings
 
 import numpy as np
 import jax
 
 from ..core.tensor import Tensor
 
-__all__ = ['enable_compilation_cache', 'AOTCompiledFunction', 'Predictor',
-           'load_inference_model']
+__all__ = ['enable_compilation_cache', 'DEFAULT_CACHE_DIR',
+           'AOTCompiledFunction', 'Predictor', 'load_inference_model']
 
 
-def enable_compilation_cache(cache_dir):
-    """Enable XLA's persistent compilation cache under ``cache_dir``.
+# the fixed default: inside the checkout (ignored by git), never a temporary
+# name — the directory is part of the cache key's world, one that moves
+# never hits
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), '.jax_cache')
+_CACHE_ENV = 'JAX_COMPILATION_CACHE_DIR'
+_env_override_said = [False]
 
-    Compiled executables for every jit (bench steps, Executor programs,
-    Predictor runs) are written there and reused across processes; the
-    first warm-start skips XLA compilation entirely.
+
+def enable_compilation_cache(cache_dir=None):
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    THE one place this repo decides where that cache lives. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and no other
+    directory is set here (a ``cache_dir`` argument is then left alone, and
+    that is said once). Where it is not set, the cache goes to ``cache_dir``
+    or, by default, to ``DEFAULT_CACHE_DIR`` inside the checkout.
     """
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update('jax_compilation_cache_dir', cache_dir)
+    placed = os.environ.get(_CACHE_ENV)
+    if placed:
+        # whoever placed the cache owns its policy too (size limit, entry
+        # thresholds: JAX's own env variables) — nothing is set here
+        if cache_dir and not _env_override_said[0]:
+            _env_override_said[0] = True
+            warnings.warn(
+                "%s=%s is set: the compile cache stays there; cache_dir=%r "
+                "is left alone" % (_CACHE_ENV, placed, os.fspath(cache_dir)))
+        return placed
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+    chosen = os.fspath(cache_dir) if cache_dir else DEFAULT_CACHE_DIR
+    os.makedirs(chosen, exist_ok=True)
+    jax.config.update('jax_compilation_cache_dir', chosen)
     # cache every computation, however small/fast to compile
     jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)
     jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
     # jax initializes the persistent cache AT MOST ONCE, on the first
-    # compile. importing paddle_tpu jit-compiles helpers before any user
-    # code runs, so by the time this function is called the cache was
-    # already initialized as DISABLED (no dir configured) and the config
-    # updates above are silently ignored — every entry "written" is
-    # dropped with "cache is disabled/not initialized". reset_cache()
-    # discards that verdict so the next compile re-initializes against
-    # cache_dir. Guarded: the private module moves between jax versions,
-    # and an older jax without it initializes lazily enough not to need it.
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except (ImportError, AttributeError):
-        pass
-    return cache_dir
+    # compile, and importing paddle_tpu jit-compiles helpers before any
+    # user code runs: without the reset the cache stays as it was then
+    _cc.reset_cache()
+    return chosen
 
 
 def _unwrap(a):
@@ -109,9 +125,8 @@ class AOTCompiledFunction:
     def save(self, path):
         from jax.experimental import serialize_executable as se
         payload = se.serialize(self._compiled)   # (bytes, in_tree, out_tree)
-        arg_shardings = self._compiled.input_shardings[0]
-        n_devices = (len(arg_shardings[0].device_set)
-                     if arg_shardings else 1)
+        from ..compilecache import executable_device_count
+        n_devices = executable_device_count(self._compiled)
         from ..resilience.atomic_io import atomic_pickle_dump
         atomic_pickle_dump({'backend': jax.default_backend(),
                             'n_devices': n_devices,
@@ -134,23 +149,11 @@ class AOTCompiledFunction:
             raise RuntimeError(
                 "AOT executable needs %d device(s); %d available"
                 % (n, len(jax.devices())))
-        # deserialize onto exactly the compiled device count — the default
-        # would map onto every local device and then reject the args
-        # (execution_devices is newer than some supported jax versions;
-        # those versions also default to the compiled device assignment,
-        # so omitting it is correct there, not just tolerated). Feature-
-        # detect via the signature: a blanket except TypeError would also
-        # swallow unrelated TypeErrors from inside deserialization.
-        import inspect
-        kwargs = {}
-        try:
-            if 'execution_devices' in inspect.signature(
-                    se.deserialize_and_load).parameters:
-                kwargs['execution_devices'] = jax.devices()[:n]
-        except (TypeError, ValueError):
-            pass
-        return cls(se.deserialize_and_load(serialized, in_tree, out_tree,
-                                           **kwargs))
+        # onto exactly the compiled device count — the default maps onto
+        # every local device and then rejects the args
+        return cls(se.deserialize_and_load(
+            serialized, in_tree, out_tree,
+            execution_devices=jax.devices()[:n]))
 
 
 class Predictor:

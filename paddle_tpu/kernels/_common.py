@@ -1,12 +1,114 @@
 """Shared Pallas kernel utilities (single source for PRNG masks + tiling)."""
+import contextlib
+import math
+import threading
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+
+def took(kernel, path):
+    """Say which implementation a kernel entry point took — ``'pallas'``
+    or ``'xla'`` (the plain-XLA reference: off-TPU, or a shape that does
+    not tile). Bumps ``kernels.<kernel>.<path>`` (once per trace) and
+    returns the ``jax.named_scope`` that marks the ops in the HLO, so a
+    run on the chip can prove which one its step contains."""
+    from .. import observability as _obs
+    if _obs.enabled():
+        _obs.counter('kernels.%s.%s' % (kernel, path)).inc()
+    return jax.named_scope('%s.%s' % (kernel, path))
+
+
+def pallas_runs(interpret):
+    """Pallas kernels run on the TPU backend, or anywhere when a test asks
+    for interpret mode; every other backend takes the XLA reference."""
+    return interpret is not False or jax.default_backend() == 'tpu'
+
+
+_tls = threading.local()
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh, batch_axes, head_axes=()):
+    """Trace-time scope of a jit whose operands are sharded over ``mesh``
+    (the engine's ``sharding=`` / ``in_shardings=`` steps, the Executor's
+    data-parallel programs): it tells the kernel sites which mesh axes
+    the batch (rows) and the attention heads are split over.
+
+    The TPU compiler refuses a Pallas kernel in such a program ("Mosaic
+    kernels cannot be automatically partitioned"), so inside this scope
+    every kernel site partitions itself (``spmd_kernel``). Enter it in the
+    function being traced. A sharded jit that does not is refused by the
+    compiler with that message — nothing silently takes another path.
+    """
+    prev = getattr(_tls, 'mesh', None)
+    _tls.mesh = (mesh, {'batch': tuple(batch_axes),
+                        'heads': tuple(head_axes)})
+    try:
+        yield
+    finally:
+        _tls.mesh = prev
+
+
+def spmd_kernel(impl, in_dims, out_dims, roles, granule=1):
+    """Make one ``pallas_call`` site partitionable: under ``kernel_mesh``
+    the site becomes a ``shard_map`` in which each device runs the kernel
+    on its own rows / (batch, heads) block — no operand is gathered.
+    Outside the scope (one device), and inside a ``shard_map`` that is
+    already manual over those axes, ``impl`` is called as it is.
+
+    impl(*arrays, shard): the kernel site; ``shard[f]`` is ``(start,
+        total)``: where this device's part of factor ``f`` begins (int32
+        scalar, 0 when unsharded) and the factor's whole extent (int), so
+        tile-keyed dropout masks do not depend on the partitioning.
+    in_dims / out_dims: per operand / result, a tuple naming each dim's
+        factor (``None``: a dim no device splits).
+    roles: ``{factor: 'batch' | 'heads'}`` — the factors the kernel is
+        independent along, and which of the scope's axes split each.
+    granule: a factor is split only when each device's part is a multiple
+        of this (the row kernels need 8 sublanes); otherwise every device
+        computes the whole of it.
+    """
+    def run(*arrays):
+        total = {}
+        for dims, a in zip(in_dims, arrays):
+            for f, n in zip(dims, a.shape):
+                if f in roles:
+                    total.setdefault(f, n)
+        axes, local = {}, {}
+        scope = getattr(_tls, 'mesh', None)
+        if scope is not None:
+            mesh, by_role = scope
+            manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+            for f, role in roles.items():
+                names = tuple(a for a in by_role[role]
+                              if a not in manual and mesh.shape[a] > 1)
+                k = math.prod(mesh.shape[a] for a in names)
+                if k > 1 and total[f] % k == 0 \
+                        and (total[f] // k) % granule == 0:
+                    axes[f], local[f] = names, total[f] // k
+        if not axes:
+            return impl(*arrays,
+                        shard={f: (jnp.int32(0), total[f]) for f in roles})
+
+        def on_shard(*arrays):
+            return impl(*arrays, shard={
+                f: (jax.lax.axis_index(axes[f]) * local[f] if f in axes
+                    else jnp.int32(0), total[f]) for f in roles})
+
+        def spec(dims):
+            return P(*(axes.get(f) for f in dims))
+
+        outs = tuple(spec(d) for d in out_dims)
+        return jax.shard_map(
+            on_shard, mesh=mesh, in_specs=tuple(spec(d) for d in in_dims),
+            out_specs=outs if len(outs) > 1 else outs[0],
+            axis_names=frozenset(mesh.axis_names) - manual,
+            check_vma=False)(*arrays)
+
+    return run
 
 
 def tile_keep_scale(seed_ref, tile_id, shape, dropout_p):
@@ -25,7 +127,7 @@ def tile_keep_scale(seed_ref, tile_id, shape, dropout_p):
 def row_block(n):
     """Largest row-tile size dividing n. Returns None when n has no multiple-
     of-8 tiling (Mosaic requires the sublane dim divisible by 8) — callers
-    must fall back to the XLA path."""
+    take the XLA path and say so (``took``)."""
     for bn in (256, 128, 64, 32, 16, 8):
         if n % bn == 0:
             return bn

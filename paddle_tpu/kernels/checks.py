@@ -1,0 +1,160 @@
+"""Checks of the Pallas kernels that only a chip can make.
+
+Interpret mode stubs the TPU's hardware PRNG to zeros, so the in-kernel
+dropout has no CPU test: ``chip_smoke.py`` (and ``bench.py`` before it times
+anything) run these on the device. Each raises on failure.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from ._common import kernel_mesh
+from .autotune import make_device_qkv
+from .flash_attention import _attn_reference, flash_attention_bhld
+from .fused_dropout_norm import fused_dropout_add_layer_norm
+
+
+def _check_hw_dropout(what, fn, x):
+    """``fn(x, seed)`` draws its mask from the TPU hardware PRNG: it must be
+    deterministic under a fixed seed, seed-sensitive, and differentiable
+    with finite gradients. Raises on any failure."""
+    f = jax.jit(lambda s: fn(x, s))
+    s1 = jnp.array([[1234]], jnp.int32)
+    o1, o2, o3 = f(s1), f(s1), f(jnp.array([[77]], jnp.int32))
+    if not bool(jnp.array_equal(o1, o2)):
+        raise AssertionError('%s dropout: not deterministic under a fixed '
+                             'seed' % what)
+    if bool(jnp.allclose(o1, o3)):
+        raise AssertionError('%s dropout: the seed has no effect' % what)
+    g = jax.jit(jax.grad(lambda xx: jnp.sum(fn(xx, s1) ** 2)))(x)
+    if not bool(jnp.isfinite(g).all()):
+        raise AssertionError('%s dropout: non-finite gradients' % what)
+
+
+def check_flash_dropout(shape=(1, 4, 512, 64), interpret=False):
+    """The in-kernel hardware-PRNG attention dropout."""
+    q, k, v = make_device_qkv(*shape, jnp.float32)
+    _check_hw_dropout('flash', lambda qq, seed: flash_attention_bhld(
+        qq, k, v, causal=True, dropout_p=0.3, dropout_seed=seed,
+        block_q=256, block_k=256, interpret=interpret), q)
+
+
+def check_norm_dropout(rows=1024, hidden=1024, interpret=False):
+    """The same checks for the fused dropout+add+LayerNorm kernel."""
+    kx, kr = jax.random.split(jax.random.PRNGKey(0))
+    x = jax.random.normal(kx, (rows, hidden), jnp.float32)
+    res = jax.random.normal(kr, (rows, hidden), jnp.float32)
+    w = jnp.ones((hidden,), jnp.float32)
+    b = jnp.zeros((hidden,), jnp.float32)
+    _check_hw_dropout('norm', lambda xx, seed: fused_dropout_add_layer_norm(
+        xx, res, w, b, dropout_p=0.3, dropout_seed=seed,
+        interpret=interpret), x)
+
+
+def check_flash_against_reference(shape, interpret=False):
+    """Kernel output vs ``_attn_reference`` (fp32) without dropout: causal,
+    and non-causal with a key-padding bias. Returns the max abs errors."""
+    b, h, L, d = shape
+    q, k, v = make_device_qkv(b, h, L, d, jnp.bfloat16)
+    # the last fifth of row 0's keys and the last half of the last row's
+    # are padding
+    keep = np.ones((b, L), bool)
+    keep[0, L - L // 5:] = False
+    keep[-1, L // 2:] = False
+    bias = jnp.where(jnp.asarray(keep), 0.0, -1e4).astype(jnp.float32)
+    errs = {}
+    for name, causal, kpad in (('causal', True, None),
+                               ('key_padding', False, bias)):
+        got = jax.jit(lambda a, b_, c: flash_attention_bhld(
+            a, b_, c, causal=causal, kpad_bias=kpad,
+            interpret=interpret))(q, k, v)
+        want = _attn_reference(
+            *(t.astype(jnp.float32) for t in (q, k, v)), causal,
+            d ** -0.5, kpad)
+        err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
+        if not err < 3e-2:      # bf16 in/out, fp32 accumulate, |o| <~ 1
+            raise AssertionError('flash %s: max abs error %g vs the XLA '
+                                 'reference' % (name, err))
+        errs[name] = err
+    return errs
+
+
+def check_partitioned(mesh, axis, shape=(8, 16, 512, 64), hidden=1024,
+                      dropout_p=0.1, interpret=False):
+    """Flash attention and fused dropout+add+LayerNorm, forward and
+    backward, in a jit whose operands are split over ``mesh``'s ``axis``
+    (as the engine's sharded steps trace them, under ``kernel_mesh``)
+    against the same call on one device with the same seed. The dropout
+    tiles are keyed on their place in the WHOLE array, so the masks — and
+    with them outputs and gradients — must agree whatever the partitioning.
+    Returns the max abs differences."""
+    b, h, L, d = shape
+    q, k, v = make_device_qkv(b, h, L, d, jnp.bfloat16)
+    keep = np.ones((b, L), bool)
+    keep[0, L - L // 5:] = False
+    bias = jnp.where(jnp.asarray(keep), 0.0, -1e4).astype(jnp.float32)
+    kx, kr = jax.random.split(jax.random.PRNGKey(1))
+    x = jax.random.normal(kx, (b * L, hidden), jnp.bfloat16)
+    res = jax.random.normal(kr, (b * L, hidden), jnp.bfloat16)
+    w = jnp.ones((hidden,), jnp.bfloat16)
+    seed = jnp.array([[4321]], jnp.int32)
+
+    def flash(q, k, v, bias, seed):
+        def loss(q, k, v):
+            o = flash_attention_bhld(q, k, v, kpad_bias=bias,
+                                     dropout_p=dropout_p, dropout_seed=seed,
+                                     interpret=interpret)
+            return jnp.sum(o.astype(jnp.float32) ** 2), o
+        (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                       has_aux=True)(q, k, v)
+        return (o,) + g
+
+    def norm(x, res, w, seed):
+        def loss(x, res, w):
+            y = fused_dropout_add_layer_norm(
+                x, res, w, w * 0, dropout_p=dropout_p, dropout_seed=seed,
+                interpret=interpret)
+            return jnp.sum(y.astype(jnp.float32) ** 2), y
+        (_, y), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                       has_aux=True)(x, res, w)
+        return (y,) + g
+
+    def split(f):
+        def traced(*args):
+            with kernel_mesh(mesh, (axis,)):
+                return f(*args)
+        return traced
+
+    rows, rep = NamedSharding(mesh, P(axis)), NamedSharding(mesh, P())
+    diffs = {}
+    # (outputs computed row by row, outputs reduced over the split rows)
+    for name, f, args, shardings, n_rowwise in (
+            ('flash', flash, (q, k, v, bias, seed),
+             (rows, rows, rows, rows, rep), 4),
+            ('dropout_add_norm', norm, (x, res, w, seed),
+             (rows, rows, rep, rep), 3)):
+        want = jax.jit(f)(*args)
+        got = jax.jit(split(f), in_shardings=shardings)(*args)
+        on = len(got[0].sharding.device_set)
+        if on != mesh.size:
+            raise AssertionError('%s: the partitioned result sits on %d of '
+                                 '%d devices' % (name, on, mesh.size))
+        rel = [float(jnp.max(jnp.abs(g.astype(jnp.float32)
+                                     - w_.astype(jnp.float32)))
+                     / (jnp.max(jnp.abs(w_.astype(jnp.float32))) + 1e-6))
+               for g, w_ in zip(got, want)]
+        # each device runs the same tiles on the same data: row-wise
+        # results agree to a bf16 ulp (2^-7) unless a mask differs (other
+        # masks change them by tenths of the largest value); the weight
+        # gradients are summed across devices in another order (bf16)
+        diffs[name] = {'rowwise': max(rel[:n_rowwise]),
+                       'reduced': max(rel[n_rowwise:], default=0.0)}
+        if not (diffs[name]['rowwise'] < 1e-2
+                and diffs[name]['reduced'] < 2e-2):
+            raise AssertionError(
+                '%s: partitioned over %d devices differs from one device '
+                'with the same seed by %s (relative): the dropout masks '
+                'depend on the partitioning' % (name, mesh.size,
+                                                diffs[name]))
+    return diffs

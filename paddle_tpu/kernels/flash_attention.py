@@ -23,9 +23,10 @@ prng_random_bits is a zero-stub, so the dropout path is validated on real TPU
 hardware (tests marked tpu-only + finite-difference check in
 tests/test_flash_attention.py::test_flash_dropout_*).
 
-On non-TPU backends the public entry point falls back to plain-XLA attention
-with identical semantics (dropout there uses jax.random — same distribution,
-different stream).
+On non-TPU backends the public entry point takes plain-XLA attention with
+identical semantics (dropout there uses jax.random — same distribution,
+different stream); which path a trace took is marked in the HLO
+(``_common.took``).
 """
 import functools
 import math
@@ -34,11 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from ._common import (pallas_runs, spmd_kernel,
+                      tile_keep_scale as _tile_keep_scale, took)
 
 NEG_INF = -1e30
 LSE_EMPTY = 1e30  # lse sentinel for fully-masked rows: exp(s - BIG) == 0
@@ -85,14 +83,34 @@ def _score_tile(q, k_tile, bias_tile, causal, q_offset, k_offset, scale):
     return s
 
 
-from ._common import tile_keep_scale as _tile_keep_scale  # noqa: E402
+def _global_bh(seed_ref, heads):
+    """This grid row's (batch, head) index in the WHOLE (B, H) array.
+    seed_ref is (1, 3) int32 [seed, first batch, first head] of this
+    device's shard (``_seed_and_shard``); heads = (local, total) head
+    counts. Dropout tiles are keyed on it, so the mask does not depend on
+    how batch and heads were partitioned."""
+    h_local, h_total = heads
+    bh = pl.program_id(0)
+    return ((seed_ref[0, 1] + bh // h_local) * h_total
+            + seed_ref[0, 2] + bh % h_local)
+
+
+def _seed_and_shard(seed, shard):
+    return jnp.stack([seed.astype(jnp.int32).reshape(()),
+                      shard['b'][0].astype(jnp.int32),
+                      shard['h'][0].astype(jnp.int32)]).reshape(1, 3)
+
+
+_BHLD = ('b', 'h', 'l', 'd')
+_ROLES = {'b': 'batch', 'h': 'heads'}
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p):
+def _fwd_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p,
+                heads):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     idx = 3
@@ -145,7 +163,7 @@ def _fwd_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p):
             p_acc = p
             if dropout_p > 0.0:
                 nq, nk = seq_len // block_q, seq_len // block_k
-                tile_id = (pl.program_id(0) * nq + q_blk) * nk + i
+                tile_id = (_global_bh(seed_ref, heads) * nq + q_blk) * nk + i
                 p_acc = p * _tile_keep_scale(seed_ref, tile_id, p.shape,
                                              dropout_p)
             # p in the value matmul rides the MXU in v's dtype (bf16 on the
@@ -167,45 +185,58 @@ def _fwd_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p):
 
 def _flash_forward(q, k, v, kpad_bias, seed, causal, scale, block_q, block_k,
                    dropout_p, interpret):
-    b, h, L, d = q.shape
+    L, d = q.shape[2:]
     bq, bk = min(block_q, L), min(block_k, L)
-    q3, k3, v3 = (t.reshape(b * h, L, d) for t in (q, k, v))
     has_bias = kpad_bias is not None
-    kernel = functools.partial(_fwd_kernel, block_k=bk, seq_len=L,
-                               causal=causal, scale=scale, has_bias=has_bias,
-                               dropout_p=dropout_p)
-    in_specs = [
-        pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0)),
-        pl.BlockSpec((1, L, d), lambda bh, i: (bh, 0, 0)),
-        pl.BlockSpec((1, L, d), lambda bh, i: (bh, 0, 0)),
-    ]
-    args = [q3, k3, v3]
+    args, dims = [q, k, v], [_BHLD] * 3
     if has_bias:
         # (B, 1, L) so the block shape (1, 1, L) satisfies TPU tiling rules
-        in_specs.append(
-            pl.BlockSpec((1, 1, L), lambda bh, i, h=h: (bh // h, 0, 0)))
         args.append(kpad_bias.astype(jnp.float32)[:, None, :])
+        dims.append(('b', None, 'l'))
     if dropout_p > 0.0:
-        in_specs.append(pl.BlockSpec((1, 1), lambda bh, i: (0, 0)))
         args.append(seed)
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, L // bq),
-        in_specs=in_specs,
-        out_specs=(pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0)),
-                   pl.BlockSpec((1, bq, 1), lambda bh, i: (bh, i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((b * h, L, d), q.dtype),
-                   jax.ShapeDtypeStruct((b * h, L, 1), jnp.float32)),
-        interpret=interpret,
-    )(*args)
-    return o.reshape(b, h, L, d), lse.reshape(b, h, L)
+        dims.append((None, None))
+
+    def call(*args, shard):
+        b, h = args[0].shape[:2]        # this device's batch and heads
+        args = [t.reshape(b * h, L, d) for t in args[:3]] + list(args[3:])
+        kernel = functools.partial(
+            _fwd_kernel, block_k=bk, seq_len=L, causal=causal, scale=scale,
+            has_bias=has_bias, dropout_p=dropout_p,
+            heads=(h, shard['h'][1]))
+        in_specs = [
+            pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0)),
+            pl.BlockSpec((1, L, d), lambda bh, i: (bh, 0, 0)),
+            pl.BlockSpec((1, L, d), lambda bh, i: (bh, 0, 0)),
+        ]
+        if has_bias:
+            in_specs.append(
+                pl.BlockSpec((1, 1, L), lambda bh, i: (bh // h, 0, 0)))
+        if dropout_p > 0.0:
+            in_specs.append(pl.BlockSpec((1, 3), lambda bh, i: (0, 0)))
+            args[-1] = _seed_and_shard(args[-1], shard)
+        o, lse = pl.pallas_call(
+            kernel,
+            grid=(b * h, L // bq),
+            in_specs=in_specs,
+            out_specs=(pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0)),
+                       pl.BlockSpec((1, bq, 1), lambda bh, i: (bh, i, 0))),
+            out_shape=(jax.ShapeDtypeStruct((b * h, L, d), q.dtype),
+                       jax.ShapeDtypeStruct((b * h, L, 1), jnp.float32)),
+            interpret=interpret,
+        )(*args)
+        return o.reshape(b, h, L, d), lse.reshape(b, h, L)
+
+    return spmd_kernel(call, dims, [_BHLD, _BHLD[:3]],
+                       _ROLES)(*args)
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p):
+def _dq_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p,
+               heads):
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     idx = 6
@@ -247,7 +278,7 @@ def _dq_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p):
             dp = jnp.dot(do, v_tile.T, preferred_element_type=jnp.float32)
             if dropout_p > 0.0:
                 nq, nk = seq_len // block_q, seq_len // block_k
-                tile_id = (pl.program_id(0) * nq + q_blk) * nk + i
+                tile_id = (_global_bh(seed_ref, heads) * nq + q_blk) * nk + i
                 dp = dp * _tile_keep_scale(seed_ref, tile_id, dp.shape,
                                            dropout_p)
             ds = p * (dp - delta)
@@ -262,7 +293,8 @@ def _dq_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p):
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p):
+def _dkv_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
+                heads):
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     idx = 6
@@ -308,7 +340,7 @@ def _dkv_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p):
             dp = jnp.dot(do_tile, v.T, preferred_element_type=jnp.float32)
             if dropout_p > 0.0:
                 nq, nk = seq_len // block_q, seq_len // block_k
-                tile_id = (pl.program_id(0) * nq + i) * nk + k_blk
+                tile_id = (_global_bh(seed_ref, heads) * nq + i) * nk + k_blk
                 keep_scale = _tile_keep_scale(seed_ref, tile_id, p.shape,
                                               dropout_p)
                 p_drop = p * keep_scale
@@ -337,61 +369,71 @@ def _dkv_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p):
 
 def _flash_backward(q, k, v, o, lse, kpad_bias, seed, g, causal, scale,
                     block_q, block_k, dropout_p, interpret):
-    b, h, L, d = q.shape
+    L, d = q.shape[2:]
     bq, bk = min(block_q, L), min(block_k, L)
-    q3, k3, v3, o3, g3 = (t.reshape(b * h, L, d) for t in (q, k, v, o, g))
-    lse3 = lse.reshape(b * h, L, 1)
-    delta = jnp.sum(g3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1, keepdims=True)             # (BH, L, 1)
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1, keepdims=True)             # (B, H, L, 1)
     has_bias = kpad_bias is not None
-    extra_args = []
+    bhl1 = ('b', 'h', 'l', None)
+    args = [q, k, v, g, lse[..., None], delta]
+    dims = [_BHLD] * 4 + [bhl1] * 2
     if has_bias:
-        extra_args.append(kpad_bias.astype(jnp.float32)[:, None, :])  # (B,1,L)
+        args.append(kpad_bias.astype(jnp.float32)[:, None, :])  # (B,1,L)
+        dims.append(('b', None, 'l'))
     if dropout_p > 0.0:
-        extra_args.append(seed)
+        args.append(seed)
+        dims.append((None, None))
 
-    tile_qd = pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0))
-    tile_q1 = pl.BlockSpec((1, bq, 1), lambda bh, i: (bh, i, 0))
-    full_ld = pl.BlockSpec((1, L, d), lambda bh, i: (bh, 0, 0))
-    full_l1 = pl.BlockSpec((1, L, 1), lambda bh, i: (bh, 0, 0))
-    bias_full = pl.BlockSpec((1, 1, L), lambda bh, i, h=h: (bh // h, 0, 0))
-    seed_spec = pl.BlockSpec((1, 1), lambda bh, i: (0, 0))
+    def call(*args, shard):
+        b, h = args[0].shape[:2]        # this device's batch and heads
+        args = [t.reshape((b * h,) + t.shape[2:]) for t in args[:6]
+                ] + list(args[6:])
+        if dropout_p > 0.0:
+            args[-1] = _seed_and_shard(args[-1], shard)
+        static = dict(seq_len=L, causal=causal, scale=scale,
+                      has_bias=has_bias, dropout_p=dropout_p,
+                      heads=(h, shard['h'][1]))
 
-    dq_in = [tile_qd, full_ld, full_ld, tile_qd, tile_q1, tile_q1]
-    if has_bias:
-        dq_in.append(bias_full)
-    if dropout_p > 0.0:
-        dq_in.append(seed_spec)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_k=bk, seq_len=L, causal=causal,
-                          scale=scale, has_bias=has_bias, dropout_p=dropout_p),
-        grid=(b * h, L // bq),
-        in_specs=dq_in,
-        out_specs=tile_qd,
-        out_shape=jax.ShapeDtypeStruct((b * h, L, d), q.dtype),
-        interpret=interpret,
-    )(q3, k3, v3, g3, lse3, delta, *extra_args)
+        tile_qd = pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0))
+        tile_q1 = pl.BlockSpec((1, bq, 1), lambda bh, i: (bh, i, 0))
+        full_ld = pl.BlockSpec((1, L, d), lambda bh, i: (bh, 0, 0))
+        full_l1 = pl.BlockSpec((1, L, 1), lambda bh, i: (bh, 0, 0))
+        bias_full = pl.BlockSpec((1, 1, L), lambda bh, i: (bh // h, 0, 0))
+        seed_spec = pl.BlockSpec((1, 3), lambda bh, i: (0, 0))
 
-    tile_kd = pl.BlockSpec((1, bk, d), lambda bh, j: (bh, j, 0))
-    bias_tile = pl.BlockSpec((1, 1, bk), lambda bh, j, h=h: (bh // h, 0, j))
-    dkv_in = [full_ld, tile_kd, tile_kd, full_ld, full_l1, full_l1]
-    if has_bias:
-        dkv_in.append(bias_tile)
-    if dropout_p > 0.0:
-        dkv_in.append(seed_spec)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=bq, seq_len=L, causal=causal,
-                          scale=scale, has_bias=has_bias, dropout_p=dropout_p),
-        grid=(b * h, L // bk),
-        in_specs=dkv_in,
-        out_specs=(tile_kd, tile_kd),
-        out_shape=(jax.ShapeDtypeStruct((b * h, L, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, L, d), v.dtype)),
-        interpret=interpret,
-    )(q3, k3, v3, g3, lse3, delta, *extra_args)
+        dq_in = [tile_qd, full_ld, full_ld, tile_qd, tile_q1, tile_q1]
+        if has_bias:
+            dq_in.append(bias_full)
+        if dropout_p > 0.0:
+            dq_in.append(seed_spec)
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, block_k=bk, **static),
+            grid=(b * h, L // bq),
+            in_specs=dq_in,
+            out_specs=tile_qd,
+            out_shape=jax.ShapeDtypeStruct((b * h, L, d), q.dtype),
+            interpret=interpret,
+        )(*args)
 
-    return (dq.reshape(b, h, L, d), dk.reshape(b, h, L, d),
-            dv.reshape(b, h, L, d))
+        tile_kd = pl.BlockSpec((1, bk, d), lambda bh, j: (bh, j, 0))
+        bias_tile = pl.BlockSpec((1, 1, bk), lambda bh, j: (bh // h, 0, j))
+        dkv_in = [full_ld, tile_kd, tile_kd, full_ld, full_l1, full_l1]
+        if has_bias:
+            dkv_in.append(bias_tile)
+        if dropout_p > 0.0:
+            dkv_in.append(seed_spec)
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_kernel, block_q=bq, **static),
+            grid=(b * h, L // bk),
+            in_specs=dkv_in,
+            out_specs=(tile_kd, tile_kd),
+            out_shape=(jax.ShapeDtypeStruct((b * h, L, d), k.dtype),
+                       jax.ShapeDtypeStruct((b * h, L, d), v.dtype)),
+            interpret=interpret,
+        )(*args)
+        return tuple(t.reshape(b, h, L, d) for t in (dq, dk, dv))
+
+    return spmd_kernel(call, dims, [_BHLD] * 3, _ROLES)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -432,9 +474,10 @@ def flash_attention_bhld(q, k, v, causal=False, scale=None, kpad_bias=None,
     kpad_bias: optional (B, Lk) additive key-padding bias (0 = keep, -1e4/-inf
     style = masked). dropout_p: attention-probability dropout rate; when > 0,
     dropout_seed must be an int32 array of shape (1, 1) (the keep-mask is a
-    deterministic function of it). Falls back to plain-XLA attention when
-    Pallas is unavailable (non-TPU backend and interpret=False) or L doesn't
-    tile.
+    deterministic function of it). Takes plain-XLA attention off the TPU
+    (unless interpret mode is asked for) or when L doesn't tile; either way
+    the ops sit under a ``flash_attention.pallas`` / ``flash_attention.xla``
+    named scope (``_common.took``).
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -446,9 +489,7 @@ def flash_attention_bhld(q, k, v, causal=False, scale=None, kpad_bias=None,
         # key-padding attention is non-causal and reads every K anyway, so
         # stream the full row
         block_k = L
-    usable = (_HAS_PLTPU and (interpret is not False
-                              or jax.default_backend() == 'tpu')
-              and k.shape[2] == L
+    usable = (pallas_runs(interpret) and k.shape[2] == L
               and L % min(block_q, L) == 0 and L % min(block_k, L) == 0)
     if dropout_p > 0.0 and dropout_seed is None:
         raise ValueError("dropout_p > 0 requires dropout_seed")
@@ -458,9 +499,11 @@ def flash_attention_bhld(q, k, v, causal=False, scale=None, kpad_bias=None,
             key = jax.random.PRNGKey(0)
             key = jax.random.fold_in(key, dropout_seed.reshape(())
                                      .astype(jnp.uint32))
-        return _attn_reference(q, k, v, causal, scale, kpad_bias,
-                               dropout_p, key)
+        with took('flash_attention', 'xla'):
+            return _attn_reference(q, k, v, causal, scale, kpad_bias,
+                                   dropout_p, key)
     seed = (dropout_seed if dropout_seed is not None
             else jnp.zeros((1, 1), jnp.int32))
-    return _flash(q, k, v, kpad_bias, seed, causal, scale, block_q, block_k,
-                  dropout_p, interpret)
+    with took('flash_attention', 'pallas'):
+        return _flash(q, k, v, kpad_bias, seed, causal, scale, block_q,
+                      block_k, dropout_p, interpret)

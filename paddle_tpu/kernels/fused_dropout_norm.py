@@ -28,14 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
-
-
-from ._common import tile_keep_scale as _keep_scale, row_block as _row_block
+from ._common import (pallas_runs, row_block as _row_block, spmd_kernel,
+                      tile_keep_scale as _keep_scale, took)
 
 
 def _fwd_kernel(*refs, eps, p, has_w, has_b):
@@ -54,7 +48,8 @@ def _fwd_kernel(*refs, eps, p, has_w, has_b):
     x = x_ref[...].astype(jnp.float32)                  # (bn, D)
     res = res_ref[...].astype(jnp.float32)
     if p > 0.0:
-        x = x * _keep_scale(seed_ref, pl.program_id(0), x.shape, p)
+        x = x * _keep_scale(seed_ref, seed_ref[0, 1] + pl.program_id(0),
+                            x.shape, p)
     yin = res + x
     mean = jnp.mean(yin, axis=-1, keepdims=True)
     xc = yin - mean
@@ -74,59 +69,81 @@ def _fwd_kernel(*refs, eps, p, has_w, has_b):
 def _dmask_kernel(g_ref, seed_ref, out_ref, *, p):
     """dx = d_yin * keep/(1-p) with the regenerated tile mask."""
     g = g_ref[...].astype(jnp.float32)
-    out = g * _keep_scale(seed_ref, pl.program_id(0), g.shape, p)
+    out = g * _keep_scale(seed_ref, seed_ref[0, 1] + pl.program_id(0),
+                          g.shape, p)
     out_ref[...] = out.astype(out_ref.dtype)
 
 
+def _seed_and_tile(seed, shard, bn):
+    """(1, 2) int32 [seed, id of this shard's first row tile]: tile ids
+    count rows of the WHOLE array, so the mask a row gets does not depend
+    on how the rows were partitioned."""
+    return jnp.concatenate(
+        [seed.astype(jnp.int32).reshape(1, 1),
+         (shard['n'][0] // bn).astype(jnp.int32).reshape(1, 1)], axis=1)
 
 
 def _fused_fwd(x, res, w, b, seed, eps, p, interpret):
-    n, d = x.shape
-    bn = _row_block(n)
+    d = x.shape[1]
     has_w, has_b = w is not None, b is not None
-    in_specs = [pl.BlockSpec((bn, d), lambda i: (i, 0)),
-                pl.BlockSpec((bn, d), lambda i: (i, 0))]
-    args = [x, res]
-    if has_w:
-        in_specs.append(pl.BlockSpec((d,), lambda i: (0,)))
-        args.append(w)
-    if has_b:
-        in_specs.append(pl.BlockSpec((d,), lambda i: (0,)))
-        args.append(b)
-    if p > 0.0:
-        in_specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0)))
-        args.append(seed)
     kernel = functools.partial(_fwd_kernel, eps=eps, p=p, has_w=has_w,
                                has_b=has_b)
-    y, yin, mean, rstd = pl.pallas_call(
-        kernel,
-        grid=(n // bn,),
-        in_specs=in_specs,
-        out_specs=(pl.BlockSpec((bn, d), lambda i: (i, 0)),
-                   pl.BlockSpec((bn, d), lambda i: (i, 0)),
-                   pl.BlockSpec((bn, 1), lambda i: (i, 0)),
-                   pl.BlockSpec((bn, 1), lambda i: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((n, d), x.dtype),
-                   jax.ShapeDtypeStruct((n, d), x.dtype),
-                   jax.ShapeDtypeStruct((n, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((n, 1), jnp.float32)),
-        interpret=interpret,
-    )(*args)
-    return y, yin, mean, rstd
+    args, dims = [x, res], [('n', 'd'), ('n', 'd')]
+    if has_w:
+        args.append(w); dims.append(('d',))
+    if has_b:
+        args.append(b); dims.append(('d',))
+    if p > 0.0:
+        args.append(seed); dims.append((None, None))
+
+    def call(*args, shard):
+        n = args[0].shape[0]            # this device's rows
+        bn = _row_block(n)
+        args = list(args)
+        in_specs = [pl.BlockSpec((bn, d), lambda i: (i, 0)),
+                    pl.BlockSpec((bn, d), lambda i: (i, 0))]
+        in_specs += [pl.BlockSpec((d,), lambda i: (0,))] * (has_w + has_b)
+        if p > 0.0:
+            in_specs.append(pl.BlockSpec((1, 2), lambda i: (0, 0)))
+            args[-1] = _seed_and_tile(args[-1], shard, bn)
+        return pl.pallas_call(
+            kernel,
+            grid=(n // bn,),
+            in_specs=in_specs,
+            out_specs=(pl.BlockSpec((bn, d), lambda i: (i, 0)),
+                       pl.BlockSpec((bn, d), lambda i: (i, 0)),
+                       pl.BlockSpec((bn, 1), lambda i: (i, 0)),
+                       pl.BlockSpec((bn, 1), lambda i: (i, 0))),
+            out_shape=(jax.ShapeDtypeStruct((n, d), x.dtype),
+                       jax.ShapeDtypeStruct((n, d), x.dtype),
+                       jax.ShapeDtypeStruct((n, 1), jnp.float32),
+                       jax.ShapeDtypeStruct((n, 1), jnp.float32)),
+            interpret=interpret,
+        )(*args)
+
+    return spmd_kernel(
+        call, dims, [('n', 'd'), ('n', 'd'), ('n', None), ('n', None)],
+        {'n': 'batch'}, granule=8)(*args)
 
 
 def _apply_dropout_grad(d_yin, seed, p, interpret):
-    n, d = d_yin.shape
-    bn = _row_block(n)
-    return pl.pallas_call(
-        functools.partial(_dmask_kernel, p=p),
-        grid=(n // bn,),
-        in_specs=[pl.BlockSpec((bn, d), lambda i: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, d), d_yin.dtype),
-        interpret=interpret,
-    )(d_yin, seed)
+    d = d_yin.shape[1]
+
+    def call(g, seed, shard):
+        n = g.shape[0]
+        bn = _row_block(n)
+        return pl.pallas_call(
+            functools.partial(_dmask_kernel, p=p),
+            grid=(n // bn,),
+            in_specs=[pl.BlockSpec((bn, d), lambda i: (i, 0)),
+                      pl.BlockSpec((1, 2), lambda i: (0, 0))],
+            out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((n, d), g.dtype),
+            interpret=interpret,
+        )(g, _seed_and_tile(seed, shard, bn))
+
+    return spmd_kernel(call, [('n', 'd'), (None, None)], [('n', 'd')],
+                       {'n': 'batch'}, granule=8)(d_yin, seed)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -137,11 +154,13 @@ def _fdln(x, res, w, b, seed, eps, p, interpret):
 
 def _fdln_fwd(x, res, w, b, seed, eps, p, interpret):
     y, yin, mean, rstd = _fused_fwd(x, res, w, b, seed, eps, p, interpret)
-    return y, (yin, mean, rstd, w, b, seed)
+    # under amp the residual stream can be fp32 while x is bf16: its
+    # cotangent must come back in ITS dtype (a 0-d marker carries it)
+    return y, (yin, mean, rstd, w, b, seed, jnp.zeros((), res.dtype))
 
 
 def _fdln_bwd(eps, p, interpret, saved, g):
-    yin, mean, rstd, w, b, seed = saved
+    yin, mean, rstd, w, b, seed, res_like = saved
     d = yin.shape[-1]
     gf = g.astype(jnp.float32)
     yin_f = yin.astype(jnp.float32)
@@ -153,12 +172,10 @@ def _fdln_bwd(eps, p, interpret, saved, g):
     m1 = jnp.mean(gy, axis=-1, keepdims=True)
     m2 = jnp.mean(gy * xhat, axis=-1, keepdims=True)
     d_yin = (gy - m1 - xhat * m2) * rstd
-    d_res = d_yin.astype(yin.dtype)
+    dx = d_yin.astype(yin.dtype)
     if p > 0.0:
-        dx = _apply_dropout_grad(d_yin.astype(yin.dtype), seed, p, interpret)
-    else:
-        dx = d_res
-    return dx, d_res, dw, db, None
+        dx = _apply_dropout_grad(dx, seed, p, interpret)
+    return dx, d_yin.astype(res_like.dtype), dw, db, None
 
 
 _fdln.defvjp(_fdln_fwd, _fdln_bwd)
@@ -171,7 +188,8 @@ def fused_dropout_add_layer_norm(x, residual, weight=None, bias=None,
 
     x/residual: (..., D) — flattened internally to (N, D) row tiles.
     dropout_seed: int32 (1, 1) array, required when dropout_p > 0.
-    Falls back to plain XLA composition off-TPU.
+    Takes the plain XLA composition off-TPU or when the rows do not tile
+    (marked ``fused_dropout_norm.xla`` in the HLO).
     """
     p = float(dropout_p)
     shape = x.shape
@@ -179,12 +197,22 @@ def fused_dropout_add_layer_norm(x, residual, weight=None, bias=None,
     n = 1
     for s in shape[:-1]:
         n *= s
-    usable = (_HAS_PLTPU and _row_block(n) is not None
-              and (interpret is not False
-                   or jax.default_backend() == 'tpu'))
+    usable = pallas_runs(interpret) and _row_block(n) is not None
     if p > 0.0 and dropout_seed is None:
         raise ValueError("dropout_p > 0 requires dropout_seed")
     if not usable:
+        return _xla_reference(x, residual, weight, bias, p, epsilon,
+                              dropout_seed)
+    seed = (dropout_seed if dropout_seed is not None
+            else jnp.zeros((1, 1), jnp.int32))
+    with took('fused_dropout_norm', 'pallas'):
+        y = _fdln(x.reshape(n, d), residual.reshape(n, d), weight, bias,
+                  seed, float(epsilon), p, interpret)
+    return y.reshape(shape)
+
+
+def _xla_reference(x, residual, weight, bias, p, epsilon, dropout_seed):
+    with took('fused_dropout_norm', 'xla'):
         xx = x
         if p > 0.0:
             key = jax.random.fold_in(
@@ -202,8 +230,3 @@ def fused_dropout_add_layer_norm(x, residual, weight=None, bias=None,
         if bias is not None:
             y = y + bias.astype(jnp.float32)
         return y.astype(x.dtype)
-    seed = (dropout_seed if dropout_seed is not None
-            else jnp.zeros((1, 1), jnp.int32))
-    y = _fdln(x.reshape(n, d), residual.reshape(n, d), weight, bias, seed,
-              float(epsilon), p, interpret)
-    return y.reshape(shape)

@@ -16,11 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from ._common import (pallas_runs, row_block as _shared_row_block,
+                      spmd_kernel, took)
 
 
 def _ln_fwd_kernel(x_ref, w_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps,
@@ -51,37 +48,40 @@ def _rms_fwd_kernel(x_ref, w_ref, y_ref, rstd_ref, *, eps, has_w):
     rstd_ref[...] = rstd
 
 
-from ._common import row_block as _shared_row_block
-
-
 def _row_block(n, d):
     # one row tile per grid step; 8-row multiples satisfy TPU sublane tiling
     return _shared_row_block(n)
 
 
 def _ln_forward(x2, w, b, eps, interpret):
-    n, d = x2.shape
-    bn = _row_block(n, d)
+    d = x2.shape[1]
     has_w, has_b = w is not None, b is not None
     w_arg = w if has_w else jnp.zeros((d,), x2.dtype)
     b_arg = b if has_b else jnp.zeros((d,), x2.dtype)
     kernel = functools.partial(_ln_fwd_kernel, eps=eps, has_w=has_w,
                                has_b=has_b)
-    y, mean, rstd = pl.pallas_call(
-        kernel,
-        grid=(n // bn,),
-        in_specs=[pl.BlockSpec((bn, d), lambda i: (i, 0)),
-                  pl.BlockSpec((d,), lambda i: (0,)),
-                  pl.BlockSpec((d,), lambda i: (0,))],
-        out_specs=(pl.BlockSpec((bn, d), lambda i: (i, 0)),
-                   pl.BlockSpec((bn, 1), lambda i: (i, 0)),
-                   pl.BlockSpec((bn, 1), lambda i: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((n, d), x2.dtype),
-                   jax.ShapeDtypeStruct((n, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((n, 1), jnp.float32)),
-        interpret=interpret,
-    )(x2, w_arg, b_arg)
-    return y, mean, rstd
+
+    def call(x2, w_arg, b_arg, shard):
+        n = x2.shape[0]                 # this device's rows
+        bn = _row_block(n, d)
+        return pl.pallas_call(
+            kernel,
+            grid=(n // bn,),
+            in_specs=[pl.BlockSpec((bn, d), lambda i: (i, 0)),
+                      pl.BlockSpec((d,), lambda i: (0,)),
+                      pl.BlockSpec((d,), lambda i: (0,))],
+            out_specs=(pl.BlockSpec((bn, d), lambda i: (i, 0)),
+                       pl.BlockSpec((bn, 1), lambda i: (i, 0)),
+                       pl.BlockSpec((bn, 1), lambda i: (i, 0))),
+            out_shape=(jax.ShapeDtypeStruct((n, d), x2.dtype),
+                       jax.ShapeDtypeStruct((n, 1), jnp.float32),
+                       jax.ShapeDtypeStruct((n, 1), jnp.float32)),
+            interpret=interpret,
+        )(x2, w_arg, b_arg)
+
+    return spmd_kernel(call, [('n', 'd'), ('d',), ('d',)],
+                       [('n', 'd'), ('n', None), ('n', None)],
+                       {'n': 'batch'}, granule=8)(x2, w_arg, b_arg)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -114,23 +114,29 @@ _fused_layer_norm2d.defvjp(_ln_fwd_rule, _ln_bwd_rule)
 
 
 def _rms_forward(x2, w, eps, interpret):
-    n, d = x2.shape
-    bn = _row_block(n, d)
+    d = x2.shape[1]
     has_w = w is not None
     w_arg = w if has_w else jnp.zeros((d,), x2.dtype)
     kernel = functools.partial(_rms_fwd_kernel, eps=eps, has_w=has_w)
-    y, rstd = pl.pallas_call(
-        kernel,
-        grid=(n // bn,),
-        in_specs=[pl.BlockSpec((bn, d), lambda i: (i, 0)),
-                  pl.BlockSpec((d,), lambda i: (0,))],
-        out_specs=(pl.BlockSpec((bn, d), lambda i: (i, 0)),
-                   pl.BlockSpec((bn, 1), lambda i: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((n, d), x2.dtype),
-                   jax.ShapeDtypeStruct((n, 1), jnp.float32)),
-        interpret=interpret,
-    )(x2, w_arg)
-    return y, rstd
+
+    def call(x2, w_arg, shard):
+        n = x2.shape[0]                 # this device's rows
+        bn = _row_block(n, d)
+        return pl.pallas_call(
+            kernel,
+            grid=(n // bn,),
+            in_specs=[pl.BlockSpec((bn, d), lambda i: (i, 0)),
+                      pl.BlockSpec((d,), lambda i: (0,))],
+            out_specs=(pl.BlockSpec((bn, d), lambda i: (i, 0)),
+                       pl.BlockSpec((bn, 1), lambda i: (i, 0))),
+            out_shape=(jax.ShapeDtypeStruct((n, d), x2.dtype),
+                       jax.ShapeDtypeStruct((n, 1), jnp.float32)),
+            interpret=interpret,
+        )(x2, w_arg)
+
+    return spmd_kernel(call, [('n', 'd'), ('d',)],
+                       [('n', 'd'), ('n', None)],
+                       {'n': 'batch'}, granule=8)(x2, w_arg)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -164,20 +170,21 @@ def fused_layer_norm(x, weight=None, bias=None, eps=1e-5, interpret=False):
     n_rows = 1
     for s in x.shape[:-1]:
         n_rows *= s
-    if not (_HAS_PLTPU and _row_block(n_rows, x.shape[-1]) is not None
-            and (interpret is not False
-                 or jax.default_backend() == 'tpu')):
-        mean = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.var(x, axis=-1, keepdims=True)
-        y = (x - mean) * jax.lax.rsqrt(var + eps)
-        if weight is not None:
-            y = y * weight
-        if bias is not None:
-            y = y + bias
-        return y.astype(x.dtype)
+    if not (pallas_runs(interpret)
+            and _row_block(n_rows, x.shape[-1]) is not None):
+        with took('fused_layer_norm', 'xla'):
+            mean = jnp.mean(x, axis=-1, keepdims=True)
+            var = jnp.var(x, axis=-1, keepdims=True)
+            y = (x - mean) * jax.lax.rsqrt(var + eps)
+            if weight is not None:
+                y = y * weight
+            if bias is not None:
+                y = y + bias
+            return y.astype(x.dtype)
     shape = x.shape
-    y = _fused_layer_norm2d(x.reshape(-1, shape[-1]), weight, bias, float(eps),
-                            interpret)
+    with took('fused_layer_norm', 'pallas'):
+        y = _fused_layer_norm2d(x.reshape(-1, shape[-1]), weight, bias,
+                                float(eps), interpret)
     return y.reshape(shape)
 
 
@@ -186,15 +193,16 @@ def fused_rms_norm(x, weight=None, eps=1e-6, interpret=False):
     n_rows = 1
     for s in x.shape[:-1]:
         n_rows *= s
-    if not (_HAS_PLTPU and _row_block(n_rows, x.shape[-1]) is not None
-            and (interpret is not False
-                 or jax.default_backend() == 'tpu')):
-        ms = jnp.mean(x * x, axis=-1, keepdims=True)
-        y = x * jax.lax.rsqrt(ms + eps)
-        if weight is not None:
-            y = y * weight
-        return y.astype(x.dtype)
+    if not (pallas_runs(interpret)
+            and _row_block(n_rows, x.shape[-1]) is not None):
+        with took('fused_rms_norm', 'xla'):
+            ms = jnp.mean(x * x, axis=-1, keepdims=True)
+            y = x * jax.lax.rsqrt(ms + eps)
+            if weight is not None:
+                y = y * weight
+            return y.astype(x.dtype)
     shape = x.shape
-    y = _fused_rms_norm2d(x.reshape(-1, shape[-1]), weight, float(eps),
-                          interpret)
+    with took('fused_rms_norm', 'pallas'):
+        y = _fused_rms_norm2d(x.reshape(-1, shape[-1]), weight, float(eps),
+                              interpret)
     return y.reshape(shape)

@@ -10,10 +10,12 @@ program sets — is captured ONCE at build/warmup time into a process-wide
   their sum, ``peak_bytes``) from ``Compiled.memory_analysis()`` — all
   available on CPU, so the numbers are provable without a chip;
 - an **analytic roofline** estimate: arithmetic intensity (flops per byte
-  accessed) against configurable device peaks names whether the program is
-  compute- or memory-bound and what its floor step time would be. The
-  peaks are *nominal* (env-overridable), the estimate is a bound, not a
-  measurement — see docs/OBSERVABILITY.md, "Cost explorer" for caveats.
+  accessed) against the device's published peaks (``DEVICE_PEAKS``, keyed
+  by ``device_kind``) names whether the program is compute- or
+  memory-bound and what its floor step time would be. The estimate is a
+  bound, not a measurement; a device that is not in the table has no
+  roofline (``device_peaks`` raises, the ledger entry's ``roofline`` is
+  None) — see docs/OBSERVABILITY.md, "Cost explorer" for caveats.
 
 Capture is an AOT ``fn.lower(*args).compile()`` — one extra backend
 compile per program, paid once while the program is being built/warmed
@@ -27,8 +29,6 @@ head, and BENCH ``extras.costs``.
 
 Env knobs:
 
-- ``PADDLE_TPU_DEVICE_PEAK_FLOPS``     roofline peak FLOP/s override
-- ``PADDLE_TPU_DEVICE_PEAK_BPS``       roofline peak memory bytes/s override
 - ``PADDLE_TPU_HBM_BUDGET``            device memory budget in bytes (the
                                        doctor's ``memory_pressure`` detector
                                        compares ledger ``peak_bytes`` to it)
@@ -41,44 +41,36 @@ import threading
 from . import events, registry, state
 
 __all__ = ['capture', 'record_compiled', 'mark_hit', 'ledger', 'entry',
-           'summary', 'reset', 'device_peaks', 'roofline', 'hbm_budget']
+           'summary', 'reset', 'DEVICE_PEAKS', 'device_peaks', 'roofline',
+           'hbm_budget']
 
 _lock = threading.Lock()
 _ledger = {}         # program label -> entry dict
 
 
-# nominal peak (FLOP/s, bytes/s) per backend for the analytic roofline —
-# deliberately round numbers: the roofline is a *bound* used to rank
-# programs and name the binding resource, not a performance prediction
-_DEFAULT_PEAKS = {
-    'tpu': (275e12, 1.2e12),     # ~v4 chip: bf16 MXU peak, HBM2 bw
-    'gpu': (312e12, 2.0e12),     # ~A100 bf16 / HBM2e
-    'cpu': (2e11, 5e10),         # a few AVX cores / dual-channel DRAM
+# published peak (bf16 FLOP/s, HBM bytes/s) of one chip, keyed by
+# ``jax.devices()[0].device_kind``. Source: Google Cloud documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM) — on-chip-measurement guide §4.
+# A device that is not here is an error, not a default.
+DEVICE_PEAKS = {
+    'TPU v5 lite': (197e12, 819e9),
 }
 
 
-def _env_float(name):
-    raw = os.environ.get(name, '')
-    if not raw:
-        return None
-    try:
-        return float(raw)
-    except ValueError:
-        return None
-
-
-def device_peaks(backend=None):
-    """(peak_flops_per_s, peak_bytes_per_s) for the roofline: the env
-    overrides when set, else the nominal table entry for the backend."""
-    if backend is None:
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:
-            backend = 'cpu'
-    flops, bps = _DEFAULT_PEAKS.get(backend, _DEFAULT_PEAKS['cpu'])
-    return (_env_float('PADDLE_TPU_DEVICE_PEAK_FLOPS') or flops,
-            _env_float('PADDLE_TPU_DEVICE_PEAK_BPS') or bps)
+def device_peaks(device_kind=None):
+    """(peak_flops_per_s, peak_bytes_per_s) of ``device_kind`` (default:
+    this process's first device). Raises ``KeyError`` for a kind that is
+    not in ``DEVICE_PEAKS``."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in DEVICE_PEAKS:
+        raise KeyError(
+            "no published peaks for device kind %r in "
+            "observability.costs.DEVICE_PEAKS (have %s): a roofline against "
+            "another device's peaks is not a roofline"
+            % (device_kind, sorted(DEVICE_PEAKS)))
+    return DEVICE_PEAKS[device_kind]
 
 
 def hbm_budget():
@@ -91,19 +83,16 @@ def hbm_budget():
             return int(float(raw))
         except ValueError:
             pass
-    try:
-        import jax
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = stats.get('bytes_limit')
-        return int(limit) if limit else None
-    except Exception:
-        return None
+    import jax
+    limit = (jax.devices()[0].memory_stats() or {}).get('bytes_limit')
+    return int(limit) if limit else None
 
 
-def roofline(flops, bytes_accessed, backend=None):
+def roofline(flops, bytes_accessed, device_kind=None):
     """Analytic roofline for one program: arithmetic intensity vs the
-    device ridge point -> binding resource + floor time estimate."""
-    peak_flops, peak_bps = device_peaks(backend)
+    device ridge point -> binding resource + floor time estimate. Raises
+    ``KeyError`` for a device without published peaks."""
+    peak_flops, peak_bps = device_peaks(device_kind)
     ai = (flops / bytes_accessed) if bytes_accessed else 0.0
     ridge = peak_flops / peak_bps
     est_s = max(flops / peak_flops if peak_flops else 0.0,
@@ -206,7 +195,13 @@ def record_costs(program, flops, bytes_accessed, mem=None, kind='jit',
         'hits': 0,
     }
     entry.update(mem)
-    entry['roofline'] = roofline(entry['flops'], entry['bytes_accessed'])
+    import jax
+    device_kind = jax.devices()[0].device_kind
+    # flops/bytes are recorded on any device; the roofline only where the
+    # device's peaks are published
+    roof = (roofline(entry['flops'], entry['bytes_accessed'], device_kind)
+            if device_kind in DEVICE_PEAKS else None)
+    entry['roofline'] = roof
     if meta:
         entry['meta'] = dict(meta)
     with _lock:
@@ -227,10 +222,9 @@ def record_costs(program, flops, bytes_accessed, mem=None, kind='jit',
                 argument_bytes=entry.get('argument_bytes', 0),
                 output_bytes=entry.get('output_bytes', 0),
                 temp_bytes=entry.get('temp_bytes', 0),
-                arithmetic_intensity=entry['roofline'][
-                    'arithmetic_intensity'],
-                bound=entry['roofline']['bound'],
-                est_ms=entry['roofline']['est_ms'])
+                **({'arithmetic_intensity': roof['arithmetic_intensity'],
+                    'bound': roof['bound'], 'est_ms': roof['est_ms']}
+                   if roof else {}))
     return entry
 
 

@@ -52,8 +52,6 @@ Cost explorer / SLO / flight-recorder knobs (owned by ``costs.py`` /
 ``slo.py`` / ``flight.py``, catalogued here so one file documents the env
 surface):
 
-- ``PADDLE_TPU_DEVICE_PEAK_FLOPS`` / ``PADDLE_TPU_DEVICE_PEAK_BPS``
-                                   roofline device peaks (see costs.py)
 - ``PADDLE_TPU_HBM_BUDGET``        device memory budget in bytes for the
                                    doctor's memory_pressure detector
 - ``PADDLE_TPU_SLO_MS`` / ``PADDLE_TPU_SLO_OBJECTIVE``

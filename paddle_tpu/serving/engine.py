@@ -294,10 +294,17 @@ class ServingEngine:
                     "predict_fn= with explicit binding")
             order = [p for p in params if p in example]
 
+        by_name = len(example) > 1
+
         def fn(feeds):
-            vals = [Tensor(feeds[k]) for k in order]
             with autograd.no_grad():
-                out = layer(*vals)
+                # multi-input feeds bind BY NAME (checked above): a
+                # positional call would hand e.g. attention_mask to the
+                # parameter that follows input_ids in forward's signature
+                if by_name:
+                    out = layer(**{k: Tensor(feeds[k]) for k in order})
+                else:
+                    out = layer(Tensor(feeds[order[0]]))
             if isinstance(out, (tuple, list)):
                 return type(out)(o._value if isinstance(o, Tensor) else o
                                  for o in out)

@@ -392,6 +392,11 @@ class Executor:
                 dp_shardings = (repl, [feed_sh] * n_feed)
 
         if train_spec is None:
+            from ..engine.builder import kernel_mesh_of
+            kernel_scope = functools.partial(
+                kernel_mesh_of, sharding_cfg,
+                (None,) + tuple(jit_kwargs.get('in_shardings', ())))
+
             @functools.partial(jax.jit, **jit_kwargs)
             def run_jit(feed_vals, param_vals):
                 env = {}
@@ -399,7 +404,8 @@ class Executor:
                     env[id(v)] = val
                 for v, val in zip(params, param_vals):
                     env[id(v)] = val
-                env = interpret(env)
+                with kernel_scope():    # data-parallel: see kernel_mesh
+                    env = interpret(env)
                 return _fetch_outs(fetch_vars, env), None
 
             fp = program._fingerprint

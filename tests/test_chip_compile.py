@@ -1,0 +1,192 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e chip.
+
+No chip is attached here: the TPU compiler that is installed with JAX
+compiles for a ``v5e:2x2`` topology that is described, so what the chip's
+compiler would refuse (a slice not aligned to the tiling, too much VMEM) is
+refused here at no chip time. Real widths: BERT-large head shapes
+``[8, 16, L, 64]`` bf16 and ``[B*L, 1024]`` rows. A compile that passes is
+not a chip run — ``chip_smoke.py`` is.
+
+Rules this file keeps (on-chip-measurement guide, section 2): the topology
+is described inside a module-scoped fixture that skips when it cannot be —
+never at import, in a ``skipif`` or in a ``parametrize`` argument; compiles
+run in the test's own process (the worker that loaded libtpu keeps its
+lock); JAX's persistent cache is off around them (an entry written for a
+described chip cannot be read back without one).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from paddle_tpu.kernels import flash_attention as fa
+from paddle_tpu.kernels import fused_dropout_norm as fdn
+from paddle_tpu.kernels import fused_norm as fn
+from paddle_tpu.kernels._common import kernel_mesh
+from paddle_tpu.kernels.autotune import _candidate_blocks
+
+B, H, D = 8, 16, 64            # BERT-large attention: 16 heads of 64
+HIDDEN = 1024
+ROWS = 8192                    # B*L: 64x128 (seq-128 batch) = 16x512
+
+
+@pytest.fixture(scope='module')
+def topo():
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        desc = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:   # no TPU compiler here: nothing to hold to
+        pytest.skip('no v5e:2x2 topology can be described here: %r' % (e,))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update('jax_enable_compilation_cache', was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope='module')
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn_, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(fn_).lower(*args).compile()
+    text = compiled.as_text()
+    assert 'tpu_custom_call' in text
+    return text
+
+
+# (causal, has_kpad, dropout_p): decoder attention; BERT's padded batches
+# with attention dropout; BERT pretraining without a mask (bench/smoke)
+_FLASH_VARIANTS = [(True, False, 0.0), (False, True, 0.1), (False, False, 0.1)]
+_FLASH_CASES = [
+    (seq, causal, kpad, p, bq, bk)
+    for seq in (512, 1024)
+    for causal, kpad, p in _FLASH_VARIANTS
+    for bq, bk in _candidate_blocks(seq, kpad)]
+
+
+@pytest.mark.parametrize(
+    'seq,causal,kpad,p,bq,bk', _FLASH_CASES,
+    ids=['l%d_c%d_m%d_p%d_%dx%d' % (s, c, m, p > 0, bq, bk)
+         for s, c, m, p, bq, bk in _FLASH_CASES])
+def test_flash_tiling_compiles_fwd_bwd(one_chip, seq, causal, kpad, p, bq,
+                                       bk):
+    """Every tiling the autotuner can emit compiles, forward and backward."""
+    scale = D ** -0.5
+
+    def loss(q, k, v, bias, seed):
+        out = fa._flash(q, k, v, bias if kpad else None, seed, causal, scale,
+                        bq, bk, p, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qkv = ((B, H, seq, D), jnp.bfloat16)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv,
+             ((B, seq), jnp.float32), ((1, 1), jnp.int32))
+
+
+_X = ((ROWS, HIDDEN), jnp.bfloat16)
+_W = ((HIDDEN,), jnp.bfloat16)
+_SEED = ((1, 1), jnp.int32)
+
+
+def test_fused_layer_norm_compiles_fwd_bwd(one_chip):
+    def loss(x, w, b):
+        y = fn._fused_layer_norm2d(x, w, b, 1e-5, False)
+        return jnp.sum(y.astype(jnp.float32))
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, _X, _W, _W)
+
+
+def test_fused_rms_norm_compiles_fwd_bwd(one_chip):
+    def loss(x, w):
+        y = fn._fused_rms_norm2d(x, w, 1e-6, False)
+        return jnp.sum(y.astype(jnp.float32))
+    _compile(jax.grad(loss, argnums=(0, 1)), one_chip, _X, _W)
+
+
+@pytest.mark.parametrize('p', [0.0, 0.1], ids=['p0', 'p0.1'])
+def test_fused_dropout_norm_compiles_fwd_bwd(one_chip, p):
+    def loss(x, res, w, b, seed):
+        y = fdn._fdln(x, res, w, b, seed, 1e-5, p, False)
+        return jnp.sum(y.astype(jnp.float32))
+    _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), one_chip, _X, _X, _W, _W,
+             _SEED)
+
+
+def _partitioned(topo, monkeypatch, f, specs, shapes, axes=('data',),
+                 mesh_shape=(4,)):
+    """Compile ``f`` for the four chips of the 2x2 topology with operands
+    sharded as ``specs``, traced as the engine traces a sharded step
+    (``kernel_mesh``); return the per-device program text."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    mesh = Mesh(np.asarray(topo.devices).reshape(mesh_shape), axes)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=NamedSharding(mesh, spec))
+            for (s, dt), spec in zip(shapes, specs)]
+    with pytest.raises(NotImplementedError, match='partition'):
+        jax.jit(f).lower(*args)          # no scope: refused, and loudly
+
+    def traced(*a):
+        with kernel_mesh(mesh, axes[:1], axes[1:]):
+            return f(*a)
+    return jax.jit(traced).lower(*args).compile().as_text()
+
+
+def test_partitioned_norms_keep_their_kernels(topo, monkeypatch):
+    """Rows sharded over the four chips: the TPU compiler refuses to
+    partition a Mosaic kernel by itself ("Mosaic kernels cannot be
+    automatically partitioned"), so under ``kernel_mesh`` every kernel
+    site partitions itself (``_common.spmd_kernel``) — each chip runs the
+    kernel on its rows, and no operand is gathered."""
+    def loss(x, res, w, b, seed):
+        y = fdn.fused_dropout_add_layer_norm(x, res, w, b, 0.1,
+                                             dropout_seed=seed)
+        y = fn.fused_rms_norm(fn.fused_layer_norm(y, w, b), w)
+        return jnp.sum(y.astype(jnp.float32))
+    rows, rep = P('data', None), P()
+    text = _partitioned(topo, monkeypatch,
+                        jax.grad(loss, argnums=(0, 1, 2, 3)),
+                        [rows, rows, rep, rep, rep],
+                        [_X, _X, _W, _W, _SEED])
+    # fwd of three kernels + the dropout-mask backward kernel
+    assert text.count('tpu_custom_call') >= 4
+    assert '.xla' not in text
+    assert 'all-gather' not in text
+    assert 'bf16[2048,1024]' in text        # a quarter of the 8192 rows
+
+
+@pytest.mark.parametrize('spec,mesh_shape,axes,local', [
+    (P('data'), (4,), ('data',), '[2,16,512,64]'),
+    (P('data', 'model'), (2, 2), ('data', 'model'), '[4,8,512,64]'),
+], ids=['batch', 'batch+heads'])
+def test_partitioned_flash_keeps_its_kernels(topo, monkeypatch, spec,
+                                             mesh_shape, axes, local):
+    """Flash attention with batch (FSDP) and batch x heads (FSDP x TP)
+    sharded over the 2x2 chips: forward, dQ and dK/dV kernels on each
+    chip's (batch, heads) block, key-padding bias and in-kernel dropout
+    included."""
+    L = 512
+    qkv = ((B, H, L, D), jnp.bfloat16)
+
+    def loss(q, k, v, bias, seed):
+        o = fa.flash_attention_bhld(q, k, v, kpad_bias=bias, dropout_p=0.1,
+                                    dropout_seed=seed)
+        return jnp.sum(o.astype(jnp.float32))
+    text = _partitioned(
+        topo, monkeypatch, jax.grad(loss, argnums=(0, 1, 2)),
+        [spec, spec, spec, P(spec[0]), P()],
+        [qkv, qkv, qkv, ((B, L), jnp.float32), _SEED],
+        axes=axes, mesh_shape=mesh_shape)
+    assert text.count('tpu_custom_call') >= 3
+    assert 'flash_attention.xla' not in text
+    assert 'all-gather' not in text
+    assert 'bf16%s' % local in text

@@ -88,8 +88,9 @@ class TestCostLedger:
             assert e['flops'] > 0 and e['bytes_accessed'] > 0
             assert e['argument_bytes'] > 0 and e['output_bytes'] > 0
             assert e['peak_bytes'] >= e['argument_bytes'] + e['output_bytes']
-            assert e['roofline']['bound'] in ('compute', 'memory')
-            assert e['roofline']['est_ms'] > 0
+            # flops/bytes are ledgered on any device; a roofline only
+            # where the device's peaks are published (not the CPU)
+            assert e['roofline'] is None
             # cache hit: SAME numbers, a hit tick, and NO new compile
             warm = _compiles()
             exe.run(main, feed=feed, fetch_list=[y])
@@ -143,14 +144,13 @@ class TestCostLedger:
         assert 'serving.clf.b1' in programs and 'serving.clf.b2' in programs
         assert all(e['flops'] > 0 for e in programs.values())
 
-    def test_roofline_env_overrides_and_summary(self, monkeypatch):
+    def test_roofline_against_published_peaks_and_summary(self):
         obs.enable()
-        monkeypatch.setenv('PADDLE_TPU_DEVICE_PEAK_FLOPS', '1e9')
-        monkeypatch.setenv('PADDLE_TPU_DEVICE_PEAK_BPS', '1e9')
-        r = costs.roofline(2e9, 1e9)      # AI=2 >= ridge=1 -> compute-bound
+        v5e = 'TPU v5 lite'               # 197 TFLOP/s, 819 GB/s: ridge 240
+        r = costs.roofline(197e12 * 2, 819e9, v5e)   # AI=481 >= ridge
         assert r['bound'] == 'compute' and r['est_ms'] == 2000.0
-        r2 = costs.roofline(1e8, 1e9)     # AI=0.1 < 1 -> memory-bound
-        assert r2['bound'] == 'memory'
+        r2 = costs.roofline(1e9, 819e9, v5e)         # AI~0.001 < ridge
+        assert r2['bound'] == 'memory' and r2['est_ms'] == 1000.0
         costs.record_costs('p1', 100.0, 50.0,
                            {'argument_bytes': 10, 'output_bytes': 5})
         s = costs.summary()

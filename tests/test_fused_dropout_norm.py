@@ -66,6 +66,29 @@ class TestFusedAddNormKernel:
             np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
                                        rtol=1e-4, atol=1e-4)
 
+    def test_backward_mixed_dtypes_interpret(self):
+        """amp: x arrives bf16 from a cast matmul while the residual stream
+        is still fp32 (the embedding output). Each cotangent comes back in
+        its own input's dtype — found by compiling the BERT-large step for
+        the chip, where this kernel runs and the XLA composition does not."""
+        rs = np.random.RandomState(2)
+        x = jnp.asarray(rs.randn(16, 128), jnp.bfloat16)
+        res = jnp.asarray(rs.randn(16, 128), jnp.float32)
+        w = jnp.asarray(rs.randn(128), jnp.float32)
+        b = jnp.asarray(rs.randn(128), jnp.float32)
+
+        def loss(x, res, w, b):
+            y = fused_dropout_add_layer_norm(x, res, w, b, dropout_p=0.0,
+                                             interpret=True)
+            return jnp.sum(y.astype(jnp.float32))
+
+        y = fused_dropout_add_layer_norm(x, res, w, b, interpret=True)
+        assert y.dtype == jnp.bfloat16
+        grads = jax.grad(loss, argnums=(0, 1, 2, 3))(x, res, w, b)
+        for g, arg in zip(grads, (x, res, w, b)):
+            assert g.dtype == arg.dtype and g.shape == arg.shape
+            assert bool(jnp.isfinite(g.astype(jnp.float32)).all())
+
     def test_functional_fallback_dropout_semantics(self):
         # off-TPU functional path: train-mode dropout is unbiased, eval exact
         from paddle_tpu.nn import functional as F

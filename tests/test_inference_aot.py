@@ -73,7 +73,17 @@ class TestPersistentCompilationCache:
             entries = os.listdir(cache)
             assert entries, "persistent cache has no entries"
         finally:
+            # hand the worker back WITHOUT a live persistent cache: an
+            # initialized one keeps serving (and writing) entries for every
+            # later test in this process, and an XLA:CPU executable loaded
+            # back from it can fail at run time ("Function ... not found")
+            from jax.experimental.compilation_cache import (
+                compilation_cache as cc)
             jax.config.update('jax_compilation_cache_dir', None)
+            jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)
+            jax.config.update('jax_persistent_cache_min_compile_time_secs',
+                              1.0)
+            cc.reset_cache()
 
 
 class TestPredictor:

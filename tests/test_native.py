@@ -54,6 +54,79 @@ def test_ring_skip_marker():
     r.destroy()
 
 
+def _blocked_producer(shm_name, seq):
+    from multiprocessing import shared_memory
+    from paddle_tpu._native.prefetch import NativePrefetchRing
+    shm = shared_memory.SharedMemory(name=shm_name)
+    ring = NativePrefetchRing.attach(shm.buf)
+    ring.put([np.zeros(4, np.float32)], seq)     # ring is full: blocks
+
+
+@pytest.mark.skipif(not native_available(), reason="no native lib")
+def test_ring_survives_a_waiter_killed_while_blocked():
+    """A worker SIGKILLed while it WAITS on the ring (full ring, consumer
+    busy — where producers spend their time) must leave nothing behind
+    that a later wake-up waits for. With a process-shared condvar the dead
+    waiter never left its group and close()/release() blocked forever: the
+    hang that cut the tier-1 run."""
+    import multiprocessing as mp
+    import os
+    import signal
+    import threading
+    import time
+    from multiprocessing import shared_memory
+    from paddle_tpu._native.prefetch import NativePrefetchRing, block_bytes
+    cap, slot = 2, 1 << 12
+    shm = shared_memory.SharedMemory(create=True,
+                                     size=block_bytes(cap, slot))
+    try:
+        ring = NativePrefetchRing(cap, slot, _buf=shm.buf)
+        for seq in range(cap):
+            ring.put([np.full(4, seq, np.float32)], seq)
+        ctx = mp.get_context('fork')
+        victims = [ctx.Process(target=_blocked_producer,
+                               args=(shm.name, cap + i), daemon=True)
+                   for i in range(2)]
+        for v in victims:
+            v.start()
+        time.sleep(0.5)                  # both blocked in the native wait
+        for v in victims:
+            assert v.is_alive()
+            os.kill(v.pid, signal.SIGKILL)
+            v.join(5)
+
+        def drain_and_close():
+            for want in range(cap):
+                arrays, release = ring.get(timeout_ms=2000)
+                assert arrays[0][0] == want
+                release()
+            # rounds of "a LIVE waiter arrives, then someone wakes it": a
+            # condvar stalls the waker as soon as its waiter groups switch
+            # while a dead waiter still holds a reference to the old one
+            for seq in range(cap, 4 * cap):
+                got = []
+                c = threading.Thread(
+                    target=lambda: got.append(ring.get(timeout_ms=5000)),
+                    daemon=True)
+                c.start()
+                time.sleep(0.1)          # the consumer is waiting now
+                assert ring.put([np.zeros(4, np.float32)], seq)
+                c.join(10)
+                assert got and got[0] not in ('timeout', None)
+                got[0][1]()
+            assert ring.get(timeout_ms=50) == 'timeout'
+            ring.close()
+        t = threading.Thread(target=drain_and_close, daemon=True)
+        t.start()
+        t.join(20)
+        assert not t.is_alive(), 'the ring waits for a dead process'
+        ring.destroy()
+        del ring
+    finally:
+        shm.close()
+        shm.unlink()
+
+
 @pytest.mark.skipif(not native_available(), reason="no native lib")
 def test_dataloader_process_workers():
     import paddle_tpu as paddle

@@ -524,6 +524,21 @@ class TestEngineLifecycle:
         eng.run_until_idle()
         np.testing.assert_allclose(np.asarray(f.result(10).outputs),
                                    a + 2.0 * b)
+
+        # feeds that skip a middle parameter still bind by NAME: a
+        # positional call would hand `mask` to `kind` (BertModel's
+        # input_ids + attention_mask, found bringing serving up on the chip)
+        class Skips(paddle.nn.Layer):
+            def forward(self, x, kind=None, mask=None):
+                assert kind is None
+                return x * mask
+
+        ep = eng.register('skips', layer=Skips(),
+                          example={'x': np.zeros((4,), np.float32),
+                                   'mask': np.zeros((4,), np.float32)})
+        f = ep.submit({'x': a, 'mask': b})
+        eng.run_until_idle()
+        np.testing.assert_allclose(np.asarray(f.result(10).outputs), a * b)
         # names that DON'T match the signature cannot bind unambiguously
         with pytest.raises(ValueError, match='bind unambiguously'):
             eng.register('bad', layer=TwoIn(),
