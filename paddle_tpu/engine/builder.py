@@ -361,6 +361,7 @@ class TrainStep:
         # with the program fingerprint) + the captured-once latch
         self.cost_label = f'engine.train_step{next(_STEP_SEQ)}'
         self._cost_captured = False
+        self._dispatches = 0         # the step number of the next dispatch
         self._params_meta = params_meta
         self._trainable = trainable
         self._with_key = with_key
@@ -633,13 +634,20 @@ class TrainStep:
             # is elementwise, and the carry constraint below reshards
             # the new state on the way out.
             repl = self.sharding.replicated()
-            params = {n: (jax.lax.with_sharding_constraint(v, repl)
-                          if n in self._gather else v)
-                      for n, v in params.items()}
+            with jax.named_scope('fsdp.gather'):
+                params = {n: (jax.lax.with_sharding_constraint(v, repl)
+                              if n in self._gather else v)
+                          for n, v in params.items()}
 
+        # the scopes below name the step's phases in every HLO
+        # instruction's op_name (metadata only: no instruction changes):
+        # what value_and_grad differentiates is `forward`, so its transpose
+        # reads `transpose(jvp(forward))`: the backward
+        # (observability.costs.instruction_phases reads them back)
         def scaled_loss(p):
-            loss, outs, new_buf = loss_fn(p, buffers, batch, key)
-            out_loss = loss * scale if use_scaler else loss
+            with jax.named_scope('forward'):
+                loss, outs, new_buf = loss_fn(p, buffers, batch, key)
+                out_loss = loss * scale if use_scaler else loss
             return out_loss, (loss, outs, new_buf)
 
         (_, (loss, outs, new_buf)), grads = jax.value_and_grad(
@@ -647,57 +655,64 @@ class TrainStep:
         if self._trainable is not None:
             grads = {n: g for n, g in grads.items() if n in self._trainable}
         if use_scaler:
-            grads = {n: g / scale for n, g in grads.items()}
-        new_params, new_opt = opt.functional_update(
-            params, grads, opt_state, params_meta=self._params_meta)
+            with jax.named_scope('guard'):
+                grads = {n: g / scale for n, g in grads.items()}
+        with jax.named_scope('update'):
+            new_params, new_opt = opt.functional_update(
+                params, grads, opt_state, params_meta=self._params_meta)
         applied = {'params': new_params, 'buffers': new_buf, 'opt': new_opt}
         kept = {'params': params, 'buffers': buffers, 'opt': opt_state}
 
-        loss_ok = jnp.isfinite(loss) if (use_guard or use_scaler) else None
-        grads_ok = None
-        if use_scaler:
-            grads_ok = functools.reduce(
-                jnp.logical_and,
-                [jnp.all(jnp.isfinite(g)) for g in
-                 jax.tree_util.tree_leaves(grads)],
-                jnp.bool_(True))
-        if use_guard and use_scaler:
-            ok = jnp.logical_and(loss_ok, grads_ok)
-        elif use_guard:
-            ok = loss_ok
-        elif use_scaler:
-            ok = jnp.logical_and(loss_ok, grads_ok)
-        else:
-            ok = None
+        with jax.named_scope('guard'):
+            loss_ok = jnp.isfinite(loss) if (use_guard or use_scaler) \
+                else None
+            grads_ok = None
+            if use_scaler:
+                grads_ok = functools.reduce(
+                    jnp.logical_and,
+                    [jnp.all(jnp.isfinite(g)) for g in
+                     jax.tree_util.tree_leaves(grads)],
+                    jnp.bool_(True))
+            if use_guard and use_scaler:
+                ok = jnp.logical_and(loss_ok, grads_ok)
+            elif use_guard:
+                ok = loss_ok
+            elif use_scaler:
+                ok = jnp.logical_and(loss_ok, grads_ok)
+            else:
+                ok = None
 
-        if ok is None:
-            new_state = applied
-        else:
-            # the donation-safe replacement for the old host-side rollback
-            # snapshot: select the pre-step state in-graph, no copy held
-            new_state = jax.lax.cond(ok, lambda: applied, lambda: kept)
-        if use_guard:
-            g = state['guard']
-            skipped = jnp.logical_not(loss_ok)
-            streak = jnp.where(skipped, g['consecutive'] + 1, 0)
-            new_state['guard'] = {
-                'steps': g['steps'] + 1,
-                'skipped': g['skipped'] + skipped.astype(jnp.int32),
-                'consecutive': streak,
-                'peak': jnp.maximum(g['peak'], streak),
-            }
-        if use_scaler:
-            new_state['scaler'] = self._advance_scaler(state['scaler'], ok)
+            if ok is None:
+                new_state = applied
+            else:
+                # the donation-safe replacement for the old host-side
+                # rollback snapshot: select the pre-step state in-graph,
+                # no copy held
+                new_state = jax.lax.cond(ok, lambda: applied, lambda: kept)
+            if use_guard:
+                g = state['guard']
+                skipped = jnp.logical_not(loss_ok)
+                streak = jnp.where(skipped, g['consecutive'] + 1, 0)
+                new_state['guard'] = {
+                    'steps': g['steps'] + 1,
+                    'skipped': g['skipped'] + skipped.astype(jnp.int32),
+                    'consecutive': streak,
+                    'peak': jnp.maximum(g['peak'], streak),
+                }
+            if use_scaler:
+                new_state['scaler'] = self._advance_scaler(
+                    state['scaler'], ok)
         if self._state_constraints is not None:
             # reshard the updated params/opt on the way out: the scan
             # carry (and the donated output buffers) stay sharded across
             # microbatches instead of riding replicated through the loop
             wsc = jax.lax.with_sharding_constraint
-            new_state['params'] = {
-                n: wsc(v, self._state_constraints['params'][n])
-                for n, v in new_state['params'].items()}
-            new_state['opt'] = jax.tree_util.tree_map(
-                wsc, new_state['opt'], self._state_constraints['opt'])
+            with jax.named_scope('fsdp.reshard'):
+                new_state['params'] = {
+                    n: wsc(v, self._state_constraints['params'][n])
+                    for n, v in new_state['params'].items()}
+                new_state['opt'] = jax.tree_util.tree_map(
+                    wsc, new_state['opt'], self._state_constraints['opt'])
         return new_state, loss, outs
 
     def _advance_scaler(self, sc, ok):
@@ -738,29 +753,32 @@ class TrainStep:
                 lambda v: jax.device_put(v, bsh), batch)
             if key is not None:
                 key = jax.device_put(key, self.sharding.replicated())
+        args = (state, batch, key) if self._with_key else (state, batch)
         telemetry = _obs.enabled()
         if telemetry and not self._cost_captured:
             # cost explorer: AOT-ledger this program's FLOPs/bytes/peak
-            # memory once, while the first dispatch is compiling anyway
+            # memory and the phase of each of its instructions once, while
+            # the first dispatch is compiling anyway
             self._cost_captured = True
-            args = (state, batch, key) if self._with_key else (state, batch)
             _obs.costs.capture(
                 self.cost_label, self._jit, *args, kind='train_step',
+                phases=True,
                 meta={'microbatch': self.k, 'donates': self.donates,
                       'sharded': self.sharding is not None})
+        n = self._dispatches
+        self._dispatches = n + 1
+        # the ENQUEUE of step n, not the step: the jit call returns before
+        # the device is done. In a profiler trace it is the per-step
+        # annotation `train_step` with step_num=n.
+        with _obs.timer('engine.dispatch', step=n, annotation='train_step',
+                        k=self.k):
+            new_state, losses, outs = self._jit(*args)
         if telemetry:
-            with _obs.timer('engine.step', k=self.k):
-                out = self._jit(state, batch, key) if self._with_key \
-                    else self._jit(state, batch)
             _obs.counter('engine.steps').inc(self.k)
             _obs.counter('engine.dispatches').inc()
             if self._collective_bytes_est:
                 _obs.counter('sharding.collective_bytes_est').inc(
                     self._collective_bytes_est * self.k)
-        else:
-            out = self._jit(state, batch, key) if self._with_key \
-                else self._jit(state, batch)
-        new_state, losses, outs = out
         loss = losses if self.k == 1 else losses[-1]
         return new_state, StepResult(DeviceLoss(loss), losses, outs)
 

@@ -130,7 +130,8 @@ def fit(network, loss, optimizer, data, *, epochs=1, microbatch=1,
         remat=None, donate='auto', matmul_precision='auto', sharding=None,
         checkpoint=None, checkpoint_every=0, async_save=True,
         resume_from=None, preempt_save=True, checkpoint_max_keep=3,
-        world=None, rank=None, serve_artifacts=None, serve_generative=None):
+        world=None, rank=None, serve_artifacts=None, serve_generative=None,
+        on_step=None):
     """Train ``network`` over ``data`` through the unified compiled step.
 
     ``data``: a DataLoader or any iterable of ``(inputs, labels)`` batches
@@ -182,6 +183,14 @@ def fit(network, loss, optimizer, data, *, epochs=1, microbatch=1,
       (a bare spec exports as ``'model'``). A preempted run skips the
       export (the artifact dir only ever holds programs a completed run
       stands behind).
+
+    ``on_step=``: a callable ``on_step(i, result)`` handed every dispatch's
+    ``StepResult`` once, in order, on the training thread, ONE BEHIND the
+    dispatch: after dispatching step ``i`` the loop hands over step
+    ``i - 1`` (its successor is already queued, so waiting on
+    ``result.loss.raw`` there times step ``i - 1``'s completion without
+    idling the device), and the last one after the loop. ``i`` counts this
+    call's dispatches from 0. It cannot change the training.
 
     Returns a report dict: floated losses at log cadence, step counts,
     steps/sec, and the final functional state (already written back into
@@ -257,6 +266,16 @@ def fit(network, loss, optimizer, data, *, epochs=1, microbatch=1,
     needs_sync = nan_guard is not None or step.scaler is not None
     sw = _obs.Stopwatch()
     first_feed = None
+    behind = None       # (i, StepResult) dispatched, not yet handed over
+    dispatched = 0
+
+    def hand_over(latest):
+        nonlocal behind
+        if on_step is not None:
+            if behind is not None:
+                on_step(*behind)
+            behind = latest
+
     try:
         for epoch in range(int(start_epoch), int(epochs)):
             source = _grouped(data, k)
@@ -293,6 +312,8 @@ def fit(network, loss, optimizer, data, *, epochs=1, microbatch=1,
                 else:
                     key = jnp.stack([_rng.next_key() for _ in range(k)])
                 state, out = step(state, (bx, by), key)
+                hand_over((dispatched, out))
+                dispatched += 1
                 report['dispatches'] += 1
                 report['steps'] += k
                 dispatch_in_epoch += 1
@@ -316,6 +337,7 @@ def fit(network, loss, optimizer, data, *, epochs=1, microbatch=1,
                                        error=repr(e))
                     save_now(epoch, dispatch_in_epoch, async_ok=False)
                     report['preempted'] = True
+                    hand_over(None)
                     return _finish(report, sw, step, state, network,
                                    optimizer, nan_guard, scaler, needs_sync,
                                    mgr, guard)
@@ -327,6 +349,7 @@ def fit(network, loss, optimizer, data, *, epochs=1, microbatch=1,
                 save_now(epoch + 1, 0)
         if mgr is not None and checkpoint_every:
             save_now(int(epochs), 0)
+        hand_over(None)
         out = _finish(report, sw, step, state, network, optimizer,
                       nan_guard, scaler, needs_sync, mgr, guard)
         if serve_artifacts is not None:

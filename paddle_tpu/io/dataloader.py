@@ -136,14 +136,20 @@ class DevicePrefetcher:
 
         def worker():
             try:
-                for batch in self.source:
-                    item = self._convert(batch)
-                    while not stop.is_set():
-                        try:
-                            out_q.put(item, timeout=0.1)
-                            break
-                        except queue.Full:
-                            continue
+                source = iter(self.source)
+                for n in itertools.count():
+                    with _obs.span('prefetch.source', batch=n):
+                        batch = next(source, done)
+                    if batch is done:
+                        return
+                    attrs = {'bytes': _host_bytes(batch)} \
+                        if _obs.enabled() else {}
+                    sw = _obs.Stopwatch()
+                    with _obs.span('prefetch.convert', batch=n, **attrs):
+                        item = self._convert(batch)
+                    _note_slow('prefetch.convert', sw, n, out_q)
+                    with _obs.span('prefetch.put_wait', batch=n):
+                        _post(item)
                     if stop.is_set():
                         return
             except BaseException as e:
@@ -163,18 +169,30 @@ class DevicePrefetcher:
                              name='paddle-tpu-device-prefetch')
         t.start()
         try:
-            while True:
-                batch = _watchdog.bounded_get(
-                    out_q, timeout=self.timeout, alive=t.is_alive,
-                    what='device prefetch batch')
+            for n in itertools.count():
+                # the depth the consumer FOUND: 0 means this get starved
+                # (the step it feeds had to wait for its input)
+                depth = out_q.qsize()
+                attrs = {}
+                if _obs.enabled():
+                    _obs.gauge('dataloader.prefetch_depth').set(depth)
+                    attrs = {
+                        'depth': depth,
+                        'gets': _obs.counter('prefetch.gets').inc(),
+                        'starved': _obs.counter('prefetch.starved').inc(
+                            1 if depth == 0 else 0)}
+                sw = _obs.Stopwatch()
+                with _obs.span('prefetch.get_wait', batch=n, **attrs):
+                    batch = _watchdog.bounded_get(
+                        out_q, timeout=self.timeout, alive=t.is_alive,
+                        what='device prefetch batch')
+                _note_slow('prefetch.get_wait', sw, n, out_q, depth)
                 if batch is done:
                     return
                 if isinstance(batch, _WorkerFailure):
                     raise DataLoaderWorkerError(
                         f"DataLoader device prefetch failed: "
                         f"{batch.exc!r}\n{batch.tb}")
-                if _obs.enabled():
-                    _obs.gauge('dataloader.prefetch_depth').set(out_q.qsize())
                 yield batch
         finally:
             stop.set()
@@ -182,6 +200,34 @@ class DevicePrefetcher:
             # stop; a worker wedged inside _convert just times the join
             # out (False) rather than hanging generator teardown
             _watchdog.join_thread(t, timeout=2.0)
+
+
+# a prefetcher boundary slower than this is an anomaly worth a record in
+# the always-on flight ring (a step's input is normally ready in ms)
+_SLOW_S = 1.0
+
+
+def _note_slow(what, sw, n, out_q, depth=None):
+    """Flight-record a ``prefetch.convert`` / ``prefetch.get_wait`` that
+    took over ``_SLOW_S``: which batch (the nth of this iterator, feeding
+    the loop's nth step) and how deep the queue was."""
+    seconds = sw.elapsed()
+    if seconds > _SLOW_S:
+        _obs.flight.record(
+            'prefetch.slow', what=what, seconds=round(seconds, 3), step=n,
+            depth=out_q.qsize() if depth is None else depth)
+
+
+def _host_bytes(batch):
+    """Bytes of a host batch's array leaves (the ``bytes`` a
+    ``prefetch.convert`` span carries)."""
+    if isinstance(batch, dict):
+        batch = list(batch.values())
+    if isinstance(batch, (list, tuple)):
+        return sum(_host_bytes(v) for v in batch)
+    if isinstance(batch, Tensor):
+        batch = batch._value
+    return int(getattr(batch, 'nbytes', 0))
 
 
 def _env_prefetch_depth():

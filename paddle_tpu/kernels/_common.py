@@ -52,7 +52,7 @@ def kernel_mesh(mesh, batch_axes, head_axes=()):
         _tls.mesh = prev
 
 
-def spmd_kernel(impl, in_dims, out_dims, roles, granule=1):
+def spmd_kernel(impl, in_dims, out_dims, roles, granule=1, scope=None):
     """Make one ``pallas_call`` site partitionable: under ``kernel_mesh``
     the site becomes a ``shard_map`` in which each device runs the kernel
     on its own rows / (batch, heads) block — no operand is gathered.
@@ -70,6 +70,10 @@ def spmd_kernel(impl, in_dims, out_dims, roles, granule=1):
     granule: a factor is split only when each device's part is a multiple
         of this (the row kernels need 8 sublanes); otherwise every device
         computes the whole of it.
+    scope: the ``took`` scope of the site (``flash_attention.pallas``),
+        entered again inside the ``shard_map``: the compiler names a
+        custom call after its innermost scope, which is ``shard_map`` there
+        otherwise, and a device trace is read by that name.
     """
     def run(*arrays):
         total = {}
@@ -78,9 +82,9 @@ def spmd_kernel(impl, in_dims, out_dims, roles, granule=1):
                 if f in roles:
                     total.setdefault(f, n)
         axes, local = {}, {}
-        scope = getattr(_tls, 'mesh', None)
-        if scope is not None:
-            mesh, by_role = scope
+        entered = getattr(_tls, 'mesh', None)
+        if entered is not None:
+            mesh, by_role = entered
             manual = set(jax.sharding.get_abstract_mesh().manual_axes)
             for f, role in roles.items():
                 names = tuple(a for a in by_role[role]
@@ -94,9 +98,11 @@ def spmd_kernel(impl, in_dims, out_dims, roles, granule=1):
                         shard={f: (jnp.int32(0), total[f]) for f in roles})
 
         def on_shard(*arrays):
-            return impl(*arrays, shard={
-                f: (jax.lax.axis_index(axes[f]) * local[f] if f in axes
-                    else jnp.int32(0), total[f]) for f in roles})
+            with jax.named_scope(scope) if scope else \
+                    contextlib.nullcontext():
+                return impl(*arrays, shard={
+                    f: (jax.lax.axis_index(axes[f]) * local[f] if f in axes
+                        else jnp.int32(0), total[f]) for f in roles})
 
         def spec(dims):
             return P(*(axes.get(f) for f in dims))

@@ -227,8 +227,8 @@ def _flash_forward(q, k, v, kpad_bias, seed, causal, scale, block_q, block_k,
         )(*args)
         return o.reshape(b, h, L, d), lse.reshape(b, h, L)
 
-    return spmd_kernel(call, dims, [_BHLD, _BHLD[:3]],
-                       _ROLES)(*args)
+    return spmd_kernel(call, dims, [_BHLD, _BHLD[:3]], _ROLES,
+                       scope='flash_attention.pallas')(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -433,7 +433,8 @@ def _flash_backward(q, k, v, o, lse, kpad_bias, seed, g, causal, scale,
         )(*args)
         return tuple(t.reshape(b, h, L, d) for t in (dq, dk, dv))
 
-    return spmd_kernel(call, dims, [_BHLD] * 3, _ROLES)(*args)
+    return spmd_kernel(call, dims, [_BHLD] * 3, _ROLES,
+                       scope='flash_attention.pallas')(*args)
 
 
 # ---------------------------------------------------------------------------

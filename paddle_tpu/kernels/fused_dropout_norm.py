@@ -123,7 +123,8 @@ def _fused_fwd(x, res, w, b, seed, eps, p, interpret):
 
     return spmd_kernel(
         call, dims, [('n', 'd'), ('n', 'd'), ('n', None), ('n', None)],
-        {'n': 'batch'}, granule=8)(*args)
+        {'n': 'batch'}, granule=8,
+        scope='fused_dropout_norm.pallas')(*args)
 
 
 def _apply_dropout_grad(d_yin, seed, p, interpret):
@@ -143,7 +144,8 @@ def _apply_dropout_grad(d_yin, seed, p, interpret):
         )(g, _seed_and_tile(seed, shard, bn))
 
     return spmd_kernel(call, [('n', 'd'), (None, None)], [('n', 'd')],
-                       {'n': 'batch'}, granule=8)(d_yin, seed)
+                       {'n': 'batch'}, granule=8,
+                       scope='fused_dropout_norm.pallas')(d_yin, seed)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))

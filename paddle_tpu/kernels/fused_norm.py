@@ -81,7 +81,8 @@ def _ln_forward(x2, w, b, eps, interpret):
 
     return spmd_kernel(call, [('n', 'd'), ('d',), ('d',)],
                        [('n', 'd'), ('n', None), ('n', None)],
-                       {'n': 'batch'}, granule=8)(x2, w_arg, b_arg)
+                       {'n': 'batch'}, granule=8,
+                       scope='fused_layer_norm.pallas')(x2, w_arg, b_arg)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -136,7 +137,8 @@ def _rms_forward(x2, w, eps, interpret):
 
     return spmd_kernel(call, [('n', 'd'), ('d',)],
                        [('n', 'd'), ('n', None)],
-                       {'n': 'batch'}, granule=8)(x2, w_arg)
+                       {'n': 'batch'}, granule=8,
+                       scope='fused_rms_norm.pallas')(x2, w_arg)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))

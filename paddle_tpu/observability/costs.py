@@ -17,6 +17,16 @@ program sets — is captured ONCE at build/warmup time into a process-wide
   roofline (``device_peaks`` raises, the ledger entry's ``roofline`` is
   None) — see docs/OBSERVABILITY.md, "Cost explorer" for caveats.
 
+An engine train step's entry also keeps the **phase of every instruction**
+of its compiled module (``instruction_phases``, ``phases(program)``):
+``forward`` / ``backward`` / ``update`` / ``guard`` / ``other`` from the
+``jax.named_scope``s ``engine.builder`` traces the step under, and a
+**mixed** phase, named by what it holds (``backward+update``), for a fusion
+that holds more than one of the first three (XLA fuses the weight-gradient
+matmul with the optimizer's update: that is said, never split or guessed).
+A device-trace event is named by its instruction, so this map is what puts
+``%fusion.808`` under a phase.
+
 Capture is an AOT ``fn.lower(*args).compile()`` — one extra backend
 compile per program, paid once while the program is being built/warmed
 anyway; repeat requests are ledger hits (``jax.compiles`` flatness gates
@@ -35,17 +45,20 @@ Env knobs:
 
 Stdlib-only at import (jax is imported lazily inside ``capture``).
 """
+import collections
 import os
+import re
 import threading
 
 from . import events, registry, state
 
 __all__ = ['capture', 'record_compiled', 'mark_hit', 'ledger', 'entry',
            'summary', 'reset', 'DEVICE_PEAKS', 'device_peaks', 'roofline',
-           'hbm_budget']
+           'hbm_budget', 'instruction_phases', 'phase_of_op_name', 'phases']
 
 _lock = threading.Lock()
 _ledger = {}         # program label -> entry dict
+_phases = {}         # program label -> {instruction name: phase}
 
 
 # published peak (bf16 FLOP/s, HBM bytes/s) of one chip, keyed by
@@ -135,13 +148,99 @@ def _memory_scalars(mem):
     }
 
 
-def capture(program, fn, *args, kind='jit', meta=None):
+# -- phases of a compiled module's instructions ------------------------------
+
+# more than one of these in an instruction makes it mixed: `backward+update`
+_MAIN = ('forward', 'backward', 'update')
+
+_COMPUTATION = re.compile(r'^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$')
+_INSTRUCTION = re.compile(r'^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s')
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLED = re.compile(r'\b(?:calls|to_apply)=%?([\w.\-]+)')
+# a path component that is the `forward` scope, bare or under transforms:
+# `forward`, `jvp(forward)`, `transpose(jvp(forward))`, `vmap(jvp(forward))`
+_FORWARD = re.compile(r'^(?:\w+\()*forward\)*$')
+
+
+def phase_of_op_name(op_name):
+    """The phase an instruction's ``op_name`` (its jax name stack) puts it
+    under. ``engine.builder`` traces the differentiated loss under
+    ``named_scope('forward')``: jax names the forward pass's instructions
+    ``.../jvp(forward)/...`` and its transpose's — the backward —
+    ``.../transpose(jvp(forward))/...``."""
+    for part in op_name.split('/'):
+        if _FORWARD.match(part):
+            return 'backward' if 'transpose(' in part else 'forward'
+        if part in ('update', 'guard'):
+            return part
+    return 'other'
+
+
+def instruction_phases(hlo_text):
+    """``{instruction name: phase}`` for every instruction of a compiled
+    module's text (``Compiled.as_text()``). An instruction's phase is that
+    of its own ``op_name`` together with those of ALL instructions of the
+    computations it calls (a fusion's fused computation, a reduce's
+    reducer): one of ``forward`` / ``backward`` / ``update`` holds it alone,
+    or it is mixed and named by what it holds, in that order
+    (``backward+update``, ``forward+backward``: a ``+`` marks it); with none
+    of the three, ``guard`` if any instruction is the guard's, else
+    ``other``."""
+    own = {}                        # instruction -> (op_name phase, calls)
+    members = collections.defaultdict(list)     # computation -> instructions
+    current = None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                current = c.group(1)
+            continue
+        name = m.group(1)
+        op = _OP_NAME.search(line)
+        own[name] = (phase_of_op_name(op.group(1)) if op else None,
+                     _CALLED.findall(line))
+        members[current].append(name)
+
+    closed = {}                     # computation -> set of phases inside it
+
+    def inside(computation):
+        if computation not in closed:
+            closed[computation] = set().union(
+                *(held(name) for name in members.get(computation, ())))
+        return closed[computation]
+
+    def held(name):             # (HLO computations do not recurse)
+        phase, calls = own[name]
+        return ({phase} if phase else set()).union(
+            *(inside(computation) for computation in calls))
+
+    out = {}
+    for name in own:
+        found = held(name)
+        main = [p for p in _MAIN if p in found]
+        if main:
+            out[name] = '+'.join(main)
+        else:
+            out[name] = 'guard' if 'guard' in found else 'other'
+    return out
+
+
+def phases(program):
+    """The ``{instruction name: phase}`` map kept for ``program`` (an
+    engine train step captured with ``phases=True``), or None."""
+    with _lock:
+        return _phases.get(program)
+
+
+def capture(program, fn, *args, kind='jit', meta=None, phases=False):
     """AOT-lower+compile ``fn`` at ``args``' shapes and ledger the result
     under ``program``. Returns the (possibly pre-existing) entry, or None
     when telemetry is off or the capture failed — a failed capture must
     never fail the program it describes. Idempotent per label: a second
     call is a ledger **hit** (no recompile), so cost numbers are stable
-    across program-cache hits."""
+    across program-cache hits. ``phases=True`` also keeps the phase of
+    every instruction of the compiled module (``phases(program)``)."""
     if not state.enabled():
         return None
     with _lock:
@@ -155,14 +254,26 @@ def capture(program, fn, *args, kind='jit', meta=None):
         events.emit('cost.capture_error', program=str(program),
                     error=repr(e))
         return None
-    return record_compiled(program, compiled, kind=kind, meta=meta)
+    return record_compiled(program, compiled, kind=kind, meta=meta,
+                           phases=phases)
 
 
-def record_compiled(program, compiled, kind='jit', meta=None):
+def record_compiled(program, compiled, kind='jit', meta=None, phases=False):
     """Ledger an already-compiled ``jax.stages.Compiled`` (the AOT-export
     path, or a capture that happened elsewhere)."""
     if not state.enabled():
         return None
+    if phases:
+        try:
+            found = instruction_phases(compiled.as_text())
+        except Exception as e:
+            events.emit('cost.capture_error', program=str(program),
+                        error=repr(e))
+        else:
+            with _lock:
+                _phases[program] = found
+            meta = dict(meta or {}, phase_instructions=dict(
+                collections.Counter(found.values())))
     try:
         flops, bytes_accessed = _cost_scalars(compiled.cost_analysis())
     except Exception:
@@ -282,3 +393,4 @@ def summary():
 def reset():
     with _lock:
         _ledger.clear()
+        _phases.clear()

@@ -556,11 +556,12 @@ def detect_checkpoint_stall(events=None, snapshot=None, cluster=None,
         h = _hist(snapshot, 'checkpoint.save_stall_ms')
         stall_mean, stall_count = float(h.get('mean', 0.0)), \
             int(h.get('count') or 0)
-        for name in ('hapi.step_ms', 'engine.step_ms'):
-            sh = _hist(snapshot, name)
-            if sh.get('count'):
-                step_mean = float(sh.get('mean', 0.0))
-                break
+        # hapi's span holds a whole step; the engine's own histogram
+        # (engine.dispatch_ms) is the enqueue of one and is no step time:
+        # an engine.fit run is judged from its event stream below
+        sh = _hist(snapshot, 'hapi.step_ms')
+        if sh.get('count'):
+            step_mean = float(sh.get('mean', 0.0))
     if (not stall_count or not step_mean) and events:
         # event-stream fallback: synchronous saves' commit time IS their
         # stall; async saves are excluded (their stall is the enqueue)

@@ -2,8 +2,17 @@
 
 ``span(name)`` times a host-side region and records a Chrome trace "complete"
 event (``ph: "X"``, microsecond ``ts``/``dur``) into a bounded in-process
-buffer; ``dump_chrome_trace(path)`` writes the buffer as a JSON array that
+ring; ``dump_chrome_trace(path)`` writes the ring as a JSON array that
 loads directly in Perfetto / chrome://tracing.
+
+One clock: a record's ``t0_ns``/``t1_ns`` are ``time.perf_counter_ns()``
+as it reads (no private epoch; ``ts`` is the same instant in microseconds),
+the clock the benchmark's own spans use, so a reader cuts the program's
+records to any window it timed itself. A record also carries ``span_id``,
+``parent`` (the ``span_id`` of the span open around it on its thread, None
+at top level) and ``step`` (the dispatch number, where the site knows one).
+The ring keeps the NEWEST ``MAX_TRACE_EVENTS`` records: a long run loses
+its oldest spans, counted by ``dropped()``, never its latest.
 
 Besides synchronous spans, the buffer carries **async (flow) events** —
 ``async_begin``/``async_instant``/``async_end`` record nestable Chrome
@@ -16,10 +25,13 @@ verify → completion) reads as a single flow in the merged cluster trace
 
 Two disciplines keep the tracer honest on an async accelerator runtime:
 
-- **device-trace bridging**: while a ``jax.profiler`` trace is active
-  (``utils.profiler.start_profiler``), every span also enters a
-  ``jax.profiler.TraceAnnotation`` so the same region shows up in the xplane
-  dump — one set of annotations, two viewers.
+- **device-trace bridging**: every span enters a
+  ``jax.profiler.TraceAnnotation`` under its name (a span with ``step=``
+  enters the per-step ``StepTraceAnnotation(..., step_num=step)``),
+  telemetry on or off. The profiler's own switch makes that a no-op while
+  no session is open, and puts the region on the host plane of the xplane
+  — on the device trace's clock — while one is, whoever opened it
+  (``jax.profiler.start_trace``, ``utils.profiler.start_profiler``).
 - **sampled sync**: a span wrapping dispatched device work measures only
   host dispatch time unless it blocks. ``span(name, sync=value)`` calls
   ``jax.block_until_ready(value)`` on a *sampled* subset of occurrences (the
@@ -28,6 +40,8 @@ Two disciplines keep the tracer honest on an async accelerator runtime:
   Synced occurrences carry ``args.synced: true`` so readers can tell real
   latencies from dispatch times.
 """
+import collections
+import itertools
 import json
 import os
 import threading
@@ -37,28 +51,16 @@ from . import state
 
 __all__ = ['span', 'Span', 'dump_chrome_trace', 'trace_events',
            'async_begin', 'async_instant', 'async_end',
-           'clear', 'MAX_TRACE_EVENTS']
+           'clear', 'dropped', 'MAX_TRACE_EVENTS']
 
 MAX_TRACE_EVENTS = 65536
 
 _lock = threading.Lock()
-_events = []
+_events = collections.deque(maxlen=MAX_TRACE_EVENTS)
 _dropped = [0]
 _sync_counts = {}
-_EPOCH = time.perf_counter()
-
-
-def _now_us():
-    return (time.perf_counter() - _EPOCH) * 1e6
-
-
-def _device_trace_active():
-    """True while utils.profiler has a jax device trace running."""
-    try:
-        from ..utils import profiler
-        return profiler._active.get('dir') is not None
-    except Exception:
-        return False
+_ids = itertools.count(1)
+_open = threading.local()       # .stack: ids of this thread's open spans
 
 
 def _should_sync(name):
@@ -71,49 +73,63 @@ def _should_sync(name):
     return n % every == 0
 
 
-def _record(name, ts_us, dur_us, args):
-    ev = {'name': name, 'ph': 'X', 'ts': round(ts_us, 3),
-          'dur': round(dur_us, 3), 'pid': os.getpid(),
-          'tid': threading.get_ident()}
-    if args:
-        ev['args'] = args
+def _append(ev):
     with _lock:
-        if len(_events) >= MAX_TRACE_EVENTS:
-            _dropped[0] += 1
-            return
+        if len(_events) == _events.maxlen:
+            _dropped[0] += 1        # the ring drops its oldest record
         _events.append(ev)
+
+
+def _annotation(name, step):
+    """The profiler's annotation for a span: a no-op object while no
+    profiler session is open (None where jax cannot be imported)."""
+    try:
+        from jax import profiler
+    except ImportError:
+        return None
+    if step is None:
+        return profiler.TraceAnnotation(name)
+    return profiler.StepTraceAnnotation(name, step_num=step)
 
 
 class Span:
     """Reentrant-per-instance context manager; use via ``span(name, ...)``.
 
-    The jax.profiler bridge engages whenever a device trace is active —
-    independent of the telemetry switch — so ``utils.profiler.annotate``
-    keeps its xplane contract even with telemetry off; the Chrome-trace
+    ``step``: the dispatch number this span belongs to (kept on the record;
+    the profiler bridge is then a ``StepTraceAnnotation``). ``annotation``:
+    the name the region has in the profiler's trace where that differs from
+    the record's. The bridge is independent of the telemetry switch; the
     record is only kept while telemetry is enabled.
     """
 
-    __slots__ = ('name', 'sync', 'args', '_t0', '_bridge', '_recording')
+    __slots__ = ('name', 'sync', 'args', 'step', 'annotation', '_t0',
+                 '_bridge', '_recording', '_id', '_parent')
 
-    def __init__(self, name, sync=None, **attrs):
+    def __init__(self, name, sync=None, step=None, annotation=None, **attrs):
         self.name = name
         self.sync = sync
+        self.step = step
+        self.annotation = annotation
         self.args = dict(attrs) if attrs else None
-        self._t0 = 0.0
+        self._t0 = 0
         self._bridge = None
         self._recording = False
+        self._id = self._parent = None
 
     def __enter__(self):
         self._recording = state.enabled()
-        if _device_trace_active():
-            try:
-                import jax
-                self._bridge = jax.profiler.TraceAnnotation(self.name)
-                self._bridge.__enter__()
-            except Exception:
-                self._bridge = None
+        self._bridge = _annotation(self.annotation or self.name, self.step)
+        if self._bridge is not None:
+            self._bridge.__enter__()
         if self._recording:
-            self._t0 = _now_us()
+            try:
+                stack = _open.stack
+            except AttributeError:
+                stack = _open.stack = []
+            self._parent = stack[-1] if stack else None
+            self._id = next(_ids)
+            stack.append(self._id)
+            self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
@@ -131,32 +147,38 @@ class Span:
                         self.args['synced'] = True
                 except Exception:
                     pass
-            t1 = _now_us()
-            _record(self.name, self._t0, t1 - self._t0, self.args)
+            t1 = time.perf_counter_ns()
+            stack = _open.stack
+            if stack and stack[-1] == self._id:
+                stack.pop()
+            ev = {'name': self.name, 'ph': 'X', 'ts': self._t0 / 1e3,
+                  'dur': (t1 - self._t0) / 1e3, 'pid': os.getpid(),
+                  'tid': threading.get_ident(), 't0_ns': self._t0,
+                  't1_ns': t1, 'span_id': self._id, 'parent': self._parent,
+                  'step': self.step}
+            if self.args:
+                ev['args'] = self.args
+            _append(ev)
         if self._bridge is not None:
             self._bridge.__exit__(exc_type, exc, tb)
             self._bridge = None
         return False
 
 
-def span(name, sync=None, **attrs):
+def span(name, sync=None, step=None, annotation=None, **attrs):
     """Context manager timing a named host region (see module docstring)."""
-    return Span(name, sync=sync, **attrs)
+    return Span(name, sync=sync, step=step, annotation=annotation, **attrs)
 
 
 def _record_async(ph, name, aid, cat, args):
     if not state.enabled():
         return
     ev = {'name': name, 'ph': ph, 'cat': cat, 'id': str(aid),
-          'ts': round(_now_us(), 3), 'pid': os.getpid(),
+          'ts': time.perf_counter_ns() / 1e3, 'pid': os.getpid(),
           'tid': threading.get_ident()}
     if args:
         ev['args'] = args
-    with _lock:
-        if len(_events) >= MAX_TRACE_EVENTS:
-            _dropped[0] += 1
-            return
-        _events.append(ev)
+    _append(ev)
 
 
 def async_begin(name, aid, cat='async', **args):
@@ -180,6 +202,7 @@ def trace_events():
 
 
 def dropped():
+    """Records the ring has dropped (its oldest) since the last clear()."""
     return _dropped[0]
 
 

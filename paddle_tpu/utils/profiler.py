@@ -109,14 +109,13 @@ profile_scope = profiler
 
 
 def annotate(name):
-    """Named trace region. Shows up in the xplane/TensorBoard dump while a
-    device trace is active (the observability span bridges into
-    ``jax.profiler.TraceAnnotation`` then) AND in the telemetry Chrome trace
+    """Named trace region. Shows up in the xplane/TensorBoard dump of any
+    open profiler session (an observability span always enters
+    ``jax.profiler.TraceAnnotation``) AND in the telemetry Chrome trace
     whenever ``PADDLE_TPU_TELEMETRY=1`` — one annotation, both viewers."""
     from .. import observability as _obs
-    if _active['dir'] is None and not _obs.enabled():
-        # no device trace, no telemetry: keep the raw TraceAnnotation so
-        # user-driven jax.profiler workflows see the region regardless
+    if not _obs.enabled():
+        # nothing to record: the raw annotation the span would enter
         return jax.profiler.TraceAnnotation(name)
     return _obs.span(name)
 

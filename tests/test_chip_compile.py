@@ -15,6 +15,7 @@ lock); JAX's persistent cache is off around them (an entry written for a
 described chip cannot be read back without one).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -190,3 +191,138 @@ def test_partitioned_flash_keeps_its_kernels(topo, monkeypatch, spec,
     assert 'flash_attention.xla' not in text
     assert 'all-gather' not in text
     assert 'bf16%s' % local in text
+
+
+# ---------------------------------------------------------------------------
+# the whole train step at a small width: its phases and its kernels' names
+# ---------------------------------------------------------------------------
+
+_STEP_SEQ, _STEP_BATCH, _STEP_MASKED = 512, 8, 76
+_KERNEL_SCOPES = ('flash_attention.pallas', 'fused_dropout_norm.pallas',
+                  'fused_layer_norm.pallas')
+_CUSTOM_CALL = re.compile(
+    r'^\s+%(\S+) = .*custom_call_target="tpu_custom_call"', re.M)
+_OP_NAMES = re.compile(r'op_name="([^"]*)"')
+
+
+def _small_bert_step_text(topo, monkeypatch, sharded, lowered=False):
+    """One layer of BERT at hidden 128 (2 heads of 64), seq 512 so that
+    attention takes the flash kernels, through ``engine.build_train_step``
+    under bf16 autocast as the benchmark calls it, compiled for one chip of
+    the described topology or (``sharded``) FSDP over its four: the
+    program's text. Nothing can be put on a described device, so the state
+    is shapes carrying the shardings."""
+    import paddle_tpu as paddle
+    from paddle_tpu import amp, engine, optimizer
+    from paddle_tpu.distributed.strategy import ShardingConfig
+    from paddle_tpu.nn.layer_base import buffer_values, param_values
+    from paddle_tpu.text.bert import BertConfig, BertForPretraining
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+
+    def shapes(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda v, s: jax.ShapeDtypeStruct(np.shape(v), v.dtype,
+                                              sharding=s), tree, shardings)
+    paddle.seed(0)
+    net = BertForPretraining(BertConfig(
+        vocab_size=512, hidden_size=128, num_hidden_layers=1,
+        num_attention_heads=2, intermediate_size=256,
+        max_position_embeddings=_STEP_SEQ, hidden_dropout_prob=0.1,
+        attention_probs_dropout_prob=0.1))
+    net.train()
+    sharding = None
+    if sharded:
+        sharding = ShardingConfig(
+            mesh=Mesh(np.asarray(topo.devices).reshape((4,)), ('data',)))
+        monkeypatch.setattr(
+            ShardingConfig, 'device_put_state',
+            lambda self, state, shardings=None: shapes(state, shardings))
+    step = engine.build_train_step(
+        net=net, loss=net.pretraining_loss, sharding=sharding,
+        optimizer=optimizer.AdamW(learning_rate=1e-3, weight_decay=0.01))
+    state = step.init_state(param_values(net), buffer_values(net))
+    if sharded:
+        batch_sh, key_sh = step._batch_sharding, sharding.replicated()
+    else:
+        batch_sh = key_sh = SingleDeviceSharding(topo.devices[0])
+        state = shapes(state, jax.tree_util.tree_map(lambda v: batch_sh,
+                                                     state))
+    rows = [(_STEP_BATCH, _STEP_SEQ)] * 3 + [(_STEP_BATCH, _STEP_MASKED)] * 2 \
+        + [(_STEP_BATCH, 1)]
+    feed = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=batch_sh)
+            for s in rows]
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=key_sh)
+    with amp.auto_cast(dtype='bfloat16'):
+        low = step._jit.lower(state, (tuple(feed[:4]), tuple(feed[4:])), key)
+        if lowered:
+            return low.as_text(debug_info=True)
+        return low.compile().as_text()
+
+
+def _without_provenance(text):
+    """The module's text with what only says where an instruction came
+    from taken out: `metadata={...}`, the header's tables of files and
+    stack frames, and the instructions' own names (the compiler names a
+    custom call after its innermost scope), numbered by first appearance."""
+    blocks = [b for b in text.split('\n\n') if b.split('\n', 1)[0] not in (
+        'FileNames', 'FunctionNames', 'FileLocations', 'StackFrames')]
+    text = re.sub(r',? ?metadata=\{[^}]*\}', '', '\n\n'.join(blocks))
+    names = {}
+    return re.sub(r'%[\w.\-]+', lambda m: names.setdefault(
+        m.group(0), '%%n%d' % len(names)), text)
+
+
+def test_step_keeps_its_phases_and_its_kernels_names(topo, monkeypatch):
+    """The step's scopes reach the chip's compiler: `forward`, its transpose
+    and `update` stand in the instructions' `op_name`, the Pallas custom
+    calls are named after their kernels, and the scopes changed nothing
+    else: with provenance taken out the program is the unscoped one's."""
+    import contextlib
+    from paddle_tpu.engine import builder
+    from paddle_tpu.observability import costs
+    text = _small_bert_step_text(topo, monkeypatch, sharded=False)
+    op_names = _OP_NAMES.findall(text)
+    for scope in ('/jvp(forward)/', '/transpose(jvp(forward))/', '/update/'):
+        assert any(scope in n for n in op_names), scope
+    calls = _CUSTOM_CALL.findall(text)
+    # forward and backward of the flash and dropout-norm kernels, the
+    # embedding and head layer norms
+    assert len(calls) >= 7
+    assert all(any(c.startswith(s) for s in _KERNEL_SCOPES) for c in calls)
+    assert {s for s in _KERNEL_SCOPES
+            if any(c.startswith(s) for c in calls)} == set(_KERNEL_SCOPES)
+    phases = costs.instruction_phases(text)
+    assert {phases[c] for c in calls} == {'forward', 'backward'}
+    assert 'backward+update' in phases.values()     # XLA fuses them: said
+
+    class NoScopes:                 # the builder's scopes alone, taken out
+        named_scope = staticmethod(lambda name: contextlib.nullcontext())
+
+        def __getattr__(self, name):
+            return getattr(jax, name)
+    monkeypatch.setattr(builder, 'jax', NoScopes())
+    unscoped = _small_bert_step_text(topo, monkeypatch, sharded=False)
+    assert not any('jvp(forward)' in n for n in _OP_NAMES.findall(unscoped))
+    assert _without_provenance(text) == _without_provenance(unscoped)
+
+
+def test_partitioned_step_names_its_kernels_and_its_gathers(topo,
+                                                            monkeypatch):
+    """FSDP over the four chips: inside `shard_map` the custom calls keep
+    their kernels' names (a device trace is read by them), and the
+    use-time gathers of the sharded parameters carry `fsdp.gather`. The
+    carry's reshard is in the traced program (`fsdp.reshard`) and compiles
+    to no instruction of its own: replicated to sharded is a slice, which
+    the partitioner folds into the update that reads it."""
+    text = _small_bert_step_text(topo, monkeypatch, sharded=True)
+    calls = _CUSTOM_CALL.findall(text)
+    assert len(calls) >= 7
+    assert all(any(c.startswith(s) for s in _KERNEL_SCOPES) for c in calls)
+    assert not re.search(r'^\s+%shard_map[.\d]* = ', text, re.M)
+    gathers = re.findall(r'^\s+%\S+ = \S+ all-gather(?:-start)?\(.*', text,
+                         re.M)
+    named = [g for g in gathers if 'fsdp.gather/' in g]
+    assert len(named) >= 10, len(gathers)
+    lowered = _small_bert_step_text(topo, monkeypatch, sharded=True,
+                                    lowered=True)
+    assert 'fsdp.reshard' in lowered and 'fsdp.gather' in lowered
