@@ -13,7 +13,9 @@ a user calls:
                 key-padding bias) at seq 512, and the in-kernel hardware-PRNG
                 dropout (flash attention and fused dropout+add+LayerNorm):
                 same seed -> same output, other seed -> other output, finite
-                gradients. Interpret mode stubs that PRNG, so only a chip
+                gradients; the dropout-on flash backward against finite
+                differences of its own forward along a direction of q, k
+                and v. Interpret mode stubs that PRNG, so only a chip
                 checks it.
 - ``train``     ``BertForPretraining`` through ``engine.build_train_step(
                 net=, loss=, optimizer=AdamW)``, bf16 compute
@@ -128,11 +130,13 @@ def phase_kernels(size, rehearsal):
     t0 = time.perf_counter()
     errs = checks.check_flash_against_reference(size['kernel_shape'],
                                                 interpret=rehearsal)
+    dropout_backward = None
     if not rehearsal:   # interpret mode has no hardware PRNG
         checks.check_flash_dropout()
         checks.check_norm_dropout()
+        dropout_backward = checks.check_flash_dropout_backward()
     say('kernels', shape=list(size['kernel_shape']), max_abs_err=errs,
-        dropout_checked=not rehearsal,
+        dropout_checked=not rehearsal, dropout_backward=dropout_backward,
         seconds=round(time.perf_counter() - t0, 2))
 
 

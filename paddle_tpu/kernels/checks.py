@@ -15,6 +15,16 @@ from .flash_attention import _attn_reference, flash_attention_bhld
 from .fused_dropout_norm import fused_dropout_add_layer_norm
 
 
+def _padding_bias(b, L, half_of_last=False):
+    """(b, L) additive key-padding bias: the last fifth of row 0's keys
+    (and, asked for, the last half of the last row's) are padding."""
+    keep = np.ones((b, L), bool)
+    keep[0, L - L // 5:] = False
+    if half_of_last:
+        keep[-1, L // 2:] = False
+    return jnp.where(jnp.asarray(keep), 0.0, -1e4).astype(jnp.float32)
+
+
 def _check_hw_dropout(what, fn, x):
     """``fn(x, seed)`` draws its mask from the TPU hardware PRNG: it must be
     deterministic under a fixed seed, seed-sensitive, and differentiable
@@ -40,6 +50,56 @@ def check_flash_dropout(shape=(1, 4, 512, 64), interpret=False):
         block_q=256, block_k=256, interpret=interpret), q)
 
 
+def check_flash_dropout_backward(shape=(2, 16, 512, 64), dropout_p=0.1,
+                                 interpret=False):
+    """The dropout-ON backward against finite differences of its own
+    forward. Forward and backward each rebuild the keep mask from the
+    hardware PRNG; no reference can follow it, and a backward that rebuilt
+    another mask than the forward's would still be finite and
+    deterministic. Under a fixed seed the loss is a smooth function of q,
+    k and v, so for a direction u of each, ``(loss(x + eps u) - loss(x -
+    eps u)) / 2 eps`` must equal ``<grad, u>``: fp32 operands and fp32
+    matmuls (``highest``), a key-padding bias, the default blocks. Returns
+    ``{name: (finite difference, <grad, u>)}``; raises where they differ
+    by over 1% (fp32 rounding of the loss reads 1e-4 on the chip)."""
+    eps, tol = 1e-2, 1e-2
+    b, h, L, d = shape
+    q, k, v = make_device_qkv(b, h, L, d, jnp.float32)
+    u = make_device_qkv(b, h, L, d, jnp.float32, seed=1)
+    weight = make_device_qkv(b, h, L, d, jnp.float32, seed=2)[0]
+    bias = _padding_bias(b, L)
+    seed = jnp.array([[1234]], jnp.int32)
+
+    def loss(q, k, v):
+        o = flash_attention_bhld(q, k, v, kpad_bias=bias,
+                                 dropout_p=dropout_p, dropout_seed=seed,
+                                 interpret=interpret)
+        return jnp.sum(o * weight)
+
+    @jax.jit
+    def readings(q, k, v):
+        grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        out = []
+        for i, (g, ui) in enumerate(zip(grads, u)):
+            def at(t):
+                x = [q, k, v]
+                x[i] = x[i] + t * ui
+                return loss(*x)
+            out.append(((at(eps) - at(-eps)) / (2 * eps), jnp.sum(g * ui)))
+        return out
+
+    with jax.default_matmul_precision('highest'):
+        got = {n: (float(fd), float(an))
+               for n, (fd, an) in zip('qkv', readings(q, k, v))}
+    for n, (fd, an) in got.items():
+        if not abs(fd - an) <= tol * max(abs(fd), abs(an)):
+            raise AssertionError(
+                'flash dropout backward: d%s along a direction reads %g, '
+                'finite differences of the forward %g: the backward does '
+                'not differentiate the forward it ran with' % (n, an, fd))
+    return got
+
+
 def check_norm_dropout(rows=1024, hidden=1024, interpret=False):
     """The same checks for the fused dropout+add+LayerNorm kernel."""
     kx, kr = jax.random.split(jax.random.PRNGKey(0))
@@ -57,12 +117,7 @@ def check_flash_against_reference(shape, interpret=False):
     and non-causal with a key-padding bias. Returns the max abs errors."""
     b, h, L, d = shape
     q, k, v = make_device_qkv(b, h, L, d, jnp.bfloat16)
-    # the last fifth of row 0's keys and the last half of the last row's
-    # are padding
-    keep = np.ones((b, L), bool)
-    keep[0, L - L // 5:] = False
-    keep[-1, L // 2:] = False
-    bias = jnp.where(jnp.asarray(keep), 0.0, -1e4).astype(jnp.float32)
+    bias = _padding_bias(b, L, half_of_last=True)
     errs = {}
     for name, causal, kpad in (('causal', True, None),
                                ('key_padding', False, bias)):
@@ -91,9 +146,7 @@ def check_partitioned(mesh, axis, shape=(8, 16, 512, 64), hidden=1024,
     Returns the max abs differences."""
     b, h, L, d = shape
     q, k, v = make_device_qkv(b, h, L, d, jnp.bfloat16)
-    keep = np.ones((b, L), bool)
-    keep[0, L - L // 5:] = False
-    bias = jnp.where(jnp.asarray(keep), 0.0, -1e4).astype(jnp.float32)
+    bias = _padding_bias(b, L)
     kx, kr = jax.random.split(jax.random.PRNGKey(1))
     x = jax.random.normal(kx, (b * L, hidden), jnp.bfloat16)
     res = jax.random.normal(kr, (b * L, hidden), jnp.bfloat16)

@@ -4,9 +4,14 @@ Replaces the reference's fused attention CUDA path
 (paddle/fluid/operators/fused/*attention*). Online-softmax tiling keeps the
 (L, L) score matrix out of HBM in both directions: the forward streams K/V
 tiles against resident Q tiles and saves only O and the per-row logsumexp;
-the backward recomputes probability tiles from (q, k, lse) on the fly inside
-two kernels (dQ: grid over Q tiles; dK/dV: grid over K tiles), so no (L, L)
-matrix is ever materialized.
+the backward is ONE kernel (grid over K tiles, loop over Q tiles): per tile
+pair it recomputes the probabilities from (q, k, lse), the dropout mask and
+dP once and takes dV, dK and dQ from them, five matmuls for the four the
+mathematics needs plus the recomputed scores. delta = rowsum(dO * O) is made
+inside it from O. With one K tile (every key-padding call) a Q tile's dQ is
+whole inside the instance and written straight out; otherwise it sums over the
+grid's K axis in an fp32 VMEM scratch and is cast at the last K tile. No
+(L, L) matrix is ever materialized.
 
 Features:
 - causal and non-causal attention;
@@ -15,13 +20,14 @@ Features:
 - attention-probability dropout INSIDE the kernel: the keep-mask for tile
   (bh, q_block, k_block) is regenerated from the TPU hardware PRNG
   (pltpu.prng_seed keyed on the tile coordinates) identically in the forward
-  and both backward kernels, so no (L, L) mask is stored.
+  and the backward kernel, so no (L, L) mask is stored.
 
 The non-dropout kernels accept interpret=True so their numerics are testable
 on the CPU backend (tests/test_flash_attention.py); the interpret emulation of
 prng_random_bits is a zero-stub, so the dropout path is validated on real TPU
-hardware (tests marked tpu-only + finite-difference check in
-tests/test_flash_attention.py::test_flash_dropout_*).
+hardware (tests marked tpu-only in tests/test_flash_attention.py; on the
+chip, ``checks.check_flash_dropout_backward`` holds the dropout-on backward
+to finite differences of its forward).
 
 On non-TPU backends the public entry point takes plain-XLA attention with
 identical semantics (dropout there uses jax.random — same distribution,
@@ -34,6 +40,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ._common import (pallas_runs, spmd_kernel,
                       tile_keep_scale as _tile_keep_scale, took)
@@ -235,86 +242,33 @@ def _flash_forward(q, k, v, kpad_bias, seed, causal, scale, block_q, block_k,
 # backward
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p,
-               heads):
-    refs = list(refs)
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
-    idx = 6
-    bias_ref = seed_ref = None
-    if has_bias:
-        bias_ref = refs[idx]; idx += 1
-    if dropout_p > 0.0:
-        seed_ref = refs[idx]; idx += 1
-    dq_ref = refs[idx]
-
-    q = q_ref[0]                                        # (block_q, d) native
-    do = do_ref[0]                                      # (block_q, d) native
-    lse = lse_ref[0].astype(jnp.float32)                # (block_q, 1)
-    delta = delta_ref[0].astype(jnp.float32)            # (block_q, 1)
-    block_q = q.shape[0]
-    q_blk = pl.program_id(1)
-    q_offset = q_blk * block_q
-
-    if causal:
-        n_blocks = (q_offset + block_q + block_k - 1) // block_k
-        # clamp-then-divide: Mosaic // truncates, floor needed on negatives
-        n_full = jnp.maximum(q_offset + 1 - block_k, 0) // block_k
-        n_full = jnp.where(q_offset + 1 >= block_k, n_full + 1, 0)
-    else:
-        n_blocks = seq_len // block_k
-        n_full = n_blocks
-
-    def make_body(masked):
-        def body(i, dq_acc):
-            k_tile = k_ref[0, pl.dslice(i * block_k, block_k), :]
-            v_tile = v_ref[0, pl.dslice(i * block_k, block_k), :]
-            bias_tile = None
-            if bias_ref is not None:
-                bias_tile = bias_ref[0, :, pl.dslice(i * block_k, block_k)
-                                     ].astype(jnp.float32)  # (1, block_k)
-            s = _score_tile(q, k_tile, bias_tile, masked, q_offset,
-                            i * block_k, scale)
-            p = jnp.exp(s - lse)                        # (block_q, block_k)
-            dp = jnp.dot(do, v_tile.T, preferred_element_type=jnp.float32)
-            if dropout_p > 0.0:
-                nq, nk = seq_len // block_q, seq_len // block_k
-                tile_id = (_global_bh(seed_ref, heads) * nq + q_blk) * nk + i
-                dp = dp * _tile_keep_scale(seed_ref, tile_id, dp.shape,
-                                           dropout_p)
-            ds = p * (dp - delta)
-            return dq_acc + jnp.dot(ds.astype(k_tile.dtype), k_tile,
-                                    preferred_element_type=jnp.float32)
-        return body
-
-    zero_dq = jnp.zeros((block_q, q.shape[1]), jnp.float32)
-    dq = jax.lax.fori_loop(0, n_full, make_body(False), zero_dq)
-    if causal:
-        dq = jax.lax.fori_loop(n_full, n_blocks, make_body(True), dq)
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
+def _bwd_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
                 heads):
+    """dQ, dK and dV of one (head, K tile) from ONE pass over its score
+    tiles. Grid (b*h, K tiles); the loop runs over the Q tiles."""
     refs = list(refs)
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = refs[:6]
     idx = 6
     bias_ref = seed_ref = None
     if has_bias:
         bias_ref = refs[idx]; idx += 1
     if dropout_p > 0.0:
         seed_ref = refs[idx]; idx += 1
-    dk_ref, dv_ref = refs[idx:idx + 2]
+    dq_ref, dk_ref, dv_ref = refs[idx:idx + 3]
 
     k = k_ref[0]                                        # (block_k, d) native
     v = v_ref[0]
     block_k = k.shape[0]
+    nq, nk = seq_len // block_q, seq_len // block_k
+    # one K tile (every key-padding call): a Q tile's dQ is whole inside
+    # this instance. More: it sums over the grid's K axis, in fp32
+    dq_acc = refs[idx + 3] if nk > 1 else None
     k_blk = pl.program_id(1)
     k_offset = k_blk * block_k
     bias_tile = None
     if bias_ref is not None:
         bias_tile = bias_ref[0].astype(jnp.float32)     # (1, block_k)
 
-    n_q_blocks = seq_len // block_q
     if causal:
         start = k_offset // block_q
         # q tiles whose every row >= every col of this k tile are unmasked:
@@ -324,59 +278,88 @@ def _dkv_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
         start = 0
         start_full = 0
 
+    if dq_acc is not None:
+        @pl.when(k_blk == 0)
+        def _():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
     def make_body(masked):
         def body(i, carry):
             dk_acc, dv_acc = carry
-            q_tile = q_ref[0, pl.dslice(i * block_q, block_q), :]
-            do_tile = do_ref[0, pl.dslice(i * block_q, block_q), :]
-            lse = lse_ref[0, pl.dslice(i * block_q, block_q), :
-                          ].astype(jnp.float32)         # (block_q, 1)
-            delta = delta_ref[0, pl.dslice(i * block_q, block_q), :
-                              ].astype(jnp.float32)     # (block_q, 1)
+            rows = pl.dslice(i * block_q, block_q)
+            q_tile = q_ref[0, rows, :]
+            do_tile = do_ref[0, rows, :]
+            lse = lse_ref[0, rows, :].astype(jnp.float32)   # (block_q, 1)
+            delta = jnp.sum(do_tile.astype(jnp.float32)
+                            * o_ref[0, rows, :].astype(jnp.float32),
+                            axis=-1, keepdims=True)         # (block_q, 1)
             s = _score_tile(q_tile, k, bias_tile, masked, i * block_q,
                             k_offset, scale)
             p = jnp.exp(s - lse)                        # (block_q, block_k)
             p_drop = p
             dp = jnp.dot(do_tile, v.T, preferred_element_type=jnp.float32)
             if dropout_p > 0.0:
-                nq, nk = seq_len // block_q, seq_len // block_k
                 tile_id = (_global_bh(seed_ref, heads) * nq + i) * nk + k_blk
                 keep_scale = _tile_keep_scale(seed_ref, tile_id, p.shape,
                                               dropout_p)
                 p_drop = p * keep_scale
                 dp = dp * keep_scale
-            dv_acc = dv_acc + jnp.dot(p_drop.T.astype(do_tile.dtype), do_tile,
+            # cast, then transpose: half the bytes through the transpose
+            # unit, the same numbers
+            dv_acc = dv_acc + jnp.dot(p_drop.astype(do_tile.dtype).T, do_tile,
                                       preferred_element_type=jnp.float32)
-            ds = p * (dp - delta)
-            dk_acc = dk_acc + jnp.dot(ds.T.astype(q_tile.dtype), q_tile,
+            ds = (p * (dp - delta)).astype(q_tile.dtype)
+            dk_acc = dk_acc + jnp.dot(ds.T, q_tile,
                                       preferred_element_type=jnp.float32)
+            dq = jnp.dot(ds, k, preferred_element_type=jnp.float32)
+            if dq_acc is None:
+                dq_ref[0, rows, :] = (dq * scale).astype(dq_ref.dtype)
+            else:
+                dq_acc[rows, :] += dq
             return dk_acc, dv_acc
         return body
 
     zero = jnp.zeros((block_k, k.shape[1]), jnp.float32)
     if causal:
-        bound = jnp.minimum(jnp.maximum(start_full, start), n_q_blocks)
+        bound = jnp.minimum(jnp.maximum(start_full, start), nq)
         dk, dv = jax.lax.fori_loop(start, bound, make_body(True),
                                    (zero, zero))
-        dk, dv = jax.lax.fori_loop(bound, n_q_blocks, make_body(False),
-                                   (dk, dv))
+        dk, dv = jax.lax.fori_loop(bound, nq, make_body(False), (dk, dv))
     else:
-        dk, dv = jax.lax.fori_loop(start, n_q_blocks, make_body(False),
-                                   (zero, zero))
+        dk, dv = jax.lax.fori_loop(start, nq, make_body(False), (zero, zero))
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+    if dq_acc is not None:
+        @pl.when(k_blk == nk - 1)
+        def _():
+            dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _bwd_vmem_limit(L, d, bq, bk, itemsize):
+    """The backward kernel keeps a head's whole Q, O, dO, lse and dQ in
+    VMEM (double-buffered, the minor dim padded to 128 lanes) beside its
+    K-tile blocks and a few (bq, bk) fp32 temporaries. While that fits the
+    16 MiB Mosaic gives a kernel unasked (every tiling to L = 1024, most
+    to 2048) the limit is left alone: None. Past it, ask for what the
+    blocks need and half as much again; the compiler refuses what the chip
+    cannot give."""
+    lanes = -(-d // 128) * 128
+    need = (2 * 4 * L * lanes * itemsize        # q, o, do in; dq out
+            + 2 * L * 128 * 4                   # lse (L, 1) fp32
+            + (L * lanes * 4 if bk < L else 0)  # dq's fp32 scratch
+            + 2 * 4 * bk * lanes * itemsize     # k, v in; dk, dv out
+            + 2 * bq * bk * 4)                  # two live fp32 score tiles
+    return None if need <= (14 << 20) else need * 3 // 2
 
 
 def _flash_backward(q, k, v, o, lse, kpad_bias, seed, g, causal, scale,
                     block_q, block_k, dropout_p, interpret):
     L, d = q.shape[2:]
     bq, bk = min(block_q, L), min(block_k, L)
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)             # (B, H, L, 1)
+    nk = L // bk
     has_bias = kpad_bias is not None
-    bhl1 = ('b', 'h', 'l', None)
-    args = [q, k, v, g, lse[..., None], delta]
-    dims = [_BHLD] * 4 + [bhl1] * 2
+    args = [q, k, v, o, g, lse[..., None]]
+    dims = [_BHLD] * 5 + [('b', 'h', 'l', None)]
     if has_bias:
         args.append(kpad_bias.astype(jnp.float32)[:, None, :])  # (B,1,L)
         dims.append(('b', None, 'l'))
@@ -390,45 +373,37 @@ def _flash_backward(q, k, v, o, lse, kpad_bias, seed, g, causal, scale,
                 ] + list(args[6:])
         if dropout_p > 0.0:
             args[-1] = _seed_and_shard(args[-1], shard)
-        static = dict(seq_len=L, causal=causal, scale=scale,
-                      has_bias=has_bias, dropout_p=dropout_p,
-                      heads=(h, shard['h'][1]))
+        kernel = functools.partial(
+            _bwd_kernel, block_q=bq, seq_len=L, causal=causal, scale=scale,
+            has_bias=has_bias, dropout_p=dropout_p,
+            heads=(h, shard['h'][1]))
 
-        tile_qd = pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0))
-        tile_q1 = pl.BlockSpec((1, bq, 1), lambda bh, i: (bh, i, 0))
-        full_ld = pl.BlockSpec((1, L, d), lambda bh, i: (bh, 0, 0))
-        full_l1 = pl.BlockSpec((1, L, 1), lambda bh, i: (bh, 0, 0))
-        bias_full = pl.BlockSpec((1, 1, L), lambda bh, i: (bh // h, 0, 0))
-        seed_spec = pl.BlockSpec((1, 3), lambda bh, i: (0, 0))
-
-        dq_in = [tile_qd, full_ld, full_ld, tile_qd, tile_q1, tile_q1]
-        if has_bias:
-            dq_in.append(bias_full)
-        if dropout_p > 0.0:
-            dq_in.append(seed_spec)
-        dq = pl.pallas_call(
-            functools.partial(_dq_kernel, block_k=bk, **static),
-            grid=(b * h, L // bq),
-            in_specs=dq_in,
-            out_specs=tile_qd,
-            out_shape=jax.ShapeDtypeStruct((b * h, L, d), q.dtype),
-            interpret=interpret,
-        )(*args)
-
+        full_ld = pl.BlockSpec((1, L, d), lambda bh, j: (bh, 0, 0))
         tile_kd = pl.BlockSpec((1, bk, d), lambda bh, j: (bh, j, 0))
-        bias_tile = pl.BlockSpec((1, 1, bk), lambda bh, j: (bh // h, 0, j))
-        dkv_in = [full_ld, tile_kd, tile_kd, full_ld, full_l1, full_l1]
+        in_specs = [full_ld, tile_kd, tile_kd, full_ld, full_ld,
+                    pl.BlockSpec((1, L, 1), lambda bh, j: (bh, 0, 0))]
         if has_bias:
-            dkv_in.append(bias_tile)
+            in_specs.append(
+                pl.BlockSpec((1, 1, bk), lambda bh, j: (bh // h, 0, j)))
         if dropout_p > 0.0:
-            dkv_in.append(seed_spec)
-        dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel, block_q=bq, **static),
-            grid=(b * h, L // bk),
-            in_specs=dkv_in,
-            out_specs=(tile_kd, tile_kd),
-            out_shape=(jax.ShapeDtypeStruct((b * h, L, d), k.dtype),
+            in_specs.append(pl.BlockSpec((1, 3), lambda bh, j: (0, 0)))
+        # dQ's block is the head's whole (L, d) at every K tile: it stays in
+        # VMEM along that axis (which must therefore run in order) and goes
+        # out once
+        dq, dk, dv = pl.pallas_call(
+            kernel,
+            grid=(b * h, nk),
+            in_specs=in_specs,
+            out_specs=(full_ld, tile_kd, tile_kd),
+            out_shape=(jax.ShapeDtypeStruct((b * h, L, d), q.dtype),
+                       jax.ShapeDtypeStruct((b * h, L, d), k.dtype),
                        jax.ShapeDtypeStruct((b * h, L, d), v.dtype)),
+            scratch_shapes=([pltpu.VMEM((L, d), jnp.float32)] if nk > 1
+                            else []),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=('parallel', 'arbitrary'),
+                vmem_limit_bytes=_bwd_vmem_limit(L, d, bq, bk,
+                                                 q.dtype.itemsize)),
             interpret=interpret,
         )(*args)
         return tuple(t.reshape(b, h, L, d) for t in (dq, dk, dv))
@@ -485,10 +460,10 @@ def flash_attention_bhld(q, k, v, causal=False, scale=None, kpad_bias=None,
     L = q.shape[2]
     dropout_p = float(dropout_p)
     if kpad_bias is not None:
-        # the fwd/dq kernels stream bias columns with an in-kernel dynamic
+        # the forward kernel streams bias columns with an in-kernel dynamic
         # slice of the minor dim, which Mosaic cannot lower for block_k < L;
         # key-padding attention is non-causal and reads every K anyway, so
-        # stream the full row
+        # stream the full row (one K tile: the backward writes dQ directly)
         block_k = L
     usable = (pallas_runs(interpret) and k.shape[2] == L
               and L % min(block_q, L) == 0 and L % min(block_k, L) == 0)

@@ -172,9 +172,10 @@ def test_partitioned_norms_keep_their_kernels(topo, monkeypatch):
 def test_partitioned_flash_keeps_its_kernels(topo, monkeypatch, spec,
                                              mesh_shape, axes, local):
     """Flash attention with batch (FSDP) and batch x heads (FSDP x TP)
-    sharded over the 2x2 chips: forward, dQ and dK/dV kernels on each
-    chip's (batch, heads) block, key-padding bias and in-kernel dropout
-    included."""
+    sharded over the 2x2 chips: the forward kernel and the one backward
+    kernel (dQ, dK and dV from one pass over the score tile) on each chip's
+    (batch, heads) block, key-padding bias and in-kernel dropout included,
+    both named after their scope (a device trace is read by that name)."""
     L = 512
     qkv = ((B, H, L, D), jnp.bfloat16)
 
@@ -187,7 +188,9 @@ def test_partitioned_flash_keeps_its_kernels(topo, monkeypatch, spec,
         [spec, spec, spec, P(spec[0]), P()],
         [qkv, qkv, qkv, ((B, L), jnp.float32), _SEED],
         axes=axes, mesh_shape=mesh_shape)
-    assert text.count('tpu_custom_call') >= 3
+    calls = _CUSTOM_CALL.findall(text)
+    assert len(calls) == 2, calls
+    assert all(c.startswith('flash_attention.pallas') for c in calls)
     assert 'flash_attention.xla' not in text
     assert 'all-gather' not in text
     assert 'bf16%s' % local in text
@@ -293,6 +296,10 @@ def test_step_keeps_its_phases_and_its_kernels_names(topo, monkeypatch):
             if any(c.startswith(s) for c in calls)} == set(_KERNEL_SCOPES)
     phases = costs.instruction_phases(text)
     assert {phases[c] for c in calls} == {'forward', 'backward'}
+    # a layer's attention: the forward kernel and ONE backward kernel
+    assert sorted(phases[c] for c in calls
+                  if c.startswith('flash_attention.pallas')) == [
+                      'backward', 'forward']
     assert 'backward+update' in phases.values()     # XLA fuses them: said
 
     class NoScopes:                 # the builder's scopes alone, taken out

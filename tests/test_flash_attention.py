@@ -46,17 +46,41 @@ def test_flash_forward_parity(causal, with_bias):
                                rtol=2e-5, atol=2e-5)
 
 
+# (block_q, block_k) at L = 128: the one fused backward kernel writes dQ
+# straight out when there is one K tile (nk == 1; a key-padding bias pins
+# block_k to L, so every biased case is one) and sums it over the grid's K
+# axis in an fp32 scratch otherwise
+_BWD_BLOCKS = [(128, 128), (64, 128), (64, 64), (32, 64)]
+_BWD_IDS = ['nq1_nk1', 'nq2_nk1', 'nq2_nk2', 'nq4_nk2']
+
+
+def _grad_close(got, want, dtype, name):
+    got = np.asarray(got.astype(jnp.float32))
+    want = np.asarray(want)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, rtol=5e-4, atol=5e-5,
+                                   err_msg=f"d{name} mismatch")
+    else:   # bf16 operands into the MXU and a bf16 result, fp32 between
+        rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)
+        assert rel < 2e-2, f"d{name} rel diff {rel}"
+
+
 @pytest.mark.skipif(jax.default_backend() == "tpu",
                     reason="interpret emulation is CPU-validation only")
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=['fp32', 'bf16'])
+@pytest.mark.parametrize("blocks", _BWD_BLOCKS, ids=_BWD_IDS)
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("with_bias", [False, True])
-def test_flash_backward_parity(causal, with_bias):
-    q, k, v = _inputs(2)
+def test_flash_backward_parity(causal, with_bias, blocks, dtype):
+    q, k, v = (t.astype(dtype) for t in _inputs(2))
     bias = _kpad(3) if with_bias else None
+    bq, bk = blocks
 
     def flash_loss(q, k, v):
         o = flash_attention_bhld(q, k, v, causal=causal, kpad_bias=bias,
-                                 block_q=BQ, block_k=BK, interpret=True)
+                                 block_q=bq, block_k=bk, interpret=True)
+        o = o.astype(jnp.float32)
         return jnp.sum(o * jnp.cos(o))  # non-trivial cotangent
 
     def ref_loss(q, k, v):
@@ -64,11 +88,11 @@ def test_flash_backward_parity(causal, with_bias):
         return jnp.sum(o * jnp.cos(o))
 
     g_flash = jax.grad(flash_loss, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(ref_loss, argnums=(0, 1, 2))(
+        *(t.astype(jnp.float32) for t in (q, k, v)))
     for a, b, name in zip(g_flash, g_ref, 'qkv'):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=5e-4, atol=5e-5,
-                                   err_msg=f"d{name} mismatch")
+        assert a.dtype == dtype
+        _grad_close(a, b, dtype, name)
 
 
 def test_flash_uneven_blocks_falls_back():
@@ -82,20 +106,70 @@ def test_flash_uneven_blocks_falls_back():
                                atol=2e-5)
 
 
-def test_flash_fully_masked_rows_zero_grads():
-    # batch entry with ALL keys masked: output 0, grads finite (not NaN)
-    q, k, v = _inputs(4)
-    bias = jnp.full((B, L), -1e9, jnp.float32)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=['fp32', 'bf16'])
+@pytest.mark.parametrize("block_q", [128, 64], ids=['nq1', 'nq2'])
+@pytest.mark.parametrize("fill", [-1e9, -np.inf],
+                         ids=['minus_1e9', 'minus_inf'])
+def test_flash_fully_masked_rows_zero_grads(fill, block_q, dtype):
+    """Batch entry 0 has ALL its keys masked. With a finite bias its rows
+    are a softmax over equal scores; with -inf the forward leaves
+    ``LSE_EMPTY`` for them and the backward's probabilities are exp(-inf):
+    output and gradients of that entry are 0, never NaN, and the other
+    entry's still equal the reference's."""
+    q, k, v = (t.astype(dtype) for t in _inputs(4))
+    bias = np.zeros((B, L), np.float32)
+    bias[0, :] = fill
+    bias[1, L - 40:] = fill
+    bias = jnp.asarray(bias)
 
     def loss(q, k, v):
         o = flash_attention_bhld(q, k, v, causal=False, kpad_bias=bias,
-                                 block_q=BQ, block_k=BK, interpret=True)
-        return jnp.sum(o ** 2)
+                                 block_q=block_q, block_k=BK, interpret=True)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
 
     val, grads = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
     assert np.isfinite(float(val))
     for g in grads:
-        assert np.all(np.isfinite(np.asarray(g)))
+        assert np.all(np.isfinite(np.asarray(g.astype(jnp.float32))))
+
+    def ref_loss(q, k, v):   # the entry that has keys left
+        o = _attn_reference(q, k, v, False, 1.0 / np.sqrt(D), bias[1:])
+        return jnp.sum(o ** 2)
+
+    want = jax.grad(ref_loss, argnums=(0, 1, 2))(
+        *(t[1:].astype(jnp.float32) for t in (q, k, v)))
+    for g, w, name in zip(grads, want, 'qkv'):
+        _grad_close(g[1:], w, dtype, name)
+        if fill == -np.inf:
+            assert not np.asarray(g[0].astype(jnp.float32)).any()
+
+
+@pytest.mark.parametrize("broken", [None, 'q', 'k', 'v'],
+                         ids=['sound', 'dq_off', 'dk_off', 'dv_off'])
+def test_directional_check_holds_the_backward_to_its_forward(monkeypatch,
+                                                             broken):
+    """``checks.check_flash_dropout_backward`` (the chip runs it with the
+    hardware PRNG's dropout on; here dropout off, interpret mode): the
+    kernels' gradients pass, a backward with one gradient 5% off is
+    refused."""
+    from paddle_tpu.kernels import checks, flash_attention as fa
+
+    def check():
+        return checks.check_flash_dropout_backward(
+            (2, 2, 128, 16), dropout_p=0.0, interpret=True)
+    if not broken:
+        got = check()
+        assert set(got) == set('qkv')
+        for fd, an in got.values():
+            assert abs(fd - an) < 2e-3 * abs(an)
+    else:
+        sound = fa._flash_backward
+        monkeypatch.setattr(fa, '_flash_backward', lambda *args: tuple(
+            g * 1.05 if n == broken else g
+            for n, g in zip('qkv', sound(*args))))
+        with pytest.raises(AssertionError, match='d%s along' % broken):
+            check()
 
 
 @pytest.mark.skipif(jax.default_backend() != 'tpu',
