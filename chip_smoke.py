@@ -27,6 +27,15 @@ a user calls:
                 the Pallas kernels (``tpu_custom_call`` under the
                 ``flash_attention.pallas`` scope, nothing under
                 ``flash_attention.xla``).
+- ``hybrid``    the cut Kimi-Linear configuration of the benchmark (5 layers
+                at the published widths: delta-rule linear attention, NoPE
+                latent attention through the flash kernels with 192-wide
+                q/k, 128-wide v and the document mask, a dense SwiGLU layer
+                and four expert layers holding 8 of 256 experts; 602M
+                parameters) through the same ``build_train_step``: a few
+                steps on one batch of packed rows of 8192 tokens. Loss
+                finite and falling, no compile after the first step, no
+                routing assignment dropped, the flash kernels in the step.
 - ``serve``     the trained BERT-large encoder behind ``ServingEngine.
                 register(layer=, example=, bucket_spec=)``: requests of mixed
                 lengths through ``submit``, all ``ok``, no compile after
@@ -66,6 +75,15 @@ FULL = dict(
             max_batch=4, prompt_buckets=(16, 64)),
     lm_prompts=(7, 7, 33, 33), lm_new_tokens=3,
     kernel_shape=(2, 16, 512, 64),
+    hybrid=dict(                            # benchmark/configs/kimi-linear-*
+        config=dict(vocab_size=20480, hidden_size=2304, num_hidden_layers=5,
+                    num_attention_heads=32, head_dim=128,
+                    intermediate_size=9216, moe_intermediate_size=1024,
+                    num_experts=256, num_experts_per_token=8,
+                    experts_held=(0, 8), kv_lora_rank=512,
+                    qk_nope_head_dim=128, qk_rope_head_dim=64,
+                    v_head_dim=128, recompute=True),
+        seq=8192, rows=2, steps=8, lr=1e-4, fall=0.3),
     sharded=(128, 32, 30),                  # --chips 4: (seq, batch, steps)
     sharded_kernels=(8, 16, 512, 64),       # --chips 4: flash (B, H, L, D)
 )
@@ -199,9 +217,10 @@ def make_step(net, size, sharding=None):
     return opt, step, state
 
 
-def run_steps(step, state, batch, n, probe=None):
+def run_steps(step, state, batch, n, probe=None, each=None):
     """n steps on one batch. Returns (state, losses, step_ms, compiles
-    after the first step[, |first update| / rate of parameter ``probe``])."""
+    after the first step[, |first update| / rate of parameter ``probe``]).
+    ``each`` is handed every step's result."""
     from paddle_tpu import amp
     from paddle_tpu.core import rng
     losses, ms, flat_from, moved = [], [], None, None
@@ -214,6 +233,8 @@ def run_steps(step, state, batch, n, probe=None):
         res.loss.raw.block_until_ready()
         ms.append(round((time.perf_counter() - t0) * 1e3, 1))
         losses.append(float(res.loss))
+        if each is not None:
+            each(res)
         if i == 0:
             flat_from = Compiles.count()
             if probe:
@@ -307,6 +328,106 @@ def phase_train(size, seed, rehearsal):
     engine.write_back_state(net, None, state)
     jax.block_until_ready(state['params'])
     return net
+
+
+# ----------------------------------------------------------------- hybrid
+
+def packed_batch(vocab, seq, rows, seed):
+    """Packed rows: documents of random lengths fill each row exactly; the
+    next id is, half the time, the current id plus one (learnable); a
+    position's label is the next id where that lies in the same document,
+    else -1. -> ((ids, segment ids, labels), ())."""
+    rs = np.random.default_rng(seed)
+    ids = rs.integers(0, vocab, (rows, seq)).astype(np.int32)
+    cuts = rs.random((rows, seq)) < 8.0 / seq
+    cuts[:, 0] = False
+    seg = np.cumsum(cuts, axis=1).astype(np.int32)
+    inside = np.concatenate([np.zeros((rows, 1), bool),
+                             seg[:, 1:] == seg[:, :-1]], axis=1)
+    copy = inside & (rs.random((rows, seq)) < 0.5)
+    for t in range(1, seq):
+        ids[:, t] = np.where(copy[:, t], (ids[:, t - 1] + 1) % vocab,
+                             ids[:, t])
+    labels = np.full((rows, seq), -1, np.int32)
+    labels[:, :-1] = np.where(inside[:, 1:], ids[:, 1:], -1)
+    return (ids, seg, labels), ()
+
+
+def phase_hybrid(size, seed, rehearsal):
+    """The cut Kimi-Linear configuration through the train engine."""
+    import jax
+    import paddle_tpu as paddle
+    from paddle_tpu import engine, optimizer
+    from paddle_tpu.nn.layer_base import buffer_values, param_values
+    from paddle_tpu.text.kimi_linear import (KimiLinearConfig,
+                                             KimiLinearForCausalLM)
+    h = size['hybrid']
+    paddle.seed(seed)
+    holder = {}
+
+    def abstract():         # the structure; the weights come from a jit
+        holder['net'] = KimiLinearForCausalLM(KimiLinearConfig(**h['config']))
+        return param_values(holder['net']), buffer_values(holder['net'])
+
+    shapes, buffer_shapes = jax.eval_shape(abstract)
+    net = holder['net']
+    net.train()
+
+    def scale(name):
+        """A leaf's start, as benchmark/configs/kimi-linear-48b-a3b.json
+        states it: norm scales 1, A_log 0, else a normal of this width."""
+        if name.endswith(('norm.weight', 'o_norm', 'kv_a_norm')):
+            return None
+        for tail, std in (('A_log', 0.0), ('dt_bias', 2.0), ('_conv', 0.5),
+                          ('o_proj', 0.0027), ('down_proj', 0.0027),
+                          ('experts_down', 0.0027),
+                          ('embed_tokens.weight', 1.0)):
+            if name.endswith(tail):
+                return std
+        return 0.02
+
+    @jax.jit
+    def weights(key):
+        keys = jax.random.split(key, len(shapes))
+        return {name: (jax.numpy.ones(s.shape, s.dtype)
+                       if scale(name) is None else
+                       scale(name) * jax.random.normal(k, s.shape, s.dtype))
+                for k, (name, s) in zip(keys, sorted(shapes.items()))}
+
+    step = engine.build_train_step(
+        net=net, loss=net.training_loss,
+        optimizer=optimizer.AdamW(learning_rate=h['lr'], weight_decay=0.1))
+    state = step.init_state(
+        weights(jax.random.PRNGKey(seed)),
+        {k: jax.numpy.zeros(s.shape, s.dtype)
+         for k, s in buffer_shapes.items()})
+    batch = packed_batch(h['config']['vocab_size'], h['seq'], h['rows'],
+                         seed + 27)
+    hlo = step_hlo_facts(step, state, batch)
+    if not rehearsal and not (hlo['scopes']['flash_attention.pallas']
+                              and not hlo['scopes']['flash_attention.xla']):
+        raise AssertionError('the hybrid step does not hold the Pallas '
+                             'flash kernels: %s' % hlo)
+    c0, s0 = Compiles.count(), Compiles.seconds()
+    counters = []
+    state, losses, ms, after_first = run_steps(
+        step, state, batch, h['steps'],
+        each=lambda res: counters.append(np.asarray(res.outputs[-1])))
+    check_losses('hybrid', losses, h['fall'])
+    if after_first:
+        raise AssertionError('hybrid: %d compile(s) after the first step'
+                             % after_first)
+    counted = dict(zip(net.step_counter_names,
+                       (float(v) for v in counters[-1])))
+    if counted['moe.dropped'] or not counted['moe.assignments_held']:
+        raise AssertionError('hybrid: the expert layers dropped or held '
+                             'nothing: %s' % counted)
+    say('hybrid', seq=h['seq'], rows=h['rows'], steps=h['steps'],
+        parameters=int(sum(np.prod(s.shape) for s in shapes.values())),
+        losses=losses, step_ms=ms, compiles=Compiles.count() - c0,
+        compiles_after_first_step=after_first,
+        compile_seconds=round(Compiles.seconds() - s0, 2), counters=counted,
+        hlo=hlo, peak_bytes_in_use=peak_bytes())
 
 
 # ------------------------------------------------------------------ serve
@@ -555,6 +676,7 @@ def run(size, chips=1, seed=0, rehearsal=False):
         phase_serve(size, net, seed, rehearsal)
         del net
         phase_generate(size, seed)
+        phase_hybrid(size, seed, rehearsal)
     else:
         phase_sharded(size, seed, chips, rehearsal)
     say('done', seconds=round(time.perf_counter() - t0, 1),

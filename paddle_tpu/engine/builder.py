@@ -292,6 +292,12 @@ def build_train_step(loss_fn=None, optimizer=None, *, net=None, loss=None,
                            if p.trainable}
         if with_key is None:
             with_key = True
+        # counters that are values of the step: the net's last output, one
+        # value per name (observability.step_counters)
+        counters = (tuple(getattr(net, 'step_counter_names', ())),
+                    frozenset(getattr(net, 'step_counter_sums', ())))
+    else:
+        counters = ((), frozenset())
     if loss_fn is None:
         raise ValueError("build_train_step: need loss_fn= or net=+loss=")
     if optimizer is None:
@@ -323,7 +329,8 @@ def build_train_step(loss_fn=None, optimizer=None, *, net=None, loss=None,
                      scaler=scaler, nan_guard=bool(nan_guard), microbatch=k,
                      donate=donate, remat=remat,
                      matmul_precision=matmul_precision, with_key=with_key,
-                     in_shardings=in_shardings, sharding=sharding)
+                     in_shardings=in_shardings, sharding=sharding,
+                     counters=counters)
 
 
 def kernel_mesh_of(sharding, in_shardings):
@@ -351,8 +358,10 @@ class TrainStep:
 
     def __init__(self, loss_fn, optimizer, params_meta, trainable, scaler,
                  nan_guard, microbatch, donate, remat, matmul_precision,
-                 with_key, in_shardings, sharding=None):
+                 with_key, in_shardings, sharding=None,
+                 counters=((), frozenset())):
         self.optimizer = optimizer
+        self._counter_names, self._counter_sums = counters
         self.k = microbatch
         self.guard_enabled = nan_guard
         self.scaler = scaler
@@ -779,6 +788,10 @@ class TrainStep:
             if self._collective_bytes_est:
                 _obs.counter('sharding.collective_bytes_est').inc(
                     self._collective_bytes_est * self.k)
+            if self._counter_names and outs:
+                # queued, not waited for: recorded once the device has them
+                _obs.step_counters.push(n, self._counter_names, outs[-1],
+                                        self._counter_sums)
         loss = losses if self.k == 1 else losses[-1]
         return new_state, StepResult(DeviceLoss(loss), losses, outs)
 

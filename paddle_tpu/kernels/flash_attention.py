@@ -17,6 +17,11 @@ Features:
 - causal and non-causal attention;
 - additive key-padding bias of shape (B, Lk) — the form BERT's (B, 1, 1, L)
   padding mask reduces to;
+- a value head size that differs from the query/key head size (latent
+  attention trains with 192-wide q/k and 128-wide v): read off the shapes;
+- packed documents under the causal mask: ``doc_start`` (B, L) names, for
+  each query position, the first position of its document; a query sees
+  the keys from there to itself;
 - attention-probability dropout INSIDE the kernel: the keep-mask for tile
   (bh, q_block, k_block) is regenerated from the TPU hardware PRNG
   (pltpu.prng_seed keyed on the tile coordinates) identically in the forward
@@ -50,15 +55,20 @@ LSE_EMPTY = 1e30  # lse sentinel for fully-masked rows: exp(s - BIG) == 0
 
 
 def _attn_reference(q, k, v, causal, scale, kpad_bias=None, dropout_p=0.0,
-                    dropout_key=None):
+                    dropout_key=None, doc_start=None):
     """Plain XLA attention on (B, H, L, D) — fallback + ground truth.
 
     kpad_bias: optional (B, Lk) additive bias (0 for keep, large negative for
-    masked keys).
+    masked keys). doc_start: optional (B, L) first position of each query's
+    document.
     """
     scores = jnp.einsum('bhld,bhmd->bhlm', q, k) * scale
     if kpad_bias is not None:
         scores = scores + kpad_bias[:, None, None, :].astype(scores.dtype)
+    if doc_start is not None:
+        cols = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+        scores = jnp.where(cols >= doc_start[:, None, :, None], scores,
+                           NEG_INF)
     if causal:
         L, M = scores.shape[-2], scores.shape[-1]
         mask = jnp.tril(jnp.ones((L, M), dtype=bool))
@@ -71,7 +81,8 @@ def _attn_reference(q, k, v, causal, scale, kpad_bias=None, dropout_p=0.0,
     return jnp.einsum('bhlm,bhmd->bhld', probs, v)
 
 
-def _score_tile(q, k_tile, bias_tile, causal, q_offset, k_offset, scale):
+def _score_tile(q, k_tile, bias_tile, causal, q_offset, k_offset, scale,
+                start=None):
     """(block_q, block_k) scores for one tile pair, masked.
 
     q/k stay in their native dtype (bf16 on the training path) so the MXU
@@ -87,6 +98,9 @@ def _score_tile(q, k_tile, bias_tile, causal, q_offset, k_offset, scale):
         rows = q_offset + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         cols = k_offset + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s = jnp.where(rows >= cols, s, NEG_INF)
+    if start is not None:       # (block_q, 1): where each row's document begins
+        cols = k_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols >= start, s, NEG_INF)
     return s
 
 
@@ -117,15 +131,17 @@ _ROLES = {'b': 'batch', 'h': 'heads'}
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p,
-                heads):
+                heads, has_doc=False):
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     idx = 3
-    bias_ref = seed_ref = None
+    bias_ref = seed_ref = start = None
     if has_bias:
         bias_ref = refs[idx]; idx += 1
     if dropout_p > 0.0:
         seed_ref = refs[idx]; idx += 1
+    if has_doc:
+        start = refs[idx][0]; idx += 1                 # (block_q, 1) int32
     o_ref, lse_ref = refs[idx:idx + 2]
 
     q = q_ref[0]                                       # (block_q, d) native
@@ -135,7 +151,7 @@ def _fwd_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p,
 
     m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l = jnp.zeros((block_q, 1), jnp.float32)
-    acc = jnp.zeros((block_q, q.shape[1]), jnp.float32)
+    acc = jnp.zeros((block_q, v_ref.shape[2]), jnp.float32)
 
     if causal:
         n_blocks = (q_offset + block_q + block_k - 1) // block_k
@@ -160,7 +176,7 @@ def _fwd_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p,
                 bias_tile = bias_ref[0, :, pl.dslice(i * block_k, block_k)
                                      ].astype(jnp.float32)  # (1, block_k)
             s = _score_tile(q, k_tile, bias_tile, masked, q_offset,
-                            i * block_k, scale)
+                            i * block_k, scale, start)
             m_new = jnp.maximum(m_i, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
             corr = jnp.exp(m_i - m_new)
@@ -190,11 +206,28 @@ def _fwd_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p,
     lse_ref[0] = lse.astype(jnp.float32)                # (block_q, 1)
 
 
-def _flash_forward(q, k, v, kpad_bias, seed, causal, scale, block_q, block_k,
-                   dropout_p, interpret):
+def _fwd_vmem_limit(L, d, dv, bq, itemsize, row_operands):
+    """The forward kernel keeps a head's whole K and V in VMEM
+    (double-buffered, the minor dim padded to 128 lanes) beside its Q-tile
+    blocks; `row_operands` counts its (block_q, 1) blocks (lse, and
+    doc_start where there is one). Inside the 16 MiB Mosaic gives a kernel
+    unasked the limit is left alone: None (every call with L <= 4096 at head
+    size 128)."""
+    lanes, lanes_v = -(-d // 128) * 128, -(-dv // 128) * 128
+    need = (2 * L * (lanes + lanes_v) * itemsize    # k, v
+            + 2 * bq * (lanes + lanes_v) * itemsize  # q in, o out
+            + 2 * bq * 128 * 4 * row_operands       # lse, doc_start
+            + 4 * bq * bq * 4)                      # live fp32 score tiles
+    return None if need <= (14 << 20) else need * 3 // 2
+
+
+def _flash_forward(q, k, v, kpad_bias, seed, doc_start, causal, scale,
+                   block_q, block_k, dropout_p, interpret):
     L, d = q.shape[2:]
+    dv = v.shape[3]
     bq, bk = min(block_q, L), min(block_k, L)
     has_bias = kpad_bias is not None
+    has_doc = doc_start is not None
     args, dims = [q, k, v], [_BHLD] * 3
     if has_bias:
         # (B, 1, L) so the block shape (1, 1, L) satisfies TPU tiling rules
@@ -203,36 +236,52 @@ def _flash_forward(q, k, v, kpad_bias, seed, causal, scale, block_q, block_k,
     if dropout_p > 0.0:
         args.append(seed)
         dims.append((None, None))
+    if has_doc:
+        args.append(doc_start.astype(jnp.int32)[:, :, None])    # (B, L, 1)
+        dims.append(('b', 'l', None))
+    extra = {'has_doc': True} if has_doc else {}
+    limit = _fwd_vmem_limit(L, d, dv, bq, q.dtype.itemsize, 1 + int(has_doc))
+    if limit is not None:
+        extra_call = {'compiler_params': pltpu.CompilerParams(
+            vmem_limit_bytes=limit)}
+    else:
+        extra_call = {}
 
     def call(*args, shard):
         b, h = args[0].shape[:2]        # this device's batch and heads
-        args = [t.reshape(b * h, L, d) for t in args[:3]] + list(args[3:])
+        args = [t.reshape((b * h, L) + t.shape[3:]) for t in args[:3]
+                ] + list(args[3:])
         kernel = functools.partial(
             _fwd_kernel, block_k=bk, seq_len=L, causal=causal, scale=scale,
             has_bias=has_bias, dropout_p=dropout_p,
-            heads=(h, shard['h'][1]))
+            heads=(h, shard['h'][1]), **extra)
         in_specs = [
             pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0)),
             pl.BlockSpec((1, L, d), lambda bh, i: (bh, 0, 0)),
-            pl.BlockSpec((1, L, d), lambda bh, i: (bh, 0, 0)),
+            pl.BlockSpec((1, L, dv), lambda bh, i: (bh, 0, 0)),
         ]
+        n_fixed = 3
         if has_bias:
             in_specs.append(
                 pl.BlockSpec((1, 1, L), lambda bh, i: (bh // h, 0, 0)))
+            n_fixed += 1
         if dropout_p > 0.0:
             in_specs.append(pl.BlockSpec((1, 3), lambda bh, i: (0, 0)))
-            args[-1] = _seed_and_shard(args[-1], shard)
+            args[n_fixed] = _seed_and_shard(args[n_fixed], shard)
+        if has_doc:
+            in_specs.append(
+                pl.BlockSpec((1, bq, 1), lambda bh, i: (bh // h, i, 0)))
         o, lse = pl.pallas_call(
             kernel,
             grid=(b * h, L // bq),
             in_specs=in_specs,
-            out_specs=(pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0)),
+            out_specs=(pl.BlockSpec((1, bq, dv), lambda bh, i: (bh, i, 0)),
                        pl.BlockSpec((1, bq, 1), lambda bh, i: (bh, i, 0))),
-            out_shape=(jax.ShapeDtypeStruct((b * h, L, d), q.dtype),
+            out_shape=(jax.ShapeDtypeStruct((b * h, L, dv), q.dtype),
                        jax.ShapeDtypeStruct((b * h, L, 1), jnp.float32)),
-            interpret=interpret,
+            interpret=interpret, **extra_call,
         )(*args)
-        return o.reshape(b, h, L, d), lse.reshape(b, h, L)
+        return o.reshape(b, h, L, dv), lse.reshape(b, h, L)
 
     return spmd_kernel(call, dims, [_BHLD, _BHLD[:3]], _ROLES,
                        scope='flash_attention.pallas')(*args)
@@ -243,17 +292,19 @@ def _flash_forward(q, k, v, kpad_bias, seed, causal, scale, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 def _bwd_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
-                heads):
+                heads, has_doc=False):
     """dQ, dK and dV of one (head, K tile) from ONE pass over its score
     tiles. Grid (b*h, K tiles); the loop runs over the Q tiles."""
     refs = list(refs)
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = refs[:6]
     idx = 6
-    bias_ref = seed_ref = None
+    bias_ref = seed_ref = start_ref = None
     if has_bias:
         bias_ref = refs[idx]; idx += 1
     if dropout_p > 0.0:
         seed_ref = refs[idx]; idx += 1
+    if has_doc:
+        start_ref = refs[idx]; idx += 1                 # (1, L, 1) int32
     dq_ref, dk_ref, dv_ref = refs[idx:idx + 3]
 
     k = k_ref[0]                                        # (block_k, d) native
@@ -293,8 +344,9 @@ def _bwd_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
             delta = jnp.sum(do_tile.astype(jnp.float32)
                             * o_ref[0, rows, :].astype(jnp.float32),
                             axis=-1, keepdims=True)         # (block_q, 1)
+            start = None if start_ref is None else start_ref[0, rows, :]
             s = _score_tile(q_tile, k, bias_tile, masked, i * block_q,
-                            k_offset, scale)
+                            k_offset, scale, start)
             p = jnp.exp(s - lse)                        # (block_q, block_k)
             p_drop = p
             dp = jnp.dot(do_tile, v.T, preferred_element_type=jnp.float32)
@@ -320,13 +372,18 @@ def _bwd_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
         return body
 
     zero = jnp.zeros((block_k, k.shape[1]), jnp.float32)
+    if v.shape[1] == k.shape[1]:
+        zero_v = zero
+    else:
+        zero_v = jnp.zeros((block_k, v.shape[1]), jnp.float32)
     if causal:
         bound = jnp.minimum(jnp.maximum(start_full, start), nq)
         dk, dv = jax.lax.fori_loop(start, bound, make_body(True),
-                                   (zero, zero))
+                                   (zero, zero_v))
         dk, dv = jax.lax.fori_loop(bound, nq, make_body(False), (dk, dv))
     else:
-        dk, dv = jax.lax.fori_loop(start, nq, make_body(False), (zero, zero))
+        dk, dv = jax.lax.fori_loop(start, nq, make_body(False),
+                                   (zero, zero_v))
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
     if dq_acc is not None:
@@ -335,7 +392,7 @@ def _bwd_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
             dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_vmem_limit(L, d, bq, bk, itemsize):
+def _bwd_vmem_limit(L, d, bq, bk, itemsize, dv=None, has_doc=False):
     """The backward kernel keeps a head's whole Q, O, dO, lse and dQ in
     VMEM (double-buffered, the minor dim padded to 128 lanes) beside its
     K-tile blocks and a few (bq, bk) fp32 temporaries. While that fits the
@@ -344,20 +401,23 @@ def _bwd_vmem_limit(L, d, bq, bk, itemsize):
     blocks need and half as much again; the compiler refuses what the chip
     cannot give."""
     lanes = -(-d // 128) * 128
-    need = (2 * 4 * L * lanes * itemsize        # q, o, do in; dq out
-            + 2 * L * 128 * 4                   # lse (L, 1) fp32
+    lanes_v = lanes if dv is None else -(-dv // 128) * 128
+    need = (2 * 2 * L * (lanes + lanes_v) * itemsize    # q, dq; o, do
+            + 2 * L * 128 * 4 * (2 if has_doc else 1)   # lse (L, 1), doc_start
             + (L * lanes * 4 if bk < L else 0)  # dq's fp32 scratch
-            + 2 * 4 * bk * lanes * itemsize     # k, v in; dk, dv out
+            + 2 * 2 * bk * (lanes + lanes_v) * itemsize     # k, dk; v, dv
             + 2 * bq * bk * 4)                  # two live fp32 score tiles
     return None if need <= (14 << 20) else need * 3 // 2
 
 
-def _flash_backward(q, k, v, o, lse, kpad_bias, seed, g, causal, scale,
-                    block_q, block_k, dropout_p, interpret):
+def _flash_backward(q, k, v, o, lse, kpad_bias, seed, doc_start, g, causal,
+                    scale, block_q, block_k, dropout_p, interpret):
     L, d = q.shape[2:]
+    dv_ = v.shape[3]
     bq, bk = min(block_q, L), min(block_k, L)
     nk = L // bk
     has_bias = kpad_bias is not None
+    has_doc = doc_start is not None
     args = [q, k, v, o, g, lse[..., None]]
     dims = [_BHLD] * 5 + [('b', 'h', 'l', None)]
     if has_bias:
@@ -366,27 +426,40 @@ def _flash_backward(q, k, v, o, lse, kpad_bias, seed, g, causal, scale,
     if dropout_p > 0.0:
         args.append(seed)
         dims.append((None, None))
+    if has_doc:
+        args.append(doc_start.astype(jnp.int32)[:, :, None])    # (B, L, 1)
+        dims.append(('b', 'l', None))
+    extra = {'has_doc': True} if has_doc else {}
 
     def call(*args, shard):
         b, h = args[0].shape[:2]        # this device's batch and heads
         args = [t.reshape((b * h,) + t.shape[2:]) for t in args[:6]
                 ] + list(args[6:])
         if dropout_p > 0.0:
-            args[-1] = _seed_and_shard(args[-1], shard)
+            at = 6 + int(has_bias)
+            args[at] = _seed_and_shard(args[at], shard)
         kernel = functools.partial(
             _bwd_kernel, block_q=bq, seq_len=L, causal=causal, scale=scale,
             has_bias=has_bias, dropout_p=dropout_p,
-            heads=(h, shard['h'][1]))
+            heads=(h, shard['h'][1]), **extra)
 
         full_ld = pl.BlockSpec((1, L, d), lambda bh, j: (bh, 0, 0))
         tile_kd = pl.BlockSpec((1, bk, d), lambda bh, j: (bh, j, 0))
-        in_specs = [full_ld, tile_kd, tile_kd, full_ld, full_ld,
+        if dv_ == d:
+            full_lv, tile_kv = full_ld, tile_kd
+        else:
+            full_lv = pl.BlockSpec((1, L, dv_), lambda bh, j: (bh, 0, 0))
+            tile_kv = pl.BlockSpec((1, bk, dv_), lambda bh, j: (bh, j, 0))
+        in_specs = [full_ld, tile_kd, tile_kv, full_lv, full_lv,
                     pl.BlockSpec((1, L, 1), lambda bh, j: (bh, 0, 0))]
         if has_bias:
             in_specs.append(
                 pl.BlockSpec((1, 1, bk), lambda bh, j: (bh // h, 0, j)))
         if dropout_p > 0.0:
             in_specs.append(pl.BlockSpec((1, 3), lambda bh, j: (0, 0)))
+        if has_doc:
+            in_specs.append(
+                pl.BlockSpec((1, L, 1), lambda bh, j: (bh // h, 0, 0)))
         # dQ's block is the head's whole (L, d) at every K tile: it stays in
         # VMEM along that axis (which must therefore run in order) and goes
         # out once
@@ -394,19 +467,20 @@ def _flash_backward(q, k, v, o, lse, kpad_bias, seed, g, causal, scale,
             kernel,
             grid=(b * h, nk),
             in_specs=in_specs,
-            out_specs=(full_ld, tile_kd, tile_kd),
+            out_specs=(full_ld, tile_kd, tile_kv),
             out_shape=(jax.ShapeDtypeStruct((b * h, L, d), q.dtype),
                        jax.ShapeDtypeStruct((b * h, L, d), k.dtype),
-                       jax.ShapeDtypeStruct((b * h, L, d), v.dtype)),
+                       jax.ShapeDtypeStruct((b * h, L, dv_), v.dtype)),
             scratch_shapes=([pltpu.VMEM((L, d), jnp.float32)] if nk > 1
                             else []),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=('parallel', 'arbitrary'),
-                vmem_limit_bytes=_bwd_vmem_limit(L, d, bq, bk,
-                                                 q.dtype.itemsize)),
+                vmem_limit_bytes=_bwd_vmem_limit(
+                    L, d, bq, bk, q.dtype.itemsize, dv_, has_doc)),
             interpret=interpret,
         )(*args)
-        return tuple(t.reshape(b, h, L, d) for t in (dq, dk, dv))
+        return (dq.reshape(b, h, L, d), dk.reshape(b, h, L, d),
+                dv.reshape(b, h, L, dv_))
 
     return spmd_kernel(call, dims, [_BHLD] * 3, _ROLES,
                        scope='flash_attention.pallas')(*args)
@@ -416,49 +490,56 @@ def _flash_backward(q, k, v, o, lse, kpad_bias, seed, g, causal, scale,
 # custom-vjp wrapper + public entry
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, kpad_bias, seed, causal, scale, block_q, block_k,
-           dropout_p, interpret):
-    o, _ = _flash_forward(q, k, v, kpad_bias, seed, causal, scale, block_q,
-                          block_k, dropout_p, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+def _flash(q, k, v, kpad_bias, seed, doc_start, causal, scale, block_q,
+           block_k, dropout_p, interpret):
+    o, _ = _flash_forward(q, k, v, kpad_bias, seed, doc_start, causal, scale,
+                          block_q, block_k, dropout_p, interpret)
     return o
 
 
-def _flash_fwd_rule(q, k, v, kpad_bias, seed, causal, scale, block_q, block_k,
-                    dropout_p, interpret):
-    o, lse = _flash_forward(q, k, v, kpad_bias, seed, causal, scale, block_q,
-                            block_k, dropout_p, interpret)
-    return o, (q, k, v, o, lse, kpad_bias, seed)
+def _flash_fwd_rule(q, k, v, kpad_bias, seed, doc_start, causal, scale,
+                    block_q, block_k, dropout_p, interpret):
+    o, lse = _flash_forward(q, k, v, kpad_bias, seed, doc_start, causal,
+                            scale, block_q, block_k, dropout_p, interpret)
+    return o, (q, k, v, o, lse, kpad_bias, seed, doc_start)
 
 
 def _flash_bwd_rule(causal, scale, block_q, block_k, dropout_p, interpret,
                     res, g):
-    q, k, v, o, lse, kpad_bias, seed = res
-    dq, dk, dv = _flash_backward(q, k, v, o, lse, kpad_bias, seed, g, causal,
-                                 scale, block_q, block_k, dropout_p, interpret)
-    return dq, dk, dv, None, None
+    q, k, v, o, lse, kpad_bias, seed, doc_start = res
+    dq, dk, dv = _flash_backward(q, k, v, o, lse, kpad_bias, seed, doc_start,
+                                 g, causal, scale, block_q, block_k,
+                                 dropout_p, interpret)
+    return dq, dk, dv, None, None, None
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def flash_attention_bhld(q, k, v, causal=False, scale=None, kpad_bias=None,
-                         dropout_p=0.0, dropout_seed=None,
+                         dropout_p=0.0, dropout_seed=None, doc_start=None,
                          block_q=512, block_k=512, interpret=False):
-    """Flash attention on (B, H, L, D) tensors.
+    """Flash attention on (B, H, L, D) tensors; v's head size may differ
+    from q's and k's.
 
     kpad_bias: optional (B, Lk) additive key-padding bias (0 = keep, -1e4/-inf
     style = masked). dropout_p: attention-probability dropout rate; when > 0,
     dropout_seed must be an int32 array of shape (1, 1) (the keep-mask is a
-    deterministic function of it). Takes plain-XLA attention off the TPU
-    (unless interpret mode is asked for) or when L doesn't tile; either way
-    the ops sit under a ``flash_attention.pallas`` / ``flash_attention.xla``
-    named scope (``_common.took``).
+    deterministic function of it). doc_start: optional (B, L) int32, with
+    causal=True: the first position of each query's document in a packed
+    row; a query then sees the keys from there to itself. Takes plain-XLA
+    attention off the TPU (unless interpret mode is asked for) or when L
+    doesn't tile; either way the ops sit under a ``flash_attention.pallas`` /
+    ``flash_attention.xla`` named scope (``_common.took``).
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     L = q.shape[2]
     dropout_p = float(dropout_p)
+    if doc_start is not None and not causal:
+        raise ValueError("doc_start describes packed causal rows: it needs "
+                         "causal=True")
     if kpad_bias is not None:
         # the forward kernel streams bias columns with an in-kernel dynamic
         # slice of the minor dim, which Mosaic cannot lower for block_k < L;
@@ -477,9 +558,9 @@ def flash_attention_bhld(q, k, v, causal=False, scale=None, kpad_bias=None,
                                      .astype(jnp.uint32))
         with took('flash_attention', 'xla'):
             return _attn_reference(q, k, v, causal, scale, kpad_bias,
-                                   dropout_p, key)
+                                   dropout_p, key, doc_start)
     seed = (dropout_seed if dropout_seed is not None
             else jnp.zeros((1, 1), jnp.int32))
     with took('flash_attention', 'pallas'):
-        return _flash(q, k, v, kpad_bias, seed, causal, scale, block_q,
-                      block_k, dropout_p, interpret)
+        return _flash(q, k, v, kpad_bias, seed, doc_start, causal, scale,
+                      block_q, block_k, dropout_p, interpret)

@@ -12,7 +12,7 @@ from ...tensor._helpers import _t
 
 __all__ = ['normalize', 'batch_norm', 'layer_norm', 'fused_dropout_add_layer_norm',
            'instance_norm', 'group_norm',
-           'local_response_norm', 'rms_norm']
+           'local_response_norm', 'rms_norm', 'rms_norm_values']
 
 
 def normalize(x, p=2, axis=1, epsilon=1e-12, name=None):
@@ -166,24 +166,23 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
     return apply_op(fn, tuple(tensors))
 
 
+def rms_norm_values(v, w=None, epsilon=1e-6):
+    """`rms_norm` over jax values: for a layer that norms inside its own
+    traced function (one that is recomputed in the backward pass)."""
+    if jax.default_backend() == 'tpu' and v.shape[-1] % 128 == 0:
+        from ...kernels.fused_norm import fused_rms_norm
+        return fused_rms_norm(v, w, eps=epsilon)
+    ms = jnp.mean(v * v, axis=-1, keepdims=True)
+    out = v / jnp.sqrt(ms + epsilon)
+    return out if w is None else out * w
+
+
 def rms_norm(x, weight=None, epsilon=1e-6, name=None):
     """RMSNorm (modern LLM stacks; pallas-fused variant in kernels/)."""
     x = _t(x)
     tensors = [x] + ([_t(weight)] if weight is not None else [])
-    if jax.default_backend() == 'tpu' and x.shape[-1] % 128 == 0:
-        from ...kernels.fused_norm import fused_rms_norm
-
-        def fused(v, *w):
-            return fused_rms_norm(v, w[0] if w else None, eps=epsilon)
-        return apply_op(fused, tuple(tensors))
-
-    def fn(v, *w):
-        ms = jnp.mean(v * v, axis=-1, keepdims=True)
-        out = v / jnp.sqrt(ms + epsilon)
-        if w:
-            out = out * w[0]
-        return out
-    return apply_op(fn, tuple(tensors))
+    return apply_op(lambda v, *w: rms_norm_values(v, w[0] if w else None,
+                                                  epsilon), tuple(tensors))
 
 
 def instance_norm(x, running_mean=None, running_var=None, weight=None, bias=None,

@@ -1,0 +1,218 @@
+"""Attention layers of the hybrid linear-attention decoders: Kimi Delta
+Attention (a gated delta rule with a decay per channel, arXiv:2510.26692)
+and multi-head latent attention without position encoding (the full-attention
+layer the same paper interleaves, one in four). Both take a packed row's
+document numbers: state, convolution and scores stop at document boundaries.
+"""
+import math
+
+import jax
+import jax.numpy as jnp
+
+from ...core.tensor import apply_op
+from ..initializer import Constant, Normal, ParamAttr
+from ..layer_base import Layer
+from ..functional.delta_rule import causal_conv, delta_rule_chunked
+from ..functional.norm import rms_norm_values
+
+__all__ = ['KimiDeltaAttention', 'LatentAttention', 'compute_dtype',
+           'doc_starts', 'pre_normed']
+
+
+def compute_dtype():
+    """The operand type of the large products: amp's while `auto_cast` is
+    entered, else None (as the operands come)."""
+    from ...amp import amp_enabled
+    st = amp_enabled()
+    return st['dtype'] if st and st['enable'] else None
+
+
+def _mm(x, w, dtype):
+    if dtype is not None:
+        x, w = x.astype(dtype), w.astype(dtype)
+    return jnp.matmul(x, w)
+
+
+def pre_normed(fn, pre_norm, recompute):
+    """`fn(x, *rest)` with the block's RMSNorm in front of it, and the whole
+    re-run in the backward pass where `recompute` says so (the block then
+    keeps `x` alone, not the normed copy nor anything inside `fn`).
+    -> (the function, the operands to put behind x)."""
+    eps = pre_norm._epsilon if pre_norm is not None else None
+
+    def run(x, *rest):
+        if pre_norm is not None:
+            x, rest = rms_norm_values(x, rest[0], eps), rest[1:]
+        return fn(x, *rest)
+    return (jax.checkpoint(run) if recompute else run), \
+        ((pre_norm.weight,) if pre_norm is not None else ())
+
+
+def doc_starts(seg):
+    """(B, T) document numbers of a packed row -> for each position, the
+    first position of its document."""
+    pos = jnp.arange(seg.shape[1], dtype=jnp.int32)[None, :]
+    first = jnp.concatenate([jnp.ones_like(seg[:, :1], bool),
+                             seg[:, 1:] != seg[:, :-1]], axis=1)
+    return jax.lax.cummax(jnp.where(first, pos, 0), axis=1)
+
+
+def _l2norm(x):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+class KimiDeltaAttention(Layer):
+    """q, k = l2norm(silu(conv(x W))), v = silu(conv(x W_v)) per head; a
+    log-decay per channel g = -exp(A_log) softplus(x W_a1 W_a2 + dt_bias);
+    beta = sigmoid(x W_beta) per head; the delta rule chunk-wise
+    (`functional.delta_rule`); W_o [RMSNorm_head(o) * sigmoid(x W_g1 W_g2)].
+    """
+
+    def __init__(self, hidden_size, num_heads, head_dim, conv_kernel=4,
+                 gate_rank=None, epsilon=1e-5, chunk=64,
+                 initializer_range=0.02):
+        super().__init__()
+        self.num_heads, self.head_dim = num_heads, head_dim
+        self.epsilon, self.chunk = epsilon, chunk
+        inner = num_heads * head_dim
+        rank = gate_rank or head_dim
+
+        def weight(*shape):
+            return self.create_parameter(list(shape), attr=ParamAttr(
+                initializer=Normal(0., initializer_range)))
+        self.q_proj, self.k_proj, self.v_proj = (
+            weight(hidden_size, inner) for _ in range(3))
+        self.q_conv, self.k_conv, self.v_conv = (
+            weight(conv_kernel, inner) for _ in range(3))
+        self.decay_a, self.decay_b = weight(hidden_size, rank), \
+            weight(rank, inner)
+        self.A_log = self.create_parameter(
+            [num_heads], default_initializer=Constant(0.0))
+        self.dt_bias = self.create_parameter(
+            [inner], default_initializer=Constant(0.0))
+        self.beta_proj = weight(hidden_size, num_heads)
+        self.gate_a, self.gate_b = weight(hidden_size, rank), \
+            weight(rank, inner)
+        self.o_norm = self.create_parameter(
+            [head_dim], default_initializer=Constant(1.0))
+        self.o_proj = weight(inner, hidden_size)
+
+    def forward(self, x, segment_ids, pre_norm=None, recompute=False):
+        """`pre_norm`: the block's `nn.RMSNorm`, applied to x first and
+        inside whatever is recomputed. Rows are always taken one at a time
+        and recomputed in the backward pass (below); `recompute` does the
+        same for a single row."""
+        H, D, eps, chunk = (self.num_heads, self.head_dim, self.epsilon,
+                            self.chunk)
+        dtype = compute_dtype()
+        norm_eps = pre_norm._epsilon if pre_norm is not None else None
+
+        def fn(x, seg, wq, wk, wv, cq, ck, cv, da, db, a_log, dt_bias, wb,
+               ga, gb, norm, wo, *pre):
+            f32 = jnp.float32
+
+            def rows(x, seg):
+                B, T, _ = x.shape
+                if pre:
+                    x = rms_norm_values(x, pre[0], norm_eps)
+                with jax.named_scope('kda.proj'):
+                    def short(w, c):
+                        y = causal_conv(_mm(x, w, dtype).astype(f32), c, seg)
+                        return jax.nn.silu(y).reshape(B, T, H, D)
+                    q, k, v = _l2norm(short(wq, cq)), \
+                        _l2norm(short(wk, ck)), short(wv, cv)
+                    raw = _mm(_mm(x, da, dtype), db, dtype).astype(f32) \
+                        + dt_bias
+                    g = -jnp.exp(a_log.astype(f32))[:, None] \
+                        * jax.nn.softplus(raw).reshape(B, T, H, D)
+                    beta = jax.nn.sigmoid(_mm(x, wb, dtype).astype(f32))
+                    gate = jax.nn.sigmoid(
+                        _mm(_mm(x, ga, dtype), gb, dtype).astype(f32))
+                with jax.named_scope('kda.scan'):
+                    o = delta_rule_chunked(q, k, v, g, beta, seg, D ** -0.5,
+                                           chunk=min(chunk, T),
+                                           sub=min(16, chunk, T), dtype=dtype)
+                with jax.named_scope('kda.proj'):
+                    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                                          + eps) * norm
+                    return _mm(o.reshape(B, T, H * D) * gate, wo, dtype)
+
+            if x.shape[0] == 1:
+                return (jax.checkpoint(rows) if recompute else rows)(x, seg)
+            # a row at a time, recomputed in the backward pass: the layer
+            # keeps two dozen arrays the size of q in float32 (the chunked
+            # form's factors, the convolutions' and the norms' inputs), and
+            # so holds them for one row only
+            one = jax.checkpoint(lambda xs: rows(xs[0][None], xs[1][None])[0])
+            return jax.lax.map(one, (x, seg))
+
+        return apply_op(fn, (x, segment_ids, self.q_proj, self.k_proj,
+                             self.v_proj, self.q_conv, self.k_conv,
+                             self.v_conv, self.decay_a, self.decay_b,
+                             self.A_log, self.dt_bias, self.beta_proj,
+                             self.gate_a, self.gate_b, self.o_norm,
+                             self.o_proj)
+                        + ((pre_norm.weight,) if pre_norm is not None else ()))
+
+
+class LatentAttention(Layer):
+    """Multi-head latent attention with no position encoding: q = x W_q;
+    [c, k_pe] = x W_kva, c = RMSNorm(c); [k_nope, v] = c W_kvb per head;
+    k = [k_nope, k_pe shared by the heads]; causal softmax(q k^T / sqrt(d_qk))
+    inside documents; W_o. q and k are wider than v: the flash-attention
+    kernels read both sizes off the shapes."""
+
+    def __init__(self, hidden_size, num_heads, qk_nope_head_dim,
+                 qk_rope_head_dim, v_head_dim, kv_lora_rank, epsilon=1e-5,
+                 initializer_range=0.02):
+        super().__init__()
+        self.num_heads = num_heads
+        self.dims = (qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                     kv_lora_rank)
+        self.epsilon = epsilon
+
+        def weight(*shape):
+            return self.create_parameter(list(shape), attr=ParamAttr(
+                initializer=Normal(0., initializer_range)))
+        self.q_proj = weight(hidden_size, num_heads
+                             * (qk_nope_head_dim + qk_rope_head_dim))
+        self.kv_a_proj = weight(hidden_size, kv_lora_rank + qk_rope_head_dim)
+        self.kv_a_norm = self.create_parameter(
+            [kv_lora_rank], default_initializer=Constant(1.0))
+        self.kv_b_proj = weight(kv_lora_rank, num_heads
+                                * (qk_nope_head_dim + v_head_dim))
+        self.o_proj = weight(num_heads * v_head_dim, hidden_size)
+
+    def forward(self, x, segment_ids, pre_norm=None, recompute=False):
+        H, eps = self.num_heads, self.epsilon
+        nope, rope, dv, rank = self.dims
+        dtype = compute_dtype()
+
+        def fn(x, seg, wq, wkva, norm, wkvb, wo):
+            from ...kernels.flash_attention import flash_attention_bhld
+            B, T, _ = x.shape
+            with jax.named_scope('mla.attention'):
+                q = _mm(x, wq, dtype).reshape(B, T, H, nope + rope)
+                kva = _mm(x, wkva, dtype)
+                c = kva[..., :rank].astype(jnp.float32)
+                c = c * jax.lax.rsqrt(jnp.mean(c * c, -1, keepdims=True)
+                                      + eps) * norm
+                kv = _mm(c, wkvb, dtype).reshape(B, T, H, nope + dv)
+                k_pe = jnp.broadcast_to(kva[..., None, rank:],
+                                        (B, T, H, rope))
+                k = jnp.concatenate([kv[..., :nope], k_pe.astype(kv.dtype)],
+                                    axis=-1)
+                q, k, v = (jnp.swapaxes(t, 1, 2)
+                           for t in (q, k, kv[..., nope:]))
+                o = flash_attention_bhld(
+                    q, k, v, causal=True,
+                    scale=1.0 / math.sqrt(nope + rope),
+                    doc_start=doc_starts(seg))
+                return _mm(jnp.swapaxes(o, 1, 2).reshape(B, T, H * dv), wo,
+                           dtype)
+
+        run, front = pre_normed(fn, pre_norm, recompute)
+        return apply_op(run, (x,) + front + (
+            segment_ids, self.q_proj, self.kv_a_proj, self.kv_a_norm,
+            self.kv_b_proj, self.o_proj))

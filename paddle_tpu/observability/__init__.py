@@ -37,6 +37,7 @@ from . import interpose, registry, spans, state, timing  # noqa: F401
 from . import aggregate, doctor, endpoint, flush  # noqa: F401  mission ctl
 from . import costs, flight, slo  # noqa: F401  cost explorer + black box
 from . import baseline, timeseries  # noqa: F401  time series + sentinel
+from . import step_counters  # noqa: F401  values of the compiled step
 from .state import enable, disable, enabled, log_dir, sync_every
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        get_registry, counter, gauge, histogram, snapshot,
@@ -80,6 +81,8 @@ __all__ = [
     'costs', 'slo', 'flight',
     # time series + cross-run regression sentinel
     'baseline', 'timeseries',
+    # counters that are values of the compiled train step
+    'step_counters',
 ]
 
 
@@ -93,6 +96,7 @@ def reset():
     slo.reset()
     flight.clear()
     timeseries.clear()
+    step_counters.clear()
 
 
 def __getattr__(name):

@@ -54,11 +54,13 @@ from . import events, registry, state
 
 __all__ = ['capture', 'record_compiled', 'mark_hit', 'ledger', 'entry',
            'summary', 'reset', 'DEVICE_PEAKS', 'device_peaks', 'roofline',
-           'hbm_budget', 'instruction_phases', 'phase_of_op_name', 'phases']
+           'hbm_budget', 'instruction_phases', 'phase_of_op_name', 'phases',
+           'register_scopes', 'instruction_scopes', 'scopes']
 
 _lock = threading.Lock()
 _ledger = {}         # program label -> entry dict
 _phases = {}         # program label -> {instruction name: phase}
+_scopes = {}         # program label -> {instruction name: layer scopes}
 
 
 # published peak (bf16 FLOP/s, HBM bytes/s) of one chip, keyed by
@@ -176,18 +178,12 @@ def phase_of_op_name(op_name):
     return 'other'
 
 
-def instruction_phases(hlo_text):
-    """``{instruction name: phase}`` for every instruction of a compiled
-    module's text (``Compiled.as_text()``). An instruction's phase is that
-    of its own ``op_name`` together with those of ALL instructions of the
-    computations it calls (a fusion's fused computation, a reduce's
-    reducer): one of ``forward`` / ``backward`` / ``update`` holds it alone,
-    or it is mixed and named by what it holds, in that order
-    (``backward+update``, ``forward+backward``: a ``+`` marks it); with none
-    of the three, ``guard`` if any instruction is the guard's, else
-    ``other``."""
-    own = {}                        # instruction -> (op_name phase, calls)
-    members = collections.defaultdict(list)     # computation -> instructions
+def _instructions(hlo_text):
+    """(own, members) of a compiled module's text: instruction ->
+    (its ``op_name`` or None, the computations it calls), computation -> its
+    instructions."""
+    own = {}
+    members = collections.defaultdict(list)
     current = None
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
@@ -198,11 +194,16 @@ def instruction_phases(hlo_text):
             continue
         name = m.group(1)
         op = _OP_NAME.search(line)
-        own[name] = (phase_of_op_name(op.group(1)) if op else None,
-                     _CALLED.findall(line))
+        own[name] = (op.group(1) if op else None, _CALLED.findall(line))
         members[current].append(name)
+    return own, members
 
-    closed = {}                     # computation -> set of phases inside it
+
+def _held(own, members, of):
+    """instruction -> the union of ``of(op_name)`` (a set) over its own
+    ``op_name`` and those of ALL instructions of the computations it calls
+    (a fusion's fused computation, a reduce's reducer)."""
+    closed = {}                     # computation -> what is inside it
 
     def inside(computation):
         if computation not in closed:
@@ -211,19 +212,68 @@ def instruction_phases(hlo_text):
         return closed[computation]
 
     def held(name):             # (HLO computations do not recurse)
-        phase, calls = own[name]
-        return ({phase} if phase else set()).union(
+        op_name, calls = own[name]
+        return (of(op_name) if op_name else set()).union(
             *(inside(computation) for computation in calls))
 
+    return {name: held(name) for name in own}
+
+
+def instruction_phases(hlo_text):
+    """``{instruction name: phase}`` for every instruction of a compiled
+    module's text (``Compiled.as_text()``). An instruction's phase is that
+    of its own ``op_name`` together with those of ALL instructions of the
+    computations it calls (a fusion's fused computation, a reduce's
+    reducer): one of ``forward`` / ``backward`` / ``update`` holds it alone,
+    or it is mixed and named by what it holds, in that order
+    (``backward+update``, ``forward+backward``: a ``+`` marks it); with none
+    of the three, ``guard`` if any instruction is the guard's, else
+    ``other``."""
+    own, members = _instructions(hlo_text)
     out = {}
-    for name in own:
-        found = held(name)
+    for name, found in _held(
+            own, members, lambda op: {phase_of_op_name(op)}).items():
         main = [p for p in _MAIN if p in found]
         if main:
             out[name] = '+'.join(main)
         else:
             out[name] = 'guard' if 'guard' in found else 'other'
     return out
+
+
+# -- layer scopes of a compiled module's instructions --------------------------
+
+_scope_names = set()    # the named scopes whose instructions are kept apart
+
+
+def register_scopes(*names):
+    """Name the ``jax.named_scope``s of a layer whose device time is read on
+    its own (``kda.scan``, ``moe.experts``): a step captured after this keeps,
+    for each of its instructions, which of them it lies under
+    (``scopes(program)``). A layer's module registers its scopes as it is
+    imported."""
+    with _lock:
+        _scope_names.update(names)
+
+
+def instruction_scopes(hlo_text, names=None):
+    """``{instruction name: sorted tuple of the scopes it lies under}`` for
+    the instructions of a compiled module's text that lie under any of
+    ``names`` (default: the registered ones): a scope is a component of the
+    instruction's ``op_name`` path, its own or that of an instruction of a
+    computation it calls (a fusion that mixes two layers lies under both)."""
+    with _lock:
+        wanted = frozenset(_scope_names if names is None else names)
+    own, members = _instructions(hlo_text)
+    found = _held(own, members, lambda op: wanted.intersection(op.split('/')))
+    return {name: tuple(sorted(f)) for name, f in found.items() if f}
+
+
+def scopes(program):
+    """The ``{instruction name: scopes}`` map kept for ``program`` (an engine
+    train step captured with ``phases=True``), or None."""
+    with _lock:
+        return _scopes.get(program)
 
 
 def phases(program):
@@ -265,13 +315,16 @@ def record_compiled(program, compiled, kind='jit', meta=None, phases=False):
         return None
     if phases:
         try:
-            found = instruction_phases(compiled.as_text())
+            text = compiled.as_text()
+            found = instruction_phases(text)
+            under = instruction_scopes(text)
         except Exception as e:
             events.emit('cost.capture_error', program=str(program),
                         error=repr(e))
         else:
             with _lock:
                 _phases[program] = found
+                _scopes[program] = under
             meta = dict(meta or {}, phase_instructions=dict(
                 collections.Counter(found.values())))
     try:
@@ -394,3 +447,4 @@ def reset():
     with _lock:
         _ledger.clear()
         _phases.clear()
+        _scopes.clear()

@@ -5,6 +5,8 @@ from .bert import (BertConfig, BertModel, BertForPretraining,
 from .ernie import (ErnieModel, ErnieForPretraining, ErnieConfig,
                     ernie_knowledge_mask, ernie_mask_batch)
 from .gpt import GPTConfig, GPTModel, gpt_small
+from .kimi_linear import (KimiLinearConfig, KimiLinearBlock,
+                          KimiLinearForCausalLM)
 from .seq2seq import Seq2SeqTransformer
 from .word2vec import SkipGram, Word2Vec
 from .lm import LSTMLanguageModel

@@ -87,13 +87,36 @@ def test_flash_tiling_compiles_fwd_bwd(one_chip, seq, causal, kpad, p, bq,
     scale = D ** -0.5
 
     def loss(q, k, v, bias, seed):
-        out = fa._flash(q, k, v, bias if kpad else None, seed, causal, scale,
-                        bq, bk, p, False)
+        out = fa._flash(q, k, v, bias if kpad else None, seed, None, causal,
+                        scale, bq, bk, p, False)
         return jnp.sum(out.astype(jnp.float32))
 
     qkv = ((B, H, seq, D), jnp.bfloat16)
     _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv,
              ((B, seq), jnp.float32), ((1, 1), jnp.int32))
+
+
+def test_flash_latent_attention_shapes_compile_fwd_bwd(one_chip):
+    """Kimi-Linear's full-attention layer at its real shapes: rows of 8192,
+    32 heads, q and k 192 wide, v 128 wide, packed documents. A head's whole
+    K and V (forward), Q, O, dO and dQ (backward) stay in VMEM: past the 16
+    MiB a kernel gets unasked, so both ask for their own limit."""
+    rows, heads, seq = 2, 32, 8192
+
+    def loss(q, k, v, start):
+        out = fa._flash(q, k, v, None, jnp.zeros((1, 1), jnp.int32), start,
+                        True, 192 ** -0.5, 512, 512, 0.0, False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qk = ((rows, heads, seq, 192), jnp.bfloat16)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, qk, qk,
+                    ((rows, heads, seq, 128), jnp.bfloat16),
+                    ((rows, seq), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert fa._fwd_vmem_limit(seq, 192, 128, 512, 2, 2) > (16 << 20)
+    # BERT's calls are inside what a kernel gets unasked: no limit is set
+    assert fa._fwd_vmem_limit(512, 64, 64, 512, 2, 1) is None
+    assert fa._bwd_vmem_limit(512, 64, 512, 512, 2) is None
 
 
 _X = ((ROWS, HIDDEN), jnp.bfloat16)
@@ -333,3 +356,57 @@ def test_partitioned_step_names_its_kernels_and_its_gathers(topo,
     lowered = _small_bert_step_text(topo, monkeypatch, sharded=True,
                                     lowered=True)
     assert 'fsdp.reshard' in lowered and 'fsdp.gather' in lowered
+
+
+def test_hybrid_step_holds_its_kernels_and_its_layers_scopes(topo,
+                                                             monkeypatch):
+    """Kimi-Linear at a small width (heads of the real sizes: 128 for the
+    delta rule, 192 / 128 for latent attention; rows of 1024 so that
+    attention takes the flash kernels) through `engine.build_train_step`
+    under bf16 autocast with per-block recomputation, compiled for one
+    described chip: the flash and RMS-norm kernels are in it, and every
+    layer scope the benchmark reads names instructions of the compiled
+    module (`observability.costs.instruction_scopes`)."""
+    import paddle_tpu as paddle
+    from paddle_tpu import amp, engine, optimizer
+    from paddle_tpu.nn.layer_base import buffer_values, param_values
+    from paddle_tpu.observability import costs
+    from paddle_tpu.text.kimi_linear import (KimiLinearConfig,
+                                             KimiLinearForCausalLM)
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    paddle.seed(0)
+    net = KimiLinearForCausalLM(KimiLinearConfig(
+        vocab_size=1024, hidden_size=256, num_hidden_layers=5,
+        num_attention_heads=2, head_dim=128, intermediate_size=512,
+        moe_intermediate_size=128, num_experts=16, num_experts_per_token=4,
+        experts_held=(0, 4), kv_lora_rank=128, recompute=True))
+    net.train()
+    step = engine.build_train_step(
+        net=net, loss=net.training_loss,
+        optimizer=optimizer.AdamW(learning_rate=1e-4, weight_decay=0.1))
+    one = SingleDeviceSharding(topo.devices[0])
+    state = jax.tree_util.tree_map(
+        lambda v: jax.ShapeDtypeStruct(np.shape(v), v.dtype, sharding=one),
+        step.init_state(param_values(net), buffer_values(net)))
+    feed = tuple(jax.ShapeDtypeStruct((2, 1024), jnp.int32, sharding=one)
+                 for _ in range(3))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
+    with amp.auto_cast(dtype='bfloat16'):
+        text = step._jit.lower(state, (feed, ()), key).compile().as_text()
+    calls = _CUSTOM_CALL.findall(text)
+    assert any(c.startswith('flash_attention.pallas') for c in calls)
+    assert any(c.startswith('fused_rms_norm.pallas') for c in calls)
+    assert 'flash_attention.xla' not in text
+    under = costs.instruction_scopes(text)
+    found = {scope for scopes in under.values() for scope in scopes}
+    assert found >= {'kda.scan', 'kda.proj', 'mla.attention', 'moe.route',
+                     'moe.experts', 'moe.shared', 'lm_head',
+                     'fused_rms_norm.pallas', 'update'}
+    assert all('fused_rms_norm.pallas' in under[c] for c in calls
+               if c.startswith('fused_rms_norm.pallas'))
+    assert all('mla.attention' in under[c] for c in calls
+               if c.startswith('flash_attention.pallas'))
+    phases = costs.instruction_phases(text)
+    assert {phases[c] for c in calls
+            if c.startswith('flash_attention.pallas')} == {'forward',
+                                                           'backward'}

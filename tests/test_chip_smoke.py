@@ -32,6 +32,15 @@ TINY = dict(
     lm_prompts=(3, 3, 9, 12), lm_new_tokens=4,
     kernel_shape=(2, 2, 128, 16),
     sharded=(16, 8, 12), sharded_kernels=(4, 4, 128, 16),
+    hybrid=dict(
+        config=dict(vocab_size=64, hidden_size=32, num_hidden_layers=5,
+                    num_attention_heads=2, head_dim=16, intermediate_size=48,
+                    moe_intermediate_size=16, num_experts=16,
+                    num_experts_per_token=4, experts_held=(4, 8),
+                    kv_lora_rank=8, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                    v_head_dim=16, recompute=True, kda_chunk=16,
+                    moe_block=8),
+        seq=64, rows=2, steps=12, lr=3e-3, fall=0.3),
 )
 _CHILD = ("import json, sys, chip_smoke; "
           "chip_smoke.run(json.loads(sys.argv[1]), chips=int(sys.argv[2]), "
@@ -71,6 +80,11 @@ def test_rehearsal_one_chip(tmp_path):
     assert by['generate'][0]['tokens_equal_reference'] is True
     assert by['generate'][0]['compiles_after_warmup'] == 0
     assert 'NOT a real model' in by['generate'][0]['spec']
+    hybrid = by['hybrid'][0]
+    assert hybrid['compiles_after_first_step'] == 0
+    assert hybrid['losses'][-1] < hybrid['losses'][0] - 0.3
+    assert hybrid['counters']['moe.dropped'] == 0
+    assert hybrid['counters']['moe.assignments'] == 4 * 2 * 64 * 4
     assert 'done' in by
 
 
