@@ -16,7 +16,9 @@ a user calls:
                 gradients; the dropout-on flash backward against finite
                 differences of its own forward along a direction of q, k
                 and v. Interpret mode stubs that PRNG, so only a chip
-                checks it.
+                checks it. The delta rule's backward kernel against finite
+                differences of its forward kernel along a direction of q,
+                k, v, g and beta.
 - ``train``     ``BertForPretraining`` through ``engine.build_train_step(
                 net=, loss=, optimizer=AdamW)``, bf16 compute
                 (``amp.auto_cast``), dropout on, donation on: a few dozen
@@ -35,7 +37,8 @@ a user calls:
                 parameters) through the same ``build_train_step``: a few
                 steps on one batch of packed rows of 8192 tokens. Loss
                 finite and falling, no compile after the first step, no
-                routing assignment dropped, the flash kernels in the step.
+                routing assignment dropped, the flash and the delta-rule
+                kernels in the step (and nothing under ``delta_rule.xla``).
 - ``serve``     the trained BERT-large encoder behind ``ServingEngine.
                 register(layer=, example=, bucket_spec=)``: requests of mixed
                 lengths through ``submit``, all ``ok``, no compile after
@@ -148,13 +151,15 @@ def phase_kernels(size, rehearsal):
     t0 = time.perf_counter()
     errs = checks.check_flash_against_reference(size['kernel_shape'],
                                                 interpret=rehearsal)
-    dropout_backward = None
-    if not rehearsal:   # interpret mode has no hardware PRNG
-        checks.check_flash_dropout()
+    dropout_backward = delta_rule_backward = None
+    if not rehearsal:   # interpret mode has no hardware PRNG (and takes
+        checks.check_flash_dropout()    # a minute over the delta rule)
         checks.check_norm_dropout()
         dropout_backward = checks.check_flash_dropout_backward()
+        delta_rule_backward = checks.check_delta_rule_backward()
     say('kernels', shape=list(size['kernel_shape']), max_abs_err=errs,
         dropout_checked=not rehearsal, dropout_backward=dropout_backward,
+        delta_rule_backward=delta_rule_backward,
         seconds=round(time.perf_counter() - t0, 2))
 
 
@@ -278,7 +283,8 @@ def step_hlo_facts(step, state, batch):
             'scopes': {s: text.count(s) for s in (
                 'flash_attention.pallas', 'flash_attention.xla',
                 'fused_dropout_norm.pallas', 'fused_dropout_norm.xla',
-                'fused_layer_norm.pallas', 'fused_layer_norm.xla')}}
+                'fused_layer_norm.pallas', 'fused_layer_norm.xla',
+                'delta_rule.pallas', 'delta_rule.xla')}}
 
 
 def _ffn_weight(state):
@@ -404,10 +410,11 @@ def phase_hybrid(size, seed, rehearsal):
     batch = packed_batch(h['config']['vocab_size'], h['seq'], h['rows'],
                          seed + 27)
     hlo = step_hlo_facts(step, state, batch)
-    if not rehearsal and not (hlo['scopes']['flash_attention.pallas']
-                              and not hlo['scopes']['flash_attention.xla']):
+    if not rehearsal and not all(
+            hlo['scopes'][k + '.pallas'] and not hlo['scopes'][k + '.xla']
+            for k in ('flash_attention', 'delta_rule')):
         raise AssertionError('the hybrid step does not hold the Pallas '
-                             'flash kernels: %s' % hlo)
+                             'flash and delta-rule kernels: %s' % hlo)
     c0, s0 = Compiles.count(), Compiles.seconds()
     counters = []
     state, losses, ms, after_first = run_steps(

@@ -4,7 +4,10 @@ Implemented here (each with interpret-mode CPU tests):
 - flash_attention: forward + backward kernels, causal/non-causal, key-padding
   bias, in-kernel PRNG attention dropout (kernels/flash_attention.py);
 - fused layer norm / rms norm forward kernels with closed-form backward
-  (kernels/fused_norm.py).
+  (kernels/fused_norm.py);
+- the chunk-wise gated delta rule of Kimi Delta Attention: a forward and a
+  backward kernel that carry the state across a head's chunks in VMEM
+  (kernels/delta_rule.py).
 
 These replace the reference's hand-written CUDA/cuDNN kernels
 (paddle/fluid/operators/fused/*attention*, layer_norm_op.cu) with TPU-native

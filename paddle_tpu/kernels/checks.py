@@ -11,6 +11,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ._common import kernel_mesh
 from .autotune import make_device_qkv
+from .delta_rule import delta_rule
 from .flash_attention import _attn_reference, flash_attention_bhld
 from .fused_dropout_norm import fused_dropout_add_layer_norm
 
@@ -97,6 +98,64 @@ def check_flash_dropout_backward(shape=(2, 16, 512, 64), dropout_p=0.1,
                 'flash dropout backward: d%s along a direction reads %g, '
                 'finite differences of the forward %g: the backward does '
                 'not differentiate the forward it ran with' % (n, an, fd))
+    return got
+
+
+def check_delta_rule_backward(shape=(1, 1024, 4, 128), interpret=False):
+    """The delta rule's backward kernel against finite differences of its
+    forward kernel: for a direction u of each of q, k, v, g and beta,
+    ``(loss(x + eps u) - loss(x - eps u)) / 2 eps`` must equal
+    ``<grad, u>``. float32 operands, every product at ``highest``, four
+    documents whose boundaries fall inside sub-blocks. A direction is
+    noise plus the gradient's own direction at the noise's length, clipped
+    to [-4, 4] (alone, noise gives a derivative that is a sum of terms of
+    both signs, small beside the rounding of the two losses; g stays below
+    zero on both sides). Returns ``{name: (finite difference, <grad, u>)}``;
+    raises where they differ by over 1%."""
+    eps, tol = 1e-2, 1e-2
+    B, T, H, K = shape
+    keys = jax.random.split(jax.random.PRNGKey(0), 11)
+    wide, narrow = (B, T, H, K), (B, T, H)
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+
+    x = [unit(jax.random.normal(keys[0], wide)),
+         unit(jax.random.normal(keys[1], wide)),
+         jax.random.normal(keys[2], wide),
+         -0.1 - jax.nn.softplus(jax.random.normal(keys[3], wide)),
+         jax.nn.sigmoid(jax.random.normal(keys[4], narrow))]
+    noise = [jax.random.normal(key, t.shape) for key, t in zip(keys[5:10], x)]
+    weight = jax.random.normal(keys[10], wide)
+    at = jnp.arange(T, dtype=jnp.int32)
+    seg = (at * 3 // T + (at >= T // 2 + 5))[None].repeat(B, 0)
+
+    def loss(*x):
+        return jnp.sum(weight * delta_rule(*x, seg, K ** -0.5,
+                                           interpret=interpret))
+
+    @jax.jit
+    def readings(*x):
+        grads = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*x)
+        out = []
+        for i, (g, z) in enumerate(zip(grads, noise)):
+            u = jnp.clip(z + g * jnp.sqrt(jnp.sum(z * z) / jnp.sum(g * g)),
+                         -4.0, 4.0)
+
+            def moved(t):
+                return loss(*x[:i], x[i] + t * u, *x[i + 1:])
+            out.append(((moved(eps) - moved(-eps)) / (2 * eps),
+                        jnp.sum(g * u)))
+        return out
+
+    with jax.default_matmul_precision('highest'):
+        got = {n: (float(fd), float(an)) for n, (fd, an) in zip(
+            ('q', 'k', 'v', 'g', 'beta'), readings(*x))}
+    for n, (fd, an) in got.items():
+        if not abs(fd - an) <= tol * max(abs(fd), abs(an)):
+            raise AssertionError(
+                'delta rule backward: d%s along a direction reads %g, '
+                'finite differences of the forward %g' % (n, an, fd))
     return got
 
 

@@ -1,0 +1,430 @@
+"""The chunk-wise gated delta rule (`nn/functional/delta_rule.py` has the
+mathematics) as a Pallas kernel pair under one `jax.custom_vjp`.
+
+q, k, v, g arrive as the projections leave them, `(B, T, H*K)` seen as a
+2-D array per row in which a `(C, K)` block at column `h*K` is one head's
+chunk: no transpose to a chunk layout, each operand read once, `o` written
+once in the layout the output norm reads. The grid is (row, heads, chunks);
+the chunks of a head run in order and carry the state in a VMEM scratch
+(transposed, `(V, K)`: the decay then scales lanes and every product with the
+state is of the `a @ b.T` form). Everything chunk-local — the running sum of
+g, the sub-block references, the scores, the unit-triangular inverse, the
+corrections `U` — lives in VMEM.
+
+Per chunk, with `S` the state it starts from:
+
+    U  = (I + Diag(beta) A)^-1 Diag(beta) (V - k_in S)
+    o  = scale (q_in S + B U)
+    S' = Diag(exp(G_C)) S + k_out^T U
+
+which is the XLA form's `U0 - W S` without `W` ever made. The inverse is
+forward substitution in blocks (`_unit_lower_inverse`): the `sub x sub`
+diagonal blocks by rank-one updates on the VPU, the rest by products; those
+products, the scores, the running sum and `k_in S` run in float32 at the
+highest precision, as the XLA form's scores, solve and state recurrence do. `B U`, `q_in S` and `k_out^T U` take `dtype`
+operands, as there.
+
+The backward kernel sweeps a head's chunks in reverse carrying dS: it
+rebuilds the chunk from q, k, v, g, beta and the chunk's start state (which
+the forward saves) and takes `jax.vjp` of the chunk-local function inside
+the kernel body, so forward and backward share one definition of the chunk.
+
+Off the TPU (unless a test asks for interpret mode), or for a shape that
+does not tile, `delta_rule` takes `delta_rule_chunked`, the XLA form, which
+is also what the tests hold the kernels to; which one a trace took is marked
+in the HLO (`_common.took`).
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ._common import pallas_runs, spmd_kernel, took
+
+__all__ = ['delta_rule']
+
+_HIGH = jax.lax.Precision.HIGHEST
+_F32 = jnp.float32
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+_MARKS = 8      # rows of a chunk's marks block: seg, cont, tail, padding
+
+
+def _dot(a, b, dims, precision=None):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=precision,
+                               preferred_element_type=_F32)
+
+
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _block_of(index, size, blocks):
+    """index // size for 0 <= index < size * blocks, without a division."""
+    out = jnp.zeros_like(index)
+    for i in range(1, blocks):
+        out = out + (index >= i * size).astype(jnp.int32)
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _unit_lower_inverse(sub):
+    """-> inv(N, known): (I + N)^-1 of a strictly lower triangular N (C, C),
+    in float32; `known`, where it is not None, is that inverse as an earlier
+    pass made it (the backward kernel reads the forward's) and only the
+    derivative is taken here.
+
+    Forward substitution, blocked. A `sub`-block of the diagonal is
+    I + sum_j n_j e_j^T = prod_j (I + n_j e_j^T) (n_j, column j, is zero down
+    to row j), so its inverse is the factors' inverses I - n_j e_j^T applied
+    to I in turn: `sub - 1` rank-one updates on the VPU, exact float32
+    multiply-adds. Then block row i of the whole inverse is
+    D_i (I - N[i, :i] X[:i]): two products of `sub` rows at the highest
+    precision."""
+
+    def inverse(n_):
+        C = n_.shape[0]
+        row, lane = _iota((sub, C), 0), _iota((sub, C), 1)
+        diag = []                       # D_i, in its place among C lanes
+        for lo in range(0, C, sub):
+            x = (lane == row + lo).astype(_F32)
+            for j in range(sub - 1):
+                x = x - n_[lo:lo + sub, lo + j:lo + j + 1] * x[j:j + 1]
+            diag.append(x)
+        rows = diag[:1]
+        for i in range(1, C // sub):
+            above = _dot(diag[i], n_, _NN, _HIGH)       # D_i N[i, :]
+            above = jnp.where(lane < i * sub, above, 0.0)
+            rows.append(diag[i] - _dot(
+                above, jnp.concatenate(rows + diag[i:], axis=0), _NN, _HIGH))
+        return jnp.concatenate(rows, axis=0)
+
+    @jax.custom_vjp
+    def inv(n_, known):
+        return inverse(n_) if known is None else known
+
+    def fwd(n_, known):
+        out = inv(n_, known)
+        return out, out
+
+    def bwd(out, ct):
+        # d(X^-1) = -X^-1 dX X^-1
+        return -_dot(_dot(out, ct, _TN, _HIGH), out, _NT, _HIGH), None
+
+    inv.defvjp(fwd, bwd)
+    return inv
+
+
+def _scores_between(G, k, q, sub):
+    """sum_c x_t[c] k_j[c] exp(G_t[c] - G_j[c]) for x = k and x = q, j in a
+    sub-block before t's: both factors are taken from the start of t's
+    sub-block (G at the row before it), where each is at most 1; the rows
+    of sub-block i against the keys as sub-block i sees them. -> two
+    (C, C); entries that are not between sub-blocks are not to be read."""
+    C = G.shape[0]
+    a_rows = [jnp.zeros((sub, C), _F32)]
+    b_rows = [jnp.zeros((sub, C), _F32)]
+    for lo in range(sub, C, sub):
+        ref = G[lo - 1:lo]
+        row = jnp.exp(G[lo:lo + sub] - ref)
+        k_col = k * jnp.exp(jnp.minimum(ref - G, 0.0))
+        ab = _dot(jnp.concatenate([k[lo:lo + sub] * row,
+                                   q[lo:lo + sub] * row], axis=0),
+                  k_col, _NT, _HIGH)
+        a_rows.append(ab[:sub])
+        b_rows.append(ab[sub:])
+    return jnp.concatenate(a_rows, 0), jnp.concatenate(b_rows, 0)
+
+
+@jax.jit
+def _scores_in_block(G, k, q, local):
+    """One sub-block of `_scores_inside`: G, k, q (sub, K); `local` (sub, C),
+    each lane's index counted from the block's first key. A jit, so that the
+    unrolled loop is traced once for every sub-block, head and layer."""
+    a = b = jnp.zeros(local.shape, _F32)
+    for j in range(G.shape[0]):
+        kk = k[j:j + 1] * jnp.exp(jnp.minimum(G - G[j:j + 1], 0.0))
+        hit = local == j
+        a = jnp.where(hit, jnp.sum(kk * k, -1, keepdims=True), a)
+        b = jnp.where(hit, jnp.sum(kk * q, -1, keepdims=True), b)
+    return a, b
+
+
+def _scores_inside(G, k, q, sub):
+    """The same scores inside each sub-block, the exponent formed before
+    the exponential: one key a step, its column of scores put in place by
+    a mask. -> two (C, C), filled on the sub-blocks of the diagonal."""
+    C = G.shape[0]
+    lane = _iota((sub, C), 1)
+    blocks = [_scores_in_block(G[lo:lo + sub], k[lo:lo + sub],
+                               q[lo:lo + sub], lane - lo)
+              for lo in range(0, C, sub)]
+    return (jnp.concatenate([a for a, _ in blocks], 0),
+            jnp.concatenate([b for _, b in blocks], 0))
+
+
+def _chunk(q, k, v, g, beta, state, seg_c, seg_r, cont, tail, inverse=None, *,
+           scale, sub, dtype):
+    """One head's chunk. q, k, g (C, K); v (C, V); beta, seg_c, cont, tail
+    (C, 1); seg_r (1, C); state (V, K), the transposed state the chunk
+    starts from; `inverse`: the chunk's (I + Diag(beta) A)^-1 where a pass
+    before this one kept it -> o (C, V), the state the chunk ends with, the
+    inverse. All float32."""
+    C = q.shape[0]
+    blocks = C // sub
+    t, j = _iota((C, C), 0), _iota((C, C), 1)
+    block_t, block_j = _block_of(t, sub, blocks), _block_of(j, sub, blocks)
+    same = seg_c == seg_r
+    lower, strict = (t >= j) & same, (t > j) & same
+    earlier = block_t > block_j
+
+    def mm(a, b, dims):
+        if dtype is not None:
+            a, b = a.astype(dtype), b.astype(dtype)
+        return _dot(a, b, dims)
+
+    G = _dot((j <= t).astype(_F32), g, _NN, _HIGH)    # the running sum of g
+    a_far, b_far = _scores_between(G, k, q, sub)
+    a_near, b_near = _scores_inside(G, k, q, sub)
+    A = jnp.where(strict, jnp.where(earlier, a_far, a_near), 0.0)
+    Bm = jnp.where(lower, jnp.where(earlier, b_far, b_near), 0.0)
+
+    inverse = _unit_lower_inverse(sub)(beta * A, inverse)
+    from_start = jnp.exp(G) * cont
+    k_in, q_in = k * from_start, q * from_start
+    U = _dot(inverse, beta * (v - _dot(k_in, state, _NT, _HIGH)), _NN, _HIGH)
+    o = scale * (mm(q_in, state, _NT) + mm(Bm, U, _NN))
+    G_end = G[C - 1:C]
+    k_out = k * jnp.exp(G_end - G) * tail
+    keep = jnp.exp(G_end) * cont[C - 1:C]
+    return o, keep * state + mm(U, k_out, _TN), inverse
+
+
+def _columns(marks_ref):
+    """A chunk's marks block (8, C) -> seg as a row, and seg, cont, tail as
+    columns; with them the function that turns any (1, C) row into a (C, 1)
+    column and its inverse (a masked reduction: no transpose)."""
+    C = marks_ref.shape[-1]
+    eye = _iota((C, C), 0) == _iota((C, C), 1)
+
+    def column(r):
+        return jnp.sum(jnp.where(eye, r, 0.0), axis=1, keepdims=True)
+
+    def as_row(c):
+        return jnp.sum(jnp.where(eye, c, 0.0), axis=0, keepdims=True)
+
+    marks = marks_ref[...]
+    return (marks[0:1], column(marks[0:1]), column(marks[1:2]),
+            column(marks[2:3]), column, as_row)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, marks_ref, o_ref,
+                *rest, heads, K, V, save, **chunk):
+    start_ref, inverse_ref = rest[:2] if save else (None, None)
+    state_ref = rest[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    seg_r, seg_c, cont, tail, column, _ = _columns(marks_ref)
+    one_head = jax.jit(functools.partial(_chunk, **chunk))  # traced once
+    for h in range(heads):
+        ks, vs = slice(h * K, (h + 1) * K), slice(h * V, (h + 1) * V)
+        state = state_ref[h]
+        if save:
+            start_ref[h] = state
+        o, state, inverse = one_head(
+            q_ref[:, ks], k_ref[:, ks], v_ref[:, vs], g_ref[:, ks],
+            column(beta_ref[h]), state, seg_c, seg_r, cont, tail)
+        o_ref[:, vs] = o
+        state_ref[h] = state
+        if save:
+            inverse_ref[h] = inverse
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, marks_ref, start_ref,
+                inverse_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                dbeta_ref, dstate_ref, *, heads, K, V, **chunk):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate_ref[...] = jnp.zeros_like(dstate_ref)
+
+    seg_r, seg_c, cont, tail, column, as_row = _columns(marks_ref)
+
+    @jax.jit        # traced once for all the heads of a step
+    def one_head(q, k, v, g, beta, state, inverse, do, dstate):
+        _, vjp = jax.vjp(
+            lambda *x: _chunk(*x, seg_c, seg_r, cont, tail, inverse,
+                              **chunk)[:2], q, k, v, g, beta, state)
+        return vjp((do, dstate))
+
+    for h in range(heads):
+        ks, vs = slice(h * K, (h + 1) * K), slice(h * V, (h + 1) * V)
+        dq, dk, dv, dg, dbeta, dstate = one_head(
+            q_ref[:, ks], k_ref[:, ks], v_ref[:, vs], g_ref[:, ks],
+            column(beta_ref[h]), start_ref[h], inverse_ref[h], do_ref[:, vs],
+            dstate_ref[h])
+        dq_ref[:, ks], dk_ref[:, ks], dg_ref[:, ks] = dq, dk, dg
+        dv_ref[:, vs] = dv
+        dbeta_ref[h] = as_row(dbeta)
+        dstate_ref[h] = dstate
+
+
+_VMEM_LIMIT = 64 << 20    # the backward holds a chunk's whole vjp per head
+
+
+def _specs(heads, C, K, V, chunk_of):
+    """BlockSpecs over the grid (row, head group, chunk step); `chunk_of`
+    maps the step to the chunk (the backward runs them in reverse)."""
+    def wide(width):        # (B, T, H*width): a (C, heads*width) block
+        return pl.BlockSpec((None, C, heads * width),
+                            lambda b, h, n: (b, chunk_of(n), h))
+    per_head = pl.BlockSpec((None, heads, None, 1, C),      # (B, H, N, 1, C)
+                            lambda b, h, n: (b, h, chunk_of(n), 0, 0))
+    marks = pl.BlockSpec((None, None, _MARKS, C),           # (B, N, 8, C)
+                         lambda b, h, n: (b, chunk_of(n), 0, 0))
+    def kept(rows, cols):   # (B, H, N, rows, cols): a matrix a chunk-head
+        return pl.BlockSpec((None, heads, None, rows, cols),
+                            lambda b, h, n: (b, h, chunk_of(n), 0, 0))
+    return wide(K), wide(V), per_head, marks, kept(V, K), kept(C, C)
+
+
+# `_forward` and `_backward` are jits of their own: a net's KDA layers make
+# the same calls, and a jit inside the step's trace is traced (the unrolled
+# chunk, and its vjp, are thousands of equations) and lowered once for all
+# of them. The scope is entered again inside: the compiler names a custom
+# call after its innermost scope.
+_STATIC = ('scale', 'chunk', 'sub', 'dtype', 'heads', 'interpret')
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC + ('save',))
+def _forward(q, k, v, g, beta, marks, *, save, scale, chunk, sub, dtype,
+             heads, interpret):
+    """-> o, and where `save` what the backward reads: the chunks' start
+    states (B, H, N, V, K) and inverses (B, H, N, C, C)."""
+    B, T, HK = q.shape
+    H, C = beta.shape[1], chunk
+    K, V, N = HK // H, v.shape[2] // H, T // C
+    wide_k, wide_v, per_head, marks_spec, states, inverses = _specs(
+        heads, C, K, V, lambda n: n)
+    out_specs, out_shape = [wide_v], [jax.ShapeDtypeStruct(v.shape, _F32)]
+    # graftlint: disable=GL006 — `save` is a static argument of this jit
+    # (never a tracer): one trace with the kept outputs, one without
+    if save:
+        out_specs += [states, inverses]
+        out_shape += [jax.ShapeDtypeStruct((B, H, N, V, K), _F32),
+                      jax.ShapeDtypeStruct((B, H, N, C, C), _F32)]
+    with jax.named_scope('delta_rule.pallas'):
+        return pl.pallas_call(
+            functools.partial(_fwd_kernel, heads=heads, K=K, V=V, save=save,
+                              scale=scale, sub=sub, dtype=dtype),
+            grid=(B, H // heads, N),
+            in_specs=[wide_k, wide_k, wide_v, wide_k, per_head, marks_spec],
+            out_specs=out_specs, out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((heads, V, K), _F32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=('parallel', 'parallel', 'arbitrary'),
+                vmem_limit_bytes=_VMEM_LIMIT),
+            interpret=interpret,
+        )(q, k, v, g, beta, marks)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _backward(q, k, v, g, beta, marks, starts, inverses, do, *, scale, chunk,
+              sub, dtype, heads, interpret):
+    B, T, HK = q.shape
+    H, C = beta.shape[1], chunk
+    K, V, N = HK // H, v.shape[2] // H, T // C
+    wide_k, wide_v, per_head, marks_spec, states, kept_inverses = _specs(
+        heads, C, K, V, lambda n: N - 1 - n)
+    with jax.named_scope('delta_rule.pallas'):
+        return pl.pallas_call(
+            functools.partial(_bwd_kernel, heads=heads, K=K, V=V, scale=scale,
+                              sub=sub, dtype=dtype),
+            grid=(B, H // heads, N),
+            in_specs=[wide_k, wide_k, wide_v, wide_k, per_head, marks_spec,
+                      states, kept_inverses, wide_v],
+            out_specs=[wide_k, wide_k, wide_v, wide_k, per_head],
+            out_shape=[jax.ShapeDtypeStruct(x.shape, _F32)
+                       for x in (q, k, v, g, beta)],
+            scratch_shapes=[pltpu.VMEM((heads, V, K), _F32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=('parallel', 'parallel', 'arbitrary'),
+                vmem_limit_bytes=_VMEM_LIMIT),
+            interpret=interpret,
+        )(q, k, v, g, beta, marks, starts, inverses, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _delta(q, k, v, g, beta, marks, static):
+    return _forward(q, k, v, g, beta, marks, save=False, **dict(static))[0]
+
+
+def _delta_fwd(q, k, v, g, beta, marks, static):
+    o, *kept = _forward(q, k, v, g, beta, marks, save=True, **dict(static))
+    return o, (q, k, v, g, beta, marks, *kept)
+
+
+def _delta_bwd(static, res, do):
+    return (*_backward(*res, do, **dict(static)), None)
+
+
+_delta.defvjp(_delta_fwd, _delta_bwd)
+
+
+def _marks(seg, chunk):
+    """(B, T) document numbers -> (B, N, 8, C) float32: per chunk the
+    document number of each position, whether it sees the state the chunk
+    starts from (`cont`), whether its update outlives the chunk (`tail`)."""
+    B, T = seg.shape
+    sc = seg.reshape(B, T // chunk, chunk)
+    before = jnp.concatenate([jnp.full((B, 1), -1, sc.dtype), sc[:, :-1, -1]],
+                             axis=1)
+    rows = [sc, sc == before[:, :, None], sc == sc[:, :, -1:]]
+    rows += [jnp.zeros_like(sc)] * (_MARKS - len(rows))
+    return jnp.stack([r.astype(_F32) for r in rows], axis=2)
+
+
+def _heads_a_step(H):
+    """Heads taken in one grid step: their chunks are independent chains
+    (the inverse's rank-one updates above all), which the scheduler
+    interleaves. One row-layer of the cell, forward + backward: 27.7 ms at
+    1, 26.1 at 2 (my chip run, PR 28); each head more is lowered again."""
+    return 2 if H % 2 == 0 else 1
+
+
+def delta_rule(q, k, v, g, beta, seg, scale, chunk=64, sub=16, dtype=None,
+               interpret=False):
+    """The gated delta rule chunk-wise, as `delta_rule_chunked` defines it:
+    q, k, g (B, T, H, K); v (B, T, H, V); beta (B, T, H); seg (B, T)
+    -> o (B, T, H, V), float32. On the TPU (or in interpret mode), with T a
+    multiple of `chunk`, `chunk` of `sub`, `sub` of the 8 sublanes and K and
+    V of the 128 lanes, the Pallas kernels; otherwise the XLA form. Either
+    way the ops sit under a `delta_rule.pallas` / `delta_rule.xla` scope."""
+    T, K, V = q.shape[1], q.shape[3], v.shape[-1]
+    if not (pallas_runs(interpret) and T % chunk == 0 and chunk % sub == 0
+            and sub % 8 == 0 and K % 128 == 0 and V % 128 == 0):
+        from ..nn.functional.delta_rule import delta_rule_chunked
+        with took('delta_rule', 'xla'):
+            return delta_rule_chunked(q, k, v, g, beta, seg, scale,
+                                      chunk=chunk, sub=sub, dtype=dtype)
+    if dtype is not None:
+        dtype = jnp.dtype(dtype).name       # hashable, for the custom_vjp
+
+    def call(q, k, v, g, beta, marks, shard):
+        b, _, h, _ = q.shape                # this device's rows and heads
+        wide = [x.astype(_F32).reshape(b, T, -1) for x in (q, k, v, g)]
+        per_head = jnp.moveaxis(beta.astype(_F32), 1, 2).reshape(
+            b, h, T // chunk, 1, chunk)
+        o = _delta(*wide, per_head, marks, tuple(zip(_STATIC, (
+            scale, chunk, sub, dtype, _heads_a_step(h), interpret))))
+        return o.reshape(b, T, h, V)
+
+    dims = ('b', None, 'h', None)
+    with took('delta_rule', 'pallas'):
+        return spmd_kernel(
+            call, [dims] * 4 + [('b', None, 'h'), ('b', None, None, None)],
+            [dims], {'b': 'batch', 'h': 'heads'},
+            scope='delta_rule.pallas')(q, k, v, g, beta, _marks(seg, chunk))

@@ -10,9 +10,10 @@ import jax
 import jax.numpy as jnp
 
 from ...core.tensor import apply_op
+from ...kernels.delta_rule import delta_rule
 from ..initializer import Constant, Normal, ParamAttr
 from ..layer_base import Layer
-from ..functional.delta_rule import causal_conv, delta_rule_chunked
+from ..functional.delta_rule import causal_conv
 from ..functional.norm import rms_norm_values
 
 __all__ = ['KimiDeltaAttention', 'LatentAttention', 'compute_dtype',
@@ -66,7 +67,8 @@ class KimiDeltaAttention(Layer):
     """q, k = l2norm(silu(conv(x W))), v = silu(conv(x W_v)) per head; a
     log-decay per channel g = -exp(A_log) softplus(x W_a1 W_a2 + dt_bias);
     beta = sigmoid(x W_beta) per head; the delta rule chunk-wise
-    (`functional.delta_rule`); W_o [RMSNorm_head(o) * sigmoid(x W_g1 W_g2)].
+    (`kernels.delta_rule`: the Pallas kernels on the TPU, off it the XLA
+    form of `functional.delta_rule`); W_o [RMSNorm_head(o) * sigmoid(x W_g1 W_g2)].
     """
 
     def __init__(self, hidden_size, num_heads, head_dim, conv_kernel=4,
@@ -130,9 +132,9 @@ class KimiDeltaAttention(Layer):
                     gate = jax.nn.sigmoid(
                         _mm(_mm(x, ga, dtype), gb, dtype).astype(f32))
                 with jax.named_scope('kda.scan'):
-                    o = delta_rule_chunked(q, k, v, g, beta, seg, D ** -0.5,
-                                           chunk=min(chunk, T),
-                                           sub=min(16, chunk, T), dtype=dtype)
+                    o = delta_rule(q, k, v, g, beta, seg, D ** -0.5,
+                                   chunk=min(chunk, T),
+                                   sub=min(16, chunk, T), dtype=dtype)
                 with jax.named_scope('kda.proj'):
                     o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                                           + eps) * norm

@@ -29,7 +29,7 @@ from ..observability import costs as _costs
 # step of this size spends whole milliseconds in
 _costs.register_scopes('kda.scan', 'kda.proj', 'mla.attention', 'moe.route',
                        'moe.experts', 'moe.shared', 'lm_head',
-                       'fused_rms_norm.pallas', 'update')
+                       'fused_rms_norm.pallas', 'delta_rule.pallas', 'update')
 
 __all__ = ['KimiLinearConfig', 'KimiLinearBlock', 'KimiLinearForCausalLM']
 

@@ -24,6 +24,7 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+from paddle_tpu.kernels import delta_rule as dr
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import fused_dropout_norm as fdn
 from paddle_tpu.kernels import fused_norm as fn
@@ -117,6 +118,36 @@ def test_flash_latent_attention_shapes_compile_fwd_bwd(one_chip):
     # BERT's calls are inside what a kernel gets unasked: no limit is set
     assert fa._fwd_vmem_limit(512, 64, 64, 512, 2, 1) is None
     assert fa._bwd_vmem_limit(512, 64, 512, 512, 2) is None
+
+
+def test_delta_rule_compiles_fwd_bwd_at_the_cells_shape(one_chip,
+                                                        monkeypatch):
+    """One row of Kimi-Linear's delta rule as the cell runs it: 8192
+    tokens, 32 heads of 128, chunks of 64 in sub-blocks of 16, float32
+    operands with bfloat16 in the three large products. The `HIGHEST`
+    float32 products, the `a^T b` state update and the chunk's `jax.vjp`
+    inside the backward kernel all have to lower: one custom call forward,
+    two (the forward that saves the chunks' start states, the backward)
+    under `jax.grad`."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    T, heads, width = 8192, 32, 128
+
+    def forward(q, k, v, g, beta, seg):
+        return dr.delta_rule(q, k, v, g, beta, seg, width ** -0.5,
+                             dtype=jnp.bfloat16)
+
+    def loss(*args):
+        return jnp.sum(jnp.sin(forward(*args)))
+
+    wide = ((1, T, heads, width), jnp.float32)
+    shapes = (wide, wide, wide, wide, ((1, T, heads), jnp.float32),
+              ((1, T), jnp.int32))
+    text = _compile(forward, one_chip, *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip,
+                    *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert 'delta_rule.pallas' in text and 'delta_rule.xla' not in text
 
 
 _X = ((ROWS, HIDDEN), jnp.bfloat16)
@@ -396,17 +427,24 @@ def test_hybrid_step_holds_its_kernels_and_its_layers_scopes(topo,
     calls = _CUSTOM_CALL.findall(text)
     assert any(c.startswith('flash_attention.pallas') for c in calls)
     assert any(c.startswith('fused_rms_norm.pallas') for c in calls)
-    assert 'flash_attention.xla' not in text
+    assert 'flash_attention.xla' not in text and 'delta_rule.xla' not in text
     under = costs.instruction_scopes(text)
     found = {scope for scopes in under.values() for scope in scopes}
     assert found >= {'kda.scan', 'kda.proj', 'mla.attention', 'moe.route',
                      'moe.experts', 'moe.shared', 'lm_head',
-                     'fused_rms_norm.pallas', 'update'}
+                     'fused_rms_norm.pallas', 'delta_rule.pallas', 'update'}
     assert all('fused_rms_norm.pallas' in under[c] for c in calls
                if c.startswith('fused_rms_norm.pallas'))
+    # the delta rule's kernels: a KDA layer maps its rows, so each of the
+    # four holds one forward kernel in the forward pass and, in the
+    # backward pass, the forward again (it saves the chunks' start states)
+    # and the backward kernel
+    delta = [c for c in calls if c.startswith('delta_rule.pallas')]
+    assert len(delta) == 12, delta
+    assert all('kda.scan' in under[c] for c in delta)
     assert all('mla.attention' in under[c] for c in calls
                if c.startswith('flash_attention.pallas'))
     phases = costs.instruction_phases(text)
-    assert {phases[c] for c in calls
-            if c.startswith('flash_attention.pallas')} == {'forward',
-                                                           'backward'}
+    for kernel in ('flash_attention.pallas', 'delta_rule.pallas'):
+        assert {phases[c] for c in calls if c.startswith(kernel)} == {
+            'forward', 'backward'}

@@ -310,7 +310,7 @@ def test_lower_precision_control_is_not_correct():
 def _state_leaks_across_documents(monkeypatch):
     real = delta_rule.delta_rule_chunked
     monkeypatch.setattr(
-        nn.layer.linear_attention, 'delta_rule_chunked',
+        nn.layer.linear_attention, 'delta_rule',
         lambda q, k, v, g, beta, seg, *a, **kw: real(
             q, k, v, g, beta, jnp.zeros_like(seg), *a, **kw))
 
