@@ -1,19 +1,34 @@
 """Checks of the Pallas kernels that only a chip can make.
 
 Interpret mode stubs the TPU's hardware PRNG to zeros, so the in-kernel
-dropout has no CPU test: ``chip_smoke.py`` (and ``bench.py`` before it times
-anything) run these on the device. Each raises on failure.
+dropout has no CPU test: ``chip_smoke.py`` runs these on the device. Each
+raises on failure.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ._common import kernel_mesh
-from .autotune import make_device_qkv
 from .delta_rule import delta_rule
 from .flash_attention import _attn_reference, flash_attention_bhld
 from .fused_dropout_norm import fused_dropout_add_layer_norm
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
+def _qkv_program(key, batch, heads, seq, head_dim, dtype):
+    return tuple(jax.random.normal(kk, (batch, heads, seq, head_dim), dtype)
+                 for kk in jax.random.split(key, 3))
+
+
+def make_device_qkv(batch, heads, seq, head_dim, dtype, seed=0):
+    """Three [b,h,s,d] standard-normal tensors generated ON DEVICE as one
+    jitted program (compiled once per shape signature per process, zero
+    host->device transfer)."""
+    return _qkv_program(jax.random.PRNGKey(seed), batch, heads, seq,
+                        head_dim, jnp.dtype(dtype))
 
 
 def _padding_bias(b, L, half_of_last=False):

@@ -34,10 +34,15 @@ hardware (tests marked tpu-only in tests/test_flash_attention.py; on the
 chip, ``checks.check_flash_dropout_backward`` holds the dropout-on backward
 to finite differences of its forward).
 
-On non-TPU backends the public entry point takes plain-XLA attention with
-identical semantics (dropout there uses jax.random — same distribution,
-different stream); which path a trace took is marked in the HLO
-(``_common.took``).
+Two entries, and each states its own rule for taking the kernels:
+``flash_attention_bhld`` for a layer that hands over (B, H, L, D) operands
+(latent attention; the chip's checks) takes them wherever they run and the
+sequence tiles, and ``attention_blhd`` for ``nn.functional``'s
+(B, L, H, D) call with a free-form mask adds what that call needs: a mask
+the kernels can express and a sequence long enough for them to win. Off
+their rule each takes plain-XLA attention with identical semantics (dropout
+there uses jax.random — same distribution, different stream); which path a
+trace took is marked in the HLO (``_common.took``).
 """
 import functools
 import math
@@ -51,6 +56,10 @@ from ._common import (pallas_runs, spmd_kernel,
                       tile_keep_scale as _tile_keep_scale, took)
 
 NEG_INF = -1e30
+_BLOCK = 512        # the default tile, along queries and along keys
+# nn.functional's attention under this length stays XLA's own fusion of the
+# scores: the benchmark's seq128 and seq512 cells stand on either side of it
+_MIN_SEQ = 512
 LSE_EMPTY = 1e30  # lse sentinel for fully-masked rows: exp(s - BIG) == 0
 
 
@@ -517,9 +526,14 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, dropout_p, interpret,
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+def _tiles(lq, lk, block_q, block_k):
+    return (lk == lq and lq % min(block_q, lq) == 0
+            and lq % min(block_k, lq) == 0)
+
+
 def flash_attention_bhld(q, k, v, causal=False, scale=None, kpad_bias=None,
                          dropout_p=0.0, dropout_seed=None, doc_start=None,
-                         block_q=512, block_k=512, interpret=False):
+                         block_q=_BLOCK, block_k=_BLOCK, interpret=False):
     """Flash attention on (B, H, L, D) tensors; v's head size may differ
     from q's and k's.
 
@@ -546,8 +560,8 @@ def flash_attention_bhld(q, k, v, causal=False, scale=None, kpad_bias=None,
         # key-padding attention is non-causal and reads every K anyway, so
         # stream the full row (one K tile: the backward writes dQ directly)
         block_k = L
-    usable = (pallas_runs(interpret) and k.shape[2] == L
-              and L % min(block_q, L) == 0 and L % min(block_k, L) == 0)
+    usable = pallas_runs(interpret) and _tiles(L, k.shape[2], block_q,
+                                               block_k)
     if dropout_p > 0.0 and dropout_seed is None:
         raise ValueError("dropout_p > 0 requires dropout_seed")
     if not usable:
@@ -564,3 +578,69 @@ def flash_attention_bhld(q, k, v, causal=False, scale=None, kpad_bias=None,
     with took('flash_attention', 'pallas'):
         return _flash(q, k, v, kpad_bias, seed, doc_start, causal, scale,
                       block_q, block_k, dropout_p, interpret)
+
+
+def _is_key_padding(mask_shape, batch, lk):
+    """A (B|1, 1, 1, Lk) mask, the shape BERT-style key-padding masks take:
+    the one mask the kernels stream (as a (B, Lk) bias)."""
+    return (len(mask_shape) == 4 and mask_shape[1] == 1
+            and mask_shape[2] == 1 and mask_shape[3] == lk
+            and mask_shape[0] in (1, batch))
+
+
+def _kpad_bias(mask, batch):
+    """The (B, Lk) additive bias of a boolean / additive key-padding mask."""
+    bias = mask.reshape((mask.shape[0], mask.shape[3]))
+    if bias.dtype == jnp.bool_:
+        bias = jnp.where(bias, 0.0, -1e9).astype(jnp.float32)
+    if bias.shape[0] == 1:
+        bias = jnp.broadcast_to(bias, (batch, bias.shape[1]))
+    return bias
+
+
+def attention_blhd(q, k, v, mask=None, causal=False, dropout_p=0.0,
+                   dropout_key=None):
+    """softmax(q k^T / sqrt(D) + mask) v on (B, L, H, D) operands, as
+    ``nn.functional.scaled_dot_product_attention`` defines it: mask boolean
+    (True = keep) or additive, broadcast against (B, H, Lq, Lk);
+    dropout_key: a jax key, required when dropout_p > 0.
+
+    The flash kernels on the TPU when they can express the call (Lq == Lk
+    in whole tiles, no mask or a key-padding one) and the sequence is at
+    least ``_MIN_SEQ`` long; otherwise the scores as one XLA expression.
+    Either way under ``flash_attention.pallas`` / ``flash_attention.xla``.
+    """
+    batch, lq, lk = q.shape[0], q.shape[1], k.shape[1]
+    if (pallas_runs(False) and lq >= _MIN_SEQ
+            and _tiles(lq, lk, _BLOCK, _BLOCK)
+            and (mask is None or _is_key_padding(mask.shape, batch, lk))):
+        seed = None
+        if dropout_p > 0.0:
+            seed = jax.random.randint(dropout_key, (1, 1), 0, 2**31 - 1
+                                      ).astype(jnp.int32)
+        kpad = None if mask is None else _kpad_bias(mask, batch)
+        # (B, L, H, D) -> (B, H, L, D)
+        q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
+        out = flash_attention_bhld(q, k, v, causal=causal, kpad_bias=kpad,
+                                   dropout_p=dropout_p, dropout_seed=seed)
+        return jnp.swapaxes(out, 1, 2)
+    with took('flash_attention', 'xla'):
+        scale = 1.0 / math.sqrt(q.shape[-1])
+        q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
+        scores = jnp.einsum('bhld,bhmd->bhlm', q, k) * scale
+        if mask is not None:
+            if mask.dtype == jnp.bool_:
+                scores = jnp.where(mask, scores, NEG_INF)
+            else:
+                scores = scores + mask
+        if causal:
+            visible = jnp.tril(jnp.ones(scores.shape[-2:], dtype=bool))
+            scores = jnp.where(visible, scores, NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        if dropout_p > 0.0:
+            keep = jax.random.bernoulli(dropout_key, 1.0 - dropout_p,
+                                        probs.shape)
+            probs = jnp.where(keep, probs / (1.0 - dropout_p),
+                              jnp.zeros_like(probs))
+        out = jnp.einsum('bhlm,bhmd->bhld', probs, v)
+        return jnp.swapaxes(out, 1, 2)

@@ -9,9 +9,7 @@ kernel reads the sum again — ~30 ms/step across 48 sublayer sites. This
 kernel reads x and residual once, generates the keep mask from the TPU
 hardware PRNG in-register (seeded by tile id, exactly like
 flash_attention.py's in-kernel dropout), and writes the normalized output
-plus the pre-norm sum in one pass. Measured on v5e BERT-large: +3.8% step
-throughput at seq128 and +4.2% at seq512 over the XLA-fused composition
-(tools/bench_2x2.py).
+plus the pre-norm sum in one pass.
 
 Backward: LayerNorm's closed-form gradient runs in plain XLA from the saved
 pre-norm sum + row stats (one fused pass); the dropout mask is REGENERATED
@@ -23,6 +21,7 @@ dropout_p > 0 parity is TPU-only (the p == 0 fused add+norm path is fully
 testable on CPU; see tests/test_fused_dropout_norm.py).
 """
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +29,12 @@ from jax.experimental import pallas as pl
 
 from ._common import (pallas_runs, row_block as _row_block, spmd_kernel,
                       tile_keep_scale as _keep_scale, took)
+from .fused_norm import fused_layer_norm
+
+# nn.functional's epilogue under this many rows stays composed ops: the
+# kernel's extra write of the pre-norm sum loses to XLA's own fusion of
+# dropout and add there
+_MIN_ROWS = 4096
 
 
 def _fwd_kernel(*refs, eps, p, has_w, has_b):
@@ -232,3 +237,34 @@ def _xla_reference(x, residual, weight, bias, p, epsilon, dropout_seed):
         if bias is not None:
             y = y + bias.astype(jnp.float32)
         return y.astype(x.dtype)
+
+
+def dropout_add_layer_norm(x, residual, weight=None, bias=None, dropout_p=0.0,
+                           epsilon=1e-5, dropout_key=None):
+    """y = LayerNorm(residual + dropout(x)) as
+    ``nn.functional.fused_dropout_add_layer_norm`` defines it; dropout_key:
+    a jax key, required when 0 < dropout_p < 1.
+
+    The one-pass kernel on the TPU for at least ``_MIN_ROWS`` rows of whole
+    128-lane registers that tile; otherwise dropout and add as XLA ops and
+    the layer norm by its own rule (``fused_layer_norm``: under the size rule
+    it is still the norm kernel). Under ``fused_dropout_norm.pallas`` /
+    ``fused_dropout_norm.xla``."""
+    p = float(dropout_p)
+    n = math.prod(x.shape[:-1])
+    if (pallas_runs(False) and n >= _MIN_ROWS and x.shape[-1] % 128 == 0
+            and _row_block(n) is not None and p < 1.0):
+        seed = None
+        if p > 0.0:
+            seed = jax.random.randint(dropout_key, (1, 1), 0, 2**31 - 1
+                                      ).astype(jnp.int32)
+        return fused_dropout_add_layer_norm(x, residual, weight, bias, p,
+                                            epsilon, seed)
+    with took('fused_dropout_norm', 'xla'):
+        y = x
+        if p >= 1.0:
+            y = jnp.zeros_like(x)
+        elif p > 0.0:
+            keep = jax.random.bernoulli(dropout_key, 1.0 - p, x.shape)
+            y = jnp.where(keep, x / (1.0 - p), jnp.zeros_like(x))
+        return fused_layer_norm(y + residual, weight, bias, epsilon)

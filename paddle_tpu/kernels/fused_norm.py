@@ -11,6 +11,7 @@ so no extra memory traffic is saved by hand-writing it.
 Testable on CPU via interpret=True (tests/test_fused_norm.py).
 """
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -167,44 +168,52 @@ def _rms_bwd_rule(eps, interpret, res, g):
 _fused_rms_norm2d.defvjp(_rms_fwd_rule, _rms_bwd_rule)
 
 
-def fused_layer_norm(x, weight=None, bias=None, eps=1e-5, interpret=False):
-    """Layer norm over the LAST axis of x (any leading shape)."""
-    n_rows = 1
-    for s in x.shape[:-1]:
-        n_rows *= s
-    if not (pallas_runs(interpret)
-            and _row_block(n_rows, x.shape[-1]) is not None):
-        with took('fused_layer_norm', 'xla'):
-            mean = jnp.mean(x, axis=-1, keepdims=True)
-            var = jnp.var(x, axis=-1, keepdims=True)
-            y = (x - mean) * jax.lax.rsqrt(var + eps)
-            if weight is not None:
-                y = y * weight
-            if bias is not None:
-                y = y + bias
-            return y.astype(x.dtype)
+def fused_layer_norm(x, weight=None, bias=None, eps=1e-5, n_axes=1,
+                     interpret=False):
+    """Layer norm over the last ``n_axes`` axes of x (any leading shape).
+
+    The kernel norms the last axis alone, rows of whole 128-lane registers
+    in tiles of 8 rows or more, on the TPU (or in interpret mode);
+    everything else is plain XLA. Either way under
+    ``fused_layer_norm.pallas`` / ``fused_layer_norm.xla``."""
     shape = x.shape
-    with took('fused_layer_norm', 'pallas'):
-        y = _fused_layer_norm2d(x.reshape(-1, shape[-1]), weight, bias,
-                                float(eps), interpret)
-    return y.reshape(shape)
+    lanes = (n_axes == 1 and pallas_runs(interpret)
+             and shape[-1] % 128 == 0)
+    if lanes and _row_block(math.prod(shape[:-1]), shape[-1]) is not None:
+        with took('fused_layer_norm', 'pallas'):
+            y = _fused_layer_norm2d(x.reshape(-1, shape[-1]), weight, bias,
+                                    float(eps), interpret)
+        return y.reshape(shape)
+    with took('fused_layer_norm', 'xla'):
+        axes = tuple(range(x.ndim - n_axes, x.ndim))
+        mean = jnp.mean(x, axis=axes, keepdims=True)
+        var = jnp.var(x, axis=axes, keepdims=True)
+        # where only the row count keeps the kernel away, its stand-in keeps
+        # its arithmetic and its contract (the result in x's dtype): a
+        # step's dtypes do not turn on its batch size. Elsewhere the result
+        # promotes as jax.numpy's does
+        y = ((x - mean) * jax.lax.rsqrt(var + eps) if lanes
+             else (x - mean) / jnp.sqrt(var + eps))
+        if weight is not None:
+            y = y * weight
+        if bias is not None:
+            y = y + bias
+        return y.astype(x.dtype) if lanes else y
 
 
 def fused_rms_norm(x, weight=None, eps=1e-6, interpret=False):
-    """RMS norm over the LAST axis of x (any leading shape)."""
-    n_rows = 1
-    for s in x.shape[:-1]:
-        n_rows *= s
-    if not (pallas_runs(interpret)
-            and _row_block(n_rows, x.shape[-1]) is not None):
-        with took('fused_rms_norm', 'xla'):
-            ms = jnp.mean(x * x, axis=-1, keepdims=True)
-            y = x * jax.lax.rsqrt(ms + eps)
-            if weight is not None:
-                y = y * weight
-            return y.astype(x.dtype)
+    """RMS norm over the LAST axis of x (any leading shape): the kernel for
+    rows of whole 128-lane registers in tiles of 8 rows or more on the TPU
+    (or in interpret mode), else plain XLA; under ``fused_rms_norm.pallas``
+    / ``fused_rms_norm.xla``."""
     shape = x.shape
-    with took('fused_rms_norm', 'pallas'):
-        y = _fused_rms_norm2d(x.reshape(-1, shape[-1]), weight, float(eps),
-                              interpret)
-    return y.reshape(shape)
+    if (pallas_runs(interpret) and shape[-1] % 128 == 0
+            and _row_block(math.prod(shape[:-1]), shape[-1]) is not None):
+        with took('fused_rms_norm', 'pallas'):
+            y = _fused_rms_norm2d(x.reshape(-1, shape[-1]), weight,
+                                  float(eps), interpret)
+        return y.reshape(shape)
+    with took('fused_rms_norm', 'xla'):
+        ms = jnp.mean(x * x, axis=-1, keepdims=True)
+        y = x / jnp.sqrt(ms + eps)
+        return y if weight is None else y * weight

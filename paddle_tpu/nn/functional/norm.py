@@ -3,6 +3,8 @@
 batch_norm takes/returns running stats explicitly in functional form so the
 stateful layer can collect updates (see layer_base.functional_call).
 """
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -125,13 +127,18 @@ def _no_grad():
     return no_grad()
 
 
+def _affine(wb, has_w, has_b):
+    """(weight, bias) of an op whose inputs carry those that are present."""
+    return (wb[0] if has_w else None, wb[int(has_w)] if has_b else None)
+
+
 def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
                name=None):
+    from ...kernels.fused_norm import fused_layer_norm
     x = _t(x)
     if isinstance(normalized_shape, int):
         normalized_shape = [normalized_shape]
-    n_norm = len(list(normalized_shape))
-    axes = tuple(range(x.ndim - n_norm, x.ndim))
+    n_axes = len(list(normalized_shape))
     tensors = [x]
     if weight is not None:
         tensors.append(_t(weight))
@@ -140,41 +147,17 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
     has_w = weight is not None
     has_b = bias is not None
 
-    if (n_norm == 1 and jax.default_backend() == 'tpu'
-            and x.shape[-1] % 128 == 0):
-        from ...kernels.fused_norm import fused_layer_norm
-
-        def fused(v, *wb):
-            i = 0
-            w = wb[i] if has_w else None
-            i += has_w
-            b = wb[i] if has_b else None
-            return fused_layer_norm(v, w, b, eps=epsilon)
-        return apply_op(fused, tuple(tensors))
-
     def fn(v, *wb):
-        mean = jnp.mean(v, axis=axes, keepdims=True)
-        var = jnp.var(v, axis=axes, keepdims=True)
-        out = (v - mean) / jnp.sqrt(var + epsilon)
-        i = 0
-        if has_w:
-            out = out * wb[i]
-            i += 1
-        if has_b:
-            out = out + wb[i]
-        return out
+        return fused_layer_norm(v, *_affine(wb, has_w, has_b), epsilon,
+                                n_axes)
     return apply_op(fn, tuple(tensors))
 
 
 def rms_norm_values(v, w=None, epsilon=1e-6):
     """`rms_norm` over jax values: for a layer that norms inside its own
     traced function (one that is recomputed in the backward pass)."""
-    if jax.default_backend() == 'tpu' and v.shape[-1] % 128 == 0:
-        from ...kernels.fused_norm import fused_rms_norm
-        return fused_rms_norm(v, w, eps=epsilon)
-    ms = jnp.mean(v * v, axis=-1, keepdims=True)
-    out = v / jnp.sqrt(ms + epsilon)
-    return out if w is None else out * w
+    from ...kernels.fused_norm import fused_rms_norm
+    return fused_rms_norm(v, w, epsilon)
 
 
 def rms_norm(x, weight=None, epsilon=1e-6, name=None):
@@ -256,58 +239,27 @@ def local_response_norm(x, size, alpha=1e-4, beta=0.75, k=1.0,
     return apply_op(fn, (x,))
 
 
-_USE_FUSED_DROPOUT_NORM = [True]
-_FUSED_DROPOUT_NORM_MIN_ROWS = 4096  # measured on v5e: below this the pallas
-# pass (extra pre-norm-sum write) loses to XLA's own dropout+add fusion
-
-
-def set_fused_dropout_norm(enabled):
-    _USE_FUSED_DROPOUT_NORM[0] = bool(enabled)
-
-
 def fused_dropout_add_layer_norm(x, residual, weight=None, bias=None,
                                  dropout_p=0.0, epsilon=1e-5, training=True,
                                  name=None):
-    """y = LayerNorm(residual + dropout(x)) — single pallas pass on TPU.
-
-    Replaces the three separate HBM passes (rng mask, dropout select,
-    residual add) + norm read of the unfused transformer sublayer epilogue
-    (kernels/fused_dropout_norm.py). Off-TPU falls back to composed ops with
-    identical semantics.
-    """
+    """y = LayerNorm(residual + dropout(x)): the transformer sublayer's
+    epilogue as one op (kernels/fused_dropout_norm.py decides between one
+    Pallas pass and the composed XLA ops, with identical semantics)."""
     from ...core import rng as _rng
-    x, residual = _t(x), _t(residual)
+    from ...kernels.fused_dropout_norm import dropout_add_layer_norm
     p_eff = float(dropout_p) if training else 0.0
-    tensors = [x, residual]
+    tensors = [_t(x), _t(residual)]
     has_w = weight is not None
     has_b = bias is not None
     if has_w:
         tensors.append(_t(weight))
     if has_b:
         tensors.append(_t(bias))
-    n_rows = 1
-    for s in x.shape[:-1]:
-        n_rows *= s
-    if (_USE_FUSED_DROPOUT_NORM[0] and n_rows >= _FUSED_DROPOUT_NORM_MIN_ROWS
-            and jax.default_backend() == 'tpu' and x.shape[-1] % 128 == 0):
-        from ...kernels.fused_dropout_norm import \
-            fused_dropout_add_layer_norm as _kernel
-        seed = None
-        if p_eff > 0.0:
-            seed = jax.random.randint(_rng.next_key(), (1, 1), 0,
-                                      2**31 - 1).astype(jnp.int32)
+    key = _rng.next_key() if 0.0 < p_eff < 1.0 else None
 
-        def fused(v, r, *wb):
-            i = 0
-            w = wb[i] if has_w else None
-            i += has_w
-            b = wb[i] if has_b else None
-            return _kernel(v, r, w, b, dropout_p=p_eff, epsilon=epsilon,
-                           dropout_seed=seed)
-        return apply_op(fused, tuple(tensors))
-
-    # composed fallback (identical math, separate passes)
-    from .common import dropout as _dropout
-    y = _dropout(x, p=p_eff, training=True) if p_eff > 0.0 else x
-    s = apply_op(lambda a, b: a + b, (y, residual))
-    return layer_norm(s, x.shape[-1], weight, bias, epsilon)
+    def fn(p, v, r, *wb):
+        return dropout_add_layer_norm(v, r, *_affine(wb, has_w, has_b), p,
+                                      epsilon, key)
+    # eval_fn: the test-mode variant for Program.clone(for_test=True)
+    return apply_op(functools.partial(fn, p_eff), tuple(tensors),
+                    eval_fn=functools.partial(fn, 0.0))

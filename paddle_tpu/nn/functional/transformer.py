@@ -1,130 +1,30 @@
 """Attention functionals.
 
-Parity: python/paddle/nn/layer/transformer.py core compute. TPU-first: one
-fused softmax(QK^T/sqrt(d))V expression XLA can fuse; the pallas flash
-attention kernel in kernels/flash_attention.py is used automatically for long
-sequences on TPU.
+Parity: python/paddle/nn/layer/transformer.py core compute. TPU-first:
+kernels/flash_attention.py computes it, and decides there between the Pallas
+flash kernels and one fused softmax(QK^T/sqrt(d))V expression XLA can fuse.
 """
-import math
-
-import jax
-import jax.numpy as jnp
-
-from ...core.tensor import Tensor, apply_op
+from ...core import rng as _rng
+from ...core.tensor import apply_op
 from ...tensor._helpers import _t
 
 __all__ = ['scaled_dot_product_attention', 'multi_head_attention']
-
-_USE_FLASH = [True]
-_FLASH_MIN_SEQ = 512  # below this, plain XLA fusion wins (measured on-chip)
-
-
-def set_flash_attention(enabled):
-    _USE_FLASH[0] = bool(enabled)
-
-
-def _mask_as_kpad_bias(m, batch, lk):
-    """Convert a (B|1, 1, 1, Lk) boolean/additive mask — the shape BERT-style
-    key-padding masks take — to the (B, Lk) additive bias the flash kernel
-    streams. Returns None for any other mask shape (caller falls back to the
-    dense path)."""
-    if m.ndim != 4 or m.shape[1] != 1 or m.shape[2] != 1:
-        return None
-    if m.shape[3] != lk or m.shape[0] not in (1, batch):
-        return None
-    bias = m.reshape((m.shape[0], lk))
-    if bias.dtype == jnp.bool_:
-        bias = jnp.where(bias, 0.0, -1e9).astype(jnp.float32)
-    if bias.shape[0] == 1:
-        bias = jnp.broadcast_to(bias, (batch, lk))
-    return bias
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, training=True,
                                  name=None):
     """query/key/value: (B, L, H, D) paddle-style. Returns (B, L, H, D)."""
-    q, k, v = _t(query), _t(key), _t(value)
-    tensors = [q, k, v]
+    from ...kernels.flash_attention import attention_blhd
+    tensors = [_t(query), _t(key), _t(value)]
     if attn_mask is not None:
         tensors.append(_t(attn_mask))
-
-    seq_len = q.shape[1]
     p_eff = float(dropout_p) if training else 0.0
-    am = _t(attn_mask) if attn_mask is not None else None
-    mask_flashable = (am is None or
-                      (am.ndim == 4 and am.shape[1] == 1 and
-                       am.shape[2] == 1 and am.shape[3] == k.shape[1] and
-                       am.shape[0] in (1, q.shape[0])))
-    flash_eligible = (_USE_FLASH[0] and mask_flashable and
-                      seq_len == k.shape[1] and
-                      jax.default_backend() == 'tpu')
-    # on-chip autotuned decision (kernels/autotune.py) overrides the static
-    # threshold when this shape signature has been measured; shapes are
-    # concrete even under tracing, so the lookup is trace-safe
-    tuned = None
-    if flash_eligible:
-        from ...kernels.autotune import lookup as _at_lookup
-        n_heads = q.shape[2] if q.ndim == 4 else 1
-        tuned = _at_lookup(q.shape[0], n_heads, seq_len, q.shape[-1],
-                           is_causal, am is not None, p_eff,
-                           dtype=str(q.dtype))
-    if tuned is not None:
-        use_flash = tuned['mode'] == 'flash'
-    else:
-        use_flash = flash_eligible and seq_len >= _FLASH_MIN_SEQ
-    if use_flash:
-        from ...kernels.flash_attention import flash_attention_bhld
-        blocks = ({'block_q': tuned['block_q'],
-                   'block_k': tuned['block_k']} if tuned else {})
-        seed = None
-        if p_eff > 0.0:
-            from ...core import rng as _rng
-            seed = jax.random.randint(_rng.next_key(), (1, 1), 0, 2**31 - 1
-                                      ).astype(jnp.int32)
+    drop_key = _rng.next_key() if p_eff > 0.0 else None
 
-        def ffn(qq, kk, vv, *mask):
-            kpad = (_mask_as_kpad_bias(mask[0], qq.shape[0], kk.shape[1])
-                    if mask else None)
-            # (B, L, H, D) -> (B, H, L, D)
-            qq, kk, vv = (jnp.swapaxes(t, 1, 2) for t in (qq, kk, vv))
-            out = flash_attention_bhld(qq, kk, vv, causal=is_causal,
-                                       kpad_bias=kpad, dropout_p=p_eff,
-                                       dropout_seed=seed, **blocks)
-            return jnp.swapaxes(out, 1, 2)
-
-        return apply_op(ffn, tuple(tensors))
-
-    drop_key = None
-    if p_eff > 0.0:
-        from ...core import rng as _rng
-        drop_key = _rng.next_key()
-
-    def fn(qq, kk, vv, *mask):
-        d = qq.shape[-1]
-        scale = 1.0 / math.sqrt(d)
-        # (B, L, H, D) -> (B, H, L, D)
-        qq = jnp.swapaxes(qq, 1, 2)
-        kk = jnp.swapaxes(kk, 1, 2)
-        vv = jnp.swapaxes(vv, 1, 2)
-        scores = jnp.einsum('bhld,bhmd->bhlm', qq, kk) * scale
-        if mask:
-            m = mask[0]
-            if m.dtype == jnp.bool_:
-                scores = jnp.where(m, scores, -1e30)
-            else:
-                scores = scores + m
-        if is_causal:
-            L, M = scores.shape[-2], scores.shape[-1]
-            causal = jnp.tril(jnp.ones((L, M), dtype=bool))
-            scores = jnp.where(causal, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        if drop_key is not None:
-            keep = jax.random.bernoulli(drop_key, 1.0 - p_eff, probs.shape)
-            probs = jnp.where(keep, probs / (1.0 - p_eff),
-                              jnp.zeros_like(probs))
-        out = jnp.einsum('bhlm,bhmd->bhld', probs, vv)
-        return jnp.swapaxes(out, 1, 2)
+    def fn(q, k, v, *mask):
+        return attention_blhd(q, k, v, mask[0] if mask else None, is_causal,
+                              p_eff, drop_key)
     return apply_op(fn, tuple(tensors))
 
 

@@ -1,9 +1,9 @@
 """Cross-run performance baseline: the ``runs.jsonl`` registry + sentinel.
 
 The in-run time series (``timeseries.py``) answers "what changed during
-this run"; this module answers "what changed since last run". ``bench.py``
-appends one summary record per round — BENCH extras, counter totals, the
-cost-ledger headline, compile counts, and a config fingerprint — to an
+this run"; this module answers "what changed since last run". ``record_run``
+appends one summary record per round — counter totals, the cost-ledger
+headline, compile counts, and a config fingerprint — to an
 append-only JSONL registry, and ``detect_regressions`` compares the latest
 record against the rolling median of the prior runs, per metric:
 

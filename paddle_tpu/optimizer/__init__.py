@@ -7,7 +7,6 @@ from . import lr
 from .lr import *  # noqa
 from .extras import (ExponentialMovingAverage, LookAhead, ModelAverage,
                      PipelineOptimizer, RecomputeOptimizer)
-from .fused import FlatFusedUpdate
 
 # -- 1.8 *Optimizer aliases + 2.0-beta *LR scheduler names -------------------
 MomentumOptimizer = Momentum

@@ -599,7 +599,7 @@ def test_gl015_exempts_engine_tests_tools(tmp_path):
     # the engine package is the sanctioned builder (donation decided at
     # runtime behind the backend gate); harnesses measure, they don't ship
     for rel in ('paddle_tpu/engine/builder.py', 'tests/mod.py',
-                'tools/mod.py', 'bench.py'):
+                'tools/mod.py', 'bench_x.py'):
         p = tmp_path / rel
         p.parent.mkdir(parents=True, exist_ok=True)
         p.write_text(_UNDONATED_SRC)
@@ -645,7 +645,7 @@ def test_gl016_flags_unsharded_param_device_put(tmp_path):
 
 
 def test_gl016_exempts_harnesses(tmp_path):
-    for rel in ('tests/mod.py', 'tools/mod.py', 'bench.py'):
+    for rel in ('tests/mod.py', 'tools/mod.py', 'bench_x.py'):
         p = tmp_path / rel
         p.parent.mkdir(parents=True, exist_ok=True)
         p.write_text(_DEVICE_PUT_SRC)
@@ -708,7 +708,7 @@ def test_gl017_flags_mask_indexing_and_nonzero_in_traced_code(tmp_path):
 
 
 def test_gl017_exempts_harnesses_and_host_code(tmp_path):
-    for rel in ('tests/mod.py', 'tools/mod.py', 'bench.py'):
+    for rel in ('tests/mod.py', 'tools/mod.py', 'bench_x.py'):
         p = tmp_path / rel
         p.parent.mkdir(parents=True, exist_ok=True)
         p.write_text(_MASK_INDEX_SRC)

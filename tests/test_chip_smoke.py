@@ -6,8 +6,7 @@ test-only entry: tiny sizes, kernels in interpret mode, no platform refusal
 — in a fresh interpreter each (``--chips 4`` needs its own 4 virtual devices,
 and a run turns on process-wide telemetry and the compile cache). Everything
 else here is what replaced the deleted fallbacks' tests: without a TPU
-``chip_smoke.py`` and ``bench.py`` exit non-zero and print no metric, a phase
-that raises fails the run, one helper places the compile cache, and an
+``chip_smoke.py`` exits non-zero and prints no metric, a phase that raises fails the run, one helper places the compile cache, and an
 unknown device has no roofline.
 """
 import json
@@ -114,18 +113,6 @@ def test_chip_smoke_refuses_without_a_tpu(tmp_path):
     assert not _phases(out.stdout)          # no phase ran, nothing measured
 
 
-def test_bench_refuses_without_a_tpu(tmp_path):
-    out = _run(['bench.py'], tmp_path)
-    assert out.returncode != 0
-    assert 'needs a TPU' in out.stderr
-    assert out.stdout.strip() == ''         # no metric line of any kind
-
-
-def test_bench_rejects_unknown_model():
-    import bench
-    assert bench.main(['inception']) == 2
-
-
 def test_a_phase_that_raises_fails_the_run(monkeypatch, capsys):
     def boom(*a, **k):
         raise RuntimeError('the chip said no')
@@ -172,8 +159,7 @@ class TestCompileCachePlacement:
 
     def test_no_temporary_name_in_any_cache_path(self):
         import re
-        for rel in ('bench.py', 'chip_smoke.py',
-                    'paddle_tpu/inference/__init__.py'):
+        for rel in ('chip_smoke.py', 'paddle_tpu/inference/__init__.py'):
             src = open(os.path.join(REPO, rel)).read()
             for m in re.finditer(r'jax_compilation_cache_dir', src):
                 near = src[max(0, m.start() - 400):m.end() + 200]

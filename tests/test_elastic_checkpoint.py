@@ -477,10 +477,23 @@ def _soak_worker(ckpt_dir, kill_marker):
     maybe_die = f.kill_rank_at_step(9, kill_marker, rank=1)
     seen = [0]
 
+    def die_after_a_commit(step):
+        """Rank 1 dies at step 9 once the epoch's checkpoint (step 6) is
+        committed: rank 0 commits when every rank's shard has landed, this
+        rank's too, and with 50 ms between the save and the kill the async
+        writers of four processes on a loaded host did not always get
+        there, so that generation 1 had nothing to restore."""
+        if step == 9 and rank == 1 and gen == 0:
+            deadline = time.monotonic() + 120
+            while not CheckpointManager(ckpt_dir).steps() \
+                    and time.monotonic() < deadline:
+                time.sleep(0.02)
+        maybe_die(step)
+
     class Chaos:
         def __iter__(self):
             for i, b in enumerate(batches):
-                maybe_die(seen[0])
+                die_after_a_commit(seen[0])
                 seen[0] += 1
                 if i == 2:
                     time.sleep(0.05)        # hung-worker flavor (bounded)

@@ -163,9 +163,3 @@ class TestRowTilingFallback:
         np.testing.assert_allclose(
             np.asarray(y), _ref(x, np.zeros_like(x), None, None),
             rtol=1e-5, atol=1e-5)
-
-    def test_flat_optimizer_decay_mask_requires_adamw(self):
-        from paddle_tpu.optimizer import SGD, FlatFusedUpdate
-        with pytest.raises(ValueError):
-            FlatFusedUpdate(SGD(0.1), {'w': jnp.zeros((4, 4))},
-                            decay_mask=lambda k: True)

@@ -1,0 +1,237 @@
+"""Which implementation each `nn.functional` kernel site takes, and that its
+program is the one it was.
+
+The rule that picks a Pallas kernel or its XLA form lives with the kernel
+(`kernels/flash_attention.py`, `fused_norm.py`, `fused_dropout_norm.py`):
+backend, the shape's tiling, what the kernel can express, and two size rules
+(sequence length 512 for attention, 4096 rows for dropout + add + norm).
+Every outcome runs under `_common.took`, which bumps
+`kernels.<kernel>.<path>` and names the scope `<kernel>.<path>`: a trace of
+a step proves which one it ran. Here the sites are traced through
+`nn.functional` with `jax.default_backend` patched; nothing is lowered.
+"""
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu import observability as obs
+from paddle_tpu.core import rng
+from paddle_tpu.core.tensor import Tensor
+from paddle_tpu.nn import functional as F
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+def _key():
+    return jax.random.key(0, impl='rbg')
+
+
+def _call(fn, arrays, key, **kw):
+    """One site, called as a layer inside a traced step calls it: Tensors
+    around the tracers, the dropout key from the scope the engine opens."""
+    with rng.key_scope(key):
+        return fn(*(Tensor(a) for a in arrays), **kw)._value
+
+
+def attention(q_shape, mask_shape=None, p=0.0, k_len=None, causal=False,
+              dtype=BF16):
+    """BERT's call: (B, L, H, D) operands, an additive float mask."""
+    b, lq, h, d = q_shape
+    kv = (b, k_len or lq, h, d)
+    shapes = [(q_shape, dtype), (kv, dtype), (kv, dtype)]
+    if mask_shape is not None:
+        shapes.append((mask_shape, F32))
+
+    def site(key, q, k, v, mask=None):
+        return _call(F.scaled_dot_product_attention, (q, k, v), key,
+                     attn_mask=None if mask is None else Tensor(mask),
+                     dropout_p=p, is_causal=causal, training=True)
+    return site, shapes
+
+
+def layer_norm(x_shape, normalized=None, dtype=BF16):
+    normalized = list(normalized or x_shape[-1:])
+    w = (tuple(normalized), F32)
+
+    def site(key, x, w, b):
+        return _call(lambda x, w, b: F.layer_norm(x, normalized, w, b, 1e-12),
+                     (x, w, b), key)
+    return site, [(x_shape, dtype), w, w]
+
+
+def rms_norm(x_shape, dtype=BF16):
+    def site(key, x, w):
+        return F.rms_norm_values(x, w, 1e-6)
+    return site, [(x_shape, dtype), (x_shape[-1:], F32)]
+
+
+def rms_norm_tensor(x_shape):
+    def site(key, x, w):
+        return _call(F.rms_norm, (x, w), key)
+    return site, [(x_shape, BF16), (x_shape[-1:], F32)]
+
+
+def dropout_add_norm(x_shape, p=0.1, dtype=BF16):
+    w = (x_shape[-1:], F32)
+
+    def site(key, x, res, w, b):
+        return _call(F.fused_dropout_add_layer_norm, (x, res, w, b), key,
+                     dropout_p=p, epsilon=1e-12, training=True)
+    return site, [(x_shape, dtype), (x_shape, dtype), w, w]
+
+
+def _structs(shapes):
+    return [jax.ShapeDtypeStruct(s, dt) for s, dt in shapes]
+
+
+# (id, kernel, backend, the site, the path it takes)
+_CHOICES = [
+    # attention: BERT's two cells stand on either side of the size rule
+    ('attention-seq128-cell', 'flash_attention', 'tpu',
+     attention((64, 128, 16, 64), (64, 1, 1, 128), 0.1), 'xla'),
+    ('attention-seq512-cell', 'flash_attention', 'tpu',
+     attention((16, 512, 16, 64), (16, 1, 1, 512), 0.1), 'pallas'),
+    ('attention-seq256-under-the-size-rule', 'flash_attention', 'tpu',
+     attention((2, 256, 4, 64)), 'xla'),
+    ('attention-seq512-no-mask', 'flash_attention', 'tpu',
+     attention((2, 512, 4, 64)), 'pallas'),
+    ('attention-seq1024-causal', 'flash_attention', 'tpu',
+     attention((2, 1024, 4, 64), causal=True), 'pallas'),
+    ('attention-seq512-one-mask-row-for-all', 'flash_attention', 'tpu',
+     attention((2, 512, 4, 64), (1, 1, 1, 512)), 'pallas'),
+    ('attention-seq640-does-not-tile', 'flash_attention', 'tpu',
+     attention((2, 640, 4, 64)), 'xla'),
+    ('attention-full-mask', 'flash_attention', 'tpu',
+     attention((2, 512, 4, 64), (2, 4, 512, 512)), 'xla'),
+    ('attention-mask-per-query', 'flash_attention', 'tpu',
+     attention((2, 512, 4, 64), (2, 1, 512, 512)), 'xla'),
+    ('attention-lq-is-not-lk', 'flash_attention', 'tpu',
+     attention((2, 512, 4, 64), k_len=1024), 'xla'),
+    ('attention-seq512-off-the-tpu', 'flash_attention', 'cpu',
+     attention((2, 512, 4, 64), (2, 1, 1, 512), 0.1), 'xla'),
+    # layer norm
+    ('layer-norm-cell', 'fused_layer_norm', 'tpu',
+     layer_norm((64, 128, 1024)), 'pallas'),
+    ('layer-norm-rows-do-not-tile', 'fused_layer_norm', 'tpu',
+     layer_norm((4095, 1024)), 'xla'),
+    ('layer-norm-lanes-do-not-tile', 'fused_layer_norm', 'tpu',
+     layer_norm((64, 200)), 'xla'),
+    ('layer-norm-over-two-axes', 'fused_layer_norm', 'tpu',
+     layer_norm((8, 16, 128), normalized=(16, 128)), 'xla'),
+    ('layer-norm-off-the-tpu', 'fused_layer_norm', 'cpu',
+     layer_norm((64, 128, 1024)), 'xla'),
+    # RMS norm, over values (a layer's own traced function) and Tensors
+    ('rms-norm-cell', 'fused_rms_norm', 'tpu',
+     rms_norm((2, 8192, 2304)), 'pallas'),
+    ('rms-norm-tensor', 'fused_rms_norm', 'tpu',
+     rms_norm_tensor((16, 256)), 'pallas'),
+    ('rms-norm-rows-do-not-tile', 'fused_rms_norm', 'tpu',
+     rms_norm((13, 256)), 'xla'),
+    ('rms-norm-lanes-do-not-tile', 'fused_rms_norm', 'tpu',
+     rms_norm((16, 200)), 'xla'),
+    ('rms-norm-off-the-tpu', 'fused_rms_norm', 'cpu',
+     rms_norm((2, 8192, 2304)), 'xla'),
+    # dropout + add + layer norm: both sides of the size rule
+    ('dropout-add-norm-cell', 'fused_dropout_norm', 'tpu',
+     dropout_add_norm((64, 128, 1024)), 'pallas'),
+    ('dropout-add-norm-4096-rows', 'fused_dropout_norm', 'tpu',
+     dropout_add_norm((4096, 1024)), 'pallas'),
+    ('dropout-add-norm-4095-rows', 'fused_dropout_norm', 'tpu',
+     dropout_add_norm((4095, 1024)), 'xla'),
+    ('dropout-add-norm-4088-rows-tile-under-the-size-rule',
+     'fused_dropout_norm', 'tpu', dropout_add_norm((4088, 1024)), 'xla'),
+    # ... where the norm by itself is still the layer-norm kernel: what
+    # BERT's step holds at fewer than 4096 rows
+    ('dropout-add-norm-4088-rows-norm-keeps-its-kernel',
+     'fused_layer_norm', 'tpu', dropout_add_norm((4088, 1024)), 'pallas'),
+    ('dropout-add-norm-4100-rows-do-not-tile', 'fused_dropout_norm', 'tpu',
+     dropout_add_norm((4100, 1024)), 'xla'),
+    ('dropout-add-norm-lanes-do-not-tile', 'fused_dropout_norm', 'tpu',
+     dropout_add_norm((8192, 200)), 'xla'),
+    ('dropout-add-norm-no-dropout', 'fused_dropout_norm', 'tpu',
+     dropout_add_norm((8192, 1024), p=0.0), 'pallas'),
+    ('dropout-add-norm-off-the-tpu', 'fused_dropout_norm', 'cpu',
+     dropout_add_norm((8192, 1024)), 'xla'),
+]
+
+
+@pytest.fixture
+def telemetry():
+    was = obs.enabled()
+    obs.enable()
+    yield
+    if not was:
+        obs.disable()
+
+
+def _taken(kernel):
+    return {path: obs.counter('kernels.%s.%s' % (kernel, path)).value
+            for path in ('pallas', 'xla')}
+
+
+@pytest.mark.parametrize('kernel,backend,site,path',
+                         [c[1:] for c in _CHOICES],
+                         ids=[c[0] for c in _CHOICES])
+def test_the_site_takes(monkeypatch, telemetry, kernel, backend, site, path):
+    """The one decision of the trace is counted under the path it took, and
+    the ops sit under that path's scope."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: backend)
+    fn, shapes = site
+    before = _taken(kernel)
+    jaxpr = jax.make_jaxpr(fn)(_key(), *_structs(shapes))
+    after = _taken(kernel)
+    other = 'xla' if path == 'pallas' else 'pallas'
+    assert (after[path] - before[path], after[other] - before[other]) == \
+        (1, 0)
+    text = jaxpr.pretty_print(name_stack=True)
+    assert '%s.%s' % (kernel, path) in text
+    assert '%s.%s' % (kernel, other) not in text
+    assert path == 'xla' or 'pallas_call' in text
+
+
+# Forward-and-gradient jaxprs of commit 31e439e at the cells' own shapes,
+# source locations taken out (name stacks are not printed): the programs
+# the benchmark's steps hold.
+_PROGRAMS = [
+    ('attention-seq128', [attention((64, 128, 16, 64), (64, 1, 1, 128), 0.1)],
+     '9728593572e4a028a0a00d326eb749ea0c1af8b8d46acba637181253fb2b6c6e'),
+    ('attention-seq512', [attention((16, 512, 16, 64), (16, 1, 1, 512), 0.1)],
+     'fb9b738a13cf138e00f4fff7152b25cf15f2319a498152d550f346242893e7b7'),
+    ('layer-norm-1024', [layer_norm((64, 128, 1024)),
+                         layer_norm((16, 512, 1024)),
+                         layer_norm((1280, 1024), dtype=F32)],
+     '0d563db57167cc3b23e0aa470079daf25e9cacf15503d8d8ec6a481c2e931b28'),
+    ('dropout-add-norm-8192-rows', [dropout_add_norm((64, 128, 1024)),
+                                    dropout_add_norm((16, 512, 1024)),
+                                    dropout_add_norm((8192, 1024), dtype=F32)],
+     '3f0cf7d174723a6dbae21fbc117c07e648f06c0e019dab5aff859b9c318624b8'),
+    ('dropout-add-norm-4095-rows', [dropout_add_norm((4095, 1024)),
+                                    dropout_add_norm((8, 128, 1024))],
+     'ad8713ac00a1bbad50501b7e8c2c50f2fb85ff57180ebccc76b6606506e1d476'),
+    ('rms-norm-2304', [rms_norm((2, 8192, 2304)),
+                       rms_norm((2, 8192, 2304), dtype=F32)],
+     '7cf430396d2a57a067805e8d4458460c4c93f9aeda0258dca33baf34bdd80c67'),
+]
+
+
+def program_digest(sites):
+    texts = []
+    for fn, shapes in sites:
+        def loss(key, *args):
+            return jnp.sum(fn(key, *args).astype(F32))
+        texts.append(str(jax.make_jaxpr(
+            jax.grad(loss, argnums=tuple(range(1, len(shapes) + 1))))(
+            _key(), *_structs(shapes))))
+    text = re.sub(r'/[\w/.\-]+\.py:\d+', 'SRC', '\n'.join(texts))
+    text = re.sub(r'at SRC|SRC', '', text)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize('sites,digest', [p[1:] for p in _PROGRAMS],
+                         ids=[p[0] for p in _PROGRAMS])
+def test_the_cells_programs_are_unchanged(monkeypatch, sites, digest):
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    assert program_digest(sites) == digest

@@ -38,12 +38,23 @@ def _xla_compile_cache(tmp_path_factory):
     tmp path per session, so nothing persists across runs (retrace-gate
     semantics elsewhere stay deterministic)."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
     d = str(tmp_path_factory.mktemp('xla_cache'))
     jax.config.update('jax_compilation_cache_dir', d)
     jax.config.update('jax_persistent_cache_min_entry_size_bytes', 0)
     jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
+    # jax decides ONCE whether a process uses its cache, on the first
+    # compile: reset, or the directory set here is never looked at
+    cc.reset_cache()
     yield
+    # hand the worker back WITHOUT a live cache (as test_inference_aot
+    # does): once initialized it serves every later test of the process,
+    # and an executable it loaded, serialized again by `compilecache`,
+    # cannot run ("Function ... not found": test_compilecache,
+    # test_tenancy)
     jax.config.update('jax_compilation_cache_dir', None)
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 1.0)
+    cc.reset_cache()
 
 
 def _lm(seed=0, **kw):

@@ -78,13 +78,3 @@ def test_native_staleness_watchlist_covers_all_sources():
                              '__init__.py')).read()
     for src in srcs:
         assert src in init, f"{src} missing from _native staleness check"
-
-
-def test_prefetch_bench_tool_importable():
-    # the bench tool must at least import and expose its two paths
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        'bench_prefetch', os.path.join(REPO, 'tools', 'bench_prefetch.py'))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    assert callable(mod.bench_ring) and callable(mod.bench_queue)

@@ -2,7 +2,7 @@
 """perfwatch: cross-run performance sentinel over the ``runs.jsonl``
 registry (docs/OBSERVABILITY.md, "Time series + regression sentinel").
 
-``bench.py`` appends one summary record per round (BENCH extras, counter
+``baseline.record_run`` appends one summary record per round (counter
 totals, cost headline, compile counts, config fingerprint); this CLI
 compares the latest record against the rolling median + MAD of the prior
 runs — robust, min-sample-guarded, direction-aware (qps down = bad,
