@@ -18,7 +18,8 @@ a user calls:
                 and v. Interpret mode stubs that PRNG, so only a chip
                 checks it. The delta rule's backward kernel against finite
                 differences of its forward kernel along a direction of q,
-                k, v, g and beta.
+                k, v, g and beta; the short convolution's likewise, along
+                a direction of its input and of its taps.
 - ``train``     ``BertForPretraining`` through ``engine.build_train_step(
                 net=, loss=, optimizer=AdamW)``, bf16 compute
                 (``amp.auto_cast``), dropout on, donation on: a few dozen
@@ -37,8 +38,9 @@ a user calls:
                 parameters) through the same ``build_train_step``: a few
                 steps on one batch of packed rows of 8192 tokens. Loss
                 finite and falling, no compile after the first step, no
-                routing assignment dropped, the flash and the delta-rule
-                kernels in the step (and nothing under ``delta_rule.xla``).
+                routing assignment dropped, the flash, the delta-rule and
+                the short-convolution kernels in the step (and nothing
+                under ``delta_rule.xla`` or ``short_conv.xla``).
 - ``serve``     the trained BERT-large encoder behind ``ServingEngine.
                 register(layer=, example=, bucket_spec=)``: requests of mixed
                 lengths through ``submit``, all ``ok``, no compile after
@@ -151,15 +153,17 @@ def phase_kernels(size, rehearsal):
     t0 = time.perf_counter()
     errs = checks.check_flash_against_reference(size['kernel_shape'],
                                                 interpret=rehearsal)
-    dropout_backward = delta_rule_backward = None
+    dropout_backward = delta_rule_backward = short_conv_backward = None
     if not rehearsal:   # interpret mode has no hardware PRNG (and takes
         checks.check_flash_dropout()    # a minute over the delta rule)
         checks.check_norm_dropout()
         dropout_backward = checks.check_flash_dropout_backward()
         delta_rule_backward = checks.check_delta_rule_backward()
+        short_conv_backward = checks.check_short_conv_backward()
     say('kernels', shape=list(size['kernel_shape']), max_abs_err=errs,
         dropout_checked=not rehearsal, dropout_backward=dropout_backward,
         delta_rule_backward=delta_rule_backward,
+        short_conv_backward=short_conv_backward,
         seconds=round(time.perf_counter() - t0, 2))
 
 
@@ -284,7 +288,8 @@ def step_hlo_facts(step, state, batch):
                 'flash_attention.pallas', 'flash_attention.xla',
                 'fused_dropout_norm.pallas', 'fused_dropout_norm.xla',
                 'fused_layer_norm.pallas', 'fused_layer_norm.xla',
-                'delta_rule.pallas', 'delta_rule.xla')}}
+                'delta_rule.pallas', 'delta_rule.xla',
+                'short_conv.pallas', 'short_conv.xla')}}
 
 
 def _ffn_weight(state):
@@ -412,9 +417,10 @@ def phase_hybrid(size, seed, rehearsal):
     hlo = step_hlo_facts(step, state, batch)
     if not rehearsal and not all(
             hlo['scopes'][k + '.pallas'] and not hlo['scopes'][k + '.xla']
-            for k in ('flash_attention', 'delta_rule')):
+            for k in ('flash_attention', 'delta_rule', 'short_conv')):
         raise AssertionError('the hybrid step does not hold the Pallas '
-                             'flash and delta-rule kernels: %s' % hlo)
+                             'flash, delta-rule and short-convolution '
+                             'kernels: %s' % hlo)
     c0, s0 = Compiles.count(), Compiles.seconds()
     counters = []
     state, losses, ms, after_first = run_steps(

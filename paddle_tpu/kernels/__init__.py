@@ -7,7 +7,10 @@ Implemented here (each with interpret-mode CPU tests):
   (kernels/fused_norm.py);
 - the chunk-wise gated delta rule of Kimi Delta Attention: a forward and a
   backward kernel that carry the state across a head's chunks in VMEM
-  (kernels/delta_rule.py).
+  (kernels/delta_rule.py);
+- the short convolution in front of it: causal depthwise taps inside
+  documents, SiLU and the per-head l2norm in one pass, forward and backward
+  (kernels/short_conv.py).
 
 These replace the reference's hand-written CUDA/cuDNN kernels
 (paddle/fluid/operators/fused/*attention*, layer_norm_op.cu) with TPU-native

@@ -15,6 +15,7 @@ from ._common import kernel_mesh
 from .delta_rule import delta_rule
 from .flash_attention import _attn_reference, flash_attention_bhld
 from .fused_dropout_norm import fused_dropout_add_layer_norm
+from .short_conv import short_conv
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
@@ -116,6 +117,37 @@ def check_flash_dropout_backward(shape=(2, 16, 512, 64), dropout_p=0.1,
     return got
 
 
+def _directional(loss, x, noise, eps, names):
+    """For each operand of ``loss(*x)``, along a direction u of it (noise
+    plus the gradient's own direction at the noise's length, clipped to
+    [-4, 4]): ``(loss(x + eps u) - loss(x - eps u)) / 2 eps`` and
+    ``<grad, u>``, one jitted program. -> ``{name: (the two)}``."""
+    @jax.jit
+    def readings(*x):
+        grads = jax.grad(loss, argnums=tuple(range(len(x))))(*x)
+        out = []
+        for i, (g, z) in enumerate(zip(grads, noise)):
+            u = jnp.clip(z + g * jnp.sqrt(jnp.sum(z * z) / jnp.sum(g * g)),
+                         -4.0, 4.0)
+
+            def moved(t):
+                return loss(*x[:i], x[i] + t * u, *x[i + 1:])
+            out.append(((moved(eps) - moved(-eps)) / (2 * eps),
+                        jnp.sum(g * u)))
+        return out
+    return {n: (float(fd), float(an))
+            for n, (fd, an) in zip(names, readings(*x))}
+
+
+def _hold_directional(what, got, tol):
+    for n, (fd, an) in got.items():
+        if not abs(fd - an) <= tol * max(abs(fd), abs(an)):
+            raise AssertionError(
+                '%s backward: d%s along a direction reads %g, finite '
+                'differences of the forward %g' % (what, n, an, fd))
+    return got
+
+
 def check_delta_rule_backward(shape=(1, 1024, 4, 128), interpret=False):
     """The delta rule's backward kernel against finite differences of its
     forward kernel: for a direction u of each of q, k, v, g and beta,
@@ -149,29 +181,39 @@ def check_delta_rule_backward(shape=(1, 1024, 4, 128), interpret=False):
         return jnp.sum(weight * delta_rule(*x, seg, K ** -0.5,
                                            interpret=interpret))
 
-    @jax.jit
-    def readings(*x):
-        grads = jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*x)
-        out = []
-        for i, (g, z) in enumerate(zip(grads, noise)):
-            u = jnp.clip(z + g * jnp.sqrt(jnp.sum(z * z) / jnp.sum(g * g)),
-                         -4.0, 4.0)
-
-            def moved(t):
-                return loss(*x[:i], x[i] + t * u, *x[i + 1:])
-            out.append(((moved(eps) - moved(-eps)) / (2 * eps),
-                        jnp.sum(g * u)))
-        return out
-
     with jax.default_matmul_precision('highest'):
-        got = {n: (float(fd), float(an)) for n, (fd, an) in zip(
-            ('q', 'k', 'v', 'g', 'beta'), readings(*x))}
-    for n, (fd, an) in got.items():
-        if not abs(fd - an) <= tol * max(abs(fd), abs(an)):
-            raise AssertionError(
-                'delta rule backward: d%s along a direction reads %g, '
-                'finite differences of the forward %g' % (n, an, fd))
-    return got
+        got = _directional(loss, x, noise, eps, ('q', 'k', 'v', 'g', 'beta'))
+    return _hold_directional('delta rule', got, tol)
+
+
+def check_short_conv_backward(shape=(1, 1024, 512), head_dim=128,
+                              interpret=False):
+    """The short convolution's backward kernel against finite differences of
+    its forward kernel, as `check_delta_rule_backward` holds the delta
+    rule's: for a direction u of the input and of the taps,
+    ``(loss(x + eps u) - loss(x - eps u)) / 2 eps`` must equal
+    ``<grad, u>``. float32 operands, with the l2norm and without, documents
+    that begin on a tile's first row, inside a tile and two rows before a
+    tile's end. Returns ``{name: (finite difference, <grad, u>)}``; raises
+    where they differ by over 1%."""
+    eps, tol = 1e-2, 1e-2
+    B, T, W = shape
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    x = [jax.random.normal(keys[0], shape),
+         0.5 * jax.random.normal(keys[1], (4, W))]
+    noise = [jax.random.normal(key, t.shape) for key, t in zip(keys[2:4], x)]
+    weight = jax.random.normal(keys[4], shape)
+    at = jnp.arange(T, dtype=jnp.int32)
+    seg = ((at >= T // 4).astype(jnp.int32) + (at >= T // 2 + 5)
+           + (at >= 3 * T // 4 - 2))[None].repeat(B, 0)
+
+    got = {}
+    for tag, norm in (('', head_dim), ('_no_norm', None)):
+        def loss(y, w):
+            return jnp.sum(weight * short_conv(y, w, seg, norm,
+                                               interpret=interpret))
+        got.update(_directional(loss, x, noise, eps, ('y' + tag, 'w' + tag)))
+    return _hold_directional('short conv', got, tol)
 
 
 def check_norm_dropout(rows=1024, hidden=1024, interpret=False):

@@ -11,9 +11,9 @@ import jax.numpy as jnp
 
 from ...core.tensor import apply_op
 from ...kernels.delta_rule import delta_rule
+from ...kernels.short_conv import short_conv
 from ..initializer import Constant, Normal, ParamAttr
 from ..layer_base import Layer
-from ..functional.delta_rule import causal_conv
 from ..functional.norm import rms_norm_values
 
 __all__ = ['KimiDeltaAttention', 'LatentAttention', 'compute_dtype',
@@ -58,14 +58,10 @@ def doc_starts(seg):
     return jax.lax.cummax(jnp.where(first, pos, 0), axis=1)
 
 
-def _l2norm(x):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
-
 class KimiDeltaAttention(Layer):
-    """q, k = l2norm(silu(conv(x W))), v = silu(conv(x W_v)) per head; a
-    log-decay per channel g = -exp(A_log) softplus(x W_a1 W_a2 + dt_bias);
+    """q, k = l2norm(silu(conv(x W))), v = silu(conv(x W_v)) per head
+    (`kernels.short_conv`: one kernel pass each on the TPU); a log-decay
+    per channel g = -exp(A_log) softplus(x W_a1 W_a2 + dt_bias);
     beta = sigmoid(x W_beta) per head; the delta rule chunk-wise
     (`kernels.delta_rule`: the Pallas kernels on the TPU, off it the XLA
     form of `functional.delta_rule`); W_o [RMSNorm_head(o) * sigmoid(x W_g1 W_g2)].
@@ -119,11 +115,11 @@ class KimiDeltaAttention(Layer):
                 if pre:
                     x = rms_norm_values(x, pre[0], norm_eps)
                 with jax.named_scope('kda.proj'):
-                    def short(w, c):
-                        y = causal_conv(_mm(x, w, dtype).astype(f32), c, seg)
-                        return jax.nn.silu(y).reshape(B, T, H, D)
-                    q, k, v = _l2norm(short(wq, cq)), \
-                        _l2norm(short(wk, ck)), short(wv, cv)
+                    def short(w, c, head_dim=None):
+                        return short_conv(_mm(x, w, dtype), c, seg,
+                                          head_dim).reshape(B, T, H, D)
+                    q, k, v = short(wq, cq, D), short(wk, ck, D), \
+                        short(wv, cv)
                     raw = _mm(_mm(x, da, dtype), db, dtype).astype(f32) \
                         + dt_bias
                     g = -jnp.exp(a_log.astype(f32))[:, None] \

@@ -25,11 +25,12 @@ from ..observability import costs as _costs
 
 # the layers' named scopes: a captured step keeps which instructions lie
 # under each (observability.costs.scopes), for the device time a layer takes;
-# with them the norms' kernel and the engine's optimizer update, which a
-# step of this size spends whole milliseconds in
+# with them the kernels' own scopes and the engine's optimizer update, which
+# a step of this size spends whole milliseconds in
 _costs.register_scopes('kda.scan', 'kda.proj', 'mla.attention', 'moe.route',
                        'moe.experts', 'moe.shared', 'lm_head',
-                       'fused_rms_norm.pallas', 'delta_rule.pallas', 'update')
+                       'fused_rms_norm.pallas', 'delta_rule.pallas',
+                       'short_conv.pallas', 'update')
 
 __all__ = ['KimiLinearConfig', 'KimiLinearBlock', 'KimiLinearForCausalLM']
 

@@ -28,6 +28,7 @@ from paddle_tpu.kernels import delta_rule as dr
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import fused_dropout_norm as fdn
 from paddle_tpu.kernels import fused_norm as fn
+from paddle_tpu.kernels import short_conv as sc
 from paddle_tpu.kernels._common import kernel_mesh
 
 B, H, D = 8, 16, 64            # BERT-large attention: 16 heads of 64
@@ -157,6 +158,35 @@ def test_delta_rule_compiles_fwd_bwd_at_the_cells_shape(one_chip,
                     *shapes)
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert 'delta_rule.pallas' in text and 'delta_rule.xla' not in text
+
+
+@pytest.mark.parametrize('head_dim', [128, None], ids=['l2norm', 'no_norm'])
+def test_short_conv_compiles_fwd_bwd_at_the_cells_shape(one_chip,
+                                                        monkeypatch,
+                                                        head_dim):
+    """One row of a KDA projection as the cell runs it: 8192 positions, 32
+    heads of 128, the matmul's bfloat16 output in, float32 out; q and k with
+    the l2norm, v without. The shifts by `roll`, the halo block read in
+    front of a tile and the taps' gradient accumulated over a row's tiles
+    all have to lower: one custom call forward, two (the forward, whose
+    output the loss reads, and the backward, which keeps nothing of the
+    forward but its operands) under `jax.grad`."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    T, width = 8192, 4096
+
+    def forward(y, w, seg):
+        return sc.short_conv(y, w, seg, head_dim)
+
+    def loss(*args):
+        return jnp.sum(jnp.sin(forward(*args)))
+
+    shapes = (((1, T, width), jnp.bfloat16), ((4, width), jnp.float32),
+              ((1, T), jnp.int32))
+    text = _compile(forward, one_chip, *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    text = _compile(jax.grad(loss, argnums=(0, 1)), one_chip, *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert 'short_conv.pallas' in text and 'short_conv.xla' not in text
 
 
 _X = ((ROWS, HIDDEN), jnp.bfloat16)
@@ -437,11 +467,13 @@ def test_hybrid_step_holds_its_kernels_and_its_layers_scopes(topo,
     assert any(c.startswith('flash_attention.pallas') for c in calls)
     assert any(c.startswith('fused_rms_norm.pallas') for c in calls)
     assert 'flash_attention.xla' not in text and 'delta_rule.xla' not in text
+    assert 'short_conv.xla' not in text
     under = costs.instruction_scopes(text)
     found = {scope for scopes in under.values() for scope in scopes}
     assert found >= {'kda.scan', 'kda.proj', 'mla.attention', 'moe.route',
                      'moe.experts', 'moe.shared', 'lm_head',
-                     'fused_rms_norm.pallas', 'delta_rule.pallas', 'update'}
+                     'fused_rms_norm.pallas', 'delta_rule.pallas',
+                     'short_conv.pallas', 'update'}
     assert all('fused_rms_norm.pallas' in under[c] for c in calls
                if c.startswith('fused_rms_norm.pallas'))
     # the delta rule's kernels: a KDA layer maps its rows, so each of the
@@ -453,7 +485,14 @@ def test_hybrid_step_holds_its_kernels_and_its_layers_scopes(topo,
     assert all('kda.scan' in under[c] for c in delta)
     assert all('mla.attention' in under[c] for c in calls
                if c.startswith('flash_attention.pallas'))
+    # the short convolutions of q, k and v: per KDA layer three forward
+    # kernels in the forward pass and, in the backward pass, the three
+    # again (the recomputation) and their three backward kernels
+    short = [c for c in calls if c.startswith('short_conv.pallas')]
+    assert len(short) == 36, short
+    assert all('kda.proj' in under[c] for c in short)
     phases = costs.instruction_phases(text)
-    for kernel in ('flash_attention.pallas', 'delta_rule.pallas'):
+    for kernel in ('flash_attention.pallas', 'delta_rule.pallas',
+                   'short_conv.pallas'):
         assert {phases[c] for c in calls if c.startswith(kernel)} == {
             'forward', 'backward'}

@@ -2,13 +2,16 @@
 program is the one it was.
 
 The rule that picks a Pallas kernel or its XLA form lives with the kernel
-(`kernels/flash_attention.py`, `fused_norm.py`, `fused_dropout_norm.py`):
-backend, the shape's tiling, what the kernel can express, and two size rules
-(sequence length 512 for attention, 4096 rows for dropout + add + norm).
+(`kernels/flash_attention.py`, `fused_norm.py`, `fused_dropout_norm.py`,
+`short_conv.py`): backend, the shape's tiling, what the kernel can express,
+and two size rules (sequence length 512 for attention, 4096 rows for
+dropout + add + norm).
 Every outcome runs under `_common.took`, which bumps
 `kernels.<kernel>.<path>` and names the scope `<kernel>.<path>`: a trace of
 a step proves which one it ran. Here the sites are traced through
-`nn.functional` with `jax.default_backend` patched; nothing is lowered.
+`nn.functional` (the short convolution, which `nn.KimiDeltaAttention` calls
+on values, through its entry) with `jax.default_backend` patched; nothing is
+lowered.
 """
 import hashlib
 import re
@@ -83,6 +86,17 @@ def dropout_add_norm(x_shape, p=0.1, dtype=BF16):
     return site, [(x_shape, dtype), (x_shape, dtype), w, w]
 
 
+def short_conv(y_shape, head_dim=None, taps=4, dtype=BF16):
+    """`nn.KimiDeltaAttention`'s call: the projection's output as the
+    matmul wrote it, the taps, the row's document numbers."""
+    from paddle_tpu.kernels.short_conv import short_conv as entry
+
+    def site(key, y, w, seg):
+        return entry(y, w, seg, head_dim)
+    return site, [(y_shape, dtype), ((taps, y_shape[-1]), F32),
+                  (y_shape[:2], jnp.int32)]
+
+
 def _structs(shapes):
     return [jax.ShapeDtypeStruct(s, dt) for s, dt in shapes]
 
@@ -155,6 +169,25 @@ _CHOICES = [
      dropout_add_norm((8192, 1024), p=0.0), 'pallas'),
     ('dropout-add-norm-off-the-tpu', 'fused_dropout_norm', 'cpu',
      dropout_add_norm((8192, 1024)), 'xla'),
+    # the short convolution: q and k of the Kimi cell (normed heads), its v
+    ('short-conv-cell-q', 'short_conv', 'tpu',
+     short_conv((1, 8192, 4096), head_dim=128), 'pallas'),
+    ('short-conv-cell-v', 'short_conv', 'tpu',
+     short_conv((1, 8192, 4096)), 'pallas'),
+    ('short-conv-float32-rows-of-48', 'short_conv', 'tpu',
+     short_conv((2, 48, 256), head_dim=256, dtype=F32), 'pallas'),
+    ('short-conv-rows-do-not-tile', 'short_conv', 'tpu',
+     short_conv((1, 8200, 4096), head_dim=128), 'xla'),
+    ('short-conv-lanes-do-not-tile', 'short_conv', 'tpu',
+     short_conv((1, 8192, 4000)), 'xla'),
+    ('short-conv-a-normed-head-of-half-a-column', 'short_conv', 'tpu',
+     short_conv((1, 8192, 4096), head_dim=64), 'xla'),
+    ('short-conv-heads-of-half-a-column-not-normed', 'short_conv', 'tpu',
+     short_conv((1, 8192, 128)), 'pallas'),
+    ('short-conv-ten-taps', 'short_conv', 'tpu',
+     short_conv((1, 8192, 4096), head_dim=128, taps=10), 'xla'),
+    ('short-conv-off-the-tpu', 'short_conv', 'cpu',
+     short_conv((1, 8192, 4096), head_dim=128), 'xla'),
 ]
 
 
