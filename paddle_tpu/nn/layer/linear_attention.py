@@ -1,8 +1,9 @@
-"""Attention layers of the hybrid linear-attention decoders: Kimi Delta
-Attention (a gated delta rule with a decay per channel, arXiv:2510.26692)
-and multi-head latent attention without position encoding (the full-attention
-layer the same paper interleaves, one in four). Both take a packed row's
-document numbers: state, convolution and scores stop at document boundaries.
+"""Attention layers of the sparse decoders: Kimi Delta Attention (a gated
+delta rule with a decay per channel, arXiv:2510.26692) and multi-head latent
+attention, without position encoding (the full-attention layer the same paper
+interleaves, one in four) or rotary with low-rank queries (arXiv:2412.19437
+section 2.1). Both take a packed row's document numbers: state, convolution,
+scores and positions stop at document boundaries.
 """
 import math
 
@@ -17,7 +18,7 @@ from ..layer_base import Layer
 from ..functional.norm import rms_norm_values
 
 __all__ = ['KimiDeltaAttention', 'LatentAttention', 'compute_dtype',
-           'doc_starts', 'pre_normed']
+           'doc_starts', 'pre_normed', 'rotate_pairs']
 
 
 def compute_dtype():
@@ -154,27 +155,58 @@ class KimiDeltaAttention(Layer):
                         + ((pre_norm.weight,) if pre_norm is not None else ()))
 
 
+def rotate_pairs(x, positions, theta):
+    """Rotary position encoding of the last axis of x (B, T, ..., d): each
+    adjacent pair (2j, 2j + 1) turned by the angle p * theta^(-2j / d), p the
+    position `positions` (B, T) gives, in float32 -> float32."""
+    d = x.shape[-1]
+    x = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    rate = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions.astype(jnp.float32)[..., None] * rate      # (B, T, d/2)
+    angle = angle.reshape(angle.shape[:2] + (1,) * (x.ndim - 4)
+                          + angle.shape[2:])
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    even, odd = x[..., 0], x[..., 1]
+    return jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
+                     axis=-1).reshape(x.shape[:-2] + (d,))
+
+
 class LatentAttention(Layer):
-    """Multi-head latent attention with no position encoding: q = x W_q;
-    [c, k_pe] = x W_kva, c = RMSNorm(c); [k_nope, v] = c W_kvb per head;
-    k = [k_nope, k_pe shared by the heads]; causal softmax(q k^T / sqrt(d_qk))
-    inside documents; W_o. q and k are wider than v: the flash-attention
-    kernels read both sizes off the shapes."""
+    """Multi-head latent attention: q = x W_q, or with `q_lora_rank` the
+    low-rank q = RMSNorm(x W_qa) W_qb; [c, k_pe] = x W_kva, c = RMSNorm(c);
+    [k_nope, v] = c W_kvb per head; k = [k_nope, k_pe shared by the heads];
+    causal softmax(q k^T / sqrt(d_qk)) inside documents; W_o. q and k are
+    wider than v: the flash-attention kernels read both sizes off the shapes.
+
+    No position encoding unless `rope_theta` is given: then the last
+    `qk_rope_head_dim` of every head's query and the one shared `k_pe` are
+    rotated (`rotate_pairs`, under the scope `mla.rope`, before `k_pe` is
+    broadcast to the heads) by a position that restarts at each document of a
+    packed row. A score depends on the two positions' difference alone, so it
+    equals the one global positions give, at smaller angles
+    (docs/EXPERT_LAYER.md, "Rotary positions in packed rows")."""
 
     def __init__(self, hidden_size, num_heads, qk_nope_head_dim,
                  qk_rope_head_dim, v_head_dim, kv_lora_rank, epsilon=1e-5,
-                 initializer_range=0.02):
+                 initializer_range=0.02, q_lora_rank=None, rope_theta=None):
         super().__init__()
         self.num_heads = num_heads
         self.dims = (qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
                      kv_lora_rank)
-        self.epsilon = epsilon
+        self.epsilon, self.rope_theta = epsilon, rope_theta
+        self.q_lora_rank = q_lora_rank
 
         def weight(*shape):
             return self.create_parameter(list(shape), attr=ParamAttr(
                 initializer=Normal(0., initializer_range)))
-        self.q_proj = weight(hidden_size, num_heads
-                             * (qk_nope_head_dim + qk_rope_head_dim))
+        q_width = num_heads * (qk_nope_head_dim + qk_rope_head_dim)
+        if q_lora_rank is None:
+            self.q_proj = weight(hidden_size, q_width)
+        else:
+            self.q_a_proj = weight(hidden_size, q_lora_rank)
+            self.q_a_norm = self.create_parameter(
+                [q_lora_rank], default_initializer=Constant(1.0))
+            self.q_b_proj = weight(q_lora_rank, q_width)
         self.kv_a_proj = weight(hidden_size, kv_lora_rank + qk_rope_head_dim)
         self.kv_a_norm = self.create_parameter(
             [kv_lora_rank], default_initializer=Constant(1.0))
@@ -183,22 +215,40 @@ class LatentAttention(Layer):
         self.o_proj = weight(num_heads * v_head_dim, hidden_size)
 
     def forward(self, x, segment_ids, pre_norm=None, recompute=False):
-        H, eps = self.num_heads, self.epsilon
+        H, eps, theta = self.num_heads, self.epsilon, self.rope_theta
         nope, rope, dv, rank = self.dims
+        low_rank = self.q_lora_rank is not None
         dtype = compute_dtype()
 
-        def fn(x, seg, wq, wkva, norm, wkvb, wo):
+        def normed(c, scale):
+            c = c.astype(jnp.float32)
+            return c * jax.lax.rsqrt(jnp.mean(c * c, -1, keepdims=True)
+                                     + eps) * scale
+
+        def fn(x, seg, *weights):
             from ...kernels.flash_attention import flash_attention_bhld
+            wq, (wkva, norm, wkvb, wo) = weights[:-4], weights[-4:]
             B, T, _ = x.shape
             with jax.named_scope('mla.attention'):
-                q = _mm(x, wq, dtype).reshape(B, T, H, nope + rope)
+                if low_rank:
+                    q = _mm(normed(_mm(x, wq[0], dtype), wq[1]), wq[2], dtype)
+                else:
+                    q = _mm(x, wq[0], dtype)
+                q = q.reshape(B, T, H, nope + rope)
                 kva = _mm(x, wkva, dtype)
-                c = kva[..., :rank].astype(jnp.float32)
-                c = c * jax.lax.rsqrt(jnp.mean(c * c, -1, keepdims=True)
-                                      + eps) * norm
-                kv = _mm(c, wkvb, dtype).reshape(B, T, H, nope + dv)
-                k_pe = jnp.broadcast_to(kva[..., None, rank:],
-                                        (B, T, H, rope))
+                kv = _mm(normed(kva[..., :rank], norm), wkvb,
+                         dtype).reshape(B, T, H, nope + dv)
+                k_pe = kva[..., None, rank:]
+                if theta is not None:
+                    with jax.named_scope('mla.rope'):
+                        at = jnp.arange(T, dtype=jnp.int32)[None, :] \
+                            - doc_starts(seg)
+                        q = jnp.concatenate([
+                            q[..., :nope],
+                            rotate_pairs(q[..., nope:], at, theta)
+                            .astype(q.dtype)], axis=-1)
+                        k_pe = rotate_pairs(k_pe, at, theta)
+                k_pe = jnp.broadcast_to(k_pe, (B, T, H, rope))
                 k = jnp.concatenate([kv[..., :nope], k_pe.astype(kv.dtype)],
                                     axis=-1)
                 q, k, v = (jnp.swapaxes(t, 1, 2)
@@ -210,7 +260,8 @@ class LatentAttention(Layer):
                 return _mm(jnp.swapaxes(o, 1, 2).reshape(B, T, H * dv), wo,
                            dtype)
 
+        q_weights = (self.q_a_proj, self.q_a_norm, self.q_b_proj) \
+            if low_rank else (self.q_proj,)
         run, front = pre_normed(fn, pre_norm, recompute)
-        return apply_op(run, (x,) + front + (
-            segment_ids, self.q_proj, self.kv_a_proj, self.kv_a_norm,
-            self.kv_b_proj, self.o_proj))
+        return apply_op(run, (x,) + front + (segment_ids,) + q_weights + (
+            self.kv_a_proj, self.kv_a_norm, self.kv_b_proj, self.o_proj))

@@ -7,6 +7,7 @@ from .ernie import (ErnieModel, ErnieForPretraining, ErnieConfig,
 from .gpt import GPTConfig, GPTModel, gpt_small
 from .kimi_linear import (KimiLinearConfig, KimiLinearBlock,
                           KimiLinearForCausalLM)
+from .joyai_flash import JoyAIFlashConfig, JoyAIFlashForCausalLM
 from .seq2seq import Seq2SeqTransformer
 from .word2vec import SkipGram, Word2Vec
 from .lm import LSTMLanguageModel

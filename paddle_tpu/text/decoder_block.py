@@ -1,0 +1,117 @@
+"""What the sparse decoders (`kimi_linear`, `joyai_flash`) share: the pre-norm
+residual block with a dense or a routed feed-forward, the next-token loss of a
+packed row taken a row at a time, and the expert layers' counters as one
+vector. The attention layer is the caller's choice.
+"""
+import jax
+import jax.numpy as jnp
+
+from .. import nn
+from ..core.tensor import Tensor, apply_op
+from ..nn.functional.moe import COUNTERS
+from ..nn.layer.linear_attention import compute_dtype
+from ..observability import costs as _costs
+
+# the shared layers' named scopes, the kernels' own and the engine's optimizer
+# update, which a step of this size spends whole milliseconds in: a captured
+# step keeps which instructions lie under each (observability.costs.scopes),
+# for the device time a layer takes
+_costs.register_scopes('mla.attention', 'moe.route', 'moe.experts',
+                       'moe.shared', 'lm_head', 'fused_rms_norm.pallas',
+                       'update')
+
+__all__ = ['SparseDecoderBlock', 'packed_head_loss', 'merge_counters',
+           'MOE_COUNTER_NAMES', 'MOE_COUNTER_SUMS']
+
+# what a sparse decoder's `forward` returns beside its loss, as
+# `engine.TrainStep` records it (the net's `step_counter_names`), and the
+# names among them that add up over steps (`step_counter_sums`)
+MOE_COUNTER_NAMES = tuple('moe.' + name for name in COUNTERS)
+MOE_COUNTER_SUMS = ('moe.assignments_held', 'moe.assignments', 'moe.dropped')
+
+
+class SparseDecoderBlock(nn.Layer):
+    """`x += attention(RMSNorm(x)); x += FFN(RMSNorm(x))` -> (x, expert
+    counters). `attention(x, segment_ids, pre_norm, recompute)` is any layer
+    of `nn.layer.linear_attention`; the feed-forward is the expert layer
+    where `sparse`, else SwiGLU. `config` names the sizes (`hidden_size`,
+    `intermediate_size`, `moe_intermediate_size`, `num_experts`,
+    `num_experts_per_token`, `num_shared_experts`, `routed_scaling_factor`,
+    `experts_held`, `moe_block`, `rms_norm_eps`, `initializer_range`) and
+    `recompute`: each half norms inside its own traced function, which is
+    then re-run in the backward pass, so the block keeps its two inputs."""
+
+    def __init__(self, config, attention, sparse):
+        super().__init__()
+        c = config
+        self.input_norm = nn.RMSNorm(c.hidden_size, epsilon=c.rms_norm_eps)
+        self.post_attention_norm = nn.RMSNorm(c.hidden_size,
+                                              epsilon=c.rms_norm_eps)
+        self.attention = attention
+        self.sparse = sparse
+        self.recompute = c.recompute
+        if self.sparse:
+            self.mlp = nn.SparseMoE(
+                c.hidden_size, c.moe_intermediate_size, c.num_experts,
+                c.num_experts_per_token, experts_held=c.experts_held,
+                shared_size=c.moe_intermediate_size * c.num_shared_experts,
+                scaling=c.routed_scaling_factor, block=c.moe_block,
+                initializer_range=c.initializer_range)
+        else:
+            self.mlp = nn.SwiGLU(c.hidden_size, c.intermediate_size,
+                                 c.initializer_range)
+
+    def forward(self, x, segment_ids, selected=None):
+        again = self.recompute
+        x = x + self.attention(x, segment_ids, self.input_norm, again) \
+            .astype('float32')
+        if self.sparse:
+            y, counters = self.mlp(x, selected, self.post_attention_norm,
+                                   again)
+        else:
+            y = self.mlp(x, self.post_attention_norm, again)
+            counters = Tensor(jnp.zeros((len(COUNTERS),), jnp.float32))
+        return x + y.astype('float32'), counters
+
+
+def packed_head_loss(x, labels, head):
+    """Mean cross-entropy of `x @ head` (x already normed) against `labels`
+    over the positions whose label is not -1, under the scope `lm_head`: a
+    row at a time, recomputed in the backward pass, so a step's logits
+    (tokens x vocabulary, float32) are never held at once."""
+    dtype = compute_dtype()
+
+    def loss_fn(x, labels, head):
+        @jax.checkpoint
+        def row(x, labels):
+            xx, hh = (x, head) if dtype is None else (
+                x.astype(dtype), head.astype(dtype))
+            logits = jnp.matmul(xx, hh,
+                                preferred_element_type=jnp.float32)
+            picked = jnp.take_along_axis(
+                logits, jnp.maximum(labels, 0)[:, None], axis=-1)[:, 0]
+            nll = jax.nn.logsumexp(logits, axis=-1) - picked
+            return jnp.sum(jnp.where(labels >= 0, nll, 0.0))
+        with jax.named_scope('lm_head'):
+            total = jnp.sum(jax.lax.map(lambda a: row(*a), (x, labels)))
+            return total / jnp.maximum(jnp.sum(labels >= 0), 1)
+
+    return apply_op(loss_fn, (x, labels, head))
+
+
+def merge_counters(counted):
+    """The expert layers' counters as one vector in COUNTERS' order: sums,
+    but the busiest expert's rows and the mean rows, which are those of the
+    layer where their ratio (the load imbalance) is largest."""
+    if not counted:
+        return Tensor(jnp.zeros((len(COUNTERS),), jnp.float32))
+    at = {name: i for i, name in enumerate(COUNTERS)}
+
+    def fn(*cs):
+        c = jnp.stack(cs)
+        top, mean = c[:, at['expert_rows_max']], c[:, at['expert_rows_mean']]
+        worst = c[jnp.argmax(top / jnp.maximum(mean, 1e-9))]
+        return jnp.stack([
+            worst[i] if name in ('expert_rows_max', 'expert_rows_mean')
+            else jnp.sum(c[:, i]) for i, name in enumerate(COUNTERS)])
+    return apply_op(fn, tuple(counted), differentiable=False)

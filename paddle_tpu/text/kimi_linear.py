@@ -14,23 +14,17 @@ here (docs/EXPERT_LAYER.md); `recompute` re-runs each half of a block (its
 norm with its attention, its norm with its feed-forward) in the backward
 pass instead of keeping its activations: a block keeps its two inputs.
 """
-import jax
-import jax.numpy as jnp
-
 from .. import nn
-from ..core.tensor import Tensor, apply_op
-from ..nn.functional.moe import COUNTERS
-from ..nn.layer.linear_attention import compute_dtype
 from ..observability import costs as _costs
+from .decoder_block import (MOE_COUNTER_NAMES, MOE_COUNTER_SUMS,
+                            SparseDecoderBlock, merge_counters,
+                            packed_head_loss)
 
-# the layers' named scopes: a captured step keeps which instructions lie
-# under each (observability.costs.scopes), for the device time a layer takes;
-# with them the kernels' own scopes and the engine's optimizer update, which
-# a step of this size spends whole milliseconds in
-_costs.register_scopes('kda.scan', 'kda.proj', 'mla.attention', 'moe.route',
-                       'moe.experts', 'moe.shared', 'lm_head',
-                       'fused_rms_norm.pallas', 'delta_rule.pallas',
-                       'short_conv.pallas', 'update')
+# the KDA layers' named scopes and their kernels' own (the scopes of what the
+# sparse decoders share are registered by `decoder_block`): a captured step
+# keeps which instructions lie under each (observability.costs.scopes)
+_costs.register_scopes('kda.scan', 'kda.proj', 'delta_rule.pallas',
+                       'short_conv.pallas')
 
 __all__ = ['KimiLinearConfig', 'KimiLinearBlock', 'KimiLinearForCausalLM']
 
@@ -58,23 +52,19 @@ class KimiLinearConfig:
             {k: v for k, v in locals().items() if k != 'self'})
 
 
-class KimiLinearBlock(nn.Layer):
+class KimiLinearBlock(SparseDecoderBlock):
     """Layer `index` (1-based) of the decoder -> (x, expert counters)."""
 
     def __init__(self, config, index):
-        super().__init__()
         c = config
-        self.input_norm = nn.RMSNorm(c.hidden_size, epsilon=c.rms_norm_eps)
-        self.post_attention_norm = nn.RMSNorm(c.hidden_size,
-                                              epsilon=c.rms_norm_eps)
         if index in c.kda_layers:
-            self.attention = nn.KimiDeltaAttention(
+            attention = nn.KimiDeltaAttention(
                 c.hidden_size, c.num_attention_heads, c.head_dim,
                 conv_kernel=c.short_conv_kernel_size,
                 gate_rank=c.gate_low_rank, epsilon=c.rms_norm_eps,
                 chunk=c.kda_chunk, initializer_range=c.initializer_range)
         elif index in c.full_attn_layers:
-            self.attention = nn.LatentAttention(
+            attention = nn.LatentAttention(
                 c.hidden_size, c.num_attention_heads, c.qk_nope_head_dim,
                 c.qk_rope_head_dim, c.v_head_dim, c.kv_lora_rank,
                 epsilon=c.rms_norm_eps,
@@ -82,40 +72,14 @@ class KimiLinearBlock(nn.Layer):
         else:
             raise ValueError('layer %d is in neither kda_layers nor '
                              'full_attn_layers' % index)
-        self.sparse = index > c.first_k_dense_replace
-        self.recompute = c.recompute
-        if self.sparse:
-            self.mlp = nn.SparseMoE(
-                c.hidden_size, c.moe_intermediate_size, c.num_experts,
-                c.num_experts_per_token, experts_held=c.experts_held,
-                shared_size=c.moe_intermediate_size * c.num_shared_experts,
-                scaling=c.routed_scaling_factor, block=c.moe_block,
-                initializer_range=c.initializer_range)
-        else:
-            self.mlp = nn.SwiGLU(c.hidden_size, c.intermediate_size,
-                                 c.initializer_range)
-
-    def forward(self, x, segment_ids, selected=None):
-        # each half norms inside its own traced function, which `recompute`
-        # re-runs in the backward pass: the block keeps its two inputs
-        again = self.recompute
-        x = x + self.attention(x, segment_ids, self.input_norm, again) \
-            .astype('float32')
-        if self.sparse:
-            y, counters = self.mlp(x, selected, self.post_attention_norm,
-                                   again)
-        else:
-            y = self.mlp(x, self.post_attention_norm, again)
-            counters = Tensor(jnp.zeros((len(COUNTERS),), jnp.float32))
-        return x + y.astype('float32'), counters
+        super().__init__(c, attention, sparse=index > c.first_k_dense_replace)
 
 
 class KimiLinearForCausalLM(nn.Layer):
     # what the second output of `forward` counts: values of the compiled
     # step, which `engine.TrainStep` records under these names
-    step_counter_names = tuple('moe.' + name for name in COUNTERS)
-    step_counter_sums = ('moe.assignments_held', 'moe.assignments',
-                         'moe.dropped')
+    step_counter_names = MOE_COUNTER_NAMES
+    step_counter_sums = MOE_COUNTER_SUMS
 
     def __init__(self, config=None, **kwargs):
         super().__init__()
@@ -143,52 +107,14 @@ class KimiLinearForCausalLM(nn.Layer):
             x, counters = block(x, segment_ids, selected)
             if block.sparse:
                 counted.append(counters)
-        return x, _merge_counters(counted)
+        return x, merge_counters(counted)
 
     def forward(self, input_ids, segment_ids, labels, selected=None):
         x, counters = self.hidden_states(input_ids, segment_ids, selected)
-        dtype = compute_dtype()
-
-        def loss_fn(x, labels, head):
-            # a row at a time, recomputed in the backward pass: a step's
-            # logits (tokens x vocabulary, float32) are never held at once
-            @jax.checkpoint
-            def row(x, labels):
-                xx, hh = (x, head) if dtype is None else (
-                    x.astype(dtype), head.astype(dtype))
-                logits = jnp.matmul(xx, hh,
-                                    preferred_element_type=jnp.float32)
-                picked = jnp.take_along_axis(
-                    logits, jnp.maximum(labels, 0)[:, None], axis=-1)[:, 0]
-                nll = jax.nn.logsumexp(logits, axis=-1) - picked
-                return jnp.sum(jnp.where(labels >= 0, nll, 0.0))
-            with jax.named_scope('lm_head'):
-                total = jnp.sum(jax.lax.map(lambda a: row(*a), (x, labels)))
-                return total / jnp.maximum(jnp.sum(labels >= 0), 1)
-
-        loss = apply_op(loss_fn, (self.norm(x), labels, self.lm_head))
-        return loss, counters
+        return packed_head_loss(self.norm(x), labels, self.lm_head), counters
 
     @staticmethod
     def training_loss(loss, counters):
         """The `loss=` of `engine.build_train_step`: `forward` has computed
         it (it takes the labels), the counters ride beside it."""
         return loss
-
-
-def _merge_counters(counted):
-    """The expert layers' counters as one vector in COUNTERS' order: sums,
-    but the busiest expert's rows and the mean rows, which are those of the
-    layer where their ratio (the load imbalance) is largest."""
-    if not counted:
-        return Tensor(jnp.zeros((len(COUNTERS),), jnp.float32))
-    at = {name: i for i, name in enumerate(COUNTERS)}
-
-    def fn(*cs):
-        c = jnp.stack(cs)
-        top, mean = c[:, at['expert_rows_max']], c[:, at['expert_rows_mean']]
-        worst = c[jnp.argmax(top / jnp.maximum(mean, 1e-9))]
-        return jnp.stack([
-            worst[i] if name in ('expert_rows_max', 'expert_rows_mean')
-            else jnp.sum(c[:, i]) for i, name in enumerate(COUNTERS)])
-    return apply_op(fn, tuple(counted), differentiable=False)

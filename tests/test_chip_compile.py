@@ -496,3 +496,58 @@ def test_hybrid_step_holds_its_kernels_and_its_layers_scopes(topo,
                    'short_conv.pallas'):
         assert {phases[c] for c in calls if c.startswith(kernel)} == {
             'forward', 'backward'}
+
+
+def test_rotary_decoder_step_holds_its_scopes(topo, monkeypatch):
+    """JoyAI-LLM-Flash at a small width (heads of the real sizes, 128 + 64 /
+    128; rows of 1024 so that attention takes the flash kernels) through
+    `engine.build_train_step` under bf16 autocast with per-half
+    recomputation, compiled for one described chip: every block's attention
+    is the flash kernels (the prediction module's too), the rotation and
+    the module name instructions of the compiled module, and the module's
+    attention lies under `mtp`, `mla.attention` and, beside it, `mla.rope`
+    alike."""
+    import paddle_tpu as paddle
+    from paddle_tpu import amp, engine, optimizer
+    from paddle_tpu.nn.layer_base import buffer_values, param_values
+    from paddle_tpu.observability import costs
+    from paddle_tpu.text.joyai_flash import (JoyAIFlashConfig,
+                                             JoyAIFlashForCausalLM)
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    paddle.seed(0)
+    net = JoyAIFlashForCausalLM(JoyAIFlashConfig(
+        vocab_size=1024, hidden_size=256, num_hidden_layers=2,
+        num_attention_heads=2, intermediate_size=512,
+        moe_intermediate_size=128, num_experts=16, num_experts_per_token=4,
+        experts_held=(0, 4), q_lora_rank=128, kv_lora_rank=128,
+        recompute=True))
+    net.train()
+    step = engine.build_train_step(
+        net=net, loss=net.training_loss,
+        optimizer=optimizer.AdamW(learning_rate=1e-4, weight_decay=0.1))
+    one = SingleDeviceSharding(topo.devices[0])
+    state = jax.tree_util.tree_map(
+        lambda v: jax.ShapeDtypeStruct(np.shape(v), v.dtype, sharding=one),
+        step.init_state(param_values(net), buffer_values(net)))
+    feed = tuple(jax.ShapeDtypeStruct((2, 1024), jnp.int32, sharding=one)
+                 for _ in range(4))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
+    with amp.auto_cast(dtype='bfloat16'):
+        text = step._jit.lower(state, (feed, ()), key).compile().as_text()
+    calls = _CUSTOM_CALL.findall(text)
+    under = costs.instruction_scopes(text)
+    found = {scope for scopes in under.values() for scope in scopes}
+    assert found >= {'mla.attention', 'mla.rope', 'mtp', 'moe.route',
+                     'moe.experts', 'moe.shared', 'lm_head',
+                     'fused_rms_norm.pallas', 'update'}
+    assert 'flash_attention.xla' not in text
+    # three blocks (two and the module's), each the forward kernel, the
+    # forward again in the recomputation and the one backward kernel
+    flash = [c for c in calls if c.startswith('flash_attention.pallas')]
+    assert len(flash) == 9, flash
+    assert all('mla.attention' in under[c] for c in flash)
+    assert sum('mtp' in under[c] for c in flash) == 3
+    both = [n for n, scopes in under.items()
+            if {'mtp', 'mla.rope'} <= set(scopes)]
+    assert both and all('mla.attention' in under[n] for n in both)
+    assert any({'mtp', 'lm_head'} <= set(scopes) for scopes in under.values())
