@@ -251,6 +251,69 @@ def check_flash_against_reference(shape, interpret=False):
     return errs
 
 
+def packed_doc_starts(rows, seq, seed, median=1024, sigma=1.0, least=64):
+    """(rows, seq) int32 ``doc_start`` of rows filled exactly with documents
+    whose lengths are log-normal (clipped to [least, seq], the last cut to
+    fit): how the benchmark's packed traffic draws them."""
+    rs = np.random.default_rng(seed)
+    starts = np.zeros((rows, seq), np.int32)
+    for row in starts:
+        at = 0
+        while at < seq:
+            n = int(np.clip(np.rint(rs.lognormal(np.log(median), sigma)),
+                            least, seq))
+            row[at:at + n] = at
+            at += n
+    return starts
+
+
+def check_flash_packed(shape=(1, 2, 8192, 192), v_dim=128, seed=0,
+                       block=512, interpret=False):
+    """The kernels on packed rows, with their loops bounded by the
+    documents, at latent attention's head sizes (q and k ``shape[-1]``
+    wide, v ``v_dim``): output and the three gradients against
+    ``_attn_reference`` in float32, which masks every (query, key) pair of
+    the whole row. Returns the largest errors, each over the reference's
+    largest entry, and the share of the causal tile pairs the forward
+    visits."""
+    from .flash_attention import doc_tile_counts
+    b, h, L, d = shape
+    q, k, v = make_device_qkv(b, h, L, d, jnp.bfloat16, seed)
+    v = v[..., :v_dim]
+    start = jnp.asarray(packed_doc_starts(b, L, seed))
+    cot = jnp.cos(jnp.arange(v_dim, dtype=jnp.float32))
+
+    def flash_loss(q, k, v):
+        o = flash_attention_bhld(q, k, v, causal=True, doc_start=start,
+                                 block_q=block, block_k=block,
+                                 interpret=interpret)
+        return jnp.sum(o.astype(jnp.float32) * cot), o
+
+    def ref_loss(q, k, v):
+        o = _attn_reference(q, k, v, True, d ** -0.5, doc_start=start)
+        return jnp.sum(o * cot), o
+
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        flash_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    with jax.default_matmul_precision('float32'):
+        (_, o_ref), want = jax.jit(jax.value_and_grad(
+            ref_loss, argnums=(0, 1, 2), has_aux=True))(
+            *(t.astype(jnp.float32) for t in (q, k, v)))
+    errs = {}
+    for name, got, ref in zip(('o', 'dq', 'dk', 'dv'), (o,) + grads,
+                              (o_ref,) + want):
+        err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref))
+                    / jnp.max(jnp.abs(ref)))
+        if not err < 2e-2:      # bf16 in/out, fp32 accumulate
+            raise AssertionError('packed flash: %s is %g of the '
+                                 "reference's largest entry off the XLA "
+                                 'reference' % (name, err))
+        errs[name] = err
+    swept, causal = doc_tile_counts(start, block, block)
+    errs['tiles_swept_share'] = float(swept / causal)
+    return errs
+
+
 def check_partitioned(mesh, axis, shape=(8, 16, 512, 64), hidden=1024,
                       dropout_p=0.1, interpret=False):
     """Flash attention and fused dropout+add+LayerNorm, forward and

@@ -21,7 +21,17 @@ Features:
   attention trains with 192-wide q/k and 128-wide v): read off the shapes;
 - packed documents under the causal mask: ``doc_start`` (B, L) names, for
   each query position, the first position of its document; a query sees
-  the keys from there to itself;
+  the keys from there to itself. It buys more than the mask: the tile
+  loops take their bounds from it (``doc_tile_bounds``, two small int32
+  arrays made in XLA once a call and read by the kernels from SMEM). The
+  forward starts a Q tile's sweep at the first K tile any of its rows can
+  see, the backward ends a K tile's sweep after the last Q tile that can
+  see it, so a tile pair wholly inside other documents is never visited
+  (on rows of 8192 packed from documents of median 1024: 45% of the pairs
+  under the diagonal are). Such a pair contributes exact zeros, so the
+  results are those of the full sweep element for element. A call without
+  ``doc_start`` keeps the constant bounds and traces to the same program
+  as before;
 - attention-probability dropout INSIDE the kernel: the keep-mask for tile
   (bh, q_block, k_block) is regenerated from the TPU hardware PRNG
   (pltpu.prng_seed keyed on the tile coordinates) identically in the forward
@@ -125,6 +135,14 @@ def _global_bh(seed_ref, heads):
             + seed_ref[0, 2] + bh % h_local)
 
 
+def _tile_bound(bound_ref, heads):
+    """This grid point's loop bound (``doc_tile_bounds``): bound_ref is the
+    (batch rows x tiles,) int32 array of this device's shard in SMEM, the
+    grid (batch x heads, tiles)."""
+    return bound_ref[(pl.program_id(0) // heads[0]) * pl.num_programs(1)
+                     + pl.program_id(1)]
+
+
 def _seed_and_shard(seed, shard):
     return jnp.stack([seed.astype(jnp.int32).reshape(()),
                       shard['b'][0].astype(jnp.int32),
@@ -149,8 +167,11 @@ def _fwd_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p,
         bias_ref = refs[idx]; idx += 1
     if dropout_p > 0.0:
         seed_ref = refs[idx]; idx += 1
+    first = 0
     if has_doc:
         start = refs[idx][0]; idx += 1                 # (block_q, 1) int32
+        # the first K tile a row of this Q tile can see
+        first = _tile_bound(refs[idx], heads); idx += 1
     o_ref, lse_ref = refs[idx:idx + 2]
 
     q = q_ref[0]                                       # (block_q, d) native
@@ -206,10 +227,12 @@ def _fwd_kernel(*refs, block_k, seq_len, causal, scale, has_bias, dropout_p,
             return m_new, l_new, acc_new
         return body
 
-    m, l, acc = jax.lax.fori_loop(0, n_full, make_body(False), (m, l, acc))
+    m, l, acc = jax.lax.fori_loop(first, n_full, make_body(False),
+                                  (m, l, acc))
     if causal:
-        m, l, acc = jax.lax.fori_loop(n_full, n_blocks, make_body(True),
-                                      (m, l, acc))
+        m, l, acc = jax.lax.fori_loop(
+            jnp.maximum(n_full, first) if has_doc else n_full, n_blocks,
+            make_body(True), (m, l, acc))
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), LSE_EMPTY)
     lse_ref[0] = lse.astype(jnp.float32)                # (block_q, 1)
@@ -230,13 +253,15 @@ def _fwd_vmem_limit(L, d, dv, bq, itemsize, row_operands):
     return None if need <= (14 << 20) else need * 3 // 2
 
 
-def _flash_forward(q, k, v, kpad_bias, seed, doc_start, causal, scale,
+def _flash_forward(q, k, v, kpad_bias, seed, doc, causal, scale,
                    block_q, block_k, dropout_p, interpret):
+    """doc: None, or (doc_start, lo, hi) as ``doc_tile_bounds`` makes the
+    bounds; the forward reads ``lo``."""
     L, d = q.shape[2:]
     dv = v.shape[3]
     bq, bk = min(block_q, L), min(block_k, L)
     has_bias = kpad_bias is not None
-    has_doc = doc_start is not None
+    has_doc = doc is not None
     args, dims = [q, k, v], [_BHLD] * 3
     if has_bias:
         # (B, 1, L) so the block shape (1, 1, L) satisfies TPU tiling rules
@@ -246,8 +271,10 @@ def _flash_forward(q, k, v, kpad_bias, seed, doc_start, causal, scale,
         args.append(seed)
         dims.append((None, None))
     if has_doc:
-        args.append(doc_start.astype(jnp.int32)[:, :, None])    # (B, L, 1)
+        args.append(doc[0].astype(jnp.int32)[:, :, None])       # (B, L, 1)
         dims.append(('b', 'l', None))
+        args.append(doc[1])                                     # (B, L // bq)
+        dims.append(('b', None))
     extra = {'has_doc': True} if has_doc else {}
     limit = _fwd_vmem_limit(L, d, dv, bq, q.dtype.itemsize, 1 + int(has_doc))
     if limit is not None:
@@ -280,6 +307,8 @@ def _flash_forward(q, k, v, kpad_bias, seed, doc_start, causal, scale,
         if has_doc:
             in_specs.append(
                 pl.BlockSpec((1, bq, 1), lambda bh, i: (bh // h, i, 0)))
+            in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+            args[-1] = args[-1].reshape(-1)
         o, lse = pl.pallas_call(
             kernel,
             grid=(b * h, L // bq),
@@ -314,6 +343,8 @@ def _bwd_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
         seed_ref = refs[idx]; idx += 1
     if has_doc:
         start_ref = refs[idx]; idx += 1                 # (1, L, 1) int32
+        # one past the last Q tile that can see a key of this K tile
+        last = _tile_bound(refs[idx], heads); idx += 1
     dq_ref, dk_ref, dv_ref = refs[idx:idx + 3]
 
     k = k_ref[0]                                        # (block_k, d) native
@@ -389,7 +420,9 @@ def _bwd_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
         bound = jnp.minimum(jnp.maximum(start_full, start), nq)
         dk, dv = jax.lax.fori_loop(start, bound, make_body(True),
                                    (zero, zero_v))
-        dk, dv = jax.lax.fori_loop(bound, nq, make_body(False), (dk, dv))
+        dk, dv = jax.lax.fori_loop(
+            bound, jnp.minimum(last, nq) if has_doc else nq,
+            make_body(False), (dk, dv))
     else:
         dk, dv = jax.lax.fori_loop(start, nq, make_body(False),
                                    (zero, zero_v))
@@ -419,14 +452,15 @@ def _bwd_vmem_limit(L, d, bq, bk, itemsize, dv=None, has_doc=False):
     return None if need <= (14 << 20) else need * 3 // 2
 
 
-def _flash_backward(q, k, v, o, lse, kpad_bias, seed, doc_start, g, causal,
+def _flash_backward(q, k, v, o, lse, kpad_bias, seed, doc, g, causal,
                     scale, block_q, block_k, dropout_p, interpret):
+    """doc: as ``_flash_forward``'s; the backward reads ``hi``."""
     L, d = q.shape[2:]
     dv_ = v.shape[3]
     bq, bk = min(block_q, L), min(block_k, L)
     nk = L // bk
     has_bias = kpad_bias is not None
-    has_doc = doc_start is not None
+    has_doc = doc is not None
     args = [q, k, v, o, g, lse[..., None]]
     dims = [_BHLD] * 5 + [('b', 'h', 'l', None)]
     if has_bias:
@@ -436,8 +470,10 @@ def _flash_backward(q, k, v, o, lse, kpad_bias, seed, doc_start, g, causal,
         args.append(seed)
         dims.append((None, None))
     if has_doc:
-        args.append(doc_start.astype(jnp.int32)[:, :, None])    # (B, L, 1)
+        args.append(doc[0].astype(jnp.int32)[:, :, None])       # (B, L, 1)
         dims.append(('b', 'l', None))
+        args.append(doc[2])                                     # (B, L // bk)
+        dims.append(('b', None))
     extra = {'has_doc': True} if has_doc else {}
 
     def call(*args, shard):
@@ -469,6 +505,8 @@ def _flash_backward(q, k, v, o, lse, kpad_bias, seed, doc_start, g, causal,
         if has_doc:
             in_specs.append(
                 pl.BlockSpec((1, L, 1), lambda bh, j: (bh // h, 0, 0)))
+            in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+            args[-1] = args[-1].reshape(-1)
         # dQ's block is the head's whole (L, d) at every K tile: it stays in
         # VMEM along that axis (which must therefore run in order) and goes
         # out once
@@ -500,30 +538,71 @@ def _flash_backward(q, k, v, o, lse, kpad_bias, seed, doc_start, g, causal,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
-def _flash(q, k, v, kpad_bias, seed, doc_start, causal, scale, block_q,
+def _flash(q, k, v, kpad_bias, seed, doc, causal, scale, block_q,
            block_k, dropout_p, interpret):
-    o, _ = _flash_forward(q, k, v, kpad_bias, seed, doc_start, causal, scale,
+    o, _ = _flash_forward(q, k, v, kpad_bias, seed, doc, causal, scale,
                           block_q, block_k, dropout_p, interpret)
     return o
 
 
-def _flash_fwd_rule(q, k, v, kpad_bias, seed, doc_start, causal, scale,
+def _flash_fwd_rule(q, k, v, kpad_bias, seed, doc, causal, scale,
                     block_q, block_k, dropout_p, interpret):
-    o, lse = _flash_forward(q, k, v, kpad_bias, seed, doc_start, causal,
+    o, lse = _flash_forward(q, k, v, kpad_bias, seed, doc, causal,
                             scale, block_q, block_k, dropout_p, interpret)
-    return o, (q, k, v, o, lse, kpad_bias, seed, doc_start)
+    return o, (q, k, v, o, lse, kpad_bias, seed, doc)
 
 
 def _flash_bwd_rule(causal, scale, block_q, block_k, dropout_p, interpret,
                     res, g):
-    q, k, v, o, lse, kpad_bias, seed, doc_start = res
-    dq, dk, dv = _flash_backward(q, k, v, o, lse, kpad_bias, seed, doc_start,
+    q, k, v, o, lse, kpad_bias, seed, doc = res
+    dq, dk, dv = _flash_backward(q, k, v, o, lse, kpad_bias, seed, doc,
                                  g, causal, scale, block_q, block_k,
                                  dropout_p, interpret)
     return dq, dk, dv, None, None, None
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def doc_tile_bounds(doc_start, block_q, block_k):
+    """The kernels' loop bounds on packed rows, from ``doc_start`` (B, L;
+    L in whole tiles of both sizes), int32:
+
+    lo (B, L // block_q): the first K tile any row of Q tile i can see,
+        ``min(doc_start[Q tile i]) // block_k`` (never past the tile of the
+        diagonal, whatever a caller's ``doc_start`` holds);
+    hi (B, L // block_k): one past the last Q tile with a row that can see a
+        key of K tile j, i.e. whose earliest ``doc_start`` is at most the
+        tile's last column.
+
+    The minimum over a tile's rows keeps them right for any ``doc_start``,
+    monotone along the row or not: a tile pair inside the bounds that no row
+    sees is masked by the kernels as every pair is."""
+    B, L = doc_start.shape
+    nq, nk = L // block_q, L // block_k
+    earliest = jnp.min(doc_start.astype(jnp.int32).reshape(B, nq, block_q),
+                       axis=2)
+    q_tiles = jnp.arange(nq, dtype=jnp.int32)
+    lo = jnp.minimum(earliest // block_k, q_tiles * block_q // block_k)
+    last_col = jnp.arange(nk, dtype=jnp.int32) * block_k + block_k - 1
+    seen = earliest[:, None, :] <= last_col[None, :, None]      # (B, nk, nq)
+    hi = jnp.max(jnp.where(seen, q_tiles + 1, 0), axis=2)
+    return lo, hi
+
+
+def doc_tile_counts(doc_start, block_q=_BLOCK, block_k=_BLOCK):
+    """-> (swept, causal), float32 scalars: the tile pairs one forward
+    call on these rows visits per head with the document bounds, and
+    without them (every tile up to the diagonal). Zeros where the rows do
+    not tile (the kernels do not run there)."""
+    L = doc_start.shape[1]
+    if not _tiles(L, L, block_q, block_k):
+        return jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)
+    bq, bk = min(block_q, L), min(block_k, L)
+    lo, _ = doc_tile_bounds(doc_start, bq, bk)
+    ends = (jnp.arange(L // bq, dtype=jnp.int32) * bq + bq + bk - 1) // bk
+    return (jnp.sum(ends - lo).astype(jnp.float32),
+            (jnp.sum(ends) * lo.shape[0]).astype(jnp.float32))
 
 
 def _tiles(lq, lk, block_q, block_k):
@@ -575,8 +654,12 @@ def flash_attention_bhld(q, k, v, causal=False, scale=None, kpad_bias=None,
                                    dropout_p, key, doc_start)
     seed = (dropout_seed if dropout_seed is not None
             else jnp.zeros((1, 1), jnp.int32))
+    doc = None
+    if doc_start is not None:
+        doc = (doc_start,) + doc_tile_bounds(doc_start, min(block_q, L),
+                                             min(block_k, L))
     with took('flash_attention', 'pallas'):
-        return _flash(q, k, v, kpad_bias, seed, doc_start, causal, scale,
+        return _flash(q, k, v, kpad_bias, seed, doc, causal, scale,
                       block_q, block_k, dropout_p, interpret)
 
 

@@ -1,15 +1,17 @@
 """What the sparse decoders (`kimi_linear`, `joyai_flash`) share: the pre-norm
 residual block with a dense or a routed feed-forward, the next-token loss of a
-packed row taken a row at a time, and the expert layers' counters as one
-vector. The attention layer is the caller's choice.
+packed row taken a row at a time, and the step's counters (the expert layers'
+and the flash kernels' tile pairs) as one vector. The attention layer is the
+caller's choice.
 """
 import jax
 import jax.numpy as jnp
 
 from .. import nn
 from ..core.tensor import Tensor, apply_op
+from ..kernels.flash_attention import doc_tile_counts
 from ..nn.functional.moe import COUNTERS
-from ..nn.layer.linear_attention import compute_dtype
+from ..nn.layer.linear_attention import compute_dtype, doc_starts
 from ..observability import costs as _costs
 
 # the shared layers' named scopes, the kernels' own and the engine's optimizer
@@ -21,13 +23,19 @@ _costs.register_scopes('mla.attention', 'moe.route', 'moe.experts',
                        'update')
 
 __all__ = ['SparseDecoderBlock', 'packed_head_loss', 'merge_counters',
-           'MOE_COUNTER_NAMES', 'MOE_COUNTER_SUMS']
+           'STEP_COUNTER_NAMES', 'STEP_COUNTER_SUMS']
 
 # what a sparse decoder's `forward` returns beside its loss, as
 # `engine.TrainStep` records it (the net's `step_counter_names`), and the
-# names among them that add up over steps (`step_counter_sums`)
-MOE_COUNTER_NAMES = tuple('moe.' + name for name in COUNTERS)
-MOE_COUNTER_SUMS = ('moe.assignments_held', 'moe.assignments', 'moe.dropped')
+# names among them that add up over steps (`step_counter_sums`): the expert
+# layers' counters, then the tile pairs ONE latent layer's forward kernel
+# visits per head on the step's rows, with the document bounds and without
+# (every latent layer of a step sees the same rows)
+FLASH_COUNTERS = ('flash.tiles_swept', 'flash.tiles_causal')
+STEP_COUNTER_NAMES = tuple('moe.' + name for name in COUNTERS) \
+    + FLASH_COUNTERS
+STEP_COUNTER_SUMS = ('moe.assignments_held', 'moe.assignments',
+                     'moe.dropped') + FLASH_COUNTERS
 
 
 class SparseDecoderBlock(nn.Layer):
@@ -99,19 +107,24 @@ def packed_head_loss(x, labels, head):
     return apply_op(loss_fn, (x, labels, head))
 
 
-def merge_counters(counted):
-    """The expert layers' counters as one vector in COUNTERS' order: sums,
-    but the busiest expert's rows and the mean rows, which are those of the
-    layer where their ratio (the load imbalance) is largest."""
-    if not counted:
-        return Tensor(jnp.zeros((len(COUNTERS),), jnp.float32))
+def merge_counters(counted, segment_ids):
+    """A step's counters as one vector in STEP_COUNTER_NAMES' order. The
+    expert layers': sums, but the busiest expert's rows and the mean rows,
+    which are those of the layer where their ratio (the load imbalance) is
+    largest. The flash kernels': from the rows' documents, once a step."""
     at = {name: i for i, name in enumerate(COUNTERS)}
 
-    def fn(*cs):
-        c = jnp.stack(cs)
-        top, mean = c[:, at['expert_rows_max']], c[:, at['expert_rows_mean']]
-        worst = c[jnp.argmax(top / jnp.maximum(mean, 1e-9))]
-        return jnp.stack([
-            worst[i] if name in ('expert_rows_max', 'expert_rows_mean')
-            else jnp.sum(c[:, i]) for i, name in enumerate(COUNTERS)])
-    return apply_op(fn, tuple(counted), differentiable=False)
+    def fn(seg, *cs):
+        moe = jnp.zeros((len(COUNTERS),), jnp.float32)
+        if cs:
+            c = jnp.stack(cs)
+            top, mean = (c[:, at['expert_rows_max']],
+                         c[:, at['expert_rows_mean']])
+            worst = c[jnp.argmax(top / jnp.maximum(mean, 1e-9))]
+            moe = jnp.stack([
+                worst[i] if name in ('expert_rows_max', 'expert_rows_mean')
+                else jnp.sum(c[:, i]) for i, name in enumerate(COUNTERS)])
+        return jnp.concatenate(
+            [moe, jnp.stack(doc_tile_counts(doc_starts(seg)))])
+    return apply_op(fn, (segment_ids,) + tuple(counted),
+                    differentiable=False)

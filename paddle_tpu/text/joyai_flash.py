@@ -42,7 +42,7 @@ from ..core.tensor import apply_op
 from ..nn.functional.norm import rms_norm_values
 from ..nn.layer.linear_attention import compute_dtype
 from ..observability import costs as _costs
-from .decoder_block import (MOE_COUNTER_NAMES, MOE_COUNTER_SUMS,
+from .decoder_block import (STEP_COUNTER_NAMES, STEP_COUNTER_SUMS,
                             SparseDecoderBlock, merge_counters,
                             packed_head_loss)
 
@@ -120,8 +120,8 @@ class PredictionModule(nn.Layer):
 class JoyAIFlashForCausalLM(nn.Layer):
     # what the second output of `forward` counts: values of the compiled
     # step, which `engine.TrainStep` records under these names
-    step_counter_names = MOE_COUNTER_NAMES + ('loss.main', 'loss.mtp')
-    step_counter_sums = MOE_COUNTER_SUMS
+    step_counter_names = STEP_COUNTER_NAMES + ('loss.main', 'loss.mtp')
+    step_counter_sums = STEP_COUNTER_SUMS
 
     def __init__(self, config=None, **kwargs):
         super().__init__()
@@ -166,7 +166,8 @@ class JoyAIFlashForCausalLM(nn.Layer):
         loss = main + self.config.mtp_loss_weight * ahead
         counters = apply_op(
             lambda c, a, b: jnp.concatenate([c, jnp.stack([a, b])]),
-            (merge_counters(counted), main, ahead), differentiable=False)
+            (merge_counters(counted, segment_ids), main, ahead),
+            differentiable=False)
         return loss, counters
 
     @staticmethod

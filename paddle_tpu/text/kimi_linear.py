@@ -16,7 +16,7 @@ pass instead of keeping its activations: a block keeps its two inputs.
 """
 from .. import nn
 from ..observability import costs as _costs
-from .decoder_block import (MOE_COUNTER_NAMES, MOE_COUNTER_SUMS,
+from .decoder_block import (STEP_COUNTER_NAMES, STEP_COUNTER_SUMS,
                             SparseDecoderBlock, merge_counters,
                             packed_head_loss)
 
@@ -78,8 +78,8 @@ class KimiLinearBlock(SparseDecoderBlock):
 class KimiLinearForCausalLM(nn.Layer):
     # what the second output of `forward` counts: values of the compiled
     # step, which `engine.TrainStep` records under these names
-    step_counter_names = MOE_COUNTER_NAMES
-    step_counter_sums = MOE_COUNTER_SUMS
+    step_counter_names = STEP_COUNTER_NAMES
+    step_counter_sums = STEP_COUNTER_SUMS
 
     def __init__(self, config=None, **kwargs):
         super().__init__()
@@ -107,7 +107,7 @@ class KimiLinearForCausalLM(nn.Layer):
             x, counters = block(x, segment_ids, selected)
             if block.sparse:
                 counted.append(counters)
-        return x, merge_counters(counted)
+        return x, merge_counters(counted, segment_ids)
 
     def forward(self, input_ids, segment_ids, labels, selected=None):
         x, counters = self.hidden_states(input_ids, segment_ids, selected)

@@ -115,7 +115,8 @@ def test_flash_latent_attention_shapes_compile_fwd_bwd(one_chip):
     rows, heads, seq = 2, 32, 8192
 
     def loss(q, k, v, start):
-        out = fa._flash(q, k, v, None, jnp.zeros((1, 1), jnp.int32), start,
+        doc = (start,) + fa.doc_tile_bounds(start, 512, 512)
+        out = fa._flash(q, k, v, None, jnp.zeros((1, 1), jnp.int32), doc,
                         True, 192 ** -0.5, 512, 512, 0.0, False)
         return jnp.sum(out.astype(jnp.float32))
 

@@ -147,7 +147,10 @@ def test_the_layer_without_the_new_arguments_traces_to_the_parents_program(
     configuration, its parameter names and, forward and gradient under bf16
     autocast with the block's norm in front and recomputation, the jaxpr it
     traced to at commit aa857e7 (digests of that commit's text at these
-    shapes, source locations taken out), on the kernels' path and off it."""
+    shapes, source locations taken out), on the kernels' path and off it.
+    PR 32 changed the kernels' program on packed rows (their loops take two
+    more operands, the tile bounds made from `doc_start`): the 'tpu' digest
+    is that commit's layer with PR 32's kernels, the 'cpu' one aa857e7's."""
     from paddle_tpu import amp
     paddle.seed(0)
     layer = nn.LatentAttention(256, 2, 128, 64, 128, 128, epsilon=1e-5)
@@ -167,8 +170,8 @@ def test_the_layer_without_the_new_arguments_traces_to_the_parents_program(
     x = jnp.zeros((2, 1024, 256), jnp.float32)
     seg = jnp.zeros((2, 1024), jnp.int32)
     for backend, digest in [
-            ('tpu', '4d9d61ead5e9ddf16f98ec63105f2a8f2e414a583a7d8fc9b79946f5'
-                    '5e9f399d'),
+            ('tpu', '454c4399c50684e60c7f707b6fb326d94eaa5343eb434d97eda35e27c'
+                    'cc14be3'),
             ('cpu', '733f06177ae1dfd8dfbcac97d8a4658f4593b1841ab299d9e8d26ffc'
                     'd7eae55f')]:
         monkeypatch.setattr(jax, 'default_backend', lambda b=backend: b)

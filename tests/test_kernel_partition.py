@@ -62,21 +62,29 @@ def test_row_kernels_partition_over_the_batch_axes(fn):
     assert got_g[0].sharding.spec[0] == 'data'   # nothing was gathered
 
 
-@pytest.mark.parametrize('causal,kpad', [(True, False), (False, True)],
-                         ids=['causal', 'key_padding'])
-def test_flash_partitions_over_batch_and_heads(causal, kpad):
+@pytest.mark.parametrize('causal,kpad,packed', [
+    (True, False, False), (False, True, False), (True, False, True)],
+    ids=['causal', 'key_padding', 'packed'])
+def test_flash_partitions_over_batch_and_heads(causal, kpad, packed):
     mesh = _mesh((2, 2), ('data', 'model'))
     B, H, L, D = 4, 4, 128, 32
     q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (B, H, L, D),
                                  jnp.float32) for i in range(3))
-    bias = None
+    bias = start = None
     if kpad:
         bias = jnp.where(jnp.arange(L)[None, :] < jnp.array(
             [[128], [96], [64], [32]]), 0.0, -1e4).astype(jnp.float32)
+    if packed:      # every row its own documents: each device's rows take
+        at = jnp.arange(L)[None, :]     # their own tile bounds with them
+        start = jnp.where(at >= jnp.array([[128], [64], [100], [32]]),
+                          jnp.array([[128], [64], [100], [32]]), 0
+                          ).astype(jnp.int32)
+    block = 32 if packed else 64
 
     def loss(q, k, v):
         o = flash_attention_bhld(q, k, v, causal=causal, kpad_bias=bias,
-                                 block_q=64, block_k=64, interpret=True)
+                                 doc_start=start, block_q=block,
+                                 block_k=block, interpret=True)
         return jnp.sum(o * o)
 
     def traced(q, k, v):
