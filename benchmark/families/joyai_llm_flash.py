@@ -157,6 +157,7 @@ def make_pool(cfg, traffic, seed, batches, rows):
 
 
 augment = _rows.augment
+layout_digest = _rows.layout_digest
 
 
 # ------------------------------------------------------------- operations

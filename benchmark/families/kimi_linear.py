@@ -11,6 +11,8 @@ a seed, the host batches from a traffic file, the operations one sample
 requires, and which of the optimizer's slots holds the first gradient. The
 plain reference is `kimi_linear_reference.py`, beside this file.
 """
+import hashlib
+
 import numpy as np
 
 REFERENCE = 'kimi_linear_reference'
@@ -175,11 +177,16 @@ def _lengths(rs, traffic, seq):
 def make_pool(cfg, traffic, seed, batches, rows):
     """`batches` host batches of `rows` packed rows, ((ids, segment ids,
     labels), ()). A row is filled exactly with documents, so nothing is
-    padding. Inside a document the next id is, with probability
-    `copy_prob`, a fixed seeded permutation of the current one (which a model
-    can learn), else uniform in the vocabulary slice. A position's label is
-    the next id where that lies in the same document, else -1."""
+    padding. The documents' lengths are the TRAFFIC's (`layout_seed`): the
+    same rows of documents in every run, because the flash kernels' time
+    follows a row's documents and two runs are to time the same work. What
+    fills them is the run's: inside a document the next id is, with
+    probability `copy_prob`, a fixed seeded permutation of the current one
+    (which a model can learn), else uniform in the vocabulary slice. A
+    position's label is the next id where that lies in the same document,
+    else -1."""
     rs = np.random.default_rng([int(seed), 0x4B1A])
+    layout = np.random.default_rng([int(traffic['layout_seed']), 0x4B1A])
     seq, V = traffic['seq_len'], cfg['vocab_size']
     successor = rs.permutation(V).astype(np.int32)
     n = batches * rows
@@ -187,7 +194,7 @@ def make_pool(cfg, traffic, seed, batches, rows):
     copy = rs.random((n, seq)) < traffic['copy_prob']
     seg = np.zeros((n, seq), np.int32)
     for r in range(n):
-        lengths = _lengths(rs, traffic, seq)
+        lengths = _lengths(layout, traffic, seq)
         seg[r] = np.repeat(np.arange(len(lengths)), lengths)
     inside = np.concatenate([np.zeros((n, 1), bool),
                              seg[:, 1:] == seg[:, :-1]], axis=1)
@@ -201,6 +208,13 @@ def make_pool(cfg, traffic, seed, batches, rows):
         s = slice(b * rows, (b + 1) * rows)
         out.append(((ids[s], seg[s], labels[s]), ()))
     return out
+
+
+def layout_digest(pool):
+    """One digest of the pool's document layouts, whatever their order."""
+    each = sorted(hashlib.sha256(np.ascontiguousarray(batch[0][1]).tobytes())
+                  .hexdigest() for batch in pool)
+    return hashlib.sha256(''.join(each).encode()).hexdigest()[:16]
 
 
 def augment(traffic, batch, rs):

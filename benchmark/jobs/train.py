@@ -15,8 +15,13 @@ A run, in order:
            the window's own call and feed, with the readings the comparison
            needs; the timed step's warm-up.
   window   dispatch step i, then wait for the loss of step i-1: the host
-           clock at that moment is step i-1's completion. It closes at the
-           first completion at or after `--seconds`.
+           clock at that moment is step i-1's completion. It is WHOLE PASSES
+           over the pool: its first counted step is the first batch of a
+           pass, and it closes at the first completion at or after
+           `--seconds` that completes one. Every pool batch is so timed once
+           a pass, in every run alike: where a step's time follows its batch
+           (the packed cells) two runs time the same work, and an interval
+           has a second reading of the same batch to be held against.
   (trace)  with --trace 1, a few more steps under the profiler.
   check    the state is freed; the plain reference follows the same three
            batches from the same seeded weights; every number is printed
@@ -28,6 +33,7 @@ kernels' hardware PRNG, which no reference can follow) it is a twin built by
 the same calls with dropout off, and the timed object is held to what
 dropout leaves steady: finite losses, no compile, a loss that falls.
 """
+import collections
 import functools
 import gc
 import json
@@ -47,6 +53,9 @@ from harness.spans import Spans  # noqa: E402
 CHECK_STEPS = 3
 WARMUP_STEPS = 3
 TRACE_STEPS = 6
+# the calls before the first counted step: the checked steps, the warm-up
+# and the one call of `window` whose completion is the window's start
+LEAD_STEPS = CHECK_STEPS + WARMUP_STEPS + 1
 
 
 _T0 = [time.perf_counter()]     # run() sets it to the process's start
@@ -59,25 +68,39 @@ def say(**facts):
 
 
 class Feed:
-    """Host batches from the seeded pool, for ever: every pass over the pool
-    in a new seeded order, every batch through the family's host
-    augmentation. Remembers the first batches it gave, for the reference.
-    Runs on the prefetcher's thread."""
+    """Host batches from the seeded pool, for ever, every batch through the
+    family's host augmentation. The first `lead` batches (set-up's and the
+    window's lead-in call) are the first of a seeded order and are not run
+    out to the end of a pass; behind them every pass over the pool is a
+    whole new seeded order, so the first counted step is the first batch of
+    a pass. Remembers the first batches it gave, for the reference, and the
+    pool index of every batch it gave (`order[k]` is the batch of the
+    consumer's call k, however far the prefetcher runs ahead). Runs on the
+    prefetcher's thread."""
 
-    def __init__(self, family, traffic, pool, seed, remember):
+    def __init__(self, family, traffic, pool, seed, remember,
+                 lead=LEAD_STEPS):
         self.family, self.traffic, self.pool = family, traffic, pool
         self.rs = np.random.default_rng([int(seed), 0xFEED])
-        self.remember = remember
-        self.first = []
+        self.lead, self.remember = lead, remember
+        self.first, self.order = [], []
+
+    def _indices(self):
+        left = self.lead
+        while left:
+            walk = self.rs.permutation(len(self.pool))[:left]
+            left -= len(walk)
+            yield from walk
+        while True:
+            yield from self.rs.permutation(len(self.pool))
 
     def __iter__(self):
-        while True:
-            for i in self.rs.permutation(len(self.pool)):
-                batch = self.family.augment(self.traffic, self.pool[i],
-                                            self.rs)
-                if len(self.first) < self.remember:
-                    self.first.append(batch)
-                yield batch
+        for i in self._indices():
+            batch = self.family.augment(self.traffic, self.pool[i], self.rs)
+            if len(self.first) < self.remember:
+                self.first.append(batch)
+            self.order.append(int(i))
+            yield batch
 
 
 class Caller:
@@ -90,8 +113,10 @@ class Caller:
         from paddle_tpu.core import rng
         self.feed, self.spans = feed_iter, spans
         self.amp, self.rng, self.dtype = amp, rng, compute_dtype
+        self.calls = 0      # call k takes the feed's batch k
 
     def __call__(self, step, state):
+        self.calls += 1
         with self.spans.span('input.wait'):
             batch = next(self.feed)
         with self.spans.span('step.key'):
@@ -194,12 +219,15 @@ def checked_steps(call, step, state, family, config, make_start):
     return state, readings
 
 
-def window(call, step, state, seconds):
-    """-> (state, t0, completions, losses, dispatched): completion instants
-    of the steps that completed from `t0` (the completion of the step before
-    the first counted one) to the first completion at or after t0 + seconds;
-    `dispatched[i]` is when the loop went on to dispatch behind step i."""
+def window(call, step, state, seconds, pool_batches):
+    """-> (state, t0, completions, losses, dispatched, first): completion
+    instants of the steps that completed from `t0` (the completion of the
+    step before the first counted one) to the first completion at or after
+    t0 + seconds that completes a pass over the pool; `dispatched[i]` is
+    when the loop went on to dispatch behind step i; `first` is which of
+    `call`'s calls the first counted step was."""
     state, current = call(step, state)
+    first = call.calls
     state, pending = call(step, state)
     current.block_until_ready()
     t0 = time.perf_counter()
@@ -212,14 +240,57 @@ def window(call, step, state, seconds):
         completions.append(now)
         losses.append(pending)
         pending = upcoming
-        if now - t0 >= seconds:
+        if now - t0 >= seconds and len(completions) % pool_batches == 0:
             break
     pending.block_until_ready()
-    return state, t0, completions, losses, dispatched
+    return state, t0, completions, losses, dispatched, first
+
+
+def other_passes(intervals, batches):
+    """For every interval the median of the SAME pool batch's intervals in
+    the window's other passes (nan where it has no other)."""
+    where = collections.defaultdict(list)
+    for i, b in enumerate(batches):
+        where[b].append(i)
+    other = np.full(len(intervals), np.nan)
+    for same in where.values():
+        for i in same:
+            rest = [intervals[j] for j in same if j != i]
+            if rest:
+                other[i] = statistics.median(rest)
+    return other
+
+
+def late_notices(intervals, batches):
+    """-> (repaired intervals, [i, ...]): the pairs (i, i + 1) in which the
+    host noticed completion i late and the device was on pace. The clock is
+    read when the host wakes up, so a late wake-up makes interval i long by
+    some d and interval i + 1 short by the same d. With every batch timed
+    once a pass that can be told exactly: interval i is longer than its
+    batch's reading in the other passes by d, more than 1% of it, and
+    interval i + 1 is shorter than ITS batch's other reading by d to within
+    a tenth. Both then take the other passes' reading. A stall that is not
+    paid back (a compile, a starved input, a slow device) is no such pair
+    and stays as it is; so does everything in a window of one pass."""
+    other = other_passes(intervals, batches)
+    repaired, found, i = np.array(intervals, float), [], 0
+    while i + 1 < len(intervals):
+        late = intervals[i] - other[i]
+        back = other[i + 1] - intervals[i + 1]
+        # (a comparison with nan, where a batch has no other pass, is False)
+        if late > 0.01 * other[i] and abs(back - late) <= 0.1 * late:
+            repaired[i:i + 2] = other[i:i + 2]
+            found.append(i)
+            i += 2
+        else:
+            i += 1
+    return repaired, found
 
 
 def traced_steps(call, step, state, spans, directory):
-    """TRACE_STEPS steady steps under the profiler -> (state, xplane path)."""
+    """TRACE_STEPS steady steps under the profiler -> (state, xplane path,
+    which of `call`'s calls were made under it: the step the trace ends in
+    is the last of them, since the loop waits for it before it stops)."""
     import jax
     shutil.rmtree(directory, ignore_errors=True)
     os.makedirs(directory)
@@ -229,8 +300,9 @@ def traced_steps(call, step, state, spans, directory):
     options.host_tracer_level = 2
     jax.profiler.start_trace(directory, profiler_options=options)
     spans.annotate = True
+    under = range(call.calls, call.calls + TRACE_STEPS + 1)
     try:
-        for _ in range(TRACE_STEPS + 1):
+        for _ in under:
             state, upcoming = call(step, state)
             pending.block_until_ready()
             pending = upcoming
@@ -243,7 +315,7 @@ def traced_steps(call, step, state, spans, directory):
     if len(found) != 1:
         raise RuntimeError('expected one .xplane.pb under %s, found %s'
                            % (directory, found))
-    return state, found[0]
+    return state, found[0], under
 
 
 def compiler_memory(call, step, state):
@@ -311,6 +383,7 @@ def run(*, cell, config, traffic, limits, family, reference, seed, seconds,
         step = wrap_step(step)
     pool = family.make_pool(config, traffic, seed, traffic['pool_batches'],
                             rows)
+    pool_batches = len(pool)
     feed = Feed(family, traffic, pool, seed, remember=CHECK_STEPS)
     feed_iter = prefetcher(step, feed)
     call = Caller(feed_iter, spans, config['compute_dtype'])
@@ -318,7 +391,9 @@ def run(*, cell, config, traffic, limits, family, reference, seed, seconds,
         def make_start():       # made anew where needed, not kept
             return params_mod.make(spec, seed)
 
-        say(phase='built', pool_batches=len(pool))
+        layout = getattr(family, 'layout_digest', None)
+        say(phase='built', pool_batches=pool_batches,
+            **({'layout': layout(pool)} if layout else {}))
         if twin:
             checked, make_checked, _ = build_step(
                 family, config, traffic, devices, deterministic=True)
@@ -349,33 +424,40 @@ def run(*, cell, config, traffic, limits, family, reference, seed, seconds,
         # what the window makes.
         gc.collect()
         gc.freeze()
-        state, t0, completions, losses, dispatched = window(
-            call, step, state, seconds)
+        state, t0, completions, losses, dispatched, first = window(
+            call, step, state, seconds, pool_batches)
+        if first != feed.lead:
+            raise RuntimeError('the first counted step is call %d and the '
+                               'feed began its passes at batch %d'
+                               % (first, feed.lead))
         compiles_in_window = compiles.count - in_window
         window_mark = spans.mark()
         memory = memory_facts(devices)
         losses = [float(v) for v in jax.device_get(losses)]
-        xplane = None
+        xplane, under_trace = None, ()
         if trace:
-            state, xplane = traced_steps(call, step, state, spans,
-                                         os.path.join(scratch, 'trace'))
+            state, xplane, under_trace = traced_steps(
+                call, step, state, spans, os.path.join(scratch, 'trace'))
             say(phase='memory_analysis', per_device=compiler_memory(
                 call, step, state), **memory)
     finally:
         feed_iter.close()
     batches = [jax.tree_util.tree_map(np.asarray, b) for b in feed.first]
+    order = list(feed.order)
     del state, pool, feed, call
     gc.collect()
 
     # ------------------------------------------------------------- numbers
     intervals = np.diff([t0] + completions) * 1e3
+    batch_of = order[first:first + len(intervals)]  # each interval's batch
+    repaired, late = late_notices(intervals, batch_of)
     length = completions[-1] - t0
     samples_per_s = len(completions) * rows / length
     peak = peaks.peaks_of(device0.device_kind)
     flops = family.flops_per_sample(config, traffic)
     values = {
         'samples_per_s': samples_per_s,
-        'step_ms_p95': float(np.quantile(intervals, 0.95)),
+        'step_ms_p95': float(np.quantile(repaired, 0.95)),
         'mfu_pct': 100.0 * samples_per_s * flops
         / (chips * peak['bf16_flops_per_s']),
         'setup_s': setup_s,
@@ -383,11 +465,15 @@ def run(*, cell, config, traffic, limits, family, reference, seed, seconds,
     slowest = int(np.argmax(intervals))
     before = completions[slowest - 1] if slowest else t0
     say(phase='slowest_step', index=slowest, ms=float(intervals[slowest]),
+        pool_batch=batch_of[slowest], late_notice=slowest in late,
         host_ms_before_wait=(dispatched[slowest] - before) * 1e3,
         wait_ms=(completions[slowest] - dispatched[slowest]) * 1e3)
     say(phase='window', steps=len(completions), window_s=length,
+        passes=len(completions) // pool_batches, late_notices=len(late),
+        late_ms=[float(intervals[i] - repaired[i]) for i in late],
         step_ms_median=statistics.median(intervals),
         step_ms_min=float(min(intervals)), step_ms_max=float(max(intervals)),
+        step_ms_p95_as_read=float(np.quantile(intervals, 0.95)),
         compiles_in_window=compiles_in_window,
         flops_per_sample=flops, setup=setup_compiles, **memory)
 
@@ -431,8 +517,12 @@ def run(*, cell, config, traffic, limits, family, reference, seed, seconds,
                                                   'step.dispatch')),
         scopes=sorted({s for r in readers.values()
                        for s in getattr(r, 'SCOPES', ())}))
+    # what the window read for the batches of the calls made under the
+    # trace: a pool batch's intervals (a late notice repaired), their mean
+    batch_ms = {b: float(np.mean([ms for ms, bb in zip(repaired, batch_of)
+                                  if bb == b])) for b in set(batch_of)}
     context = {'spans': spans, 'window': (mark, window_mark),
-               'step_ms_median': statistics.median(intervals),
+               'traced_step_ms': [batch_ms[order[k]] for k in under_trace],
                'compiles_in_window': compiles_in_window, 'trace': reduced,
                'config': config, 'traffic': traffic, 'rows': rows,
                'chips': chips, 'peaks': peak}
