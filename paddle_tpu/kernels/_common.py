@@ -117,6 +117,24 @@ def spmd_kernel(impl, in_dims, out_dims, roles, granule=1, scope=None):
     return run
 
 
+def head_lanes(size):
+    """The lanes a head of `size` channels is laid on: `size` where it is
+    whole 128-lane registers; else the next multiple of 128 where the zero
+    channels that fill it cost at most a third more work (96 -> 128,
+    192 -> 256); None past that (64: the XLA form is cheaper than a kernel
+    that does twice the work)."""
+    lanes = -(-size // 128) * 128
+    return lanes if 3 * lanes <= 4 * size else None
+
+
+def on_lanes(x, lanes):
+    """Zero channels behind the last axis of x up to `lanes`."""
+    extra = lanes - x.shape[-1]
+    if not extra:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, extra)])
+
+
 def tile_keep_scale(seed_ref, tile_id, shape, dropout_p):
     """Regenerate a dropout keep/(1-p) mask for one tile from the TPU
     hardware PRNG. Deterministic in (seed, tile_id), so forward and backward

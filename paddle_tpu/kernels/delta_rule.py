@@ -29,6 +29,12 @@ rebuilds the chunk from q, k, v, g, beta and the chunk's start state (which
 the forward saves) and takes `jax.vjp` of the chunk-local function inside
 the kernel body, so forward and backward share one definition of the chunk.
 
+K and V need not be equal (a Gated DeltaNet head has keys of 96 and values
+of 192), and a head a third short of whole 128-lane registers is laid on
+them behind zero channels by `delta_rule` (96 -> 128, 192 -> 256; exact, its
+docstring says why). A decay per head, one scalar a token, arrives as K
+equal channels.
+
 Off the TPU (unless a test asks for interpret mode), or for a shape that
 does not tile, `delta_rule` takes `delta_rule_chunked`, the XLA form, which
 is also what the tests hold the kernels to; which one a trace took is marked
@@ -41,7 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import pallas_runs, spmd_kernel, took
+from ._common import head_lanes, on_lanes, pallas_runs, spmd_kernel, took
 
 __all__ = ['delta_rule']
 
@@ -398,20 +404,33 @@ def _heads_a_step(H):
 def delta_rule(q, k, v, g, beta, seg, scale, chunk=64, sub=16, dtype=None,
                interpret=False):
     """The gated delta rule chunk-wise, as `delta_rule_chunked` defines it:
-    q, k, g (B, T, H, K); v (B, T, H, V); beta (B, T, H); seg (B, T)
-    -> o (B, T, H, V), float32. On the TPU (or in interpret mode), with T a
-    multiple of `chunk`, `chunk` of `sub`, `sub` of the 8 sublanes and K and
-    V of the 128 lanes, the Pallas kernels; otherwise the XLA form. Either
-    way the ops sit under a `delta_rule.pallas` / `delta_rule.xla` scope."""
+    q, k (B, T, H, K); v (B, T, H, V); g (B, T, H, K), a log-decay per
+    channel, or (B, T, H), one per head (handed to the kernels as K equal
+    channels); beta (B, T, H); seg (B, T) -> o (B, T, H, V), float32.
+
+    On the TPU (or in interpret mode), with T a multiple of `chunk`, `chunk`
+    of `sub` and `sub` of the 8 sublanes, the Pallas kernels: as they are
+    where K and V are whole 128-lane registers, and for heads a third short
+    of them (`_common.head_lanes`: keys of 96, values of 192) with each head
+    laid on whole registers behind zero channels, which is exact: a zero key
+    channel adds nothing to a score or to a read of the state, the state's
+    rows and columns of zero channels stay zero, and the zero value channels'
+    outputs are dropped. Otherwise the XLA form. Either way the ops sit
+    under a `delta_rule.pallas` / `delta_rule.xla` scope."""
     T, K, V = q.shape[1], q.shape[3], v.shape[-1]
+    lanes_k, lanes_v = head_lanes(K), head_lanes(V)
     if not (pallas_runs(interpret) and T % chunk == 0 and chunk % sub == 0
-            and sub % 8 == 0 and K % 128 == 0 and V % 128 == 0):
+            and sub % 8 == 0 and lanes_k and lanes_v):
         from ..nn.functional.delta_rule import delta_rule_chunked
         with took('delta_rule', 'xla'):
             return delta_rule_chunked(q, k, v, g, beta, seg, scale,
                                       chunk=chunk, sub=sub, dtype=dtype)
     if dtype is not None:
         dtype = jnp.dtype(dtype).name       # hashable, for the custom_vjp
+    if g.ndim == 3:
+        g = jnp.broadcast_to(g[..., None], g.shape + (lanes_k,))
+    q, k, g = (on_lanes(x, lanes_k) for x in (q, k, g))
+    v = on_lanes(v, lanes_v)
 
     def call(q, k, v, g, beta, marks, shard):
         b, _, h, _ = q.shape                # this device's rows and heads
@@ -420,11 +439,12 @@ def delta_rule(q, k, v, g, beta, seg, scale, chunk=64, sub=16, dtype=None,
             b, h, T // chunk, 1, chunk)
         o = _delta(*wide, per_head, marks, tuple(zip(_STATIC, (
             scale, chunk, sub, dtype, _heads_a_step(h), interpret))))
-        return o.reshape(b, T, h, V)
+        return o.reshape(b, T, h, lanes_v)
 
     dims = ('b', None, 'h', None)
     with took('delta_rule', 'pallas'):
-        return spmd_kernel(
+        o = spmd_kernel(
             call, [dims] * 4 + [('b', None, 'h'), ('b', None, None, None)],
             [dims], {'b': 'batch', 'h': 'heads'},
             scope='delta_rule.pallas')(q, k, v, g, beta, _marks(seg, chunk))
+        return o if lanes_v == V else o[..., :V]

@@ -682,11 +682,14 @@ def _kpad_bias(mask, batch):
 
 
 def attention_blhd(q, k, v, mask=None, causal=False, dropout_p=0.0,
-                   dropout_key=None):
+                   dropout_key=None, doc_start=None):
     """softmax(q k^T / sqrt(D) + mask) v on (B, L, H, D) operands, as
     ``nn.functional.scaled_dot_product_attention`` defines it: mask boolean
     (True = keep) or additive, broadcast against (B, H, Lq, Lk);
-    dropout_key: a jax key, required when dropout_p > 0.
+    dropout_key: a jax key, required when dropout_p > 0. doc_start: (B, L)
+    int32, with causal=True and nothing else: packed rows, each query sees
+    the keys from its document's first position to itself; the choice of
+    path is then ``flash_attention_bhld``'s (the kernels wherever L tiles).
 
     The flash kernels on the TPU when they can express the call (Lq == Lk
     in whole tiles, no mask or a key-padding one) and the sequence is at
@@ -694,6 +697,12 @@ def attention_blhd(q, k, v, mask=None, causal=False, dropout_p=0.0,
     Either way under ``flash_attention.pallas`` / ``flash_attention.xla``.
     """
     batch, lq, lk = q.shape[0], q.shape[1], k.shape[1]
+    if doc_start is not None:
+        if mask is not None or dropout_p > 0.0:
+            raise ValueError("doc_start takes no mask and no dropout")
+        q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
+        return jnp.swapaxes(flash_attention_bhld(
+            q, k, v, causal=causal, doc_start=doc_start), 1, 2)
     if (pallas_runs(False) and lq >= _MIN_SEQ
             and _tiles(lq, lk, _BLOCK, _BLOCK)
             and (mask is None or _is_key_padding(mask.shape, batch, lk))):

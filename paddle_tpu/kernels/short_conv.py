@@ -32,7 +32,10 @@ arithmetic is float32, a tap formed in `causal_conv`'s order.
 
 `short_conv` takes the kernels on the TPU (or in interpret mode) when `T`
 tiles (a multiple of 16), `W` is whole 128-lane columns, a normed head is
-whole columns too and the taps reach no further than 8 rows; otherwise
+whole columns too and the taps reach no further than 8 rows. A caller that
+names its heads' size gets heads a third short of whole columns (a Gated
+DeltaNet layer's 96 and 192) laid on them behind zero channels, which is
+exact; heads shorter than that, and every other shape, take
 today's XLA ops (`nn.functional.delta_rule.causal_conv`, which is also what
 the tests hold the kernels to). Which one a trace took is marked in the HLO
 (`_common.took`): `short_conv.pallas` / `short_conv.xla`.
@@ -44,7 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import pallas_runs, spmd_kernel, took
+from ._common import head_lanes, on_lanes, pallas_runs, spmd_kernel, took
 
 __all__ = ['short_conv']
 
@@ -273,33 +276,46 @@ def _xla(y, w, seg, head_dim):
     return x.reshape(B, T, W)
 
 
-def short_conv(y, w, seg, head_dim=None, interpret=False):
-    """silu(causal_conv(y, w, seg)) and, where `head_dim` is given, each
-    head of `head_dim` channels scaled to unit length (`x * rsqrt(sum(x*x) +
-    1e-6)`): y (B, T, W) of any float dtype, w (n, W), seg (B, T)
-    -> (B, T, W) float32. The kernels or the XLA form as the module's
-    docstring says; either way under a `short_conv.pallas` / `.xla` scope."""
+def short_conv(y, w, seg, head_dim=None, interpret=False, norm=True):
+    """silu(causal_conv(y, w, seg)) and, where `head_dim` is given and
+    `norm` is left on, each head of `head_dim` channels scaled to unit
+    length (`x * rsqrt(sum(x*x) + 1e-6)`): y (B, T, W) of any float dtype,
+    w (n, W), seg (B, T) -> (B, T, W) float32. A `head_dim` with `norm` off
+    only says how the width divides into heads. Heads a third short of whole
+    128-lane registers (`_common.head_lanes`: 96, 192) are laid on them
+    behind zero channels, which the convolution, SiLU and the norm's sum of
+    squares leave zero, and dropped from the result. The kernels or the XLA
+    form as the module's docstring says; either way under a
+    `short_conv.pallas` / `.xla` scope."""
     B, T, W = y.shape
     taps = w.shape[0]
-    strip = head_dim or 128
+    normed = head_dim is not None and norm
+    lanes = 128 if head_dim is None else head_lanes(head_dim)
+    strip = lanes if normed else 128
     if not (pallas_runs(interpret) and _row_tile(T) is not None
-            and W % strip == 0 and strip % 128 == 0
+            and lanes is not None and W % (head_dim or 128) == 0
             and 2 <= taps <= _EDGE + 1):
         with took('short_conv', 'xla'):
-            return _xla(y, w, seg, head_dim)
+            return _xla(y, w, seg, head_dim if normed else None)
+    heads = W // (head_dim or 128)
+    wide = heads * lanes            # the width with every head on its lanes
+
+    def laid(x):                    # (..., W) -> (..., wide / strip, strip)
+        x = on_lanes(x.reshape(x.shape[:-1] + (heads, -1)), lanes)
+        return x.reshape(x.shape[:-2] + (wide // strip, strip))
 
     def call(y, w, bits, shard):
         b, _, h, _ = y.shape                # this device's rows and strips
         return _conv(y.reshape(b, T, h * strip), w.reshape(taps, h * strip),
-                     bits, tuple(zip(_STATIC, (
-                         strip, head_dim is not None, interpret)))
+                     bits, tuple(zip(_STATIC, (strip, normed, interpret)))
                      ).reshape(b, T, h, strip)
 
-    wide = ('b', None, 'h', None)
+    dims = ('b', None, 'h', None)
     with took('short_conv', 'pallas'):
-        return spmd_kernel(
-            call, [wide, (None, 'h', None), ('b', None, None)], [wide],
+        out = spmd_kernel(
+            call, [dims, (None, 'h', None), ('b', None, None)], [dims],
             {'b': 'batch', 'h': 'heads'}, scope='short_conv.pallas')(
-                y.reshape(B, T, W // strip, strip),
-                w.reshape(taps, W // strip, strip),
-                _marks(seg, taps)).reshape(B, T, W)
+                laid(y), laid(w), _marks(seg, taps))
+        if wide != W:
+            out = out.reshape(B, T, heads, lanes)[..., :head_dim]
+        return out.reshape(B, T, W)

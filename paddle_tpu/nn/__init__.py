@@ -45,7 +45,8 @@ from .layer.transformer import (MultiHeadAttention, TransformerEncoderLayer,
                                 TransformerEncoder, TransformerDecoderLayer,
                                 TransformerDecoder, Transformer)
 from .layer.distance import PairwiseDistance
-from .layer.linear_attention import KimiDeltaAttention, LatentAttention
+from .layer.linear_attention import (CausalSelfAttention, GatedDeltaNet,
+                                     KimiDeltaAttention, LatentAttention)
 from .layer.moe import SwiGLU, SparseMoE
 from .utils import weight_norm, remove_weight_norm, spectral_norm
 

@@ -66,12 +66,15 @@ def delta_rule_chunked(q, k, v, g, beta, seg, scale, chunk=64, sub=16,
                        dtype=None):
     """The recurrence chunk-wise (module docstring). q, k, g (B, T, H, K);
     v (B, T, H, V); beta (B, T, H); seg (B, T) -> o (B, T, H, V), float32.
+    A `g` of (B, T, H) is one log-decay a head: every channel's alike.
     T is a multiple of `chunk`, `chunk` of `sub`.
     `dtype`: the type of the large matrix products' operands (None:
     float32); the scores inside a chunk, the triangular solve and the
     state's own recurrence stay in float32 at the highest precision."""
     B, T, H, K = q.shape
     V = v.shape[-1]
+    if g.ndim == 3:
+        g = jnp.broadcast_to(g[..., None], q.shape)
     C, s = chunk, sub
     N, n = T // C, C // s
     f32 = jnp.float32

@@ -153,9 +153,15 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
     return apply_op(fn, tuple(tensors))
 
 
-def rms_norm_values(v, w=None, epsilon=1e-6):
+def rms_norm_values(v, w=None, epsilon=1e-6, mean_square=None):
     """`rms_norm` over jax values: for a layer that norms inside its own
-    traced function (one that is recomputed in the backward pass)."""
+    traced function (one that is recomputed in the backward pass).
+    `mean_square` (v's shape with a last axis of 1), where the caller has
+    it, stands for v's own: a layer that holds a share of the channels norms
+    by the mean square over all of them (docs/HEAD_SHARE.md)."""
+    if mean_square is not None:
+        y = v.astype(jnp.float32) * jax.lax.rsqrt(mean_square + epsilon)
+        return (y if w is None else y * w).astype(v.dtype)
     from ...kernels.fused_norm import fused_rms_norm
     return fused_rms_norm(v, w, epsilon)
 

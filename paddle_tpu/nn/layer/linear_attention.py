@@ -1,9 +1,12 @@
-"""Attention layers of the sparse decoders: Kimi Delta Attention (a gated
-delta rule with a decay per channel, arXiv:2510.26692) and multi-head latent
+"""Attention layers of the decoders: Kimi Delta Attention (a gated delta
+rule with a decay per channel, arXiv:2510.26692) and multi-head latent
 attention, without position encoding (the full-attention layer the same paper
 interleaves, one in four) or rotary with low-rank queries (arXiv:2412.19437
-section 2.1). Both take a packed row's document numbers: state, convolution,
-scores and positions stop at document boundaries.
+section 2.1); Gated DeltaNet (a decay per head, arXiv:2412.06464) and plain
+multi-head causal attention with normed queries and keys (the OLMo 2/3
+block's), either of which may hold a share of its heads
+(docs/HEAD_SHARE.md). All take a packed row's document numbers: state,
+convolution, scores and positions stop at document boundaries.
 """
 import math
 
@@ -12,13 +15,15 @@ import jax.numpy as jnp
 
 from ...core.tensor import apply_op
 from ...kernels.delta_rule import delta_rule
+from ...kernels.flash_attention import attention_blhd
 from ...kernels.short_conv import short_conv
 from ..initializer import Constant, Normal, ParamAttr
 from ..layer_base import Layer
 from ..functional.norm import rms_norm_values
 
-__all__ = ['KimiDeltaAttention', 'LatentAttention', 'compute_dtype',
-           'doc_starts', 'pre_normed', 'rotate_pairs']
+__all__ = ['KimiDeltaAttention', 'LatentAttention', 'GatedDeltaNet',
+           'CausalSelfAttention', 'compute_dtype', 'doc_starts', 'pre_normed',
+           'post_normed', 'rotate_pairs']
 
 
 def compute_dtype():
@@ -48,6 +53,30 @@ def pre_normed(fn, pre_norm, recompute):
         return fn(x, *rest)
     return (jax.checkpoint(run) if recompute else run), \
         ((pre_norm.weight,) if pre_norm is not None else ())
+
+
+def post_normed(fn, post_norm, recompute):
+    """`fn(x, *rest)` with the block's RMSNorm BEHIND it (the OLMo 2/3
+    block: `x + RMSNorm(f(x))`), and the whole re-run in the backward pass
+    where `recompute` says so. -> (the function, the operands to put behind
+    x)."""
+    if post_norm is None:
+        return (jax.checkpoint(fn) if recompute else fn), ()
+    eps = post_norm._epsilon
+
+    def run(x, scale, *rest):
+        return rms_norm_values(fn(x, *rest), scale, eps)
+    return (jax.checkpoint(run) if recompute else run), (post_norm.weight,)
+
+
+def _held(num_heads, heads_held):
+    """`heads_held = (first, count)` of a layer's `num_heads` -> the count
+    (all of them where it is None)."""
+    first, count = heads_held or (0, num_heads)
+    if not (0 <= first and 0 < count and first + count <= num_heads):
+        raise ValueError('heads_held %r is no range of %d heads'
+                         % (heads_held, num_heads))
+    return count
 
 
 def doc_starts(seg):
@@ -265,3 +294,164 @@ class LatentAttention(Layer):
         run, front = pre_normed(fn, pre_norm, recompute)
         return apply_op(run, (x,) + front + (segment_ids,) + q_weights + (
             self.kv_a_proj, self.kv_a_norm, self.kv_b_proj, self.o_proj))
+
+
+class GatedDeltaNet(Layer):
+    """Gated DeltaNet (arXiv:2412.06464), keys of `key_dim` and values of
+    `value_dim` a head: q, k = l2norm(silu(conv(x W))), v = silu(conv(x W_v))
+    (`kernels.short_conv`); one log-decay a head and token,
+    g = -exp(A_log) softplus(x W_a + dt_bias); beta = sigmoid(x W_b), doubled
+    where `allow_neg_eigval` (the transition then has eigenvalues down to
+    -1); the delta rule (`kernels.delta_rule`) at scale key_dim^-0.5;
+    W_o [RMSNorm_head(o) * silu(x W_g)].
+
+    `heads_held = (first, count)`: the layer holds those heads' columns of
+    every projection, their taps, `A_log` and `dt_bias`, and their rows of
+    W_o; what it returns is their addend of the layer's result
+    (docs/HEAD_SHARE.md). No head reads another, so a share needs nothing of
+    the heads it lacks."""
+
+    def __init__(self, hidden_size, num_heads, key_dim, value_dim,
+                 conv_kernel=4, allow_neg_eigval=False, heads_held=None,
+                 epsilon=1e-6, chunk=64, initializer_range=0.02):
+        super().__init__()
+        self.num_heads, self.heads_held = num_heads, heads_held
+        self.heads = H = _held(num_heads, heads_held)
+        self.key_dim, self.value_dim = key_dim, value_dim
+        self.allow_neg_eigval = allow_neg_eigval
+        self.epsilon, self.chunk = epsilon, chunk
+
+        def weight(*shape):
+            return self.create_parameter(list(shape), attr=ParamAttr(
+                initializer=Normal(0., initializer_range)))
+        self.q_proj, self.k_proj = (weight(hidden_size, H * key_dim)
+                                    for _ in range(2))
+        self.v_proj = weight(hidden_size, H * value_dim)
+        self.q_conv, self.k_conv = (weight(conv_kernel, H * key_dim)
+                                    for _ in range(2))
+        self.v_conv = weight(conv_kernel, H * value_dim)
+        self.a_proj, self.b_proj = weight(hidden_size, H), \
+            weight(hidden_size, H)
+        self.A_log = self.create_parameter(
+            [H], default_initializer=Constant(0.0))
+        self.dt_bias = self.create_parameter(
+            [H], default_initializer=Constant(0.0))
+        self.g_proj = weight(hidden_size, H * value_dim)
+        self.o_norm = self.create_parameter(
+            [value_dim], default_initializer=Constant(1.0))
+        self.o_proj = weight(H * value_dim, hidden_size)
+
+    def forward(self, x, segment_ids, post_norm=None, recompute=False):
+        """`post_norm`: the block's `nn.RMSNorm`, applied to the result
+        inside whatever is recomputed. More rows than one are taken one at a
+        time and recomputed in the backward pass, as in
+        `KimiDeltaAttention`."""
+        H, K, V = self.heads, self.key_dim, self.value_dim
+        eps, chunk = self.epsilon, self.chunk
+        top = 2.0 if self.allow_neg_eigval else 1.0
+        dtype = compute_dtype()
+
+        def fn(x, seg, wq, wk, wv, cq, ck, cv, wa, wb, a_log, dt_bias, wg,
+               norm, wo):
+            f32 = jnp.float32
+
+            def rows(x, seg):
+                B, T, _ = x.shape
+                with jax.named_scope('gdn.proj'):
+                    q = short_conv(_mm(x, wq, dtype), cq, seg, K)
+                    k = short_conv(_mm(x, wk, dtype), ck, seg, K)
+                    v = short_conv(_mm(x, wv, dtype), cv, seg, V, norm=False)
+                    g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+                        _mm(x, wa, dtype).astype(f32) + dt_bias)
+                    beta = top * jax.nn.sigmoid(_mm(x, wb, dtype).astype(f32))
+                    gate = jax.nn.silu(_mm(x, wg, dtype).astype(f32))
+                with jax.named_scope('gdn.scan'):
+                    o = delta_rule(q.reshape(B, T, H, K),
+                                   k.reshape(B, T, H, K),
+                                   v.reshape(B, T, H, V), g, beta, seg,
+                                   K ** -0.5, chunk=min(chunk, T),
+                                   sub=min(16, chunk, T), dtype=dtype)
+                with jax.named_scope('gdn.proj'):
+                    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                                          + eps) * norm
+                    return _mm(o.reshape(B, T, H * V) * gate, wo, dtype)
+
+            if x.shape[0] == 1:
+                return rows(x, seg)
+            one = jax.checkpoint(lambda xs: rows(xs[0][None], xs[1][None])[0])
+            return jax.lax.map(one, (x, seg))
+
+        # (more rows than one are recomputed row by row inside `fn`: a
+        # checkpoint around that would run the forward a third time)
+        run, behind = post_normed(fn, post_norm,
+                                  recompute and x.shape[0] == 1)
+        return apply_op(run, (x,) + behind + (
+            segment_ids, self.q_proj, self.k_proj, self.v_proj, self.q_conv,
+            self.k_conv, self.v_conv, self.a_proj, self.b_proj, self.A_log,
+            self.dt_bias, self.g_proj, self.o_norm, self.o_proj))
+
+
+class CausalSelfAttention(Layer):
+    """Multi-head causal attention as the OLMo 2/3 block has it: q =
+    RMSNorm(x W_q), k = RMSNorm(x W_k), each norm over the WHOLE projection
+    (every head's channels together), v = x W_v, no bias, no rotation;
+    softmax(q k^T / sqrt(head_dim)) inside documents (`attention_blhd`: the
+    flash kernels with `doc_start` on the TPU); W_o.
+
+    `heads_held = (first, count)`: the layer holds those heads' columns of
+    W_q, W_k, W_v and of the two norms' scales and their rows of W_o, and
+    returns their addend of the layer's result. The one number a share
+    lacks is the two norms' mean square over the heads it does not hold:
+    `forward` takes it as `qk_mean_square` (what the chips of a group would
+    add up; `qk_mean_square(x)` gives a share's own) and norms by the held
+    heads' where none is given (docs/HEAD_SHARE.md)."""
+
+    def __init__(self, hidden_size, num_heads, head_dim, heads_held=None,
+                 epsilon=1e-6, initializer_range=0.02):
+        super().__init__()
+        self.num_heads, self.heads_held = num_heads, heads_held
+        self.heads = H = _held(num_heads, heads_held)
+        self.head_dim, self.epsilon = head_dim, epsilon
+
+        def weight(*shape):
+            return self.create_parameter(list(shape), attr=ParamAttr(
+                initializer=Normal(0., initializer_range)))
+        self.q_proj, self.k_proj, self.v_proj = (
+            weight(hidden_size, H * head_dim) for _ in range(3))
+        self.q_norm, self.k_norm = (self.create_parameter(
+            [H * head_dim], default_initializer=Constant(1.0))
+            for _ in range(2))
+        self.o_proj = weight(H * head_dim, hidden_size)
+
+    def qk_mean_square(self, x):
+        """-> the mean squares (B, T, 1), float32, of this share's query and
+        key projections: what a group's chips average for the two norms."""
+        dtype = compute_dtype()
+
+        def fn(x, w):
+            return jnp.mean(jnp.square(_mm(x, w, dtype).astype(jnp.float32)),
+                            -1, keepdims=True)
+        return tuple(apply_op(fn, (x, w)) for w in (self.q_proj, self.k_proj))
+
+    def forward(self, x, segment_ids, post_norm=None, recompute=False,
+                qk_mean_square=None):
+        H, D, eps = self.heads, self.head_dim, self.epsilon
+        dtype = compute_dtype()
+
+        def fn(x, seg, wq, wk, wv, nq, nk, wo, ms_q=None, ms_k=None):
+            B, T, _ = x.shape
+            with jax.named_scope('attn.full'):
+                q = rms_norm_values(_mm(x, wq, dtype), nq, eps, ms_q)
+                k = rms_norm_values(_mm(x, wk, dtype), nk, eps, ms_k)
+                if dtype is not None:
+                    q, k = q.astype(dtype), k.astype(dtype)
+                q, k, v = (t.reshape(B, T, H, D)
+                           for t in (q, k, _mm(x, wv, dtype)))
+                o = attention_blhd(q, k, v, causal=True,
+                                   doc_start=doc_starts(seg))
+                return _mm(o.reshape(B, T, H * D), wo, dtype)
+
+        run, behind = post_normed(fn, post_norm, recompute)
+        return apply_op(run, (x,) + behind + (
+            segment_ids, self.q_proj, self.k_proj, self.v_proj, self.q_norm,
+            self.k_norm, self.o_proj) + tuple(qk_mean_square or ()))

@@ -8,6 +8,7 @@ from .gpt import GPTConfig, GPTModel, gpt_small
 from .kimi_linear import (KimiLinearConfig, KimiLinearBlock,
                           KimiLinearForCausalLM)
 from .joyai_flash import JoyAIFlashConfig, JoyAIFlashForCausalLM
+from .olmo_hybrid import OlmoHybridConfig, OlmoHybridForCausalLM
 from .seq2seq import Seq2SeqTransformer
 from .word2vec import SkipGram, Word2Vec
 from .lm import LSTMLanguageModel

@@ -1,7 +1,8 @@
-"""What the sparse decoders (`kimi_linear`, `joyai_flash`) share: the pre-norm
-residual block with a dense or a routed feed-forward, the next-token loss of a
-packed row taken a row at a time, and the step's counters (the expert layers'
-and the flash kernels' tile pairs) as one vector. The attention layer is the
+"""What the decoders (`kimi_linear`, `joyai_flash`, `olmo_hybrid`) share: the
+pre-norm residual block with a dense or a routed feed-forward, the OLMo 2/3
+block that norms BEHIND each sublayer, the next-token loss of a packed row
+taken a row at a time, and the step's counters (the expert layers' and the
+flash kernels' tile pairs) as one vector. The attention layer is the
 caller's choice.
 """
 import jax
@@ -10,8 +11,9 @@ import jax.numpy as jnp
 from .. import nn
 from ..core.tensor import Tensor, apply_op
 from ..kernels.flash_attention import doc_tile_counts
-from ..nn.functional.moe import COUNTERS
-from ..nn.layer.linear_attention import compute_dtype, doc_starts
+from ..nn.functional.moe import COUNTERS, swiglu
+from ..nn.layer.linear_attention import (compute_dtype, doc_starts,
+                                         post_normed)
 from ..observability import costs as _costs
 
 # the shared layers' named scopes, the kernels' own and the engine's optimizer
@@ -22,8 +24,8 @@ _costs.register_scopes('mla.attention', 'moe.route', 'moe.experts',
                        'moe.shared', 'lm_head', 'fused_rms_norm.pallas',
                        'update')
 
-__all__ = ['SparseDecoderBlock', 'packed_head_loss', 'merge_counters',
-           'STEP_COUNTER_NAMES', 'STEP_COUNTER_SUMS']
+__all__ = ['SparseDecoderBlock', 'PostNormDecoderBlock', 'packed_head_loss',
+           'merge_counters', 'STEP_COUNTER_NAMES', 'STEP_COUNTER_SUMS']
 
 # what a sparse decoder's `forward` returns beside its loss, as
 # `engine.TrainStep` records it (the net's `step_counter_names`), and the
@@ -80,6 +82,44 @@ class SparseDecoderBlock(nn.Layer):
             y = self.mlp(x, self.post_attention_norm, again)
             counters = Tensor(jnp.zeros((len(COUNTERS),), jnp.float32))
         return x + y.astype('float32'), counters
+
+
+class PostNormDecoderBlock(nn.Layer):
+    """`h = x + RMSNorm(mixer(x)); out = h + RMSNorm(SwiGLU(h))`: the OLMo
+    2/3 block. `mixer(x, segment_ids, post_norm, recompute)` is
+    `nn.GatedDeltaNet` or `nn.CausalSelfAttention`, which may hold a share of
+    its heads (what it returns, and so what is normed and added, is then
+    that share's addend: docs/HEAD_SHARE.md); the SwiGLU is whole, under the
+    scope `ffn.dense`. `config` names `hidden_size`, `intermediate_size`,
+    `rms_norm_eps`, `initializer_range` and `recompute`: each half, with its
+    norm, is re-run in the backward pass, so the block keeps its two
+    inputs."""
+
+    def __init__(self, config, mixer):
+        super().__init__()
+        c = config
+        self.mixer = mixer
+        self.post_attention_norm = nn.RMSNorm(c.hidden_size,
+                                              epsilon=c.rms_norm_eps)
+        self.post_feedforward_norm = nn.RMSNorm(c.hidden_size,
+                                                epsilon=c.rms_norm_eps)
+        self.mlp = nn.SwiGLU(c.hidden_size, c.intermediate_size,
+                             c.initializer_range)
+        self.recompute = c.recompute
+
+    def forward(self, x, segment_ids):
+        x = x + self.mixer(x, segment_ids, self.post_attention_norm,
+                           self.recompute).astype('float32')
+        dtype = compute_dtype()
+
+        def ffn(x, gate, up, down):
+            with jax.named_scope('ffn.dense'):
+                return swiglu(x, gate, up, down, dtype)
+        run, behind = post_normed(ffn, self.post_feedforward_norm,
+                                  self.recompute)
+        y = apply_op(run, (x,) + behind + (
+            self.mlp.gate_proj, self.mlp.up_proj, self.mlp.down_proj))
+        return x + y.astype('float32')
 
 
 def packed_head_loss(x, labels, head):

@@ -552,3 +552,68 @@ def test_rotary_decoder_step_holds_its_scopes(topo, monkeypatch):
             if {'mtp', 'mla.rope'} <= set(scopes)]
     assert both and all('mla.attention' in under[n] for n in both)
     assert any({'mtp', 'lm_head'} <= set(scopes) for scopes in under.values())
+
+
+def test_head_share_step_holds_its_kernels_and_its_layers_scopes(
+        topo, monkeypatch):
+    """Olmo-Hybrid at a small depth and width with the REAL head sizes (keys
+    of 96, values of 192, attention heads of 128; 3 of 6 heads held; one
+    row of 1024 so that attention takes the flash kernels) through
+    `engine.build_train_step` under bf16 autocast with per-half
+    recomputation, compiled for one described chip: heads off the lane grid
+    take the delta-rule and short-convolution KERNELS (laid on 128 / 256
+    lanes), the q/k norms and the norms behind the sublayers the RMS-norm
+    kernel, no site its XLA form, and the four layer scopes the new cell's
+    readers read name instructions of the compiled module."""
+    import paddle_tpu as paddle
+    from paddle_tpu import amp, engine, optimizer
+    from paddle_tpu.nn.layer_base import buffer_values, param_values
+    from paddle_tpu.observability import costs
+    from paddle_tpu.text.olmo_hybrid import (OlmoHybridConfig,
+                                             OlmoHybridForCausalLM)
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    paddle.seed(0)
+    net = OlmoHybridForCausalLM(OlmoHybridConfig(
+        vocab_size=1024, hidden_size=256, num_hidden_layers=4,
+        num_attention_heads=6, linear_num_heads=6, intermediate_size=512,
+        heads_held=(3, 3), recompute=True))
+    net.train()
+    step = engine.build_train_step(
+        net=net, loss=net.training_loss,
+        optimizer=optimizer.AdamW(learning_rate=1e-4, weight_decay=0.1))
+    one = SingleDeviceSharding(topo.devices[0])
+    state = jax.tree_util.tree_map(
+        lambda v: jax.ShapeDtypeStruct(np.shape(v), v.dtype, sharding=one),
+        step.init_state(param_values(net), buffer_values(net)))
+    feed = tuple(jax.ShapeDtypeStruct((1, 1024), jnp.int32, sharding=one)
+                 for _ in range(3))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
+    with amp.auto_cast(dtype='bfloat16'):
+        text = step._jit.lower(state, (feed, ()), key).compile().as_text()
+    calls = _CUSTOM_CALL.findall(text)
+    for kernel in ('delta_rule', 'short_conv', 'flash_attention',
+                   'fused_rms_norm'):
+        assert any(c.startswith(kernel + '.pallas') for c in calls), kernel
+        assert kernel + '.xla' not in text, kernel
+    under = costs.instruction_scopes(text)
+    found = {scope for scopes in under.values() for scope in scopes}
+    assert found >= {'gdn.scan', 'gdn.proj', 'attn.full', 'ffn.dense',
+                     'lm_head', 'fused_rms_norm.pallas', 'delta_rule.pallas',
+                     'short_conv.pallas', 'flash_attention.pallas', 'update'}
+    # three linear layers of one row: the forward kernel, the forward again
+    # in the recomputation (it saves the chunks' start states) and the
+    # backward kernel; q, k and v as many times each
+    delta = [c for c in calls if c.startswith('delta_rule.pallas')]
+    assert len(delta) == 9, delta
+    assert all('gdn.scan' in under[c] for c in delta)
+    short = [c for c in calls if c.startswith('short_conv.pallas')]
+    assert len(short) == 27, short
+    assert all('gdn.proj' in under[c] for c in short)
+    flash = [c for c in calls if c.startswith('flash_attention.pallas')]
+    assert len(flash) == 3, flash
+    assert all('attn.full' in under[c] for c in flash)
+    # the q and k norms of the one full layer lie under its scope; the
+    # eight norms behind the sublayers and the final one do not
+    norms = [c for c in calls if c.startswith('fused_rms_norm.pallas')]
+    assert sum('attn.full' in under[c] for c in norms) >= 4
+    assert len(norms) >= 4 + 2 * 8 + 1
