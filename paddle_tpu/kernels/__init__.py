@@ -10,7 +10,11 @@ Implemented here (each with interpret-mode CPU tests):
   (kernels/delta_rule.py);
 - the short convolution in front of it: causal depthwise taps inside
   documents, SiLU and the per-head l2norm in one pass, forward and backward
-  (kernels/short_conv.py).
+  (kernels/short_conv.py);
+- the routed experts' grouped matrix product over a row buffer in tiles, one
+  expert a tile: a forward kernel (also the input gradient's, on the
+  transposed matrix) and the weight gradient's kernel, both skipping the
+  tiles that hold no rows (kernels/grouped_matmul.py).
 
 These replace the reference's hand-written CUDA/cuDNN kernels
 (paddle/fluid/operators/fused/*attention*, layer_norm_op.cu) with TPU-native

@@ -52,6 +52,13 @@ def kernel_mesh(mesh, batch_axes, head_axes=()):
         _tls.mesh = prev
 
 
+def sharded_step():
+    """Whether this trace lies inside `kernel_mesh`: a kernel site whose
+    rows belong to no one device (it has nothing `spmd_kernel` could split)
+    takes its XLA form there."""
+    return getattr(_tls, 'mesh', None) is not None
+
+
 def spmd_kernel(impl, in_dims, out_dims, roles, granule=1, scope=None):
     """Make one ``pallas_call`` site partitionable: under ``kernel_mesh``
     the site becomes a ``shard_map`` in which each device runs the kernel

@@ -11,24 +11,29 @@ each token, the part of the sum that runs over the experts it holds:
 
 and nothing that stands in for the other chips. Summed over the shares that
 tile [0, E) the parts give the whole layer (`tests/test_kimi_linear.py` holds
-that). No token is dropped whatever the imbalance: the assignments are sorted
-by expert and laid out in blocks of `block` rows, each block one expert's
-(grouped matrix products: one batched product over the blocks); where the
-blocks a batch needs pass the static `blocks`, the step takes the dense form
-instead (every held expert over every token, masked), which is exact at any
-imbalance and G times the work.
+that). No token is dropped whatever the imbalance, and the work follows the
+rows held: the held assignments are sorted by expert into one row buffer,
+each expert's rows padded to whole tiles of `row_tile` rows, and ONE grouped
+product a weight stack (`kernels.grouped_matmul`) runs over the tiles that
+hold rows. The buffer is static, at one of two sizes (`buffer_tiles`: twice
+and four times an even router's share, the smaller where the step's rows fit
+it); rows past the larger are the next round of the same product, in a loop
+that runs once unless the routing sends this chip more than that.
 """
-import math
+import collections
+import functools
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ['route_sigmoid_topk', 'swiglu', 'expert_share', 'share_blocks',
-           'COUNTERS']
+from ...kernels.grouped_matmul import grouped_matmul
+
+__all__ = ['route_sigmoid_topk', 'swiglu', 'expert_share', 'row_tile',
+           'buffer_tiles', 'COUNTERS']
 
 # what `expert_share` counts, in this order
 COUNTERS = ('assignments_held', 'assignments', 'expert_rows_max',
-            'expert_rows_mean', 'dropped')
+            'expert_rows_mean', 'dropped', 'rows_computed', 'rounds')
 
 
 def route_sigmoid_topk(x, w_router, correction_bias, top_k, scaling):
@@ -53,82 +58,179 @@ def swiglu(x, gate, up, down, dtype=None):
     return jnp.matmul(h, down)
 
 
-def share_blocks(tokens, top_k, held, experts, block, capacity_factor):
-    """The static number of row blocks of the grouped form: room for
-    `capacity_factor` times the rows an even router sends, and one partly
-    filled block for each held expert."""
-    expected = tokens * top_k * held / experts
-    return int(math.ceil(capacity_factor * expected / block)) + held
+def row_tile(tokens, top_k, experts):
+    """Rows of a tile of the row buffer: half the rows an even router sends
+    one expert, as a power of two from 8 to 256. An expert's last tile is
+    then half empty on average: a tenth to a quarter more rows than held,
+    whatever the shapes; 256 rows fill the MXU's passes."""
+    even = max(tokens * top_k // experts, 1)
+    return min(256, max(8, 1 << max(even.bit_length() - 2, 0)))
+
+
+def buffer_tiles(tokens, top_k, held, experts, tile):
+    """(small, large): tiles of the two static sizes of the row buffer,
+    twice and four times the rows an even router sends the `held` experts
+    (the rows and every expert's partly filled last tile); neither more
+    than the worst routing fills: all tokens, each to as many held experts
+    as it can pick. A step takes the smaller one that holds its padded rows
+    in one round."""
+    even = tokens * top_k * held / experts
+    worst = -(-tokens * min(top_k, held) // tile) + held
+    return tuple(min(-int(-room * even // tile), worst) for room in (2, 4))
+
+
+# what a round of the routed product is built from, beside its operands
+_Layout = collections.namedtuple(
+    '_Layout', 'top_k tile sizes dtype interpret')
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _round(layout, tiles, r, order, counts, x, weights, gate, up, down):
+    """Round r of the routed product in a buffer of `tiles` tiles: tiles
+    [r tiles, (r + 1) tiles) of the sorted, padded rows -> (their part of y
+    (T, H) float32, the held assignments among them, float32). `order`: the
+    assignments sorted by local expert, held first; `counts` (G,): rows of
+    each held expert. A jit of its own: a step's expert layers make the
+    same call at the same shapes (every layer, forward and in the backward
+    rule), and it is traced once for all of them."""
+    k, tile = layout.top_k, layout.tile
+    T, G = x.shape[0], counts.shape[0]
+    tiles_of = -(-counts // tile)
+    ends = jnp.cumsum(tiles_of)
+    starts = jnp.cumsum(counts) - counts
+    t = r * tiles + jnp.arange(tiles, dtype=jnp.int32)
+    active = jnp.clip(ends[-1] - r * tiles, 0, tiles)
+    of = jnp.clip(jnp.searchsorted(ends, t, side='right'), 0, G - 1)
+    rank = ((t - (ends - tiles_of)[of]) * tile)[:, None] \
+        + jnp.arange(tile, dtype=jnp.int32)[None, :]
+    valid = ((rank < counts[of][:, None])
+             & (t < ends[-1])[:, None]).reshape(-1)
+    a = order[jnp.clip(starts[of][:, None] + rank, 0, T * k - 1)] \
+        .reshape(-1)
+    tok = a // k
+    w = jnp.where(valid, weights.reshape(-1)[a], 0.0)
+    if layout.dtype is not None:
+        x = x.astype(layout.dtype)
+    rows = jnp.where(valid[:, None], x[tok], 0)
+    product = functools.partial(
+        grouped_matmul, tile_group=of, active=active.reshape(1),
+        interpret=layout.interpret)
+    h = jax.nn.silu(product(rows, gate)) * product(rows, up)
+    out = product(h, down, out_dtype=jnp.float32)
+    y = jnp.zeros((T, x.shape[1]), jnp.float32).at[tok].add(
+        out * w[:, None])
+    return y, jnp.sum(valid, dtype=jnp.float32)
+
+
+def _tiles_needed(layout, counts):
+    return jnp.sum(-(-counts // layout.tile))
+
+
+def _with_room(layout, counts, rounds):
+    """`rounds(tiles, trips)` in the smaller buffer where the padded rows
+    fit it (one round, by that very test), in the larger one for
+    every heavier load (round 0 and, while rows are left, the next ones):
+    the same product at two static sizes, chosen by what the step's routing
+    needs (the XLA ops around the kernels run over the whole buffer, so room
+    costs time)."""
+    small, large = layout.sizes
+    needed = _tiles_needed(layout, counts)
+
+    def all_of(tiles):
+        return rounds(tiles, -(-needed // tiles))
+    if small == large:
+        return all_of(small)
+    return jax.lax.cond(needed <= small, lambda: rounds(small, 1),
+                        lambda: all_of(large))
+
+
+def _rounds(layout, counts):
+    """Rounds of the buffer the step takes: 1 unless the padded rows pass
+    the larger one too."""
+    small, large = layout.sizes
+    needed = _tiles_needed(layout, counts)
+    return jnp.maximum(
+        -(-needed // jnp.where(needed <= small, small, large)), 1)
+
+
+def _sum_of_rounds(one, trips):
+    """one(0) + ... + one(trips - 1), at least one(0); `trips` a value (a
+    loop) or the number 1 (no loop is traced). Round 0's number is an array
+    as the loop's are, so that both are one call of `_round`'s jit."""
+    first = one(jnp.int32(0))
+    if isinstance(trips, int):
+        return first
+    return jax.lax.fori_loop(
+        1, trips, lambda r, total: jax.tree.map(jnp.add, total, one(r)),
+        first)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed(layout, order, counts, x, weights, gate, up, down):
+    """Every round's part of y, summed: round 0 always, the next ones while
+    rows are left -> (y, the held assignments computed). jax cannot
+    differentiate a loop whose trip count is a value, hence the rule below:
+    it keeps the operands and nothing else, and the backward runs the same
+    rounds, each by the vjp of `_round` (which computes the round again: a
+    block that recomputes, as the cells' do, drops this function's forward
+    from its second pass, so the count of products is the same)."""
+    return _with_room(layout, counts, lambda tiles, trips: _sum_of_rounds(
+        lambda r: _round(layout, tiles, r, order, counts, x, weights, gate,
+                         up, down), trips))
+
+
+def _routed_fwd(layout, order, counts, *operands):
+    return (_routed(layout, order, counts, *operands),
+            (order, counts, operands))
+
+
+def _routed_bwd(layout, res, cotangent):
+    order, counts, operands = res
+
+    def back(tiles, r):
+        return jax.vjp(functools.partial(_round, layout, tiles, r, order,
+                                         counts), *operands)[1](cotangent)
+    return (None, None) + tuple(_with_room(
+        layout, counts, lambda tiles, trips: _sum_of_rounds(
+            functools.partial(back, tiles), trips)))
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 def expert_share(x, idx, weights, gate, up, down, held, experts, *,
-                 block=512, capacity_factor=4.0, dtype=None):
+                 tile=None, dtype=None, interpret=False):
     """This chip's part of the routed sum.
 
     x (T, H); idx, weights (T, k) from the router (global expert numbers);
     gate, up (G, H, F), down (G, F, H): the G = hi - lo experts held;
     held = (lo, hi) of the `experts` the router scores.
     -> (y (T, H) float32, counters (len(COUNTERS),) float32).
-    `capacity_factor` is the tests' (a small one forces the dense form); the
-    layer has no option for it.
+    `tile`: rows of a tile of the row buffer (None: `row_tile`); the buffer
+    is one of `buffer_tiles`' two sizes. `dtype`: the products' operand type
+    (None: as they come); `interpret`: the kernels' interpret mode, for
+    tests.
     """
-    T, H = x.shape
-    k = idx.shape[1]
+    T, k = idx.shape
     lo, hi = held
     G = hi - lo
     if gate.shape[0] != G:
         raise ValueError('expert_share: holds %d experts, was told %r'
                          % (gate.shape[0], held))
+    tile = tile or row_tile(T, k, experts)
+    layout = _Layout(k, tile, buffer_tiles(T, k, G, experts, tile), dtype,
+                     interpret)
     is_held = (idx >= lo) & (idx < hi)
     local = jnp.where(is_held, idx - lo, G).reshape(-1)        # (T k,)
     counts = jnp.sum(local[:, None] == jnp.arange(G)[None, :], axis=0,
                      dtype=jnp.int32)                          # (G,)
-    n_held = jnp.sum(counts)
-    block = min(block, T)
-    # never more blocks than the worst routing fills: all tokens, each to
-    # as many held experts as it can pick
-    nb = min(share_blocks(T, k, G, experts, block, capacity_factor),
-             -(-T * min(k, G) // block) + G)
-    padded = -(-counts // block) * block
-    ends = jnp.cumsum(padded)
-    needed = ends[-1] // block
-
-    def grouped():
-        order = jnp.argsort(local, stable=True)       # held first, by expert
-        starts = jnp.cumsum(counts) - counts
-        first = jnp.arange(nb, dtype=jnp.int32) * block
-        of = jnp.clip(jnp.searchsorted(ends, first, side='right'), 0, G - 1)
-        rank = first[:, None] + jnp.arange(block)[None, :] \
-            - (ends - padded)[of][:, None]
-        valid = (rank < counts[of][:, None]) \
-            & (jnp.arange(nb)[:, None] < needed)
-        a = order[jnp.clip(starts[of][:, None] + rank, 0, T * k - 1)]
-        tok = a // k                                           # (nb, block)
-        w = jnp.where(valid, weights.reshape(-1)[a], 0.0)
-        cast = (lambda t: t) if dtype is None else (lambda t: t.astype(dtype))
-        rows = jnp.where(valid[..., None], cast(x)[tok], 0)
-        h = jax.nn.silu(jnp.einsum('bmh,bhf->bmf', rows, cast(gate)[of])) \
-            * jnp.einsum('bmh,bhf->bmf', rows, cast(up)[of])
-        out = jnp.einsum('bmf,bfh->bmh', h, cast(down)[of],
-                         preferred_element_type=jnp.float32)
-        y = jnp.zeros((T, H), jnp.float32).at[tok.reshape(-1)].add(
-            (out * w[..., None]).reshape(-1, H))
-        return y, jnp.sum(valid, dtype=jnp.int32)
-
-    def dense():
-        y = jnp.zeros((T, H), jnp.float32)
-        one = jax.checkpoint(
-            lambda x, g, u, d, m: m[:, None] * swiglu(x, g, u, d, dtype)
-            .astype(jnp.float32))
-        for e in range(G):
-            m = jnp.sum(jnp.where(idx == lo + e, weights, 0.0), axis=1)
-            y = y + one(x, gate[e], up[e], down[e], m)
-        return y, n_held
-
-    y, computed = jax.lax.cond(needed <= nb, grouped, dense)
+    order = jnp.argsort(local, stable=True)           # held first, by expert
+    y, computed = _routed(layout, order, counts, x, weights, gate, up, down)
     f32 = jnp.float32
+    n_held = jnp.sum(counts).astype(f32)
     counters = jnp.stack([
-        n_held.astype(f32), jnp.asarray(T * k, f32),
-        jnp.max(counts).astype(f32), n_held.astype(f32) / G,
-        (n_held - computed).astype(f32)])
+        n_held, jnp.asarray(T * k, f32), jnp.max(counts).astype(f32),
+        n_held / G, n_held - computed,
+        (_tiles_needed(layout, counts) * tile).astype(f32),
+        _rounds(layout, counts).astype(f32)])
     return y, counters

@@ -49,12 +49,14 @@ class SparseMoE(Layer):
     buffer), weights renormalised and scaled; the routed sum over the experts
     `experts_held = (lo, hi)` this chip holds, no token dropped; plus the
     shared expert(s), which every chip of the group would compute for its own
-    tokens. `forward` returns (y, counters): `functional.moe.COUNTERS`; a
-    list given as `selected` is handed each token's picks, sorted."""
+    tokens. `block`: rows of a tile of the routed product's row buffer
+    (None: worked out from the tokens, `functional.moe.row_tile`). `forward`
+    returns (y, counters): `functional.moe.COUNTERS`; a list given as
+    `selected` is handed each token's picks, sorted."""
 
     def __init__(self, hidden_size, expert_size, num_experts, top_k,
                  experts_held=None, shared_size=None, scaling=1.0,
-                 block=512, initializer_range=0.02):
+                 block=None, initializer_range=0.02):
         super().__init__()
         lo, hi = experts_held or (0, num_experts)
         if not 0 <= lo < hi <= num_experts:
@@ -91,7 +93,7 @@ class SparseMoE(Layer):
             with jax.named_scope('moe.experts'):
                 y, counters = F_moe.expert_share(
                     x, idx, weights, gate, up, down, held, experts,
-                    block=block, dtype=dtype)
+                    tile=block, dtype=dtype)
             return (y.reshape(shape), counters,
                     jnp.sort(idx, axis=-1).reshape(shape[:-1] + (top_k,)))
 

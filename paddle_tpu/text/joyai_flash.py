@@ -65,7 +65,7 @@ class JoyAIFlashConfig:
                  v_head_dim=128, rope_theta=32e6, num_nextn_predict_layers=1,
                  mtp_loss_weight=0.3, rms_norm_eps=1e-6,
                  initializer_range=0.02, experts_held=None, recompute=False,
-                 moe_block=512):
+                 moe_block=None):
         if num_nextn_predict_layers != 1:
             raise ValueError('the model trains ONE prediction module, not %r'
                              % num_nextn_predict_layers)

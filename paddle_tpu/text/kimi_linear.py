@@ -41,7 +41,7 @@ class KimiLinearConfig:
                  short_conv_kernel_size=4, gate_low_rank=None,
                  rms_norm_eps=1e-5, initializer_range=0.02,
                  experts_held=None, recompute=False, kda_chunk=64,
-                 moe_block=512):
+                 moe_block=None):
         if full_attn_layers is None:        # every fourth layer, 1-based
             full_attn_layers = [i for i in range(1, num_hidden_layers + 1)
                                 if i % 4 == 0]

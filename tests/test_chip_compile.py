@@ -28,6 +28,7 @@ from paddle_tpu.kernels import delta_rule as dr
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import fused_dropout_norm as fdn
 from paddle_tpu.kernels import fused_norm as fn
+from paddle_tpu.kernels import grouped_matmul as gm
 from paddle_tpu.kernels import short_conv as sc
 from paddle_tpu.kernels._common import kernel_mesh
 
@@ -193,6 +194,41 @@ def test_short_conv_compiles_fwd_bwd_at_the_cells_shape(one_chip,
 _X = ((ROWS, HIDDEN), jnp.bfloat16)
 _W = ((HIDDEN,), jnp.bfloat16)
 _SEED = ((1, 1), jnp.int32)
+
+
+@pytest.mark.parametrize('hidden,width,held', [(2048, 768, 16),
+                                               (2304, 1024, 8)],
+                         ids=['joyai-llm-flash', 'kimi-linear'])
+def test_grouped_matmul_compiles_fwd_bwd_at_the_cells_shapes(
+        one_chip, hidden, width, held):
+    """The routed experts' three products and their backward at the two
+    cells' shapes: 16384 tokens top 8 of 256, the larger of the two buffers
+    (four times the even share) in tiles of 256 rows, bf16 rows against
+    float32 weight stacks whose whole (K, N) matrix a group is one block in
+    VMEM."""
+    from paddle_tpu.nn.functional import moe
+    tile = moe.row_tile(16384, 8, 256)
+    tiles = moe.buffer_tiles(16384, 8, held, 256, tile)[1]
+
+    def loss(rows, gate, up, down, tile_group, active):
+        def product(lhs, rhs, out=None):
+            return gm.grouped_matmul(lhs, rhs, tile_group, active, out)
+        h = jax.nn.silu(product(rows, gate)) * product(rows, up)
+        return jnp.sum(product(h, down, jnp.float32) ** 2)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, 'default_backend', lambda: 'tpu')
+        text = _compile(
+            jax.grad(loss, argnums=(0, 1, 2, 3)), one_chip,
+            ((tiles * tile, hidden), jnp.bfloat16),
+            ((held, hidden, width), jnp.float32),
+            ((held, hidden, width), jnp.float32),
+            ((held, width, hidden), jnp.float32),
+            ((tiles,), jnp.int32), ((1,), jnp.int32))
+    calls = [c for c in _CUSTOM_CALL.findall(text)
+             if 'grouped_matmul.pallas' in c]
+    # three products forward, then d lhs and d rhs of each
+    assert len(calls) == 9, calls
+    assert 'ragged-dot' not in text
 
 
 def test_fused_layer_norm_compiles_fwd_bwd(one_chip):
@@ -429,6 +465,24 @@ def test_partitioned_step_names_its_kernels_and_its_gathers(topo,
     assert 'fsdp.reshard' in lowered and 'fsdp.gather' in lowered
 
 
+def _holds_one_routed_product(text, calls, under, phases):
+    """The expert layers of a compiled step: the grouped-product kernels,
+    forward and backward, every one under `moe.experts` (which so still
+    names its instructions); no second form (no XLA stand-in, and no plain
+    product left under the scope: the dense branch went with PR 38; the one
+    `conditional` picks the buffer's size), and the rounds past the buffer
+    as loops."""
+    grouped = [c for c in calls if c.startswith('grouped_matmul.pallas')]
+    assert grouped and all('moe.experts' in under[c] for c in grouped)
+    assert {phases[c] for c in grouped} == {'forward', 'backward'}
+    assert 'grouped_matmul.xla' not in text and 'ragged-dot' not in text
+    lines = {m.group(1): m.group(2) for m in re.finditer(
+        r'^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(', text, re.M)}
+    kinds = {lines.get(name) for name, scopes in under.items()
+             if 'moe.experts' in scopes}
+    assert 'while' in kinds and not kinds & {'dot', 'convolution'}, kinds
+
+
 def test_hybrid_step_holds_its_kernels_and_its_layers_scopes(topo,
                                                              monkeypatch):
     """Kimi-Linear at a small width (heads of the real sizes: 128 for the
@@ -474,7 +528,9 @@ def test_hybrid_step_holds_its_kernels_and_its_layers_scopes(topo,
     assert found >= {'kda.scan', 'kda.proj', 'mla.attention', 'moe.route',
                      'moe.experts', 'moe.shared', 'lm_head',
                      'fused_rms_norm.pallas', 'delta_rule.pallas',
-                     'short_conv.pallas', 'update'}
+                     'short_conv.pallas', 'grouped_matmul.pallas', 'update'}
+    _holds_one_routed_product(text, calls, under,
+                              costs.instruction_phases(text))
     assert all('fused_rms_norm.pallas' in under[c] for c in calls
                if c.startswith('fused_rms_norm.pallas'))
     # the delta rule's kernels: a KDA layer maps its rows, so each of the
@@ -542,6 +598,8 @@ def test_rotary_decoder_step_holds_its_scopes(topo, monkeypatch):
                      'moe.experts', 'moe.shared', 'lm_head',
                      'fused_rms_norm.pallas', 'update'}
     assert 'flash_attention.xla' not in text
+    _holds_one_routed_product(text, calls, under,
+                              costs.instruction_phases(text))
     # three blocks (two and the module's), each the forward kernel, the
     # forward again in the recomputation and the one backward kernel
     flash = [c for c in calls if c.startswith('flash_attention.pallas')]

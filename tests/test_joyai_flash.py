@@ -22,11 +22,13 @@ sys.path.insert(0, os.path.join(ROOT, 'benchmark', 'tests'))
 import _tiny  # noqa: E402  (puts benchmark/ on the path)
 from harness import check  # noqa: E402
 # how a run's set-up drives the program's first steps, and the reference
-from test_kimi_linear import program_readings, reference_readings  # noqa: E402
+from test_kimi_linear import (counted, program_readings,  # noqa: E402
+                              reference_readings)
 
 import paddle_tpu as paddle  # noqa: E402
 from paddle_tpu import nn  # noqa: E402
 from paddle_tpu.core.tensor import Tensor  # noqa: E402
+from paddle_tpu.nn.functional import moe  # noqa: E402
 from paddle_tpu.nn.layer.linear_attention import rotate_pairs  # noqa: E402
 from paddle_tpu.nn.layer_base import functional_call, param_values  # noqa: E402
 from paddle_tpu.text.joyai_flash import (JoyAIFlashConfig,  # noqa: E402
@@ -242,8 +244,11 @@ def test_embedding_and_head_get_the_sum_of_the_two_losses_gradients():
         loss, counters = loss_and_counters(net, w, batch)
         return jnp.stack([loss, counters[-2], counters[-1]]), counters
 
-    rows, counters = jax.jit(jax.jacrev(three, has_aux=True))(weights)
-    whole, main, mtp = ({k: v[i] for k, v in rows.items()} for i in range(3))
+    def a_row_each(w):      # (the grouped product has no batching rule)
+        _, back, counters = jax.vjp(three, w, has_aux=True)
+        return [back(jnp.eye(3)[i])[0] for i in range(3)], counters
+
+    (whole, main, mtp), counters = jax.jit(a_row_each)(weights)
     for leaf in whole:
         want = main[leaf] + lam * mtp[leaf]
         np.testing.assert_allclose(whole[leaf], want, atol=1e-6
@@ -271,7 +276,7 @@ def test_sixteen_shares_add_up_to_the_uncut_layer():
 
     def layer(held):
         return nn.SparseMoE(16, 8, 256, 8, experts_held=held, shared_size=8,
-                            scaling=2.5, block=8, initializer_range=0.3)
+                            scaling=2.5, initializer_range=0.3)
     whole = layer((0, 256))
     x = Tensor(jnp.asarray(rs.normal(size=(2, 24, 16)), jnp.float32))
     want, counters = whole(x)
@@ -292,6 +297,61 @@ def test_sixteen_shares_add_up_to_the_uncut_layer():
         assert float(c.numpy()[4]) == 0.0                  # dropped
     assert held_sum == 2 * 24 * 8
     np.testing.assert_allclose(total + shared, want.numpy(), atol=2e-5)
+
+
+def test_the_cells_row_buffers():
+    """What `expert_share` works out from the shapes of the two cells that
+    run it (16384 tokens a step, top 8 of 256): tiles of 256 rows, half an
+    expert's even share, and buffers of twice and four times the share
+    held."""
+    assert moe.row_tile(16384, 8, 256) == 256
+    assert moe.buffer_tiles(16384, 8, 16, 256, 256) == (64, 128)   # JoyAI
+    assert moe.buffer_tiles(16384, 8, 8, 256, 256) == (32, 64)     # Kimi
+    # never more than every token to every held expert it can pick
+    assert moe.buffer_tiles(64, 8, 2, 4, 8) == (64 * 2 // 8 + 2,) * 2
+
+
+@pytest.mark.parametrize('dtype', [None, jnp.bfloat16])
+def test_the_kernels_follow_the_xla_form_at_the_cells_cut(dtype):
+    """16 of 256 experts held, top 8, a seeded router: the grouped-product
+    kernels (interpret mode), in float32 and with bfloat16 operands, against
+    `ragged_dot` on the same layout in float32: output and the five
+    gradients; what the products ran over is the rows held plus less than a
+    tile an expert."""
+    rs = np.random.default_rng(5)
+    T, H, F, E, k, held = 256, 128, 128, 256, 8, (32, 48)
+    x = jnp.asarray(rs.normal(size=(T, H)), jnp.float32)
+    gate, up = (jnp.asarray(rs.normal(size=(16, H, F)) * H ** -0.5,
+                            jnp.float32) for _ in range(2))
+    down = jnp.asarray(rs.normal(size=(16, F, H)) * F ** -0.5, jnp.float32)
+    idx, w = moe.route_sigmoid_topk(
+        x, jnp.asarray(rs.normal(size=(H, E)) * 0.3, jnp.float32),
+        jnp.zeros((E,)), k, 2.5)
+    cot = jnp.asarray(rs.normal(size=(T, H)), jnp.float32)
+    tile = moe.row_tile(T, k, E) * (2 if dtype is not None else 1)
+
+    def run(dtype, interpret):
+        def loss(*a):
+            y, c = moe.expert_share(a[0], idx, *a[1:], held, E, tile=tile,
+                                    dtype=dtype, interpret=interpret)
+            return jnp.sum(y * cot), (y, c)
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(
+                x, w, gate, up, down)
+    (_, (y, c)), got = run(dtype, True)
+    (_, (want_y, want_c)), want = run(None, False)
+    for a, b in zip((y,) + got, (want_y,) + want):
+        gap = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+        assert gap < (1e-5 if dtype is None else 2e-2), gap
+    np.testing.assert_array_equal(c, want_c)
+    c = counted(c)
+    assert c['assignments_held'] > 0 and c['dropped'] == 0
+    # tiles of 16 rows (bfloat16's sublanes) for experts of ~10: the padded
+    # rows pass the smaller buffer and the step takes the larger one
+    small = tile * moe.buffer_tiles(T, k, 16, E, tile)[0]
+    assert (c['rows_computed'] <= small) == (dtype is None)
+    assert c['rounds'] == 1
+    assert c['rows_computed'] < c['assignments_held'] + 16 * tile
 
 
 # ---------------------------------------------- program against reference

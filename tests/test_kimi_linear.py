@@ -156,7 +156,7 @@ def test_attention_with_two_head_sizes_and_a_document_mask(path):
 
 def expert_layer(held, experts=16, top_k=4, hidden=16, width=8):
     return nn.SparseMoE(hidden, width, experts, top_k, experts_held=held,
-                        shared_size=width, scaling=2.446, block=8,
+                        shared_size=width, scaling=2.446,
                         initializer_range=0.3)
 
 
@@ -190,31 +190,126 @@ def test_the_shares_add_up_to_the_uncut_layer(per_share):
     np.testing.assert_allclose(total + shared, want.numpy(), atol=2e-5)
 
 
-@pytest.mark.parametrize('capacity_factor', [0.25, 4.0])
+def per_expert_loop(x, idx, w, gate, up, down, held):
+    """The routed sum as it is written: every held expert over every token,
+    masked, in float32."""
+    y = jnp.zeros(x.shape, jnp.float32)
+    for e in range(*held):
+        m = jnp.sum(jnp.where(idx == e, w, 0.0), axis=1)[:, None]
+        y = y + m * moe.swiglu(x, gate[e - held[0]], up[e - held[0]],
+                               down[e - held[0]])
+    return y
+
+
+def counted(c):
+    return dict(zip(moe.COUNTERS, np.asarray(c).tolist()))
+
+
+@pytest.mark.parametrize('held,buffer,rounds', [
+    ((1, 2), 'large', 2), ((0, 2), 'large', 1), ((0, 4), 'small', 1)])
 def test_no_token_is_dropped_when_every_token_goes_to_one_expert(
-        capacity_factor):
-    """A router that sends every token to the same held experts: far past
-    the grouped form's static blocks at the small factor, inside them at the
-    large one; either way every assignment is computed."""
+        held, buffer, rounds):
+    """A router that sends every token to the same held expert. The row
+    buffer is twice an even router's share, or four times where that is too
+    small: a chip that holds four experts of 16 takes the smaller one, one
+    that holds two the larger one, and for one that holds this expert alone
+    the rows are twice the larger buffer, so the same product runs a second
+    round. Either way every assignment is computed."""
     rs = np.random.default_rng(4)
-    T, H, F, E, k = 96, 16, 8, 16, 2
+    T, H, F, E, k, G = 96, 16, 8, 16, 2, held[1] - held[0]
     x = jnp.asarray(rs.normal(size=(T, H)), jnp.float32)
-    gate, up = (jnp.asarray(rs.normal(size=(4, H, F)) * 0.3, jnp.float32)
+    gate, up = (jnp.asarray(rs.normal(size=(G, H, F)) * 0.3, jnp.float32)
                 for _ in range(2))
-    down = jnp.asarray(rs.normal(size=(4, F, H)) * 0.3, jnp.float32)
+    down = jnp.asarray(rs.normal(size=(G, F, H)) * 0.3, jnp.float32)
     bias = jnp.zeros((E,)).at[jnp.asarray([1, 9])].set(10.0)
     idx, w = moe.route_sigmoid_topk(x, jnp.zeros((H, E)), bias, k, 2.446)
     assert set(np.unique(np.asarray(idx))) == {1, 9}
-    y, c = jax.jit(lambda *a: moe.expert_share(
-        *a, (0, 4), E, block=8, capacity_factor=capacity_factor))(
-            x, idx, w, gate, up, down)
+    tile = moe.row_tile(T, k, E)
+    small, large = (n * tile for n in moe.buffer_tiles(T, k, G, E, tile))
+    assert (T <= small, T <= large) == (buffer == 'small', rounds == 1)
+    y, c = jax.jit(lambda *a: moe.expert_share(*a, held, E))(
+        x, idx, w, gate, up, down)
+    at = 1 - held[0]
     want = jnp.sum(jnp.where(idx == 1, w, 0.0), 1)[:, None] \
-        * moe.swiglu(x, gate[1], up[1], down[1])
+        * moe.swiglu(x, gate[at], up[at], down[at])
     np.testing.assert_allclose(y, want, atol=1e-5)
-    names = dict(zip(moe.COUNTERS, np.asarray(c)))
-    assert names == {'assignments_held': T, 'assignments': T * k,
-                     'expert_rows_max': T, 'expert_rows_mean': T / 4,
-                     'dropped': 0.0}
+    assert counted(c) == {
+        'assignments_held': T, 'assignments': T * k, 'expert_rows_max': T,
+        'expert_rows_mean': T / G, 'dropped': 0.0, 'rows_computed': T,
+        'rounds': rounds}
+
+
+def _picks(T, k, experts, rows):
+    """idx (T, k): `rows[e]` tokens pick held expert e (the first rows[e]
+    tokens, so a token may pick several), every other pick goes to the
+    experts from 8 on, which nobody holds."""
+    idx = np.tile(np.arange(8, 8 + k), (T, 1))
+    for slot, (e, n) in enumerate(sorted(rows.items())):
+        idx[:n, slot] = e
+    assert idx.max() < experts and all(len(set(r)) == k for r in idx)
+    return jnp.asarray(idx, jnp.int32)
+
+
+# T 64, top 4 of 32, experts (0, 4) held: tiles of 8 rows, buffers of 8 and
+# of 16 tiles (64 and 128 rows)
+_ROUTINGS = {
+    'an expert with zero rows': ({0: 20, 1: 0, 2: 7, 3: 0}, 1),
+    'every row to the last held expert': ({3: 64}, 1),
+    'the rows exactly fill the smaller buffer': ({0: 32, 2: 32}, 1),
+    'one row more than the smaller buffer': ({0: 32, 1: 1, 2: 32}, 1),
+    'the rows exactly fill the larger buffer': ({0: 64, 2: 64}, 1),
+    'one row more than the larger buffer': ({0: 64, 1: 1, 2: 64}, 2),
+    'no row at all': ({}, 1),
+}
+
+
+@pytest.mark.parametrize('path', ['xla', 'pallas-interpret'])
+@pytest.mark.parametrize('routing', sorted(_ROUTINGS))
+def test_the_routed_product_equals_the_per_expert_loop(routing, path):
+    """Output, and the gradients of x, of the routing weights and of the
+    three weight stacks, against every held expert over every token in
+    float32; the rows computed stay within a tile an expert of the rows
+    held, none dropped, and a second round exactly when the padded rows
+    pass the larger buffer. The kernels run in interpret mode at widths of
+    whole lane registers."""
+    rows, rounds = _ROUTINGS[routing]
+    T, E, k, held = 64, 32, 4, (0, 4)
+    H, F = (128, 256) if path != 'xla' else (16, 8)
+    rs = np.random.default_rng(3)
+    x = jnp.asarray(rs.normal(size=(T, H)), jnp.float32)
+    gate, up = (jnp.asarray(rs.normal(size=(4, H, F)) * H ** -0.5,
+                            jnp.float32) for _ in range(2))
+    down = jnp.asarray(rs.normal(size=(4, F, H)) * F ** -0.5, jnp.float32)
+    idx = _picks(T, k, E, rows)
+    w = jnp.asarray(rs.uniform(0.2, 1.0, size=(T, k)), jnp.float32)
+    cot = jnp.asarray(rs.normal(size=(T, H)), jnp.float32)
+    tile = moe.row_tile(T, k, E)
+    assert (tile, moe.buffer_tiles(T, k, 4, E, tile)) == (8, (8, 16))
+
+    def program(*a):
+        y, c = moe.expert_share(a[0], idx, *a[1:], held, E,
+                                interpret=path != 'xla')
+        return jnp.sum(y * cot), (y, c)
+
+    def plain(*a):
+        y = per_expert_loop(a[0], idx, *a[1:], held)
+        return jnp.sum(y * cot), y
+    every = (0, 1, 2, 3, 4)
+    (_, (y, c)), got = jax.jit(jax.value_and_grad(
+        program, argnums=every, has_aux=True))(x, w, gate, up, down)
+    (_, want_y), want = jax.jit(jax.value_and_grad(
+        plain, argnums=every, has_aux=True))(x, w, gate, up, down)
+    np.testing.assert_allclose(y, want_y, atol=2e-5)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-4)
+    if any(rows.values()):
+        assert all(float(jnp.max(jnp.abs(g))) > 0 for g in got)
+    c, n_held = counted(c), sum(rows.values())
+    assert c['assignments_held'] == n_held and c['dropped'] == 0.0
+    assert c['rounds'] == rounds
+    assert n_held <= c['rows_computed'] <= n_held + 4 * tile
+    assert c['rows_computed'] == sum(-(-n // tile) * tile
+                                     for n in rows.values())
 
 
 # ---------------------------------------------- program against reference
