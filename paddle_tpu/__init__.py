@@ -4,6 +4,11 @@ Compute path: JAX/XLA (+ Pallas kernels); eager dygraph semantics with a
 vjp tape; whole-program XLA compilation for static graph & jitted train steps;
 SPMD parallelism over jax.sharding meshes.
 """
+import time as _time
+# graftlint: disable=GL011 — a stamp, not a timing: where the span record
+# `paddle_tpu.import` starts (observability.state.note_import, last line)
+_IMPORT_T0_NS = _time.perf_counter_ns()
+
 from .core.tensor import Tensor, Parameter, to_tensor
 from .core import autograd
 from .core.autograd import no_grad, enable_grad, grad, is_grad_enabled, set_grad_enabled
@@ -216,3 +221,6 @@ def monkey_patch_math_varbase():
 def monkey_patch_variable():
     """No-op: static Variable operators are installed at import
     (static/graph.py)."""
+
+
+observability.state.note_import(_IMPORT_T0_NS)

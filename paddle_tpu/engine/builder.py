@@ -321,16 +321,36 @@ def build_train_step(loss_fn=None, optimizer=None, *, net=None, loss=None,
     if sharding is not None and in_shardings is not None:
         raise ValueError("build_train_step: sharding= derives the step's "
                          "in_shardings itself — pass one or the other")
-    return TrainStep(loss_fn, optimizer, params_meta=params_meta,
-                     # an EMPTY set is a real filter (every param frozen:
-                     # update nothing) — only None means "no filter"
-                     trainable=(frozenset(trainable)
-                                if trainable is not None else None),
-                     scaler=scaler, nan_guard=bool(nan_guard), microbatch=k,
-                     donate=donate, remat=remat,
-                     matmul_precision=matmul_precision, with_key=with_key,
-                     in_shardings=in_shardings, sharding=sharding,
-                     counters=counters)
+    with _obs.span('engine.build'):
+        return TrainStep(
+            loss_fn, optimizer, params_meta=params_meta,
+            # an EMPTY set is a real filter (every param frozen: update
+            # nothing) — only None means "no filter"
+            trainable=(frozenset(trainable)
+                       if trainable is not None else None),
+            scaler=scaler, nan_guard=bool(nan_guard), microbatch=k,
+            donate=donate, remat=remat, matmul_precision=matmul_precision,
+            with_key=with_key, in_shardings=in_shardings, sharding=sharding,
+            counters=counters)
+
+
+def _tree_bytes(tree):
+    """Total bytes of a pytree's leaves, by shape and dtype."""
+    return sum(int(np.prod(np.shape(leaf) or (1,))) *
+               np.dtype(getattr(leaf, 'dtype', np.float32)).itemsize
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
+def _abstract(leaf):
+    """What ``jit.lower`` needs of an array the step has donated: its
+    shape, dtype and, where it is committed, its sharding, so that the
+    lowering asked for is the one the dispatch made (found in jit's own
+    cache, not lowered or loaded a second time)."""
+    if not isinstance(leaf, jax.Array):
+        return leaf
+    return jax.ShapeDtypeStruct(
+        leaf.shape, leaf.dtype, weak_type=leaf.weak_type,
+        sharding=leaf.sharding if leaf.committed else None)
 
 
 def kernel_mesh_of(sharding, in_shardings):
@@ -415,6 +435,17 @@ class TrainStep:
         the in-graph counters so a resumed run continues its skip/scale
         history exactly.
         """
+        with _obs.span('engine.init_state',
+                       sharded=self.sharding is not None) as span:
+            state = self._assemble_state(params, buffers, opt_state,
+                                         nan_guard, scaler)
+            if self.sharding is not None:
+                state = self._shard_state(state)
+            if _obs.enabled():
+                span.args['bytes'] = _tree_bytes(state)
+        return state
+
+    def _assemble_state(self, params, buffers, opt_state, nan_guard, scaler):
         state = {'params': dict(params), 'buffers': dict(buffers or {}),
                  'opt': opt_state if opt_state is not None
                  else self.optimizer.init_state_values(dict(params))}
@@ -440,8 +471,6 @@ class TrainStep:
                 'good': jnp.int32(s._good_steps),
                 'bad': jnp.int32(s._bad_steps),
             }
-        if self.sharding is not None:
-            state = self._shard_state(state)
         return state
 
     def _shard_state(self, state):
@@ -476,7 +505,8 @@ class TrainStep:
             if self.donates:
                 jit_kwargs['donate_argnums'] = (0,)
             self._jit = jax.jit(self._make_step(), **jit_kwargs)
-        state = cfg.device_put_state(state, self._state_shardings)
+        with _obs.span('engine.place_state'):
+            state = cfg.device_put_state(state, self._state_shardings)
         if first and _obs.enabled():
             _obs.gauge('sharding.param_bytes_per_device').set(
                 cfg.bytes_per_device(state['params']))
@@ -547,15 +577,8 @@ class TrainStep:
         state — what bench/tier-1 assert the memory win with."""
         cfg = self.sharding
         if cfg is None:
-            nbytes = sum(
-                int(np.prod(np.shape(leaf) or (1,))) *
-                np.dtype(getattr(leaf, 'dtype', np.float32)).itemsize
-                for leaf in jax.tree_util.tree_leaves(state))
-            return {'param_bytes_per_device': sum(
-                        int(np.prod(np.shape(v) or (1,))) *
-                        np.dtype(getattr(v, 'dtype', np.float32)).itemsize
-                        for v in state['params'].values()),
-                    'state_bytes_per_device': nbytes,
+            return {'param_bytes_per_device': _tree_bytes(state['params']),
+                    'state_bytes_per_device': _tree_bytes(state),
                     'mesh_devices': 1, 'collective_bytes_per_step_est': 0,
                     'sharded_params': 0}
         specs = cfg.param_specs(state['params'])
@@ -764,24 +787,31 @@ class TrainStep:
                 key = jax.device_put(key, self.sharding.replicated())
         args = (state, batch, key) if self._with_key else (state, batch)
         telemetry = _obs.enabled()
-        if telemetry and not self._cost_captured:
-            # cost explorer: AOT-ledger this program's FLOPs/bytes/peak
-            # memory and the phase of each of its instructions once, while
-            # the first dispatch is compiling anyway
-            self._cost_captured = True
-            _obs.costs.capture(
-                self.cost_label, self._jit, *args, kind='train_step',
-                phases=True,
-                meta={'microbatch': self.k, 'donates': self.donates,
-                      'sharded': self.sharding is not None})
+        capture = telemetry and not self._cost_captured
+        if capture:
+            # the dispatch donates the state: what the capture below needs
+            # of it is taken first
+            args_then = (jax.tree_util.tree_map(_abstract, state),) + args[1:]
         n = self._dispatches
         self._dispatches = n + 1
         # the ENQUEUE of step n, not the step: the jit call returns before
         # the device is done. In a profiler trace it is the per-step
-        # annotation `train_step` with step_num=n.
+        # annotation `train_step` with step_num=n. Dispatch 0 (`first`)
+        # also traces, lowers and compiles or loads the program: those are
+        # its child records (`jax.*`), with telemetry on or off alike.
         with _obs.timer('engine.dispatch', step=n, annotation='train_step',
-                        k=self.k):
+                        k=self.k, **({} if n else {'first': True})):
             new_state, losses, outs = self._jit(*args)
+        if capture:
+            # cost explorer: AOT-ledger this program's FLOPs/bytes/peak
+            # memory and the phase of each of its instructions once, from
+            # the executable the dispatch above has just made
+            self._cost_captured = True
+            _obs.costs.capture(
+                self.cost_label, self._jit, *args_then, kind='train_step',
+                phases=True,
+                meta={'microbatch': self.k, 'donates': self.donates,
+                      'sharded': self.sharding is not None})
         if telemetry:
             _obs.counter('engine.steps').inc(self.k)
             _obs.counter('engine.dispatches').inc()

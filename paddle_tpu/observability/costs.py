@@ -27,10 +27,14 @@ matmul with the optimizer's update: that is said, never split or guessed).
 A device-trace event is named by its instruction, so this map is what puts
 ``%fusion.808`` under a phase.
 
-Capture is an AOT ``fn.lower(*args).compile()`` — one extra backend
-compile per program, paid once while the program is being built/warmed
-anyway; repeat requests are ledger hits (``jax.compiles`` flatness gates
-stay flat after warmup). Everything is off until telemetry is enabled.
+Capture is an AOT ``fn.lower(*args).compile()`` under the span
+``costs.capture`` — one extra backend compile per program, paid once while
+the program is being built/warmed anyway (the engine's train step asks
+AFTER its first dispatch, with the arguments' shapes and shardings: jit
+then hands back the lowering and the executable that dispatch made, and
+nothing is lowered or compiled twice); repeat requests are ledger hits
+(``jax.compiles`` flatness gates stay flat after warmup). Everything is
+off until telemetry is enabled.
 
 Surfaces: ``cost.flops{program=}`` / ``cost.peak_bytes{program=}`` gauges,
 one ``cost.program`` event per capture (what ``tools/telemetry_dump.py
@@ -50,7 +54,7 @@ import os
 import re
 import threading
 
-from . import events, registry, state
+from . import events, registry, spans, state
 
 __all__ = ['capture', 'record_compiled', 'mark_hit', 'ledger', 'entry',
            'summary', 'reset', 'DEVICE_PEAKS', 'device_peaks', 'roofline',
@@ -298,14 +302,15 @@ def capture(program, fn, *args, kind='jit', meta=None, phases=False):
     if ent is not None:
         mark_hit(program)
         return ent
-    try:
-        compiled = fn.lower(*args).compile()
-    except Exception as e:
-        events.emit('cost.capture_error', program=str(program),
-                    error=repr(e))
-        return None
-    return record_compiled(program, compiled, kind=kind, meta=meta,
-                           phases=phases)
+    with spans.span('costs.capture', program=str(program)):
+        try:
+            compiled = fn.lower(*args).compile()
+        except Exception as e:
+            events.emit('cost.capture_error', program=str(program),
+                        error=repr(e))
+            return None
+        return record_compiled(program, compiled, kind=kind, meta=meta,
+                               phases=phases)
 
 
 def record_compiled(program, compiled, kind='jit', meta=None, phases=False):

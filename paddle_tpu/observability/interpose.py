@@ -2,13 +2,15 @@
 
 Two families of counters no library author has to remember to bump:
 
-- **retrace/compile**: ``install_jax_hooks()`` registers a
-  ``jax.monitoring`` duration listener; every jaxpr trace and every backend
+- **retrace/compile**: ``install_jax_hooks()`` registers two
+  ``jax.monitoring`` listeners; every jaxpr trace and every backend
   compile anywhere in the process (Executor programs, hapi jit steps, bench
   loops, user code) increments ``jax.traces`` / ``jax.compiles`` and
   accumulates ``jax.compile_ms``. A growing ``jax.traces`` count on a
   steady-state loop is the retrace-storm signal GL004–GL006 lint for
-  statically.
+  statically. Each phase of JAX's compile path is also one **span record**
+  (``jax.trace`` / ``jax.lower`` / ``jax.backend`` / ``jax.cache_load``)
+  under the program span that caused it: see ``_on_duration``.
 - **host transfers**: the narrow host-boundary waists (``Tensor.numpy()``,
   ``Executor.run``'s fetch) call ``record_host_transfer(nbytes)``; the
   ``host_transfer.bytes`` counter is the "how much crosses PCIe/ICI per
@@ -18,40 +20,97 @@ Collectives report through ``record_collective(op, nbytes)`` from the eager
 wrappers (inside a traced region the record happens once at trace time, so
 counts there reflect compilations, not executions).
 """
-from . import registry, state
+import threading
+import time
 
-__all__ = ['install_jax_hooks', 'record_host_transfer', 'record_collective',
-           'summary']
+from . import registry, spans, state
+
+__all__ = ['install_jax_hooks', 'remove_jax_hooks', 'record_host_transfer',
+           'record_collective', 'summary']
 
 _installed = [False]
 
+# JAX's duration events of the compile path -> the span record each becomes
+_PHASES = {
+    '/jax/core/compile/jaxpr_trace_duration': 'jax.trace',
+    '/jax/core/compile/jaxpr_to_mlir_module_duration': 'jax.lower',
+    '/jax/core/compile/backend_compile_duration': 'jax.backend',
+    '/jax/compilation_cache/cache_retrieval_time_sec': 'jax.cache_load',
+}
+# the persistent cache's events -> (what a backend record says, the counter)
+_CACHE_EVENTS = {
+    '/jax/compilation_cache/cache_hits': ('hit', 'jax.cache_hits'),
+    '/jax/compilation_cache/cache_misses': ('miss', 'jax.cache_misses'),
+}
+# what the persistent cache said inside the backend phase this thread is in:
+# JAX fires the cache's events before the phase's own, on the same thread
+_inside = threading.local()     # .cache: 'hit' | 'miss'; .load: (t0, t1)
+
 
 def install_jax_hooks():
-    """Register the jax.monitoring listener once. Safe to call repeatedly;
-    returns True when the hooks are (already) in place. The listener guards
-    on ``state.enabled()`` so a later ``disable()`` silences it without an
-    unregister API."""
+    """Register the jax.monitoring listeners once. Safe to call repeatedly;
+    returns True when the hooks are (already) in place."""
     if _installed[0]:
         return True
     try:
         import jax
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
     except Exception:
         return False
     _installed[0] = True
     return True
 
 
-def _on_duration(name, secs, **kwargs):
-    if not state.enabled():
+def remove_jax_hooks():
+    """Unregister the listeners: with telemetry off JAX calls nothing of
+    ours."""
+    if not _installed[0]:
         return
-    if name.endswith('jaxpr_trace_duration'):
+    import jax
+    jax.monitoring.unregister_event_duration_listener(_on_duration)
+    jax.monitoring.unregister_event_listener(_on_event)
+    _installed[0] = False
+
+
+def _on_event(name, **kwargs):
+    if name not in _CACHE_EVENTS or not state.enabled():
+        return
+    _inside.cache, counter = _CACHE_EVENTS[name]
+    registry.counter(counter).inc()
+
+
+def _on_duration(name, secs, **kwargs):
+    """One ``ph: 'X'`` record a phase. JAX fires the event at the END of the
+    phase, on the thread that did it: ``t1_ns`` is now, ``t0_ns`` that less
+    the duration, ``parent`` the program span open on this thread
+    (``engine.dispatch``, ``engine.init_state``, ``costs.capture``; None for
+    an eager op or a caller's own ``jit``). A trace made inside another
+    trace fires its own event and both records cover it: a reader takes the
+    UNION of a name's intervals, never the sum."""
+    phase = _PHASES.get(name)
+    if phase is None or not state.enabled():
+        return
+    t1 = time.perf_counter_ns()
+    t0 = t1 - int(secs * 1e9)
+    if phase == 'jax.cache_load':
+        _inside.load = (t0, t1)     # written under the backend record
+        return
+    args = {'fun_name': kwargs.get('fun_name')}
+    load = None
+    if phase == 'jax.trace':
         registry.counter('jax.traces').inc()
-        registry.histogram('jax.trace_ms').observe(secs * 1e3)
-    elif name.endswith('backend_compile_duration'):
+    elif phase == 'jax.backend':
         registry.counter('jax.compiles').inc()
         registry.counter('jax.compile_ms').inc(secs * 1e3)
-        registry.histogram('jax.compile_duration_ms').observe(secs * 1e3)
+        heard = vars(_inside)
+        load = heard.pop('load', None)
+        if 'cache' in heard:
+            args['cache'] = heard.pop('cache')
+    span_id = spans.record(phase, t0, t1, **args)
+    if load is not None:
+        spans.record('jax.cache_load', *load, parent=span_id,
+                     fun_name=args['fun_name'])
 
 
 def record_host_transfer(nbytes, kind='device_get'):

@@ -12,7 +12,9 @@ records to any window it timed itself. A record also carries ``span_id``,
 ``parent`` (the ``span_id`` of the span open around it on its thread, None
 at top level) and ``step`` (the dispatch number, where the site knows one).
 The ring keeps the NEWEST ``MAX_TRACE_EVENTS`` records: a long run loses
-its oldest spans, counted by ``dropped()``, never its latest.
+its oldest spans, counted by ``dropped()``, never its latest. ``record()``
+writes an interval that was timed elsewhere (JAX's compile-path phases, the
+package's import) as the same kind of record.
 
 Besides synchronous spans, the buffer carries **async (flow) events** —
 ``async_begin``/``async_instant``/``async_end`` record nestable Chrome
@@ -49,7 +51,7 @@ import time
 
 from . import state
 
-__all__ = ['span', 'Span', 'dump_chrome_trace', 'trace_events',
+__all__ = ['span', 'Span', 'record', 'dump_chrome_trace', 'trace_events',
            'async_begin', 'async_instant', 'async_end',
            'clear', 'dropped', 'MAX_TRACE_EVENTS']
 
@@ -78,6 +80,35 @@ def _append(ev):
         if len(_events) == _events.maxlen:
             _dropped[0] += 1        # the ring drops its oldest record
         _events.append(ev)
+
+
+def _complete(name, t0_ns, t1_ns, span_id, parent, step, args):
+    """Append one ``ph: 'X'`` record."""
+    ev = {'name': name, 'ph': 'X', 'ts': t0_ns / 1e3,
+          'dur': (t1_ns - t0_ns) / 1e3, 'pid': os.getpid(),
+          'tid': threading.get_ident(), 't0_ns': t0_ns, 't1_ns': t1_ns,
+          'span_id': span_id, 'parent': parent, 'step': step}
+    if args:
+        ev['args'] = args
+    _append(ev)
+
+
+_OPEN = object()        # record(parent=): the span open on this thread
+
+
+def record(name, t0_ns, t1_ns, parent=_OPEN, **args):
+    """Write an interval timed elsewhere (``perf_counter_ns`` stamps) as a
+    span record -> its ``span_id``, or None while telemetry is off.
+    ``parent`` defaults to the span open on the calling thread: what caused
+    the work."""
+    if not state.enabled():
+        return None
+    if parent is _OPEN:
+        stack = getattr(_open, 'stack', None)
+        parent = stack[-1] if stack else None
+    span_id = next(_ids)
+    _complete(name, t0_ns, t1_ns, span_id, parent, None, args)
+    return span_id
 
 
 def _annotation(name, step):
@@ -151,14 +182,8 @@ class Span:
             stack = _open.stack
             if stack and stack[-1] == self._id:
                 stack.pop()
-            ev = {'name': self.name, 'ph': 'X', 'ts': self._t0 / 1e3,
-                  'dur': (t1 - self._t0) / 1e3, 'pid': os.getpid(),
-                  'tid': threading.get_ident(), 't0_ns': self._t0,
-                  't1_ns': t1, 'span_id': self._id, 'parent': self._parent,
-                  'step': self.step}
-            if self.args:
-                ev['args'] = self.args
-            _append(ev)
+            _complete(self.name, self._t0, t1, self._id, self._parent,
+                      self.step, self.args)
         if self._bridge is not None:
             self._bridge.__exit__(exc_type, exc, tb)
             self._bridge = None

@@ -62,6 +62,7 @@ surface):
 """
 import os
 import threading
+import time
 
 _DEFAULT_DIR = '/tmp/paddle_tpu_telemetry'
 
@@ -87,6 +88,8 @@ class _State:
                                       _DEFAULT_DIR)
         self.sync_every = _env_int('PADDLE_TPU_TELEMETRY_SYNC_EVERY', 16)
         self.lock = threading.Lock()
+        # (t0_ns, t1_ns) of `import paddle_tpu`, until it is a span record
+        self.import_ns = None
 
 
 _STATE = _State()
@@ -106,12 +109,31 @@ def enable(log_dir=None, sync_every=None):
     _STATE.enabled = True
     from . import interpose
     interpose.install_jax_hooks()
+    _record_import()
+
+
+def note_import(t0_ns):
+    """``paddle_tpu/__init__.py`` calls this as its last statement with the
+    ``perf_counter_ns()`` reading of its first: the package's import
+    precedes any ``enable()``, so its span is kept here and written as the
+    record ``paddle_tpu.import`` when telemetry first comes on."""
+    _STATE.import_ns = (t0_ns, time.perf_counter_ns())
+    if _STATE.enabled:      # PADDLE_TPU_TELEMETRY=1: on since before it
+        _record_import()
+
+
+def _record_import():
+    stamps, _STATE.import_ns = _STATE.import_ns, None
+    if stamps is not None:
+        from . import spans
+        spans.record('paddle_tpu.import', *stamps, parent=None)
 
 
 def disable():
-    """Turn telemetry off. Hooks stay registered (they are no-ops while
-    disabled; jax.monitoring has no targeted unregister)."""
+    """Turn telemetry off and take the jax hooks out again."""
     _STATE.enabled = False
+    from . import interpose
+    interpose.remove_jax_hooks()
 
 
 def log_dir():

@@ -443,6 +443,25 @@ def test_step_keeps_its_phases_and_its_kernels_names(topo, monkeypatch):
     assert _without_provenance(text) == _without_provenance(unscoped)
 
 
+@pytest.mark.parametrize('sharded', [False, True], ids=['one_chip', '2x2'])
+def test_telemetry_leaves_the_chips_program_alone(topo, monkeypatch, sharded):
+    """ISSUE 39's spans (the build, the state's making and placing, the
+    first dispatch's `first`) and the records of JAX's compile path are the
+    host's: with telemetry on the chip's compiler is handed the program it
+    is handed with telemetry off."""
+    from paddle_tpu import observability as obs
+    off = _small_bert_step_text(topo, monkeypatch, sharded=sharded)
+    obs.enable()
+    try:
+        on = _small_bert_step_text(topo, monkeypatch, sharded=sharded)
+        assert [e for e in obs.trace_events()
+                if e['name'] == 'engine.init_state']
+    finally:
+        obs.disable()
+        obs.reset()
+    assert _without_provenance(on) == _without_provenance(off)
+
+
 def test_partitioned_step_names_its_kernels_and_its_gathers(topo,
                                                             monkeypatch):
     """FSDP over the four chips: inside `shard_map` the custom calls keep

@@ -412,6 +412,14 @@ def test_scopes_change_metadata_only(monkeypatch):
     assert _strip(scoped) == _strip(unscoped)
 
 
+def test_telemetry_leaves_the_compiled_step_alone():
+    """ISSUE 39's spans and records are host-side: with telemetry on the
+    step compiles to the text it compiles to with telemetry off."""
+    off = _compiled_text(nan_guard=True)
+    obs.enable()
+    assert _strip(_compiled_text(nan_guard=True)) == _strip(off)
+
+
 _FUSED = '''HloModule jit_step
 
 %fused_computation (p0: f32[8,4], p1: f32[8,2], p2: f32[4,2]) -> f32[4,2] {
