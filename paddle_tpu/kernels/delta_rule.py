@@ -24,10 +24,15 @@ products, the scores, the running sum and `k_in S` run in float32 at the
 highest precision, as the XLA form's scores, solve and state recurrence do. `B U`, `q_in S` and `k_out^T U` take `dtype`
 operands, as there.
 
-The backward kernel sweeps a head's chunks in reverse carrying dS: it
-rebuilds the chunk from q, k, v, g, beta and the chunk's start state (which
-the forward saves) and takes `jax.vjp` of the chunk-local function inside
-the kernel body, so forward and backward share one definition of the chunk.
+The backward kernel sweeps a head's chunks in reverse carrying dS and works
+a chunk's gradient out by hand (`_chunk_backward`), from the chunk's own
+equations and from what the forward kept of the chunk when it ran with
+`save`: the state it started from, the inverse, `U` and the two score
+matrices `A` and `B`. Of the chunk's forward it forms again only the running
+sum of g, the decays to and from the chunk's edges and `k_in S`; the scores'
+gradient goes back to q, k and G through the sub-block references the
+forward uses. `_chunk` is the one definition of the forward, and `jax.vjp`
+of it is what the tests hold `_chunk_backward` to.
 
 K and V need not be equal (a Gated DeltaNet head has keys of 96 and values
 of 192), and a head a third short of whole 128-lane registers is laid on
@@ -55,6 +60,7 @@ _HIGH = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
 _NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 _MARKS = 8      # rows of a chunk's marks block: seg, cont, tail, padding
+_ROWS = 8       # of a float32 register
 
 
 def _dot(a, b, dims, precision=None):
@@ -76,10 +82,9 @@ def _block_of(index, size, blocks):
 
 @functools.lru_cache(maxsize=None)
 def _unit_lower_inverse(sub):
-    """-> inv(N, known): (I + N)^-1 of a strictly lower triangular N (C, C),
-    in float32; `known`, where it is not None, is that inverse as an earlier
-    pass made it (the backward kernel reads the forward's) and only the
-    derivative is taken here.
+    """-> inv(N): (I + N)^-1 of a strictly lower triangular N (C, C), in
+    float32, with the closed form of its derivative (what `jax.vjp` of
+    `_chunk`, the tests' reference for the backward kernel, takes).
 
     Forward substitution, blocked. A `sub`-block of the diagonal is
     I + sum_j n_j e_j^T = prod_j (I + n_j e_j^T) (n_j, column j, is zero down
@@ -107,16 +112,16 @@ def _unit_lower_inverse(sub):
         return jnp.concatenate(rows, axis=0)
 
     @jax.custom_vjp
-    def inv(n_, known):
-        return inverse(n_) if known is None else known
+    def inv(n_):
+        return inverse(n_)
 
-    def fwd(n_, known):
-        out = inv(n_, known)
+    def fwd(n_):
+        out = inv(n_)
         return out, out
 
     def bwd(out, ct):
         # d(X^-1) = -X^-1 dX X^-1
-        return -_dot(_dot(out, ct, _TN, _HIGH), out, _NT, _HIGH), None
+        return (-_dot(_dot(out, ct, _TN, _HIGH), out, _NT, _HIGH),)
 
     inv.defvjp(fwd, bwd)
     return inv
@@ -170,13 +175,21 @@ def _scores_inside(G, k, q, sub):
             jnp.concatenate([b for _, b in blocks], 0))
 
 
-def _chunk(q, k, v, g, beta, state, seg_c, seg_r, cont, tail, inverse=None, *,
-           scale, sub, dtype):
+def _mm(a, b, dims, dtype):
+    """One of the chunk's three large products, or a transpose of one: its
+    operands in `dtype` where one is given, float32 out."""
+    if dtype is not None:
+        a, b = a.astype(dtype), b.astype(dtype)
+    return _dot(a, b, dims)
+
+
+def _chunk(q, k, v, g, beta, state, seg_c, seg_r, cont, tail, *, scale, sub,
+           dtype):
     """One head's chunk. q, k, g (C, K); v (C, V); beta, seg_c, cont, tail
     (C, 1); seg_r (1, C); state (V, K), the transposed state the chunk
-    starts from; `inverse`: the chunk's (I + Diag(beta) A)^-1 where a pass
-    before this one kept it -> o (C, V), the state the chunk ends with, the
-    inverse. All float32."""
+    starts from -> o (C, V), the state the chunk ends with, and what the
+    backward reads of the chunk beside its operands: the inverse
+    (I + Diag(beta) A)^-1, U, A and B. All float32."""
     C = q.shape[0]
     blocks = C // sub
     t, j = _iota((C, C), 0), _iota((C, C), 1)
@@ -185,26 +198,158 @@ def _chunk(q, k, v, g, beta, state, seg_c, seg_r, cont, tail, inverse=None, *,
     lower, strict = (t >= j) & same, (t > j) & same
     earlier = block_t > block_j
 
-    def mm(a, b, dims):
-        if dtype is not None:
-            a, b = a.astype(dtype), b.astype(dtype)
-        return _dot(a, b, dims)
-
     G = _dot((j <= t).astype(_F32), g, _NN, _HIGH)    # the running sum of g
     a_far, b_far = _scores_between(G, k, q, sub)
     a_near, b_near = _scores_inside(G, k, q, sub)
     A = jnp.where(strict, jnp.where(earlier, a_far, a_near), 0.0)
     Bm = jnp.where(lower, jnp.where(earlier, b_far, b_near), 0.0)
 
-    inverse = _unit_lower_inverse(sub)(beta * A, inverse)
+    inverse = _unit_lower_inverse(sub)(beta * A)
     from_start = jnp.exp(G) * cont
     k_in, q_in = k * from_start, q * from_start
     U = _dot(inverse, beta * (v - _dot(k_in, state, _NT, _HIGH)), _NN, _HIGH)
-    o = scale * (mm(q_in, state, _NT) + mm(Bm, U, _NN))
+    o = scale * (_mm(q_in, state, _NT, dtype) + _mm(Bm, U, _NN, dtype))
     G_end = G[C - 1:C]
     k_out = k * jnp.exp(G_end - G) * tail
     keep = jnp.exp(G_end) * cont[C - 1:C]
-    return o, keep * state + mm(U, k_out, _TN), inverse
+    return (o, keep * state + _mm(U, k_out, _TN, dtype),
+            (inverse, U, A, Bm))
+
+
+def _running_sum(x, reverse=False):
+    """The sum of x's rows up to each row (from each row on where `reverse`),
+    by log2(rows) shifted adds: float32 adds where the forward takes a
+    product of `HIGHEST` passes."""
+    C = x.shape[0]
+    at = _iota(x.shape, 0)
+    shift = 1
+    while shift < C:
+        if reverse:
+            x = x + jnp.where(at < C - shift, pltpu.roll(x, C - shift, 0), 0.0)
+        else:
+            x = x + jnp.where(at >= shift, pltpu.roll(x, shift, 0), 0.0)
+        shift *= 2
+    return x
+
+
+def _scores_between_backward(G, k, q, dA, dBm, sub):
+    """The transposes of `_scores_between`'s products. dA, dBm (C, C): the
+    scores' cotangents, zero where a score is not read -> what they give
+    q's rows, k's rows and k as a key, each (C, K). The decays' own
+    gradient follows from these three (`_chunk_backward`)."""
+    C = G.shape[0]
+    zero = jnp.zeros((sub, G.shape[1]), _F32)
+    dq_rows, dk_rows, dk_keys = [zero], [zero], 0.0
+    for lo in range(sub, C, sub):
+        ref = G[lo - 1:lo]
+        row = jnp.exp(G[lo:lo + sub] - ref)
+        to_ref = jnp.exp(jnp.minimum(ref - G[:lo], 0.0))
+        rows = jnp.concatenate([k[lo:lo + sub] * row, q[lo:lo + sub] * row],
+                               axis=0)
+        ct = jnp.concatenate([dA[lo:lo + sub, :lo], dBm[lo:lo + sub, :lo]],
+                             axis=0)
+        d_rows = _dot(ct, k[:lo] * to_ref, _NN, _HIGH)
+        dk_rows.append(d_rows[:sub] * row)
+        dq_rows.append(d_rows[sub:] * row)
+        dk_keys = dk_keys + jnp.concatenate(
+            [_dot(ct, rows, _TN, _HIGH) * to_ref,
+             jnp.zeros((C - lo, G.shape[1]), _F32)], axis=0)
+    return (jnp.concatenate(dq_rows, 0), jnp.concatenate(dk_rows, 0),
+            dk_keys)
+
+
+@jax.jit
+def _scores_in_block_backward(G, k, q, dA, dBm):
+    """The same for one sub-block of `_scores_inside`: G, k, q (sub, K);
+    dA, dBm (sub, sub), the cotangents of the block's scores. One key a
+    step: its column of cotangents spread over the lanes, the rows'
+    gradients by multiply-adds, the key's by a sum over the sublanes."""
+    sub = G.shape[0]
+    registers = [slice(lo, lo + _ROWS) for lo in range(0, sub, _ROWS)]
+    zero = jnp.zeros((_ROWS, G.shape[1]), _F32)
+    dq_rows, dk_rows = [zero] * len(registers), [zero] * len(registers)
+    at = _iota(G.shape, 0)
+    dk_keys = jnp.zeros(G.shape, _F32)
+    for j in range(sub):
+        # a key's scores are read from its own row on: the registers of
+        # rows before it hold none
+        key = 0.0
+        for i in range(j // _ROWS, len(registers)):
+            rows = registers[i]
+            decay = jnp.exp(jnp.minimum(G[rows] - G[j:j + 1], 0.0))
+            kk = k[j:j + 1] * decay
+            da, db = dA[rows, j:j + 1], dBm[rows, j:j + 1]
+            dk_rows[i] = dk_rows[i] + da * kk
+            dq_rows[i] = dq_rows[i] + db * kk
+            key = key + (da * k[rows] + db * q[rows]) * decay
+        dk_keys = jnp.where(at == j, jnp.sum(key, axis=0, keepdims=True),
+                            dk_keys)
+    return (jnp.concatenate(dq_rows, 0), jnp.concatenate(dk_rows, 0),
+            dk_keys)
+
+
+def _chunk_backward(q, k, v, g, beta, state, seg_c, seg_r, cont, tail,
+                    inverse, U, A, Bm, do, dstate, *, scale, sub, dtype):
+    """`_chunk`'s gradient from its equations. Operands as `_chunk` takes
+    them; inverse, U, A, Bm as it returned them; do (C, V) and dstate
+    (V, K), the cotangents of `o` and of the state the chunk ends with
+    -> dq, dk, dv, dg, dbeta (C, 1), and the cotangent of the state the
+    chunk starts from.
+
+    With X the inverse and R = beta (v - k_in S): U = X R, so dR = X^T dU
+    and d(beta A) = -X^T (dU R^T) X^T = -dR U^T. A product takes the
+    precision of the forward's product it transposes."""
+    C = q.shape[0]
+    t, j = _iota((C, C), 0), _iota((C, C), 1)
+    same = seg_c == seg_r
+    lower, strict = (t >= j) & same, (t > j) & same
+
+    G = _running_sum(g)
+    from_start = jnp.exp(G) * cont
+    k_in, q_in = k * from_start, q * from_start
+    G_end = G[C - 1:C]
+    to_end = jnp.exp(G_end - G) * tail
+    k_out = k * to_end
+    keep = jnp.exp(G_end) * cont[C - 1:C]
+    seen = v - _dot(k_in, state, _NT, _HIGH)
+
+    do = scale * do
+    dU = _mm(Bm, do, _TN, dtype) + _mm(k_out, dstate, _NT, dtype)
+    dBm = jnp.where(lower, _mm(do, U, _NT, dtype), 0.0)
+    dq_in = _mm(do, state, _NN, dtype)
+    dk_out = _mm(U, dstate, _NN, dtype)
+    dR = _dot(inverse, dU, _TN, _HIGH)
+    dN = jnp.where(strict, -_dot(dR, U, _NT, _HIGH), 0.0)
+    dv = beta * dR
+    dk_in = -_dot(dv, state, _NN, _HIGH)
+    dstart = (keep * dstate + _mm(do, q_in, _TN, dtype)
+              - _dot(dv, k_in, _TN, _HIGH))
+    dbeta = (jnp.sum(dR * seen, -1, keepdims=True)
+             + jnp.sum(dN * A, -1, keepdims=True))
+
+    dA = beta * dN
+    dq_rows, dk_rows, dk_keys = _scores_between_backward(G, k, q, dA, dBm,
+                                                         sub)
+    near = [_scores_in_block_backward(
+        G[lo:lo + sub], k[lo:lo + sub], q[lo:lo + sub],
+        dA[lo:lo + sub, lo:lo + sub], dBm[lo:lo + sub, lo:lo + sub])
+        for lo in range(0, C, sub)]
+    dq_rows, dk_rows, dk_keys = (
+        far + jnp.concatenate(blocks, 0)
+        for far, blocks in zip((dq_rows, dk_rows, dk_keys), zip(*near)))
+
+    # a score reads G as G_t - G_j alone: what it gives G follows from what
+    # it gives its row and its key; so for the decays to the chunk's edges
+    dq = dq_rows + dq_in * from_start
+    dk = dk_rows + dk_keys + dk_in * from_start + dk_out * to_end
+    to_the_end = dk_out * k_out
+    dG = (q * dq_rows + k * (dk_rows - dk_keys) + dq_in * q_in
+          + dk_in * k_in - to_the_end)
+    dG_end = (jnp.sum(to_the_end, 0, keepdims=True)
+              + keep * jnp.sum(dstate * state, 0, keepdims=True))
+    # G_end is in every row's sum
+    dg = _running_sum(dG, reverse=True) + dG_end
+    return dq, dk, dv, dg, dbeta, dstart
 
 
 def _columns(marks_ref):
@@ -227,8 +372,7 @@ def _columns(marks_ref):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, marks_ref, o_ref,
                 *rest, heads, K, V, save, **chunk):
-    start_ref, inverse_ref = rest[:2] if save else (None, None)
-    state_ref = rest[-1]
+    kept_refs, state_ref = rest[:-1], rest[-1]
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -238,47 +382,46 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, marks_ref, o_ref,
     one_head = jax.jit(functools.partial(_chunk, **chunk))  # traced once
     for h in range(heads):
         ks, vs = slice(h * K, (h + 1) * K), slice(h * V, (h + 1) * V)
-        state = state_ref[h]
-        if save:
-            start_ref[h] = state
-        o, state, inverse = one_head(
+        start = state_ref[h]
+        o, state, (inverse, U, A, Bm) = one_head(
             q_ref[:, ks], k_ref[:, ks], v_ref[:, vs], g_ref[:, ks],
-            column(beta_ref[h]), state, seg_c, seg_r, cont, tail)
+            column(beta_ref[h]), start, seg_c, seg_r, cont, tail)
         o_ref[:, vs] = o
         state_ref[h] = state
         if save:
-            inverse_ref[h] = inverse
+            start_ref, inverse_ref, u_ref, scores_ref = kept_refs
+            start_ref[h], inverse_ref[h] = start, inverse
+            u_ref[:, vs] = U
+            scores_ref[h] = jnp.concatenate([A, Bm], axis=1)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, marks_ref, start_ref,
-                inverse_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref,
-                dbeta_ref, dstate_ref, *, heads, K, V, **chunk):
+                inverse_ref, u_ref, scores_ref, do_ref, dq_ref, dk_ref,
+                dv_ref, dg_ref, dbeta_ref, dstate_ref, *, heads, K, V,
+                **chunk):
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate_ref[...] = jnp.zeros_like(dstate_ref)
 
     seg_r, seg_c, cont, tail, column, as_row = _columns(marks_ref)
-
-    @jax.jit        # traced once for all the heads of a step
-    def one_head(q, k, v, g, beta, state, inverse, do, dstate):
-        _, vjp = jax.vjp(
-            lambda *x: _chunk(*x, seg_c, seg_r, cont, tail, inverse,
-                              **chunk)[:2], q, k, v, g, beta, state)
-        return vjp((do, dstate))
-
+    C = q_ref.shape[0]
+    # traced once for all the heads of a step
+    one_head = jax.jit(functools.partial(_chunk_backward, **chunk))
     for h in range(heads):
         ks, vs = slice(h * K, (h + 1) * K), slice(h * V, (h + 1) * V)
+        scores = scores_ref[h]
         dq, dk, dv, dg, dbeta, dstate = one_head(
             q_ref[:, ks], k_ref[:, ks], v_ref[:, vs], g_ref[:, ks],
-            column(beta_ref[h]), start_ref[h], inverse_ref[h], do_ref[:, vs],
-            dstate_ref[h])
+            column(beta_ref[h]), start_ref[h], seg_c, seg_r, cont, tail,
+            inverse_ref[h], u_ref[:, vs], scores[:, :C], scores[:, C:],
+            do_ref[:, vs], dstate_ref[h])
         dq_ref[:, ks], dk_ref[:, ks], dg_ref[:, ks] = dq, dk, dg
         dv_ref[:, vs] = dv
         dbeta_ref[h] = as_row(dbeta)
         dstate_ref[h] = dstate
 
 
-_VMEM_LIMIT = 64 << 20    # the backward holds a chunk's whole vjp per head
+_VMEM_LIMIT = 64 << 20    # the backward holds a chunk's whole gradient per head
 
 
 def _specs(heads, C, K, V, chunk_of):
@@ -294,13 +437,14 @@ def _specs(heads, C, K, V, chunk_of):
     def kept(rows, cols):   # (B, H, N, rows, cols): a matrix a chunk-head
         return pl.BlockSpec((None, heads, None, rows, cols),
                             lambda b, h, n: (b, h, chunk_of(n), 0, 0))
-    return wide(K), wide(V), per_head, marks, kept(V, K), kept(C, C)
+    return (wide(K), wide(V), per_head, marks, kept(V, K), kept(C, C),
+            kept(C, 2 * C))
 
 
 # `_forward` and `_backward` are jits of their own: a net's KDA layers make
 # the same calls, and a jit inside the step's trace is traced (the unrolled
-# chunk, and its vjp, are thousands of equations) and lowered once for all
-# of them. The scope is entered again inside: the compiler names a custom
+# chunk, and its gradient, are thousands of equations) and lowered once for
+# all of them. The scope is entered again inside: the compiler names a custom
 # call after its innermost scope.
 _STATIC = ('scale', 'chunk', 'sub', 'dtype', 'heads', 'interpret')
 
@@ -308,20 +452,23 @@ _STATIC = ('scale', 'chunk', 'sub', 'dtype', 'heads', 'interpret')
 @functools.partial(jax.jit, static_argnames=_STATIC + ('save',))
 def _forward(q, k, v, g, beta, marks, *, save, scale, chunk, sub, dtype,
              heads, interpret):
-    """-> o, and where `save` what the backward reads: the chunks' start
-    states (B, H, N, V, K) and inverses (B, H, N, C, C)."""
+    """-> o, and where `save` what the backward reads of every chunk: the
+    state it starts from (B, H, N, V, K), its inverse (B, H, N, C, C), its U
+    (laid as v is) and its scores A and B side by side (B, H, N, C, 2C)."""
     B, T, HK = q.shape
     H, C = beta.shape[1], chunk
     K, V, N = HK // H, v.shape[2] // H, T // C
-    wide_k, wide_v, per_head, marks_spec, states, inverses = _specs(
+    wide_k, wide_v, per_head, marks_spec, states, inverses, scores = _specs(
         heads, C, K, V, lambda n: n)
     out_specs, out_shape = [wide_v], [jax.ShapeDtypeStruct(v.shape, _F32)]
     # graftlint: disable=GL006 — `save` is a static argument of this jit
     # (never a tracer): one trace with the kept outputs, one without
     if save:
-        out_specs += [states, inverses]
+        out_specs += [states, inverses, wide_v, scores]
         out_shape += [jax.ShapeDtypeStruct((B, H, N, V, K), _F32),
-                      jax.ShapeDtypeStruct((B, H, N, C, C), _F32)]
+                      jax.ShapeDtypeStruct((B, H, N, C, C), _F32),
+                      jax.ShapeDtypeStruct(v.shape, _F32),
+                      jax.ShapeDtypeStruct((B, H, N, C, 2 * C), _F32)]
     with jax.named_scope('delta_rule.pallas'):
         return pl.pallas_call(
             functools.partial(_fwd_kernel, heads=heads, K=K, V=V, save=save,
@@ -338,20 +485,20 @@ def _forward(q, k, v, g, beta, marks, *, save, scale, chunk, sub, dtype,
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _backward(q, k, v, g, beta, marks, starts, inverses, do, *, scale, chunk,
-              sub, dtype, heads, interpret):
+def _backward(q, k, v, g, beta, marks, starts, inverses, us, scores, do, *,
+              scale, chunk, sub, dtype, heads, interpret):
     B, T, HK = q.shape
     H, C = beta.shape[1], chunk
     K, V, N = HK // H, v.shape[2] // H, T // C
-    wide_k, wide_v, per_head, marks_spec, states, kept_inverses = _specs(
-        heads, C, K, V, lambda n: N - 1 - n)
+    (wide_k, wide_v, per_head, marks_spec, states, kept_inverses,
+     kept_scores) = _specs(heads, C, K, V, lambda n: N - 1 - n)
     with jax.named_scope('delta_rule.pallas'):
         return pl.pallas_call(
             functools.partial(_bwd_kernel, heads=heads, K=K, V=V, scale=scale,
                               sub=sub, dtype=dtype),
             grid=(B, H // heads, N),
             in_specs=[wide_k, wide_k, wide_v, wide_k, per_head, marks_spec,
-                      states, kept_inverses, wide_v],
+                      states, kept_inverses, wide_v, kept_scores, wide_v],
             out_specs=[wide_k, wide_k, wide_v, wide_k, per_head],
             out_shape=[jax.ShapeDtypeStruct(x.shape, _F32)
                        for x in (q, k, v, g, beta)],
@@ -360,7 +507,7 @@ def _backward(q, k, v, g, beta, marks, starts, inverses, do, *, scale, chunk,
                 dimension_semantics=('parallel', 'parallel', 'arbitrary'),
                 vmem_limit_bytes=_VMEM_LIMIT),
             interpret=interpret,
-        )(q, k, v, g, beta, marks, starts, inverses, do)
+        )(q, k, v, g, beta, marks, starts, inverses, us, scores, do)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))

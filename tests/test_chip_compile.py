@@ -137,10 +137,11 @@ def test_delta_rule_compiles_fwd_bwd_at_the_cells_shape(one_chip,
     """One row of Kimi-Linear's delta rule as the cell runs it: 8192
     tokens, 32 heads of 128, chunks of 64 in sub-blocks of 16, float32
     operands with bfloat16 in the three large products. The `HIGHEST`
-    float32 products, the `a^T b` state update and the chunk's `jax.vjp`
-    inside the backward kernel all have to lower: one custom call forward,
-    two (the forward that saves the chunks' start states, the backward)
-    under `jax.grad`."""
+    float32 products, the `a^T b` state update and the chunk's gradient
+    written out in the backward kernel (the sub-block scores' transposes on
+    slices of 16, 32 and 48 keys, a score column spread over the lanes) all
+    have to lower: one custom call forward, two (the forward that saves what
+    the backward reads of every chunk, the backward) under `jax.grad`."""
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
     T, heads, width = 8192, 32, 128
 
