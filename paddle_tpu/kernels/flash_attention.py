@@ -19,6 +19,16 @@ Features:
   padding mask reduces to;
 - a value head size that differs from the query/key head size (latent
   attention trains with 192-wide q/k and 128-wide v): read off the shapes;
+- grouped-query heads: k and v may hold fewer heads than q (a divisor of
+  its count), read off the shapes; query head h reads K/V head h // group
+  through the block index, so no K or V at the query head count exists. The
+  backward's grid then runs (batch x K/V heads, group, K tiles) and keeps a
+  K/V head's whole dK and dV in VMEM, in float32, over its group: the
+  group's sum is made there (docs/ATTENTION.md). No bias, no dropout there;
+- an attention window on packed rows: ``window`` keys a query sees at most,
+  itself among them. It enters through the per-row first key alone
+  (``row_starts``: the later of the document's start and position - window
+  + 1), so masks, tile bounds and tile counts hold for it as for documents;
 - packed documents under the causal mask: ``doc_start`` (B, L) names, for
   each query position, the first position of its document; a query sees
   the keys from there to itself. It buys more than the mask: the tile
@@ -78,9 +88,14 @@ def _attn_reference(q, k, v, causal, scale, kpad_bias=None, dropout_p=0.0,
     """Plain XLA attention on (B, H, L, D) — fallback + ground truth.
 
     kpad_bias: optional (B, Lk) additive bias (0 for keep, large negative for
-    masked keys). doc_start: optional (B, L) first position of each query's
-    document.
+    masked keys). doc_start: optional (B, L) first key each query sees
+    (``row_starts``). k and v may hold fewer heads than q: each is then
+    repeated over its group of query heads (here, off the kernels' path,
+    only).
     """
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
     scores = jnp.einsum('bhld,bhmd->bhlm', q, k) * scale
     if kpad_bias is not None:
         scores = scores + kpad_bias[:, None, None, :].astype(scores.dtype)
@@ -135,12 +150,12 @@ def _global_bh(seed_ref, heads):
             + seed_ref[0, 2] + bh % h_local)
 
 
-def _tile_bound(bound_ref, heads):
+def _tile_bound(bound_ref, heads, axis=1):
     """This grid point's loop bound (``doc_tile_bounds``): bound_ref is the
     (batch rows x tiles,) int32 array of this device's shard in SMEM, the
-    grid (batch x heads, tiles)."""
-    return bound_ref[(pl.program_id(0) // heads[0]) * pl.num_programs(1)
-                     + pl.program_id(1)]
+    grid (batch x heads, ..., tiles) with the tiles along `axis`."""
+    return bound_ref[(pl.program_id(0) // heads[0]) * pl.num_programs(axis)
+                     + pl.program_id(axis)]
 
 
 def _seed_and_shard(seed, shard):
@@ -285,16 +300,23 @@ def _flash_forward(q, k, v, kpad_bias, seed, doc, causal, scale,
 
     def call(*args, shard):
         b, h = args[0].shape[:2]        # this device's batch and heads
-        args = [t.reshape((b * h, L) + t.shape[3:]) for t in args[:3]
+        group = h // args[1].shape[1]   # query heads to a K/V head
+        args = [t.reshape((-1, L) + t.shape[3:]) for t in args[:3]
                 ] + list(args[3:])
         kernel = functools.partial(
             _fwd_kernel, block_k=bk, seq_len=L, causal=causal, scale=scale,
             has_bias=has_bias, dropout_p=dropout_p,
             heads=(h, shard['h'][1]), **extra)
+        if group == 1:
+            def kv(bh, i):
+                return (bh, 0, 0)
+        else:       # the heads of a group follow each other: its K and V
+            def kv(bh, i):      # are fetched once for all of them
+                return (bh // group, 0, 0)
         in_specs = [
             pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, L, d), lambda bh, i: (bh, 0, 0)),
-            pl.BlockSpec((1, L, dv), lambda bh, i: (bh, 0, 0)),
+            pl.BlockSpec((1, L, d), kv),
+            pl.BlockSpec((1, L, dv), kv),
         ]
         n_fixed = 3
         if has_bias:
@@ -330,9 +352,15 @@ def _flash_forward(q, k, v, kpad_bias, seed, doc, causal, scale,
 # ---------------------------------------------------------------------------
 
 def _bwd_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
-                heads, has_doc=False):
+                heads, has_doc=False, group=1):
     """dQ, dK and dV of one (head, K tile) from ONE pass over its score
-    tiles. Grid (b*h, K tiles); the loop runs over the Q tiles."""
+    tiles. Grid (b*h, K tiles); the loop runs over the Q tiles.
+
+    `group` > 1 (grouped-query heads): grid (b*K/V heads, group, K tiles),
+    `heads` counting the K/V heads. dK and dV are then the K/V head's WHOLE
+    (L, d) float32 blocks, which stay in VMEM over the group and the K tiles
+    and take each query head's part of a K tile as it comes."""
+    axis = 1 if group == 1 else 2           # the grid's K-tile axis
     refs = list(refs)
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = refs[:6]
     idx = 6
@@ -344,7 +372,7 @@ def _bwd_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
     if has_doc:
         start_ref = refs[idx]; idx += 1                 # (1, L, 1) int32
         # one past the last Q tile that can see a key of this K tile
-        last = _tile_bound(refs[idx], heads); idx += 1
+        last = _tile_bound(refs[idx], heads, axis); idx += 1
     dq_ref, dk_ref, dv_ref = refs[idx:idx + 3]
 
     k = k_ref[0]                                        # (block_k, d) native
@@ -354,7 +382,7 @@ def _bwd_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
     # one K tile (every key-padding call): a Q tile's dQ is whole inside
     # this instance. More: it sums over the grid's K axis, in fp32
     dq_acc = refs[idx + 3] if nk > 1 else None
-    k_blk = pl.program_id(1)
+    k_blk = pl.program_id(axis)
     k_offset = k_blk * block_k
     bias_tile = None
     if bias_ref is not None:
@@ -426,22 +454,36 @@ def _bwd_kernel(*refs, block_q, seq_len, causal, scale, has_bias, dropout_p,
     else:
         dk, dv = jax.lax.fori_loop(start, nq, make_body(False),
                                    (zero, zero_v))
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    if group == 1:
+        dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+    else:
+        at = pl.dslice(k_offset, block_k)
+
+        @pl.when(pl.program_id(1) == 0)
+        def _():
+            dk_ref[0, at, :] = dk * scale
+            dv_ref[0, at, :] = dv
+
+        @pl.when(pl.program_id(1) > 0)
+        def _():
+            dk_ref[0, at, :] += dk * scale
+            dv_ref[0, at, :] += dv
     if dq_acc is not None:
         @pl.when(k_blk == nk - 1)
         def _():
             dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_vmem_limit(L, d, bq, bk, itemsize, dv=None, has_doc=False):
+def _bwd_vmem_limit(L, d, bq, bk, itemsize, dv=None, has_doc=False,
+                    grouped=False):
     """The backward kernel keeps a head's whole Q, O, dO, lse and dQ in
     VMEM (double-buffered, the minor dim padded to 128 lanes) beside its
     K-tile blocks and a few (bq, bk) fp32 temporaries. While that fits the
     16 MiB Mosaic gives a kernel unasked (every tiling to L = 1024, most
     to 2048) the limit is left alone: None. Past it, ask for what the
     blocks need and half as much again; the compiler refuses what the chip
-    cannot give."""
+    cannot give. `grouped`: dK and dV are whole float32 heads besides."""
     lanes = -(-d // 128) * 128
     lanes_v = lanes if dv is None else -(-dv // 128) * 128
     need = (2 * 2 * L * (lanes + lanes_v) * itemsize    # q, dq; o, do
@@ -449,6 +491,8 @@ def _bwd_vmem_limit(L, d, bq, bk, itemsize, dv=None, has_doc=False):
             + (L * lanes * 4 if bk < L else 0)  # dq's fp32 scratch
             + 2 * 2 * bk * (lanes + lanes_v) * itemsize     # k, dk; v, dv
             + 2 * bq * bk * 4)                  # two live fp32 score tiles
+    if grouped:
+        need += 2 * L * (lanes + lanes_v) * 4
     return None if need <= (14 << 20) else need * 3 // 2
 
 
@@ -478,6 +522,8 @@ def _flash_backward(q, k, v, o, lse, kpad_bias, seed, doc, g, causal,
 
     def call(*args, shard):
         b, h = args[0].shape[:2]        # this device's batch and heads
+        if args[1].shape[1] != h:
+            return grouped_call(*args)
         args = [t.reshape((b * h,) + t.shape[2:]) for t in args[:6]
                 ] + list(args[6:])
         if dropout_p > 0.0:
@@ -529,6 +575,61 @@ def _flash_backward(q, k, v, o, lse, kpad_bias, seed, doc, g, causal,
         return (dq.reshape(b, h, L, d), dk.reshape(b, h, L, d),
                 dv.reshape(b, h, L, dv_))
 
+    def grouped_call(*args):
+        """Grouped-query heads (no bias, no dropout: the entry refuses
+        them): grid (batch x K/V heads, group, K tiles). A query head's Q, O,
+        dO, lse and dQ stay in VMEM over its K tiles as above; the K/V
+        head's dK and dV, whole and in float32, stay over the group too and
+        go out once, so the group's sum is made in VMEM and no per-query-head
+        dK or dV exists."""
+        b, h, hk = args[0].shape[0], args[0].shape[1], args[1].shape[1]
+        group = h // hk
+        args = [t.reshape((-1,) + t.shape[2:]) for t in args[:6]
+                ] + list(args[6:])
+        kernel = functools.partial(
+            _bwd_kernel, block_q=bq, seq_len=L, causal=causal, scale=scale,
+            has_bias=False, dropout_p=0.0, heads=(hk, hk), group=group,
+            **extra)
+
+        def head(n, g, j):
+            return (n * group + g, 0, 0)
+
+        def whole(width):
+            return pl.BlockSpec((1, L, width), head)
+
+        def tile(width):
+            return pl.BlockSpec((1, bk, width), lambda n, g, j: (n, j, 0))
+
+        def summed(width):
+            return pl.BlockSpec((1, L, width), lambda n, g, j: (n, 0, 0))
+        in_specs = [whole(d), tile(d), tile(dv_), whole(dv_), whole(dv_),
+                    whole(1)]
+        if has_doc:
+            in_specs.append(pl.BlockSpec(
+                (1, L, 1), lambda n, g, j: (n // hk, 0, 0)))
+            in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+            args[-1] = args[-1].reshape(-1)
+        dq, dk, dv = pl.pallas_call(
+            kernel,
+            grid=(b * hk, group, nk),
+            in_specs=in_specs,
+            out_specs=(whole(d), summed(d), summed(dv_)),
+            out_shape=(jax.ShapeDtypeStruct((b * h, L, d), q.dtype),
+                       jax.ShapeDtypeStruct((b * hk, L, d), jnp.float32),
+                       jax.ShapeDtypeStruct((b * hk, L, dv_), jnp.float32)),
+            scratch_shapes=([pltpu.VMEM((L, d), jnp.float32)] if nk > 1
+                            else []),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=('parallel', 'arbitrary', 'arbitrary'),
+                vmem_limit_bytes=_bwd_vmem_limit(
+                    L, d, bq, bk, q.dtype.itemsize, dv_, has_doc,
+                    grouped=True)),
+            interpret=interpret,
+        )(*args)
+        return (dq.reshape(b, h, L, d),
+                dk.reshape(b, hk, L, d).astype(k.dtype),
+                dv.reshape(b, hk, L, dv_).astype(v.dtype))
+
     return spmd_kernel(call, dims, [_BHLD] * 3, _ROLES,
                        scope='flash_attention.pallas')(*args)
 
@@ -564,9 +665,23 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, dropout_p, interpret,
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+def row_starts(doc_start, window=None):
+    """(B, L) int32: the first key each query of a packed causal row sees.
+    Its document's first position, or under an attention `window` the later
+    of that and `position - window + 1` (the query's own position counts
+    among the `window`). The ONE place a window enters: masks, tile bounds
+    and tile counts are all made from what this returns."""
+    start = doc_start.astype(jnp.int32)
+    if window is None:
+        return start
+    at = jnp.arange(start.shape[1], dtype=jnp.int32)[None, :]
+    return jnp.maximum(start, at - (int(window) - 1))
+
+
 def doc_tile_bounds(doc_start, block_q, block_k):
     """The kernels' loop bounds on packed rows, from ``doc_start`` (B, L;
-    L in whole tiles of both sizes), int32:
+    L in whole tiles of both sizes; any per-row first visible key, a
+    document's start or ``row_starts``' under a window), int32:
 
     lo (B, L // block_q): the first K tile any row of Q tile i can see,
         ``min(doc_start[Q tile i]) // block_k`` (never past the tile of the
@@ -590,15 +705,18 @@ def doc_tile_bounds(doc_start, block_q, block_k):
     return lo, hi
 
 
-def doc_tile_counts(doc_start, block_q=_BLOCK, block_k=_BLOCK):
+def doc_tile_counts(doc_start, block_q=_BLOCK, block_k=_BLOCK, window=None):
     """-> (swept, causal), float32 scalars: the tile pairs one forward
-    call on these rows visits per head with the document bounds, and
-    without them (every tile up to the diagonal). Zeros where the rows do
-    not tile (the kernels do not run there)."""
+    call on these rows visits per head with the document bounds (and the
+    `window`'s, where one is given), and without them (every tile up to the
+    diagonal). Zeros where the rows do not tile (the kernels do not run
+    there)."""
     L = doc_start.shape[1]
     if not _tiles(L, L, block_q, block_k):
         return jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)
     bq, bk = min(block_q, L), min(block_k, L)
+    if window is not None:
+        doc_start = row_starts(doc_start, window)
     lo, _ = doc_tile_bounds(doc_start, bq, bk)
     ends = (jnp.arange(L // bq, dtype=jnp.int32) * bq + bq + bk - 1) // bk
     return (jnp.sum(ends - lo).astype(jnp.float32),
@@ -612,16 +730,22 @@ def _tiles(lq, lk, block_q, block_k):
 
 def flash_attention_bhld(q, k, v, causal=False, scale=None, kpad_bias=None,
                          dropout_p=0.0, dropout_seed=None, doc_start=None,
-                         block_q=_BLOCK, block_k=_BLOCK, interpret=False):
+                         window=None, block_q=_BLOCK, block_k=_BLOCK,
+                         interpret=False):
     """Flash attention on (B, H, L, D) tensors; v's head size may differ
-    from q's and k's.
+    from q's and k's, and k and v may hold fewer heads than q (grouped-query
+    attention: a divisor of q's count; query head h reads K/V head
+    h // group, dK and dV come back at the K/V head count; no bias and no
+    dropout there).
 
     kpad_bias: optional (B, Lk) additive key-padding bias (0 = keep, -1e4/-inf
     style = masked). dropout_p: attention-probability dropout rate; when > 0,
     dropout_seed must be an int32 array of shape (1, 1) (the keep-mask is a
     deterministic function of it). doc_start: optional (B, L) int32, with
     causal=True: the first position of each query's document in a packed
-    row; a query then sees the keys from there to itself. Takes plain-XLA
+    row; a query then sees the keys from there to itself. window: with
+    doc_start, the number of keys a query sees at most, itself among them
+    (``row_starts``). Takes plain-XLA
     attention off the TPU (unless interpret mode is asked for) or when L
     doesn't tile; either way the ops sit under a ``flash_attention.pallas`` /
     ``flash_attention.xla`` named scope (``_common.took``).
@@ -633,6 +757,18 @@ def flash_attention_bhld(q, k, v, causal=False, scale=None, kpad_bias=None,
     if doc_start is not None and not causal:
         raise ValueError("doc_start describes packed causal rows: it needs "
                          "causal=True")
+    if window is not None:
+        if doc_start is None:
+            raise ValueError("window narrows a packed row's doc_start: give "
+                             "both (zeros for a row of one document)")
+        doc_start = row_starts(doc_start, window)
+    if q.shape[1] != k.shape[1]:
+        if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+            raise ValueError("%d query heads do not group over %d / %d K/V "
+                             "heads" % (q.shape[1], k.shape[1], v.shape[1]))
+        if kpad_bias is not None or dropout_p > 0.0:
+            raise ValueError("grouped-query heads take no key-padding bias "
+                             "and no dropout")
     if kpad_bias is not None:
         # the forward kernel streams bias columns with an in-kernel dynamic
         # slice of the minor dim, which Mosaic cannot lower for block_k < L;

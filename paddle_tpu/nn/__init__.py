@@ -46,6 +46,7 @@ from .layer.transformer import (MultiHeadAttention, TransformerEncoderLayer,
                                 TransformerDecoder, Transformer)
 from .layer.distance import PairwiseDistance
 from .layer.linear_attention import (CausalSelfAttention, GatedDeltaNet,
+                                     GroupedQueryAttention,
                                      KimiDeltaAttention, LatentAttention)
 from .layer.moe import SwiGLU, SparseMoE
 from .utils import weight_norm, remove_weight_norm, spectral_norm

@@ -3,7 +3,9 @@ jax arrays.
 
 An expert layer of E experts is spread over the chips of an expert-parallel
 group; this chip holds the experts `held = (lo, hi)`. The router scores a
-token over ALL E experts and picks its top k among all of them, so the
+token over ALL E experts (by a sigmoid with a correction bias and a scaling
+factor, or by a plain softmax: the two published routers of the decoders
+here) and picks its top k among all of them, so the
 routing is the same on every chip of the group; this chip then computes, for
 each token, the part of the sum that runs over the experts it holds:
 
@@ -28,8 +30,8 @@ import jax.numpy as jnp
 
 from ...kernels.grouped_matmul import grouped_matmul
 
-__all__ = ['route_sigmoid_topk', 'swiglu', 'expert_share', 'row_tile',
-           'buffer_tiles', 'COUNTERS']
+__all__ = ['route_sigmoid_topk', 'route_softmax_topk', 'swiglu',
+           'expert_share', 'row_tile', 'buffer_tiles', 'COUNTERS']
 
 # what `expert_share` counts, in this order
 COUNTERS = ('assignments_held', 'assignments', 'expert_rows_max',
@@ -47,6 +49,19 @@ def route_sigmoid_topk(x, w_router, correction_bias, top_k, scaling):
     picked = jnp.take_along_axis(s, idx, axis=-1)
     weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scaling
     return idx.astype(jnp.int32), weights
+
+
+def route_softmax_topk(x, w_router, top_k):
+    """Scores s = softmax(x W_r) over all experts, in float32; the top k by
+    s; weights s_sel / sum(s_sel) (the picks' probabilities renormalised).
+    No bias and no scaling. x (T, H) -> idx (T, k) int32, weights (T, k)
+    float32."""
+    s = jax.nn.softmax(jnp.matmul(
+        x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    picked, idx = jax.lax.top_k(s, top_k)
+    return idx.astype(jnp.int32), \
+        picked / jnp.sum(picked, axis=-1, keepdims=True)
 
 
 def swiglu(x, gate, up, down, dtype=None):

@@ -5,13 +5,16 @@ interleaves, one in four) or rotary with low-rank queries (arXiv:2412.19437
 section 2.1); Gated DeltaNet (a decay per head, arXiv:2412.06464) and plain
 multi-head causal attention with normed queries and keys (the OLMo 2/3
 block's), either of which may hold a share of its heads
-(docs/HEAD_SHARE.md). All take a packed row's document numbers: state,
-convolution, scores and positions stop at document boundaries.
+(docs/HEAD_SHARE.md); grouped-query attention with a rotary table over the
+whole head and, where asked, an attention window (docs/ATTENTION.md). All
+take a packed row's document numbers: state, convolution, scores and
+positions stop at document boundaries.
 """
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...core.tensor import apply_op
 from ...kernels.delta_rule import delta_rule
@@ -22,8 +25,9 @@ from ..layer_base import Layer
 from ..functional.norm import rms_norm_values
 
 __all__ = ['KimiDeltaAttention', 'LatentAttention', 'GatedDeltaNet',
-           'CausalSelfAttention', 'compute_dtype', 'doc_starts', 'pre_normed',
-           'post_normed', 'rotate_pairs']
+           'CausalSelfAttention', 'GroupedQueryAttention', 'compute_dtype',
+           'doc_starts', 'pre_normed', 'post_normed', 'rotate_pairs',
+           'rotate_halves', 'rope_inv_freq', 'yarn_inv_freq']
 
 
 def compute_dtype():
@@ -198,6 +202,114 @@ def rotate_pairs(x, positions, theta):
     even, odd = x[..., 0], x[..., 1]
     return jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
                      axis=-1).reshape(x.shape[:-2] + (d,))
+
+
+def rope_inv_freq(theta, dim):
+    """(dim / 2,) float64: the plain rotary table's inverse frequencies,
+    theta^(-2j / dim)."""
+    return theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+
+def yarn_inv_freq(theta, dim, factor, original_max_position_embeddings,
+                  beta_fast=32.0, beta_slow=1.0):
+    """-> ((dim / 2,) float64 inverse frequencies, low, high): YaRN
+    (arXiv:2309.00071 section 3.2, as the public `rope_type: yarn`
+    computes it). Dimension j turns c(b) = dim ln(original / (2 pi b)) /
+    (2 ln theta) at b rotations over the original context; the dimensions
+    under low = floor(c(beta_fast)) keep their rate, those over high =
+    ceil(c(beta_slow)) are slowed `factor` times, a linear ramp between."""
+    def turns(b):
+        return dim * math.log(original_max_position_embeddings
+                              / (2 * math.pi * b)) / (2 * math.log(theta))
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), dim - 1)
+    plain = rope_inv_freq(theta, dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp), low, high
+
+
+def rotate_halves(x, cos, sin):
+    """Rotary position encoding of the last axis of x (B, T, H, d) in the
+    half-split form: x * cos + [-x2, x1] * sin with x = [x1, x2] the two
+    halves; cos, sin (B, T, 1, d) float32, each of its d / 2 angles repeated
+    over the halves. x keeps its layout (the half turn is a roll of the
+    lanes, not a reshape to pairs); float32 -> float32."""
+    half = x.shape[-1] // 2
+    x = x.astype(jnp.float32)
+    sign = jnp.where(jnp.arange(x.shape[-1]) < half, -1.0, 1.0)
+    return x * cos + jnp.roll(x, half, axis=-1) * (sin * sign)
+
+
+class GroupedQueryAttention(Layer):
+    """Causal attention of `num_heads` query heads over `num_kv_heads` key /
+    value heads of `head_dim` (query head h reads K/V head h // group), no
+    bias: q = x W_q, k = x W_k, v = x W_v; q and k rotated over the whole
+    head in the half-split form (`rotate_halves`, under the scope
+    `attn.rope`) by a position that restarts at each document of a packed
+    row; softmax(q k^T / sqrt(head_dim)) over the keys of the query's
+    document, the last `window` of them where a window is given; W_o.
+
+    The rotary table is DATA: `inv_freq` (head_dim / 2,) and `rope_factor`,
+    which multiplies cos and sin (YaRN's attention factor; the scores take
+    its square). A plain table and a YaRN table are one code path. The layer
+    runs under the scope `attn.window` with a window, else `attn.full`; k
+    and v go to the flash kernels at their own head count."""
+
+    def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
+                 inv_freq, rope_factor=1.0, window=None,
+                 initializer_range=0.02):
+        super().__init__()
+        if num_heads % num_kv_heads:
+            raise ValueError('%d query heads do not group over %d K/V heads'
+                             % (num_heads, num_kv_heads))
+        self.heads = (num_heads, num_kv_heads, head_dim)
+        self.inv_freq = np.asarray(inv_freq, np.float32)
+        if self.inv_freq.shape != (head_dim // 2,):
+            raise ValueError('inv_freq %r is no table of a %d-wide head'
+                             % (self.inv_freq.shape, head_dim))
+        self.rope_factor, self.window = float(rope_factor), window
+
+        def weight(*shape):
+            return self.create_parameter(list(shape), attr=ParamAttr(
+                initializer=Normal(0., initializer_range)))
+        self.q_proj = weight(hidden_size, num_heads * head_dim)
+        self.k_proj = weight(hidden_size, num_kv_heads * head_dim)
+        self.v_proj = weight(hidden_size, num_kv_heads * head_dim)
+        self.o_proj = weight(num_heads * head_dim, hidden_size)
+
+    def forward(self, x, segment_ids, pre_norm=None, recompute=False):
+        H, HK, D = self.heads
+        inv_freq, factor, window = self.inv_freq, self.rope_factor, \
+            self.window
+        dtype = compute_dtype()
+
+        def fn(x, seg, wq, wk, wv, wo):
+            from ...kernels.flash_attention import flash_attention_bhld
+            B, T, _ = x.shape
+            with jax.named_scope('attn.full' if window is None
+                                 else 'attn.window'):
+                q = _mm(x, wq, dtype).reshape(B, T, H, D)
+                k = _mm(x, wk, dtype).reshape(B, T, HK, D)
+                v = _mm(x, wv, dtype).reshape(B, T, HK, D)
+                start = doc_starts(seg)
+                with jax.named_scope('attn.rope'):
+                    at = jnp.arange(T, dtype=jnp.int32)[None, :] - start
+                    angle = at.astype(jnp.float32)[..., None] * inv_freq
+                    angle = jnp.concatenate([angle, angle], -1)[:, :, None]
+                    cos, sin = (factor * jnp.cos(angle),
+                                factor * jnp.sin(angle))
+                    q = rotate_halves(q, cos, sin).astype(q.dtype)
+                    k = rotate_halves(k, cos, sin).astype(k.dtype)
+                q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
+                o = flash_attention_bhld(q, k, v, causal=True,
+                                         doc_start=start, window=window)
+                return _mm(jnp.swapaxes(o, 1, 2).reshape(B, T, H * D), wo,
+                           dtype)
+
+        run, front = pre_normed(fn, pre_norm, recompute)
+        return apply_op(run, (x,) + front + (
+            segment_ids, self.q_proj, self.k_proj, self.v_proj, self.o_proj))
 
 
 class LatentAttention(Layer):

@@ -44,34 +44,43 @@ class SwiGLU(Layer):
 
 
 class SparseMoE(Layer):
-    """One chip's share of an expert layer: sigmoid router over all
-    `num_experts` in float32, top k by score + `e_score_correction_bias` (a
-    buffer), weights renormalised and scaled; the routed sum over the experts
-    `experts_held = (lo, hi)` this chip holds, no token dropped; plus the
-    shared expert(s), which every chip of the group would compute for its own
-    tokens. `block`: rows of a tile of the routed product's row buffer
-    (None: worked out from the tokens, `functional.moe.row_tile`). `forward`
+    """One chip's share of an expert layer: a router over all
+    `num_experts` in float32, `router='sigmoid'` (top k by score +
+    `e_score_correction_bias`, a buffer; weights renormalised and scaled by
+    `scaling`) or `router='softmax'` (top k by probability, weights
+    renormalised; no bias, no buffer, no scaling); the routed sum over the
+    experts `experts_held = (lo, hi)` this chip holds, no token dropped; plus
+    the shared expert(s), where there are any, which every chip of the group
+    would compute for its own tokens. `block`: rows of a tile of the routed
+    product's row buffer (None: worked out from the tokens,
+    `functional.moe.row_tile`). `forward`
     returns (y, counters): `functional.moe.COUNTERS`; a list given as
     `selected` is handed each token's picks, sorted."""
 
     def __init__(self, hidden_size, expert_size, num_experts, top_k,
                  experts_held=None, shared_size=None, scaling=1.0,
-                 block=None, initializer_range=0.02):
+                 block=None, initializer_range=0.02, router='sigmoid'):
         super().__init__()
+        if router not in ('sigmoid', 'softmax'):
+            raise ValueError('no router %r' % (router,))
+        if router == 'softmax' and scaling != 1.0:
+            raise ValueError('the softmax router scales nothing')
         lo, hi = experts_held or (0, num_experts)
         if not 0 <= lo < hi <= num_experts:
             raise ValueError('experts_held %r is no range of %d experts'
                              % (experts_held, num_experts))
         self.experts_held, self.num_experts = (lo, hi), num_experts
         self.top_k, self.scaling = top_k, scaling
-        self.block = block
+        self.block, self.kind = block, router
 
         def weight(*shape):
             return self.create_parameter(list(shape), attr=ParamAttr(
                 initializer=Normal(0., initializer_range)))
         self.router = weight(hidden_size, num_experts)
-        self.register_buffer('e_score_correction_bias',
-                             Tensor(jnp.zeros((num_experts,), jnp.float32)))
+        if router == 'sigmoid':
+            self.register_buffer(
+                'e_score_correction_bias',
+                Tensor(jnp.zeros((num_experts,), jnp.float32)))
         held = hi - lo
         self.experts_gate = weight(held, hidden_size, expert_size)
         self.experts_up = weight(held, hidden_size, expert_size)
@@ -83,13 +92,18 @@ class SparseMoE(Layer):
         dtype = compute_dtype()
         held, experts = self.experts_held, self.num_experts
         top_k, scaling, block = self.top_k, self.scaling, self.block
+        sigmoid = self.kind == 'sigmoid'
 
-        def fn(x, router, bias, gate, up, down):
+        def fn(x, router, *rest):
+            gate, up, down = rest[-3:]
             shape = x.shape
             x = x.reshape(-1, shape[-1])
             with jax.named_scope('moe.route'):
-                idx, weights = F_moe.route_sigmoid_topk(x, router, bias,
-                                                        top_k, scaling)
+                if sigmoid:
+                    idx, weights = F_moe.route_sigmoid_topk(
+                        x, router, rest[0], top_k, scaling)
+                else:
+                    idx, weights = F_moe.route_softmax_topk(x, router, top_k)
             with jax.named_scope('moe.experts'):
                 y, counters = F_moe.expert_share(
                     x, idx, weights, gate, up, down, held, experts,
@@ -99,9 +113,10 @@ class SparseMoE(Layer):
 
         run, front = pre_normed(fn, pre_norm, recompute)
         y, counters, picks = apply_op(
-            run, (x,) + front + (
-                self.router, self.e_score_correction_bias, self.experts_gate,
-                self.experts_up, self.experts_down), n_outputs=3)
+            run, (x,) + front + (self.router,) + (
+                (self.e_score_correction_bias,) if sigmoid else ()) + (
+                self.experts_gate, self.experts_up, self.experts_down),
+            n_outputs=3)
         if selected is not None:
             selected.append(picks)
         if self.shared is not None:

@@ -1,9 +1,9 @@
-"""What the decoders (`kimi_linear`, `joyai_flash`, `olmo_hybrid`) share: the
-pre-norm residual block with a dense or a routed feed-forward, the OLMo 2/3
-block that norms BEHIND each sublayer, the next-token loss of a packed row
-taken a row at a time, and the step's counters (the expert layers' and the
-flash kernels' tile pairs) as one vector. The attention layer is the
-caller's choice.
+"""What the decoders (`kimi_linear`, `joyai_flash`, `olmo_hybrid`, `mellum`)
+share: the pre-norm residual block with a dense or a routed feed-forward, the
+OLMo 2/3 block that norms BEHIND each sublayer, the next-token loss of a
+packed row taken a row at a time, and the step's counters (the expert
+layers' and the flash kernels' tile pairs) as one vector. The attention
+layer is the caller's choice.
 """
 import jax
 import jax.numpy as jnp
@@ -48,7 +48,8 @@ class SparseDecoderBlock(nn.Layer):
     where `sparse`, else SwiGLU. `config` names the sizes (`hidden_size`,
     `intermediate_size`, `moe_intermediate_size`, `num_experts`,
     `num_experts_per_token`, `num_shared_experts`, `routed_scaling_factor`,
-    `experts_held`, `moe_block`, `rms_norm_eps`, `initializer_range`) and
+    `experts_held`, `moe_block`, `rms_norm_eps`, `initializer_range`; and
+    `router`, 'sigmoid' where the configuration names none) and
     `recompute`: each half norms inside its own traced function, which is
     then re-run in the backward pass, so the block keeps its two inputs."""
 
@@ -67,7 +68,8 @@ class SparseDecoderBlock(nn.Layer):
                 c.num_experts_per_token, experts_held=c.experts_held,
                 shared_size=c.moe_intermediate_size * c.num_shared_experts,
                 scaling=c.routed_scaling_factor, block=c.moe_block,
-                initializer_range=c.initializer_range)
+                initializer_range=c.initializer_range,
+                router=getattr(c, 'router', 'sigmoid'))
         else:
             self.mlp = nn.SwiGLU(c.hidden_size, c.intermediate_size,
                                  c.initializer_range)

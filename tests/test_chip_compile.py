@@ -132,6 +132,38 @@ def test_flash_latent_attention_shapes_compile_fwd_bwd(one_chip):
     assert fa._bwd_vmem_limit(512, 64, 512, 512, 2) is None
 
 
+def test_flash_grouped_query_shapes_compile_fwd_bwd(one_chip):
+    """Mellum's attention at its real shapes: rows of 8192, 32 query heads
+    on 4 K/V heads of 128, packed documents, with the window of 1024 and
+    without. The backward keeps a K/V head's whole dK and dV in VMEM, in
+    float32, over its 8 query heads and its K tiles: 16.8 MB more than the
+    ungrouped call asks for."""
+    rows, heads, kv_heads, seq = 2, 32, 4, 8192
+
+    def loss(window):
+        def fn(q, k, v, doc_start):
+            start = fa.row_starts(doc_start, window)
+            doc = (start,) + fa.doc_tile_bounds(start, 512, 512)
+            out = fa._flash(q, k, v, None, jnp.zeros((1, 1), jnp.int32), doc,
+                            True, 128 ** -0.5, 512, 512, 0.0, False)
+            return jnp.sum(out.astype(jnp.float32))
+        return jax.grad(fn, argnums=(0, 1, 2))
+
+    for window in (1024, None):
+        text = _compile(loss(window), one_chip,
+                        ((rows, heads, seq, 128), jnp.bfloat16),
+                        ((rows, kv_heads, seq, 128), jnp.bfloat16),
+                        ((rows, kv_heads, seq, 128), jnp.bfloat16),
+                        ((rows, seq), jnp.int32))
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        assert 'f32[8,8192,128]' in text        # dK, dV: 2 rows x 4 heads
+    plain = fa._bwd_vmem_limit(seq, 128, 512, 512, 2, 128, True)
+    grouped = fa._bwd_vmem_limit(seq, 128, 512, 512, 2, 128, True,
+                                 grouped=True)
+    assert grouped - plain == 2 * seq * 256 * 4 * 3 // 2
+    assert grouped < (128 << 20)
+
+
 def test_delta_rule_compiles_fwd_bwd_at_the_cells_shape(one_chip,
                                                         monkeypatch):
     """One row of Kimi-Linear's delta rule as the cell runs it: 8192
@@ -197,19 +229,20 @@ _W = ((HIDDEN,), jnp.bfloat16)
 _SEED = ((1, 1), jnp.int32)
 
 
-@pytest.mark.parametrize('hidden,width,held', [(2048, 768, 16),
-                                               (2304, 1024, 8)],
-                         ids=['joyai-llm-flash', 'kimi-linear'])
+@pytest.mark.parametrize('hidden,width,held,experts', [
+    (2048, 768, 16, 256), (2304, 1024, 8, 256), (2304, 896, 16, 64)],
+    ids=['joyai-llm-flash', 'kimi-linear', 'mellum2'])
 def test_grouped_matmul_compiles_fwd_bwd_at_the_cells_shapes(
-        one_chip, hidden, width, held):
-    """The routed experts' three products and their backward at the two
-    cells' shapes: 16384 tokens top 8 of 256, the larger of the two buffers
-    (four times the even share) in tiles of 256 rows, bf16 rows against
-    float32 weight stacks whose whole (K, N) matrix a group is one block in
-    VMEM."""
+        one_chip, hidden, width, held, experts):
+    """The routed experts' three products and their backward at the three
+    cells' shapes: 16384 tokens top 8 of 256 (of 64: experts of 7 x 128
+    lanes, a buffer of 131072 rows, every assignment there can be), the
+    larger of the two buffers (four times the even share) in tiles of 256
+    rows, bf16 rows against float32 weight stacks whose whole (K, N) matrix
+    a group is one block in VMEM."""
     from paddle_tpu.nn.functional import moe
-    tile = moe.row_tile(16384, 8, 256)
-    tiles = moe.buffer_tiles(16384, 8, held, 256, tile)[1]
+    tile = moe.row_tile(16384, 8, experts)
+    tiles = moe.buffer_tiles(16384, 8, held, experts, tile)[1]
 
     def loss(rows, gate, up, down, tile_group, active):
         def product(lhs, rhs, out=None):
@@ -695,3 +728,76 @@ def test_head_share_step_holds_its_kernels_and_its_layers_scopes(
     norms = [c for c in calls if c.startswith('fused_rms_norm.pallas')]
     assert sum('attn.full' in under[c] for c in norms) >= 4
     assert len(norms) >= 4 + 2 * 8 + 1
+
+
+def test_grouped_query_decoder_step_holds_its_kernels_and_its_scopes(
+        topo, monkeypatch):
+    """Mellum at a small width (heads of the real size, 128; 8 query heads
+    on 2 K/V heads; rows of 1024 under a window of 256, so that attention
+    takes the flash kernels and the window binds) through
+    `engine.build_train_step` under bf16 autocast with per-half
+    recomputation, compiled for one described chip: one period of the
+    pattern, three window layers and a full one. Flash attention, the
+    grouped product and the fused norm are the Pallas kernels under the new
+    scopes, no site took its XLA form, and K and V (dK and dV too) enter
+    and leave the flash kernels at 2 heads, never at 8."""
+    import paddle_tpu as paddle
+    from paddle_tpu import amp, engine, optimizer
+    from paddle_tpu.nn.layer_base import buffer_values, param_values
+    from paddle_tpu.observability import costs
+    from paddle_tpu.text.mellum import MellumConfig, MellumForCausalLM
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    paddle.seed(0)
+    net = MellumForCausalLM(MellumConfig(
+        vocab_size=1024, hidden_size=256, num_hidden_layers=4,
+        num_attention_heads=8, num_key_value_heads=2, head_dim=128,
+        sliding_window=256, moe_intermediate_size=128, num_experts=16,
+        num_experts_per_token=4, experts_held=(4, 8), recompute=True))
+    net.train()
+    assert not buffer_values(net)           # the softmax router has no bias
+    step = engine.build_train_step(
+        net=net, loss=net.training_loss,
+        optimizer=optimizer.AdamW(learning_rate=1e-4, weight_decay=0.1))
+    one = SingleDeviceSharding(topo.devices[0])
+    state = jax.tree_util.tree_map(
+        lambda v: jax.ShapeDtypeStruct(np.shape(v), v.dtype, sharding=one),
+        step.init_state(param_values(net), buffer_values(net)))
+    feed = tuple(jax.ShapeDtypeStruct((2, 1024), jnp.int32, sharding=one)
+                 for _ in range(3))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
+    with amp.auto_cast(dtype='bfloat16'):
+        text = step._jit.lower(state, (feed, ()), key).compile().as_text()
+    calls = _CUSTOM_CALL.findall(text)
+    under = costs.instruction_scopes(text)
+    found = {scope for scopes in under.values() for scope in scopes}
+    assert found >= {'attn.window', 'attn.full', 'attn.rope', 'moe.route',
+                     'moe.experts', 'lm_head', 'flash_attention.pallas',
+                     'fused_rms_norm.pallas', 'grouped_matmul.pallas',
+                     'update'}
+    assert not re.findall(r'[\w.]+\.xla\b', text)
+    _holds_one_routed_product(text, calls, under,
+                              costs.instruction_phases(text))
+    assert any(c.startswith('fused_rms_norm.pallas') for c in calls)
+    # four blocks, each the forward kernel, the forward again in the
+    # recomputation and the one backward kernel; three of them window layers
+    flash = [c for c in calls if c.startswith('flash_attention.pallas')]
+    assert len(flash) == 12, flash
+    assert sum('attn.window' in under[c] for c in flash) == 9
+    assert sum('attn.full' in under[c] for c in flash) == 3
+    # the rotation lies inside either kind of layer
+    turned = [set(s) for s in under.values() if 'attn.rope' in s]
+    assert any('attn.window' in s for s in turned)
+    assert any('attn.full' in s for s in turned)
+    assert all(s & {'attn.window', 'attn.full'} for s in turned)
+    # operands and results of the flash kernels by their leading size: 2 rows
+    # x 8 query heads = 16 (forward q, o; backward q, o, dO, dQ) and 2 rows x
+    # 2 K/V heads = 4 (forward k, v; backward k, v, dK, dV)
+    seen = []
+    for line in text.splitlines():
+        m = re.match(r'\s*%?(flash_attention\.pallas[\w.\-]*) = ', line)
+        if m and 'custom-call(' in line:
+            head = line.split('frontend_attributes')[0]
+            sizes = re.findall(r'(?:bf16|f32)\[(\d+),1024,128\]', head)
+            seen.append(sorted(sizes.count(n) for n in ('16', '4')))
+            assert set(sizes) == {'16', '4'}, line[:300]
+    assert sorted(seen) == [[2, 2]] * 8 + [[4, 4]] * 4, seen
