@@ -14,7 +14,11 @@ Implemented here (each with interpret-mode CPU tests):
 - the routed experts' grouped matrix product over a row buffer in tiles, one
   expert a tile: a forward kernel (also the input gradient's, on the
   transposed matrix) and the weight gradient's kernel, both skipping the
-  tiles that hold no rows (kernels/grouped_matmul.py).
+  tiles that hold no rows (kernels/grouped_matmul.py);
+- the routed experts' rows between token order and that buffer: a gather and
+  its transpose, a sum by token, which keep the token side in VMEM a column
+  chunk at a time and move one row for each row held
+  (kernels/row_permute.py).
 
 These replace the reference's hand-written CUDA/cuDNN kernels
 (paddle/fluid/operators/fused/*attention*, layer_norm_op.cu) with TPU-native

@@ -17,7 +17,12 @@ that). No token is dropped whatever the imbalance, and the work follows the
 rows held: the held assignments are sorted by expert into one row buffer,
 each expert's rows padded to whole tiles of `row_tile` rows, and ONE grouped
 product a weight stack (`kernels.grouped_matmul`) runs over the tiles that
-hold rows. The buffer is static, at one of two sizes (`buffer_tiles`: twice
+hold rows. The rows move between token order and the buffer's expert order
+inside two kernels that touch one row of the token side for each row HELD
+(`kernels.row_permute`: `gather_rows` in, `combine_rows` back, each the
+other's backward); of the XLA ops around the kernels only `silu(gate) * up`,
+the casts and the sum of the two `d rows` still run over the whole buffer.
+The buffer is static, at one of two sizes (`buffer_tiles`: twice
 and four times an even router's share, the smaller where the step's rows fit
 it); rows past the larger are the next round of the same product, in a loop
 that runs once unless the routing sends this chip more than that.
@@ -29,13 +34,17 @@ import jax
 import jax.numpy as jnp
 
 from ...kernels.grouped_matmul import grouped_matmul
+from ...kernels.row_permute import combine_rows, gather_rows
 
 __all__ = ['route_sigmoid_topk', 'route_softmax_topk', 'swiglu',
            'expert_share', 'row_tile', 'buffer_tiles', 'COUNTERS']
 
-# what `expert_share` counts, in this order
+# what `expert_share` counts, in this order (`rows_moved`: the rows that
+# `gather_rows` brought into the buffer, the valid rows of the tiles the
+# rounds took: `assignments_held - dropped`, counted where the rows move)
 COUNTERS = ('assignments_held', 'assignments', 'expert_rows_max',
-            'expert_rows_mean', 'dropped', 'rows_computed', 'rounds')
+            'expert_rows_mean', 'dropped', 'rows_computed', 'rounds',
+            'rows_moved')
 
 
 def route_sigmoid_topk(x, w_router, correction_bias, top_k, scaling):
@@ -105,9 +114,12 @@ def _round(layout, tiles, r, order, counts, x, weights, gate, up, down):
     [r tiles, (r + 1) tiles) of the sorted, padded rows -> (their part of y
     (T, H) float32, the held assignments among them, float32). `order`: the
     assignments sorted by local expert, held first; `counts` (G,): rows of
-    each held expert. A jit of its own: a step's expert layers make the
-    same call at the same shapes (every layer, forward and in the backward
-    rule), and it is traced once for all of them."""
+    each held expert. The rows come into the buffer and go back to the
+    tokens by `kernels.row_permute` (`tok`, `held`: the token of every row
+    and the rows each tile holds, its first ones). A jit of its own: a
+    step's expert layers make the same call at the same shapes (every
+    layer, forward and in the backward rule), and it is traced once for all
+    of them."""
     k, tile = layout.top_k, layout.tile
     T, G = x.shape[0], counts.shape[0]
     tiles_of = -(-counts // tile)
@@ -123,18 +135,19 @@ def _round(layout, tiles, r, order, counts, x, weights, gate, up, down):
     a = order[jnp.clip(starts[of][:, None] + rank, 0, T * k - 1)] \
         .reshape(-1)
     tok = a // k
-    w = jnp.where(valid, weights.reshape(-1)[a], 0.0)
+    # the rows a tile holds are its first ones: their count says which
+    held = jnp.sum(valid.reshape(tiles, tile), axis=1, dtype=jnp.int32)
     if layout.dtype is not None:
         x = x.astype(layout.dtype)
-    rows = jnp.where(valid[:, None], x[tok], 0)
+    rows = gather_rows(x, tok, held, interpret=layout.interpret)
     product = functools.partial(
         grouped_matmul, tile_group=of, active=active.reshape(1),
         interpret=layout.interpret)
     h = jax.nn.silu(product(rows, gate)) * product(rows, up)
     out = product(h, down, out_dtype=jnp.float32)
-    y = jnp.zeros((T, x.shape[1]), jnp.float32).at[tok].add(
-        out * w[:, None])
-    return y, jnp.sum(valid, dtype=jnp.float32)
+    y = combine_rows(out, weights.reshape(-1)[a], tok, held, T,
+                     interpret=layout.interpret)
+    return y, jnp.sum(held).astype(jnp.float32)
 
 
 def _tiles_needed(layout, counts):
@@ -146,8 +159,8 @@ def _with_room(layout, counts, rounds):
     fit it (one round, by that very test), in the larger one for
     every heavier load (round 0 and, while rows are left, the next ones):
     the same product at two static sizes, chosen by what the step's routing
-    needs (the XLA ops around the kernels run over the whole buffer, so room
-    costs time)."""
+    needs (`silu(gate) * up`, the casts and the backward's sum of the two
+    `d rows` still run over the whole buffer, so room costs time)."""
     small, large = layout.sizes
     needed = _tiles_needed(layout, counts)
 
@@ -247,5 +260,5 @@ def expert_share(x, idx, weights, gate, up, down, held, experts, *,
         n_held, jnp.asarray(T * k, f32), jnp.max(counts).astype(f32),
         n_held / G, n_held - computed,
         (_tiles_needed(layout, counts) * tile).astype(f32),
-        _rounds(layout, counts).astype(f32)])
+        _rounds(layout, counts).astype(f32), computed])
     return y, counters

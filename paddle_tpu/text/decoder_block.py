@@ -22,7 +22,8 @@ from ..observability import costs as _costs
 # for the device time a layer takes
 _costs.register_scopes('mla.attention', 'moe.route', 'moe.experts',
                        'moe.shared', 'lm_head', 'fused_rms_norm.pallas',
-                       'grouped_matmul.pallas', 'update')
+                       'grouped_matmul.pallas', 'row_permute.pallas',
+                       'update')
 
 __all__ = ['SparseDecoderBlock', 'PostNormDecoderBlock', 'packed_head_loss',
            'merge_counters', 'STEP_COUNTER_NAMES', 'STEP_COUNTER_SUMS']
@@ -38,7 +39,7 @@ STEP_COUNTER_NAMES = tuple('moe.' + name for name in COUNTERS) \
     + FLASH_COUNTERS
 STEP_COUNTER_SUMS = ('moe.assignments_held', 'moe.assignments',
                      'moe.dropped', 'moe.rows_computed',
-                     'moe.rounds') + FLASH_COUNTERS
+                     'moe.rounds', 'moe.rows_moved') + FLASH_COUNTERS
 
 
 class SparseDecoderBlock(nn.Layer):

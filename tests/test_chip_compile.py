@@ -29,6 +29,7 @@ from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import fused_dropout_norm as fdn
 from paddle_tpu.kernels import fused_norm as fn
 from paddle_tpu.kernels import grouped_matmul as gm
+from paddle_tpu.kernels import row_permute as rp
 from paddle_tpu.kernels import short_conv as sc
 from paddle_tpu.kernels._common import kernel_mesh
 
@@ -263,6 +264,42 @@ def test_grouped_matmul_compiles_fwd_bwd_at_the_cells_shapes(
     # three products forward, then d lhs and d rhs of each
     assert len(calls) == 9, calls
     assert 'ragged-dot' not in text
+
+
+@pytest.mark.parametrize('hidden,held,experts', [
+    (2048, 16, 256), (2304, 8, 256), (2304, 16, 64)],
+    ids=['joyai-llm-flash', 'kimi-linear', 'mellum2'])
+def test_row_permute_compiles_fwd_bwd_at_the_cells_shapes(
+        one_chip, hidden, held, experts):
+    """The routed experts' rows into the buffer and back at the three cells'
+    shapes (16384 tokens, the larger buffer in tiles of 256 rows, bfloat16
+    rows in, float32 rows back): the gather with its backward (the combine
+    without weights) and the combine with its backward (the gather with the
+    weights and the dots), the token side whole in VMEM a column chunk at a
+    time: 72 MiB of the chip's 128."""
+    from paddle_tpu.nn.functional import moe
+    tokens = 16384
+    tile = moe.row_tile(tokens, 8, experts)
+    tiles = moe.buffer_tiles(tokens, 8, held, experts, tile)[1]
+
+    def loss(x, out, scale, tok, held_rows):
+        rows = rp.gather_rows(x, tok, held_rows)
+        y = rp.combine_rows(out + rows.astype(jnp.float32), scale, tok,
+                            held_rows, tokens)
+        return jnp.sum(y ** 2)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, 'default_backend', lambda: 'tpu')
+        text = _compile(
+            jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+            ((tokens, hidden), jnp.bfloat16),
+            ((tiles * tile, hidden), jnp.float32),
+            ((tiles * tile,), jnp.float32),
+            ((tiles * tile,), jnp.int32), ((tiles,), jnp.int32))
+    calls = [c for c in _CUSTOM_CALL.findall(text)
+             if 'row_permute.pallas' in c]
+    # gather and combine forward, then each one's backward
+    assert len(calls) == 4, calls
+    assert not re.search(r' (scatter|gather)\(', text)
 
 
 def test_fused_layer_norm_compiles_fwd_bwd(one_chip):
@@ -529,6 +566,12 @@ def _holds_one_routed_product(text, calls, under, phases):
     assert grouped and all('moe.experts' in under[c] for c in grouped)
     assert {phases[c] for c in grouped} == {'forward', 'backward'}
     assert 'grouped_matmul.xla' not in text and 'ragged-dot' not in text
+    # the rows' way in and out: the kernels, forward and backward, and no
+    # gather or scatter of rows left beside them
+    moved = [c for c in calls if c.startswith('row_permute.pallas')]
+    assert moved and all('moe.experts' in under[c] for c in moved)
+    assert {phases[c] for c in moved} == {'forward', 'backward'}
+    assert 'row_permute.xla' not in text
     lines = {m.group(1): m.group(2) for m in re.finditer(
         r'^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([\w\-]+)\(', text, re.M)}
     kinds = {lines.get(name) for name, scopes in under.items()

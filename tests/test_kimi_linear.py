@@ -236,7 +236,7 @@ def test_no_token_is_dropped_when_every_token_goes_to_one_expert(
     assert counted(c) == {
         'assignments_held': T, 'assignments': T * k, 'expert_rows_max': T,
         'expert_rows_mean': T / G, 'dropped': 0.0, 'rows_computed': T,
-        'rounds': rounds}
+        'rounds': rounds, 'rows_moved': T}
 
 
 def _picks(T, k, experts, rows):
