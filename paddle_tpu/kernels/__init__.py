@@ -9,8 +9,11 @@ Implemented here (each with interpret-mode CPU tests):
   backward kernel that carry the state across a head's chunks in VMEM
   (kernels/delta_rule.py);
 - the short convolution in front of it: causal depthwise taps inside
-  documents, SiLU and the per-head l2norm in one pass, forward and backward
-  (kernels/short_conv.py);
+  documents, an optional bias, SiLU and the per-head l2norm in one pass,
+  forward and backward (kernels/short_conv.py);
+- Mamba-2's state-space rule chunk-wise: a forward and a backward kernel
+  that carry the stacked states of a group's heads across its chunks in VMEM
+  (kernels/ssd.py);
 - the routed experts' grouped matrix product over a row buffer in tiles, one
   expert a tile: a forward kernel (also the input gradient's, on the
   transposed matrix) and the weight gradient's kernel, both skipping the

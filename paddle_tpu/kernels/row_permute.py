@@ -342,6 +342,13 @@ def gather_rows(x, tok, held, interpret=False):
     if _in_kernels(x.shape[0], x.shape[1], tile, x.dtype, interpret):
         with took('row_permute', 'pallas'):
             return _gathered(x, tok, held, interpret)
+    if x.dtype == jnp.bfloat16 \
+            and _in_kernels(x.shape[0], x.shape[1], tile, _F32, interpret):
+        # a width whose bfloat16 pairs fill no whole registers (2688: 10.5
+        # of them): the rows move as float32 words and are cast behind
+        with took('row_permute', 'pallas'):
+            return _gathered(x.astype(_F32), tok, held,
+                             interpret).astype(x.dtype)
     with took('row_permute', 'xla'):
         return jnp.where(rows_valid(held, tile)[:, None], x[tok], 0)
 

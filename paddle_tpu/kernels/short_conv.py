@@ -95,32 +95,36 @@ def _strip(y_ref, halo_ref, w_ref, cols):
             w_ref[:, cols].astype(_F32))
 
 
-def _convolved(x, before, w, keep):
+def _convolved(x, before, w, keep, biased=False):
     """-> the convolution of a strip, and the taps it summed: tap i is the
-    rows i back, zero where the marks drop it. `causal_conv`'s order."""
-    n = w.shape[0]
+    rows i back, zero where the marks drop it. `causal_conv`'s order. Where
+    `biased`, the last row of `w` is no tap but the bias, added last."""
+    n = w.shape[0] - int(biased)
     taps = [x] + [jnp.where(keep[i - 1] != 0, _rows_back(x, before, i), 0.0)
                   for i in range(1, n)]
     u = taps[0] * w[n - 1:n]
     for i in range(1, n):
         u = u + taps[i] * w[n - 1 - i:n - i]
+    if biased:
+        u = u + w[n:n + 1]
     return u, taps
 
 
-def _fwd_kernel(y_ref, halo_ref, w_ref, bits_ref, o_ref, *, strip, l2norm):
-    keep = _keeps(bits_ref, w_ref.shape[0], strip)
+def _fwd_kernel(y_ref, halo_ref, w_ref, bits_ref, o_ref, *, strip, l2norm,
+                biased=False):
+    keep = _keeps(bits_ref, w_ref.shape[0] - int(biased), strip)
     for lo in range(0, y_ref.shape[1], strip):
         cols = slice(lo, lo + strip)
         s = jax.nn.silu(_convolved(*_strip(y_ref, halo_ref, w_ref, cols),
-                                   keep)[0])
+                                   keep, biased)[0])
         if l2norm:
             s = s * jax.lax.rsqrt(jnp.sum(s * s, -1, keepdims=True) + _EPS)
         o_ref[:, cols] = s
 
 
 def _bwd_kernel(y_ref, halo_ref, w_ref, bits_ref, do_ref, dy_ref, dw_ref,
-                carry_ref, *, strip, l2norm):
-    n = w_ref.shape[0]
+                carry_ref, *, strip, l2norm, biased=False):
+    n = w_ref.shape[0] - int(biased)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -132,7 +136,7 @@ def _bwd_kernel(y_ref, halo_ref, w_ref, bits_ref, do_ref, dy_ref, dw_ref,
     def one(x, before, w, do, after):
         """-> dy, the n rows of dw, and what the tile before this one needs
         of this one: each tap's masked gradient, first 8 rows."""
-        u, taps = _convolved(x, before, w, keep)
+        u, taps = _convolved(x, before, w, keep, biased)
         sig = jax.nn.sigmoid(u)
         ds = do
         if l2norm:
@@ -144,6 +148,8 @@ def _bwd_kernel(y_ref, halo_ref, w_ref, bits_ref, do_ref, dy_ref, dw_ref,
         dy = du * w[n - 1:n]
         dw = [jnp.sum(du * taps[n - 1 - j], 0, keepdims=True)
               for j in range(n)]
+        if biased:
+            dw.append(jnp.sum(du, 0, keepdims=True))
         first = []
         for i in range(1, n):
             z = jnp.where(keep[i - 1] != 0, du, 0.0)
@@ -193,17 +199,18 @@ def _specs(tt, wb, taps, tile_of):
 # and lowered once for all of them (as `kernels/delta_rule.py`'s are). The
 # scope is entered again inside: the compiler names a custom call after its
 # innermost scope.
-_STATIC = ('strip', 'l2norm', 'interpret')
+_STATIC = ('strip', 'l2norm', 'biased', 'interpret')
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _forward(y, w, bits, *, strip, l2norm, interpret):
+def _forward(y, w, bits, *, strip, l2norm, biased, interpret):
     B, T, W = y.shape
     tt, wb = _row_tile(T), _col_block(W, strip)
     tile, halo, taps, marks = _specs(tt, wb, w.shape[0], lambda t: t)
     with jax.named_scope('short_conv.pallas'):
         return pl.pallas_call(
-            functools.partial(_fwd_kernel, strip=strip, l2norm=l2norm),
+            functools.partial(_fwd_kernel, strip=strip, l2norm=l2norm,
+                              biased=biased),
             grid=(B, W // wb, T // tt),
             in_specs=[tile, halo, taps, marks], out_specs=tile,
             out_shape=jax.ShapeDtypeStruct(y.shape, _F32),
@@ -214,8 +221,9 @@ def _forward(y, w, bits, *, strip, l2norm, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _backward(y, w, bits, do, *, strip, l2norm, interpret):
-    """-> dy in y's dtype, dw (B, n, W) float32: a row's share of it."""
+def _backward(y, w, bits, do, *, strip, l2norm, biased, interpret):
+    """-> dy in y's dtype, dw (B, n, W) float32: a row's share of it (n:
+    the rows of `w`, the bias's among them where there is one)."""
     B, T, W = y.shape
     n, tt, wb = w.shape[0], _row_tile(T), _col_block(W, strip)
     N = T // tt
@@ -223,13 +231,14 @@ def _backward(y, w, bits, do, *, strip, l2norm, interpret):
     per_row = pl.BlockSpec((None, n, wb), lambda b, g, t: (b, 0, g))
     with jax.named_scope('short_conv.pallas'):
         return pl.pallas_call(
-            functools.partial(_bwd_kernel, strip=strip, l2norm=l2norm),
+            functools.partial(_bwd_kernel, strip=strip, l2norm=l2norm,
+                              biased=biased),
             grid=(B, W // wb, N),
             in_specs=[tile, halo, taps, marks, tile],
             out_specs=[tile, per_row],
             out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype),
                        jax.ShapeDtypeStruct((B, n, W), _F32)],
-            scratch_shapes=[pltpu.VMEM((n - 1, _EDGE, wb), _F32)],
+            scratch_shapes=[pltpu.VMEM((n - 1 - biased, _EDGE, wb), _F32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=('parallel', 'parallel', 'arbitrary')),
             interpret=interpret,
@@ -266,18 +275,22 @@ def _marks(seg, taps):
     return bits[..., None]
 
 
-def _xla(y, w, seg, head_dim):
+def _xla(y, w, seg, head_dim, bias=None):
     from ..nn.functional.delta_rule import causal_conv
     B, T, W = y.shape
-    x = jax.nn.silu(causal_conv(y.astype(_F32), w, seg))
+    x = causal_conv(y.astype(_F32), w, seg)
+    x = jax.nn.silu(x if bias is None else x + bias)
     if head_dim is not None:
         x = x.reshape(B, T, W // head_dim, head_dim)
         x = x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + _EPS)
     return x.reshape(B, T, W)
 
 
-def short_conv(y, w, seg, head_dim=None, interpret=False, norm=True):
-    """silu(causal_conv(y, w, seg)) and, where `head_dim` is given and
+def short_conv(y, w, seg, head_dim=None, interpret=False, norm=True,
+               bias=None):
+    """silu(causal_conv(y, w, seg)), with `bias` (W,) added before the SiLU
+    where one is given (the kernels take it as one more row of `w`, and its
+    gradient as that row's), and, where `head_dim` is given and
     `norm` is left on, each head of `head_dim` channels scaled to unit
     length (`x * rsqrt(sum(x*x) + 1e-6)`): y (B, T, W) of any float dtype,
     w (n, W), seg (B, T) -> (B, T, W) float32. A `head_dim` with `norm` off
@@ -296,7 +309,10 @@ def short_conv(y, w, seg, head_dim=None, interpret=False, norm=True):
             and lanes is not None and W % (head_dim or 128) == 0
             and 2 <= taps <= _EDGE + 1):
         with took('short_conv', 'xla'):
-            return _xla(y, w, seg, head_dim if normed else None)
+            return _xla(y, w, seg, head_dim if normed else None, bias)
+    if bias is not None:
+        w = jnp.concatenate([w, bias[None].astype(w.dtype)], axis=0)
+    rows = w.shape[0]               # the taps, and the bias behind them
     heads = W // (head_dim or 128)
     wide = heads * lanes            # the width with every head on its lanes
 
@@ -306,8 +322,9 @@ def short_conv(y, w, seg, head_dim=None, interpret=False, norm=True):
 
     def call(y, w, bits, shard):
         b, _, h, _ = y.shape                # this device's rows and strips
-        return _conv(y.reshape(b, T, h * strip), w.reshape(taps, h * strip),
-                     bits, tuple(zip(_STATIC, (strip, normed, interpret)))
+        return _conv(y.reshape(b, T, h * strip), w.reshape(rows, h * strip),
+                     bits, tuple(zip(_STATIC, (
+                         strip, normed, bias is not None, interpret)))
                      ).reshape(b, T, h, strip)
 
     dims = ('b', None, 'h', None)

@@ -48,7 +48,8 @@ from .layer.distance import PairwiseDistance
 from .layer.linear_attention import (CausalSelfAttention, GatedDeltaNet,
                                      GroupedQueryAttention,
                                      KimiDeltaAttention, LatentAttention)
-from .layer.moe import SwiGLU, SparseMoE
+from .layer.moe import SwiGLU, SquaredReLU, SparseMoE
+from .layer.state_space import Mamba2
 from .utils import weight_norm, remove_weight_norm, spectral_norm
 
 # -- 2.0-beta top-level nn surface tail --------------------------------------

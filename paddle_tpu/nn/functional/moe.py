@@ -37,7 +37,7 @@ from ...kernels.grouped_matmul import grouped_matmul
 from ...kernels.row_permute import combine_rows, gather_rows
 
 __all__ = ['route_sigmoid_topk', 'route_softmax_topk', 'swiglu',
-           'expert_share', 'row_tile', 'buffer_tiles', 'COUNTERS']
+           'relu2_mlp', 'expert_share', 'row_tile', 'buffer_tiles', 'COUNTERS']
 
 # what `expert_share` counts, in this order (`rows_moved`: the rows that
 # `gather_rows` brought into the buffer, the valid rows of the tiles the
@@ -82,6 +82,13 @@ def swiglu(x, gate, up, down, dtype=None):
     return jnp.matmul(h, down)
 
 
+def relu2_mlp(x, up, down, dtype=None):
+    """down(relu(up x)^2): the ungated feed-forward; weights (in, out)."""
+    if dtype is not None:
+        x, up, down = (t.astype(dtype) for t in (x, up, down))
+    return jnp.matmul(jnp.square(jax.nn.relu(jnp.matmul(x, up))), down)
+
+
 def row_tile(tokens, top_k, experts):
     """Rows of a tile of the row buffer: half the rows an even router sends
     one expert, as a power of two from 8 to 256. An expert's last tile is
@@ -109,17 +116,22 @@ _Layout = collections.namedtuple(
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
-def _round(layout, tiles, r, order, counts, x, weights, gate, up, down):
+def _round(layout, tiles, r, order, counts, x, weights, *experts):
     """Round r of the routed product in a buffer of `tiles` tiles: tiles
     [r tiles, (r + 1) tiles) of the sorted, padded rows -> (their part of y
     (T, H) float32, the held assignments among them, float32). `order`: the
     assignments sorted by local expert, held first; `counts` (G,): rows of
     each held expert. The rows come into the buffer and go back to the
     tokens by `kernels.row_permute` (`tok`, `held`: the token of every row
-    and the rows each tile holds, its first ones). A jit of its own: a
-    step's expert layers make the same call at the same shapes (every
-    layer, forward and in the backward rule), and it is traced once for all
-    of them."""
+    and the rows each tile holds, its first ones). `experts`: the held
+    experts' matrices, (gate, up, down) of `down(silu(gate x) * up x)` or
+    (up, down) of the ungated `down(relu(up x)^2)`; a width that is not
+    whole 128-lane registers (1856) is laid on them behind zero columns of
+    `gate` / `up` and zero rows of `down`, which is exact (silu(0) * 0 and
+    relu(0)^2 are 0 and meet zero rows) and keeps the product on the
+    kernels. A jit of its own: a step's expert layers make the same call at
+    the same shapes (every layer, forward and in the backward rule), and it
+    is traced once for all of them."""
     k, tile = layout.top_k, layout.tile
     T, G = x.shape[0], counts.shape[0]
     tiles_of = -(-counts // tile)
@@ -143,7 +155,17 @@ def _round(layout, tiles, r, order, counts, x, weights, gate, up, down):
     product = functools.partial(
         grouped_matmul, tile_group=of, active=active.reshape(1),
         interpret=layout.interpret)
-    h = jax.nn.silu(product(rows, gate)) * product(rows, up)
+    *into, down = experts
+    short = -down.shape[1] % 128
+    if short:
+        into = [jnp.pad(w, ((0, 0), (0, 0), (0, short))) for w in into]
+        down = jnp.pad(down, ((0, 0), (0, short), (0, 0)))
+    # graftlint: disable=GL006 — the number of matrices handed over (a
+    # Python tuple's length, never a tracer): the gated form or the ungated
+    if len(into) == 2:
+        h = jax.nn.silu(product(rows, into[0])) * product(rows, into[1])
+    else:
+        h = jnp.square(jax.nn.relu(product(rows, into[0])))
     out = product(h, down, out_dtype=jnp.float32)
     y = combine_rows(out, weights.reshape(-1)[a], tok, held, T,
                      interpret=layout.interpret)
@@ -194,7 +216,7 @@ def _sum_of_rounds(one, trips):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _routed(layout, order, counts, x, weights, gate, up, down):
+def _routed(layout, order, counts, x, weights, *experts):
     """Every round's part of y, summed: round 0 always, the next ones while
     rows are left -> (y, the held assignments computed). jax cannot
     differentiate a loop whose trip count is a value, hence the rule below:
@@ -203,8 +225,8 @@ def _routed(layout, order, counts, x, weights, gate, up, down):
     block that recomputes, as the cells' do, drops this function's forward
     from its second pass, so the count of products is the same)."""
     return _with_room(layout, counts, lambda tiles, trips: _sum_of_rounds(
-        lambda r: _round(layout, tiles, r, order, counts, x, weights, gate,
-                         up, down), trips))
+        lambda r: _round(layout, tiles, r, order, counts, x, weights,
+                         *experts), trips))
 
 
 def _routed_fwd(layout, order, counts, *operands):
@@ -231,8 +253,10 @@ def expert_share(x, idx, weights, gate, up, down, held, experts, *,
     """This chip's part of the routed sum.
 
     x (T, H); idx, weights (T, k) from the router (global expert numbers);
-    gate, up (G, H, F), down (G, F, H): the G = hi - lo experts held;
-    held = (lo, hi) of the `experts` the router scores.
+    gate, up (G, H, F), down (G, F, H): the G = hi - lo experts held,
+    `down(silu(gate x) * up x)`, or with `gate=None` the ungated
+    `down(relu(up x)^2)`; held = (lo, hi) of the `experts` the router
+    scores.
     -> (y (T, H) float32, counters (len(COUNTERS),) float32).
     `tile`: rows of a tile of the row buffer (None: `row_tile`); the buffer
     is one of `buffer_tiles`' two sizes. `dtype`: the products' operand type
@@ -242,9 +266,9 @@ def expert_share(x, idx, weights, gate, up, down, held, experts, *,
     T, k = idx.shape
     lo, hi = held
     G = hi - lo
-    if gate.shape[0] != G:
+    if up.shape[0] != G:
         raise ValueError('expert_share: holds %d experts, was told %r'
-                         % (gate.shape[0], held))
+                         % (up.shape[0], held))
     tile = tile or row_tile(T, k, experts)
     layout = _Layout(k, tile, buffer_tiles(T, k, G, experts, tile), dtype,
                      interpret)
@@ -253,7 +277,8 @@ def expert_share(x, idx, weights, gate, up, down, held, experts, *,
     counts = jnp.sum(local[:, None] == jnp.arange(G)[None, :], axis=0,
                      dtype=jnp.int32)                          # (G,)
     order = jnp.argsort(local, stable=True)           # held first, by expert
-    y, computed = _routed(layout, order, counts, x, weights, gate, up, down)
+    y, computed = _routed(layout, order, counts, x, weights,
+                          *((up, down) if gate is None else (gate, up, down)))
     f32 = jnp.float32
     n_held = jnp.sum(counts).astype(f32)
     counters = jnp.stack([

@@ -252,7 +252,9 @@ class GroupedQueryAttention(Layer):
 
     The rotary table is DATA: `inv_freq` (head_dim / 2,) and `rope_factor`,
     which multiplies cos and sin (YaRN's attention factor; the scores take
-    its square). A plain table and a YaRN table are one code path. The layer
+    its square). A plain table and a YaRN table are one code path; with
+    `inv_freq=None` nothing is rotated and no `attn.rope` scope is entered
+    (a decoder whose state-space layers carry the order). The layer
     runs under the scope `attn.window` with a window, else `attn.full`; k
     and v go to the flash kernels at their own head count."""
 
@@ -264,8 +266,9 @@ class GroupedQueryAttention(Layer):
             raise ValueError('%d query heads do not group over %d K/V heads'
                              % (num_heads, num_kv_heads))
         self.heads = (num_heads, num_kv_heads, head_dim)
-        self.inv_freq = np.asarray(inv_freq, np.float32)
-        if self.inv_freq.shape != (head_dim // 2,):
+        self.inv_freq = None if inv_freq is None \
+            else np.asarray(inv_freq, np.float32)
+        if inv_freq is not None and self.inv_freq.shape != (head_dim // 2,):
             raise ValueError('inv_freq %r is no table of a %d-wide head'
                              % (self.inv_freq.shape, head_dim))
         self.rope_factor, self.window = float(rope_factor), window
@@ -293,14 +296,16 @@ class GroupedQueryAttention(Layer):
                 k = _mm(x, wk, dtype).reshape(B, T, HK, D)
                 v = _mm(x, wv, dtype).reshape(B, T, HK, D)
                 start = doc_starts(seg)
-                with jax.named_scope('attn.rope'):
-                    at = jnp.arange(T, dtype=jnp.int32)[None, :] - start
-                    angle = at.astype(jnp.float32)[..., None] * inv_freq
-                    angle = jnp.concatenate([angle, angle], -1)[:, :, None]
-                    cos, sin = (factor * jnp.cos(angle),
-                                factor * jnp.sin(angle))
-                    q = rotate_halves(q, cos, sin).astype(q.dtype)
-                    k = rotate_halves(k, cos, sin).astype(k.dtype)
+                if inv_freq is not None:
+                    with jax.named_scope('attn.rope'):
+                        at = jnp.arange(T, dtype=jnp.int32)[None, :] - start
+                        angle = at.astype(jnp.float32)[..., None] * inv_freq
+                        angle = jnp.concatenate([angle, angle],
+                                                -1)[:, :, None]
+                        cos, sin = (factor * jnp.cos(angle),
+                                    factor * jnp.sin(angle))
+                        q = rotate_halves(q, cos, sin).astype(q.dtype)
+                        k = rotate_halves(k, cos, sin).astype(k.dtype)
                 q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
                 o = flash_attention_bhld(q, k, v, causal=True,
                                          doc_start=start, window=window)

@@ -1,6 +1,8 @@
-"""The feed-forward layers of the sparse decoders: a gated MLP (SwiGLU) and
-one chip's share of a routed expert layer (`functional.moe`,
-docs/EXPERT_LAYER.md)."""
+"""The feed-forward layers of the sparse decoders: a gated MLP (SwiGLU), an
+ungated one (squared ReLU) and one chip's share of a routed expert layer of
+either form (`functional.moe`, docs/EXPERT_LAYER.md)."""
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -10,7 +12,12 @@ from ..layer_base import Layer
 from ..functional import moe as F_moe
 from .linear_attention import compute_dtype, pre_normed
 
-__all__ = ['SwiGLU', 'SparseMoE']
+__all__ = ['SwiGLU', 'SquaredReLU', 'SparseMoE']
+
+
+def _under(scope):
+    """The named scope a feed-forward runs under, where it was given one."""
+    return jax.named_scope(scope) if scope else contextlib.nullcontext()
 
 
 class SwiGLU(Layer):
@@ -34,13 +41,36 @@ class SwiGLU(Layer):
         dtype, scope = compute_dtype(), self.scope
 
         def fn(x, gate, up, down):
-            if scope is None:
-                return F_moe.swiglu(x, gate, up, down, dtype)
-            with jax.named_scope(scope):
+            with _under(scope):
                 return F_moe.swiglu(x, gate, up, down, dtype)
         run, front = pre_normed(fn, pre_norm, recompute)
         return apply_op(run, (x,) + front + (self.gate_proj, self.up_proj,
                                              self.down_proj))
+
+
+class SquaredReLU(Layer):
+    """down(relu(up x)^2), no biases, no gate matrix."""
+
+    def __init__(self, hidden_size, intermediate_size,
+                 initializer_range=0.02, scope=None):
+        super().__init__()
+        self.scope = scope
+
+        def weight(*shape):
+            return self.create_parameter(list(shape), attr=ParamAttr(
+                initializer=Normal(0., initializer_range)))
+        self.up_proj = weight(hidden_size, intermediate_size)
+        self.down_proj = weight(intermediate_size, hidden_size)
+
+    def forward(self, x, pre_norm=None, recompute=False):
+        """As `SwiGLU.forward`."""
+        dtype, scope = compute_dtype(), self.scope
+
+        def fn(x, up, down):
+            with _under(scope):
+                return F_moe.relu2_mlp(x, up, down, dtype)
+        run, front = pre_normed(fn, pre_norm, recompute)
+        return apply_op(run, (x,) + front + (self.up_proj, self.down_proj))
 
 
 class SparseMoE(Layer):
@@ -51,7 +81,10 @@ class SparseMoE(Layer):
     renormalised; no bias, no buffer, no scaling); the routed sum over the
     experts `experts_held = (lo, hi)` this chip holds, no token dropped; plus
     the shared expert(s), where there are any, which every chip of the group
-    would compute for its own tokens. `block`: rows of a tile of the routed
+    would compute for its own tokens. `activation`: 'silu', experts and
+    shared expert `down(silu(gate x) * up x)`, or 'relu2', the ungated
+    `down(relu(up x)^2)`, which has no `experts_gate`.
+    `block`: rows of a tile of the routed
     product's row buffer (None: worked out from the tokens,
     `functional.moe.row_tile`). `forward`
     returns (y, counters): `functional.moe.COUNTERS`; a list given as
@@ -59,10 +92,13 @@ class SparseMoE(Layer):
 
     def __init__(self, hidden_size, expert_size, num_experts, top_k,
                  experts_held=None, shared_size=None, scaling=1.0,
-                 block=None, initializer_range=0.02, router='sigmoid'):
+                 block=None, initializer_range=0.02, router='sigmoid',
+                 activation='silu'):
         super().__init__()
         if router not in ('sigmoid', 'softmax'):
             raise ValueError('no router %r' % (router,))
+        if activation not in ('silu', 'relu2'):
+            raise ValueError('no expert activation %r' % (activation,))
         if router == 'softmax' and scaling != 1.0:
             raise ValueError('the softmax router scales nothing')
         lo, hi = experts_held or (0, num_experts)
@@ -72,6 +108,7 @@ class SparseMoE(Layer):
         self.experts_held, self.num_experts = (lo, hi), num_experts
         self.top_k, self.scaling = top_k, scaling
         self.block, self.kind = block, router
+        self.gated = activation == 'silu'
 
         def weight(*shape):
             return self.create_parameter(list(shape), attr=ParamAttr(
@@ -82,20 +119,22 @@ class SparseMoE(Layer):
                 'e_score_correction_bias',
                 Tensor(jnp.zeros((num_experts,), jnp.float32)))
         held = hi - lo
-        self.experts_gate = weight(held, hidden_size, expert_size)
+        if self.gated:
+            self.experts_gate = weight(held, hidden_size, expert_size)
         self.experts_up = weight(held, hidden_size, expert_size)
         self.experts_down = weight(held, expert_size, hidden_size)
-        self.shared = SwiGLU(hidden_size, shared_size, initializer_range,
-                             scope='moe.shared') if shared_size else None
+        self.shared = (SwiGLU if self.gated else SquaredReLU)(
+            hidden_size, shared_size, initializer_range,
+            scope='moe.shared') if shared_size else None
 
     def forward(self, x, selected=None, pre_norm=None, recompute=False):
         dtype = compute_dtype()
         held, experts = self.experts_held, self.num_experts
         top_k, scaling, block = self.top_k, self.scaling, self.block
-        sigmoid = self.kind == 'sigmoid'
+        sigmoid, gated = self.kind == 'sigmoid', self.gated
 
         def fn(x, router, *rest):
-            gate, up, down = rest[-3:]
+            gate, up, down = rest[-3:] if gated else (None,) + rest[-2:]
             shape = x.shape
             x = x.reshape(-1, shape[-1])
             with jax.named_scope('moe.route'):
@@ -115,7 +154,8 @@ class SparseMoE(Layer):
         y, counters, picks = apply_op(
             run, (x,) + front + (self.router,) + (
                 (self.e_score_correction_bias,) if sigmoid else ()) + (
-                self.experts_gate, self.experts_up, self.experts_down),
+                ((self.experts_gate,) if gated else ())
+                + (self.experts_up, self.experts_down)),
             n_outputs=3)
         if selected is not None:
             selected.append(picks)

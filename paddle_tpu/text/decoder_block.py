@@ -1,9 +1,9 @@
-"""What the decoders (`kimi_linear`, `joyai_flash`, `olmo_hybrid`, `mellum`)
-share: the pre-norm residual block with a dense or a routed feed-forward, the
-OLMo 2/3 block that norms BEHIND each sublayer, the next-token loss of a
-packed row taken a row at a time, and the step's counters (the expert
-layers' and the flash kernels' tile pairs) as one vector. The attention
-layer is the caller's choice.
+"""What the decoders (`kimi_linear`, `joyai_flash`, `olmo_hybrid`, `mellum`,
+`nemotron_h`) share: the pre-norm residual block with a dense or a routed
+feed-forward, the OLMo 2/3 block that norms BEHIND each sublayer, the block
+of ONE sublayer, the next-token loss of a packed row taken a row at a time,
+and the step's counters (the expert layers' and the flash kernels' tile
+pairs) as one vector. The attention layer is the caller's choice.
 """
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,8 @@ _costs.register_scopes('mla.attention', 'moe.route', 'moe.experts',
                        'grouped_matmul.pallas', 'row_permute.pallas',
                        'update')
 
-__all__ = ['SparseDecoderBlock', 'PostNormDecoderBlock', 'packed_head_loss',
+__all__ = ['SparseDecoderBlock', 'PostNormDecoderBlock',
+           'SingleMixerBlock', 'packed_head_loss',
            'merge_counters', 'STEP_COUNTER_NAMES', 'STEP_COUNTER_SUMS']
 
 # what a sparse decoder's `forward` returns beside its loss, as
@@ -124,6 +125,31 @@ class PostNormDecoderBlock(nn.Layer):
         y = apply_op(run, (x,) + behind + (
             self.mlp.gate_proj, self.mlp.up_proj, self.mlp.down_proj))
         return x + y.astype('float32')
+
+
+class SingleMixerBlock(nn.Layer):
+    """`x += mixer(RMSNorm(x))`: a layer of ONE sublayer (the Nemotron-H
+    decoders') -> (x, expert counters). `mixer` is a layer that takes
+    `(x, segment_ids, pre_norm, recompute)`, or, where `sparse`, an
+    `nn.SparseMoE`, whose counters the block hands on (zeros otherwise).
+    `config` names `hidden_size`, `rms_norm_eps` and `recompute`: the mixer
+    norms inside its own traced function, which is then re-run in the
+    backward pass, so the block keeps its one input."""
+
+    def __init__(self, config, mixer, sparse=False):
+        super().__init__()
+        self.norm = nn.RMSNorm(config.hidden_size,
+                               epsilon=config.rms_norm_eps)
+        self.mixer, self.sparse = mixer, sparse
+        self.recompute = config.recompute
+
+    def forward(self, x, segment_ids, selected=None):
+        if self.sparse:
+            y, counters = self.mixer(x, selected, self.norm, self.recompute)
+        else:
+            y = self.mixer(x, segment_ids, self.norm, self.recompute)
+            counters = Tensor(jnp.zeros((len(COUNTERS),), jnp.float32))
+        return x + y.astype('float32'), counters
 
 
 def packed_head_loss(x, labels, head):

@@ -31,6 +31,7 @@ from paddle_tpu.kernels import fused_norm as fn
 from paddle_tpu.kernels import grouped_matmul as gm
 from paddle_tpu.kernels import row_permute as rp
 from paddle_tpu.kernels import short_conv as sc
+from paddle_tpu.kernels import ssd as ssd_kernels
 from paddle_tpu.kernels._common import kernel_mesh
 
 B, H, D = 8, 16, 64            # BERT-large attention: 16 heads of 64
@@ -163,6 +164,16 @@ def test_flash_grouped_query_shapes_compile_fwd_bwd(one_chip):
                                  grouped=True)
     assert grouped - plain == 2 * seq * 256 * 4 * 3 // 2
     assert grouped < (128 << 20)
+    # the Nemotron-H attention layer: 32 query heads on TWO K/V heads, no
+    # window; a group of 16 asks for no more VMEM than a group of 8 (a K/V
+    # head's dK and dV stay over however many query heads share it)
+    text = _compile(loss(None), one_chip,
+                    ((rows, heads, seq, 128), jnp.bfloat16),
+                    ((rows, 2, seq, 128), jnp.bfloat16),
+                    ((rows, 2, seq, 128), jnp.bfloat16),
+                    ((rows, seq), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert 'f32[4,8192,128]' in text            # dK, dV: 2 rows x 2 heads
 
 
 def test_delta_rule_compiles_fwd_bwd_at_the_cells_shape(one_chip,
@@ -225,14 +236,65 @@ def test_short_conv_compiles_fwd_bwd_at_the_cells_shape(one_chip,
     assert 'short_conv.pallas' in text and 'short_conv.xla' not in text
 
 
+def test_ssd_compiles_fwd_bwd_at_the_cells_shape(one_chip, monkeypatch):
+    """One row of a Mamba-2 layer's rule as the Nemotron-H cell runs it: 8192
+    tokens in chunks of 128, 64 heads of 64 in 8 groups, a state of 128,
+    float32 operands with bfloat16 in the products. The single-lane slices
+    of the column pack spread over a tile, the 0/1 products at `HIGHEST`,
+    the `(C, 2C) x (2C, 128)` product of a register of heads and the
+    `a^T b` state update all have to lower: one custom call forward, two
+    (the forward that saves every chunk's start state, the backward) under
+    `jax.grad`."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    T, heads, width, groups, state = 8192, 64, 64, 8, 128
+
+    def forward(x, dt, A, Bm, Cm, D, seg):
+        return ssd_kernels.ssd(x, dt, A, Bm, Cm, D, seg, chunk=128,
+                               dtype=jnp.bfloat16)
+
+    def loss(*args):
+        return jnp.sum(jnp.sin(forward(*args)))
+
+    shared = ((1, T, groups, state), jnp.float32)
+    shapes = (((1, T, heads, width), jnp.float32),
+              ((1, T, heads), jnp.float32), ((heads,), jnp.float32),
+              shared, shared, ((heads,), jnp.float32), ((1, T), jnp.int32))
+    text = _compile(forward, one_chip, *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    text = _compile(jax.grad(loss, argnums=tuple(range(6))), one_chip,
+                    *shapes)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert 'ssd.pallas' in text and 'ssd.xla' not in text
+    assert 'f32[1,8,64,512,128]' in text    # the chunks' start states
+
+
+def test_short_conv_with_a_bias_compiles_fwd_bwd(one_chip, monkeypatch):
+    """The convolution in front of the Mamba-2 rule, on B's (or C's) 1024
+    columns of a row of 8192, with the bias as a fifth row of the taps and
+    its gradient as that row's."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    T, width = 8192, 1024
+
+    def loss(y, w, b, seg):
+        return jnp.sum(jnp.sin(sc.short_conv(y, w, seg, bias=b)))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    ((1, T, width), jnp.bfloat16), ((4, width), jnp.float32),
+                    ((width,), jnp.float32), ((1, T), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert 'short_conv.pallas' in text and 'short_conv.xla' not in text
+    assert 'f32[1,5,1024]' in text          # the taps' and the bias's rows
+
+
 _X = ((ROWS, HIDDEN), jnp.bfloat16)
 _W = ((HIDDEN,), jnp.bfloat16)
 _SEED = ((1, 1), jnp.int32)
 
 
 @pytest.mark.parametrize('hidden,width,held,experts', [
-    (2048, 768, 16, 256), (2304, 1024, 8, 256), (2304, 896, 16, 64)],
-    ids=['joyai-llm-flash', 'kimi-linear', 'mellum2'])
+    (2048, 768, 16, 256), (2304, 1024, 8, 256), (2304, 896, 16, 64),
+    (2688, 1920, 8, 128)],
+    ids=['joyai-llm-flash', 'kimi-linear', 'mellum2', 'nemotron-h'])
 def test_grouped_matmul_compiles_fwd_bwd_at_the_cells_shapes(
         one_chip, hidden, width, held, experts):
     """The routed experts' three products and their backward at the three
@@ -240,7 +302,9 @@ def test_grouped_matmul_compiles_fwd_bwd_at_the_cells_shapes(
     lanes, a buffer of 131072 rows, every assignment there can be), the
     larger of the two buffers (four times the even share) in tiles of 256
     rows, bf16 rows against float32 weight stacks whose whole (K, N) matrix
-    a group is one block in VMEM."""
+    a group is one block in VMEM. The Nemotron-H experts' 1856 columns come
+    laid on 1920 (15 registers) behind zeros, as `moe._round` hands them
+    over (their rows are a sixth of the others': 8 held of 128, top 6)."""
     from paddle_tpu.nn.functional import moe
     tile = moe.row_tile(16384, 8, experts)
     tiles = moe.buffer_tiles(16384, 8, held, experts, tile)[1]
@@ -267,8 +331,8 @@ def test_grouped_matmul_compiles_fwd_bwd_at_the_cells_shapes(
 
 
 @pytest.mark.parametrize('hidden,held,experts', [
-    (2048, 16, 256), (2304, 8, 256), (2304, 16, 64)],
-    ids=['joyai-llm-flash', 'kimi-linear', 'mellum2'])
+    (2048, 16, 256), (2304, 8, 256), (2304, 16, 64), (2688, 8, 128)],
+    ids=['joyai-llm-flash', 'kimi-linear', 'mellum2', 'nemotron-h'])
 def test_row_permute_compiles_fwd_bwd_at_the_cells_shapes(
         one_chip, hidden, held, experts):
     """The routed experts' rows into the buffer and back at the three cells'
@@ -276,7 +340,9 @@ def test_row_permute_compiles_fwd_bwd_at_the_cells_shapes(
     rows in, float32 rows back): the gather with its backward (the combine
     without weights) and the combine with its backward (the gather with the
     weights and the dots), the token side whole in VMEM a column chunk at a
-    time: 72 MiB of the chip's 128."""
+    time: 72 MiB of the chip's 128. A hidden size of 2688 is 10.5 registers
+    of bfloat16 pairs: its rows are gathered as float32 words (three column
+    chunks of 7 registers), still by the kernels."""
     from paddle_tpu.nn.functional import moe
     tokens = 16384
     tile = moe.row_tile(tokens, 8, experts)
@@ -844,3 +910,79 @@ def test_grouped_query_decoder_step_holds_its_kernels_and_its_scopes(
             seen.append(sorted(sizes.count(n) for n in ('16', '4')))
             assert set(sizes) == {'16', '4'}, line[:300]
     assert sorted(seen) == [[2, 2]] * 8 + [[4, 4]] * 4, seen
+
+
+def test_state_space_decoder_step_holds_its_kernels_and_its_scopes(
+        topo, monkeypatch):
+    """The Nemotron-H decoder at a small hidden size with the REAL inner
+    sizes (Mamba-2 heads of 64 with 8 to a group and a state of 128; 32
+    query heads on 2 K/V heads of 128; experts of 1856 columns and a shared
+    one of 3712; two rows of 1024 so that attention takes the flash kernels)
+    through `engine.build_train_step` under bf16 autocast with
+    recomputation, compiled for one described chip: one layer of each
+    letter, M E *. The rule is the `ssd.pallas` kernels under `ssm.scan`,
+    the three convolutions the short-convolution kernels under `ssm.conv`
+    inside `ssm.proj`, the experts' 1856 columns go through the grouped
+    product's kernels, no site took its XLA form, nothing is rotated, and K
+    and V (dK and dV too) enter and leave the flash kernels at 2 heads."""
+    import paddle_tpu as paddle
+    from paddle_tpu import amp, engine, optimizer
+    from paddle_tpu.nn.layer_base import buffer_values, param_values
+    from paddle_tpu.observability import costs
+    from paddle_tpu.text.nemotron_h import (NemotronHConfig,
+                                            NemotronHForCausalLM)
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    paddle.seed(0)
+    net = NemotronHForCausalLM(NemotronHConfig(
+        vocab_size=1024, hidden_size=256, num_hidden_layers=3,
+        hybrid_override_pattern='ME*', mamba_num_heads=16, n_groups=2,
+        n_routed_experts=16, num_experts_per_tok=4, experts_held=(4, 8),
+        recompute=True))
+    net.train()
+    assert sorted(buffer_values(net)) == [
+        'layers.1.mixer.e_score_correction_bias']
+    step = engine.build_train_step(
+        net=net, loss=net.training_loss,
+        optimizer=optimizer.AdamW(learning_rate=1e-4, weight_decay=0.1))
+    one = SingleDeviceSharding(topo.devices[0])
+    state = jax.tree_util.tree_map(
+        lambda v: jax.ShapeDtypeStruct(np.shape(v), v.dtype, sharding=one),
+        step.init_state(param_values(net), buffer_values(net)))
+    feed = tuple(jax.ShapeDtypeStruct((2, 1024), jnp.int32, sharding=one)
+                 for _ in range(3))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
+    with amp.auto_cast(dtype='bfloat16'):
+        text = step._jit.lower(state, (feed, ()), key).compile().as_text()
+    calls = _CUSTOM_CALL.findall(text)
+    under = costs.instruction_scopes(text)
+    found = {scope for scopes in under.values() for scope in scopes}
+    assert found >= {'ssm.proj', 'ssm.conv', 'ssm.scan', 'ssd.pallas',
+                     'short_conv.pallas', 'attn.full', 'moe.route',
+                     'moe.experts', 'moe.shared', 'lm_head',
+                     'flash_attention.pallas', 'fused_rms_norm.pallas',
+                     'grouped_matmul.pallas', 'row_permute.pallas', 'update'}
+    assert 'attn.rope' not in found
+    assert not re.findall(r'[\w.]+\.xla\b', text)
+    _holds_one_routed_product(text, calls, under,
+                              costs.instruction_phases(text))
+    # one Mamba-2 layer, its two rows taken one at a time inside a loop: the
+    # forward kernel, the forward again in the recomputation (it saves the
+    # chunks' start states) and the backward kernel; x, B and C as many
+    # convolutions each
+    scan = [c for c in calls if c.startswith('ssd.pallas')]
+    assert len(scan) == 3, scan
+    assert all('ssm.scan' in under[c] for c in scan)
+    short = [c for c in calls if c.startswith('short_conv.pallas')]
+    assert len(short) == 9, short
+    assert all({'ssm.proj', 'ssm.conv'} <= set(under[c]) for c in short)
+    flash = [c for c in calls if c.startswith('flash_attention.pallas')]
+    assert len(flash) == 3, flash
+    assert all('attn.full' in under[c] for c in flash)
+    # operands and results of the flash kernels by their leading size: 2 rows
+    # x 32 query heads = 64 and 2 rows x 2 K/V heads = 4
+    for line in text.splitlines():
+        m = re.match(r'\s*%?(flash_attention\.pallas[\w.\-]*) = ', line)
+        if m and 'custom-call(' in line:
+            head = line.split('frontend_attributes')[0]
+            sizes = re.findall(r'(?:bf16|f32)\[(\d+),1024,128\]', head)
+            assert set(sizes) == {'64', '4'}, line[:300]
