@@ -154,7 +154,7 @@ def phase_kernels(size, rehearsal):
     errs = checks.check_flash_against_reference(size['kernel_shape'],
                                                 interpret=rehearsal)
     dropout_backward = delta_rule_backward = short_conv_backward = None
-    packed = None
+    packed = rotary = None
     if not rehearsal:   # interpret mode has no hardware PRNG (and takes
         checks.check_flash_dropout()    # a minute over the delta rule)
         checks.check_norm_dropout()
@@ -162,11 +162,12 @@ def phase_kernels(size, rehearsal):
         packed = checks.check_flash_packed()
         delta_rule_backward = checks.check_delta_rule_backward()
         short_conv_backward = checks.check_short_conv_backward()
+        rotary = checks.check_rotary()
     say('kernels', shape=list(size['kernel_shape']), max_abs_err=errs,
         dropout_checked=not rehearsal, dropout_backward=dropout_backward,
         packed_rel_err=packed,
         delta_rule_backward=delta_rule_backward,
-        short_conv_backward=short_conv_backward,
+        short_conv_backward=short_conv_backward, rotary_rel_err=rotary,
         seconds=round(time.perf_counter() - t0, 2))
 
 

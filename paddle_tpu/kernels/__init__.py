@@ -21,7 +21,11 @@ Implemented here (each with interpret-mode CPU tests):
 - the routed experts' rows between token order and that buffer: a gather and
   its transpose, a sum by token, which keep the token side in VMEM a column
   chunk at a time and move one row for each row held
-  (kernels/row_permute.py).
+  (kernels/row_permute.py);
+- the rotary position encoding of q and k in one pass: bfloat16 read once in
+  the projections' layout, turned in float32 in VMEM, written once in the
+  flash kernels' layout; the backward the same kernel with the sine negated
+  (kernels/rotary.py).
 
 These replace the reference's hand-written CUDA/cuDNN kernels
 (paddle/fluid/operators/fused/*attention*, layer_norm_op.cu) with TPU-native

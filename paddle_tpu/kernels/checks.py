@@ -314,6 +314,63 @@ def check_flash_packed(shape=(1, 2, 8192, 192), v_dim=128, seed=0,
     return errs
 
 
+def check_rotary(rows=2, seq=8192, q_heads=36, latent_heads=32,
+                 interpret=False):
+    """The rotary kernel (`kernels/rotary.py`) against the XLA form it
+    replaces, forward and `jax.vjp`, bfloat16 rows packed from documents, at
+    the two rotary cells' shapes: `halves` over 36 heads of 128 (Mellum2's 32
+    query and 4 K/V heads) and `pairs` over the last 64 lanes of 32 heads of
+    128 + 64 (JoyAI's queries). The XLA form here is written out from
+    `rotate_halves` / `rotate_pairs` and a `swapaxes`, as the layers had it.
+    Both sides turn in float32 and round once, so they may differ by one
+    bfloat16 rounding and no more. Returns the largest difference over the
+    largest entry, per result; raises past 2^-7."""
+    from ..nn.layer.linear_attention import (rope_inv_freq, rotate_halves,
+                                             rotate_pairs)
+    from .rotary import rotary_halves, rotary_pairs
+    at = jnp.arange(seq, dtype=jnp.int32)[None] \
+        - jnp.asarray(packed_doc_starts(rows, seq, 0, median=seq // 8))
+    inv_freq = rope_inv_freq(500000.0, 128).astype(np.float32)
+    factor, theta = 1.2772588722239782, 32e6
+
+    def halves_xla(x):
+        angle = at.astype(jnp.float32)[..., None] * inv_freq
+        angle = jnp.concatenate([angle, angle], -1)[:, :, None]
+        return rotate_halves(x, factor * jnp.cos(angle),
+                             factor * jnp.sin(angle))
+
+    def pairs_xla(x):
+        return jnp.concatenate([x[..., :128].astype(jnp.float32),
+                                rotate_pairs(x[..., 128:], at, theta)], -1)
+
+    errs = {}
+    for name, heads, d, kernel, xla in (
+            ('halves', q_heads, 128, lambda x: rotary_halves(
+                x, at, inv_freq, factor, interpret=interpret), halves_xla),
+            ('pairs', latent_heads, 192, lambda x: rotary_pairs(
+                x, at, theta, 64, interpret=interpret), pairs_xla)):
+        kx, kc = jax.random.split(jax.random.PRNGKey(len(errs)))
+        x = jax.random.normal(kx, (rows, seq, heads, d), jnp.bfloat16)
+        cot = jax.random.normal(kc, (rows, heads, seq, d), jnp.bfloat16)
+
+        def both(f):
+            y, pull = jax.vjp(f, x)
+            return y, pull(cot)[0]
+        got = jax.jit(lambda: both(kernel))()
+        want = jax.jit(lambda: both(lambda x: jnp.swapaxes(
+            xla(x).astype(x.dtype), 1, 2)))()
+        for tag, a, b in zip(('', '_dx'), got, want):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            err = float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+            if a.shape != b.shape or not err < 2 ** -7:
+                raise AssertionError(
+                    'rotary %s%s: the kernel is %g of the largest entry off '
+                    'the XLA form, more than one bfloat16 rounding'
+                    % (name, tag, err))
+            errs[name + tag] = err
+    return errs
+
+
 def check_partitioned(mesh, axis, shape=(8, 16, 512, 64), hidden=1024,
                       dropout_p=0.1, interpret=False):
     """Flash attention and fused dropout+add+LayerNorm, forward and

@@ -19,6 +19,7 @@ import numpy as np
 from ...core.tensor import apply_op
 from ...kernels.delta_rule import delta_rule
 from ...kernels.flash_attention import attention_blhd
+from ...kernels.rotary import rotary_halves, rotary_pairs
 from ...kernels.short_conv import short_conv
 from ..initializer import Constant, Normal, ParamAttr
 from ..layer_base import Layer
@@ -246,9 +247,11 @@ class GroupedQueryAttention(Layer):
     value heads of `head_dim` (query head h reads K/V head h // group), no
     bias: q = x W_q, k = x W_k, v = x W_v; q and k rotated over the whole
     head in the half-split form (`rotate_halves`, under the scope
-    `attn.rope`) by a position that restarts at each document of a packed
-    row; softmax(q k^T / sqrt(head_dim)) over the keys of the query's
-    document, the last `window` of them where a window is given; W_o.
+    `attn.rope`; on the TPU one pass of `kernels/rotary.py`, which also
+    writes the flash kernels' layout) by a position that restarts at each
+    document of a packed row; softmax(q k^T / sqrt(head_dim)) over the keys
+    of the query's document, the last `window` of them where a window is
+    given; W_o.
 
     The rotary table is DATA: `inv_freq` (head_dim / 2,) and `rope_factor`,
     which multiplies cos and sin (YaRN's attention factor; the scores take
@@ -299,14 +302,11 @@ class GroupedQueryAttention(Layer):
                 if inv_freq is not None:
                     with jax.named_scope('attn.rope'):
                         at = jnp.arange(T, dtype=jnp.int32)[None, :] - start
-                        angle = at.astype(jnp.float32)[..., None] * inv_freq
-                        angle = jnp.concatenate([angle, angle],
-                                                -1)[:, :, None]
-                        cos, sin = (factor * jnp.cos(angle),
-                                    factor * jnp.sin(angle))
-                        q = rotate_halves(q, cos, sin).astype(q.dtype)
-                        k = rotate_halves(k, cos, sin).astype(k.dtype)
-                q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
+                        q, k = (rotary_halves(t, at, inv_freq, factor)
+                                for t in (q, k))
+                else:
+                    q, k = jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2)
+                v = jnp.swapaxes(v, 1, 2)
                 o = flash_attention_bhld(q, k, v, causal=True,
                                          doc_start=start, window=window)
                 return _mm(jnp.swapaxes(o, 1, 2).reshape(B, T, H * D), wo,
@@ -327,9 +327,11 @@ class LatentAttention(Layer):
     No position encoding unless `rope_theta` is given: then the last
     `qk_rope_head_dim` of every head's query and the one shared `k_pe` are
     rotated (`rotate_pairs`, under the scope `mla.rope`, before `k_pe` is
-    broadcast to the heads) by a position that restarts at each document of a
-    packed row. A score depends on the two positions' difference alone, so it
-    equals the one global positions give, at smaller angles
+    broadcast to the heads; the query on the TPU in one pass of
+    `kernels/rotary.py`, which also writes the flash kernels' layout) by a
+    position that restarts at each document of a packed row. A score depends
+    on the two positions' difference alone, so it equals the one global
+    positions give, at smaller angles
     (docs/EXPERT_LAYER.md, "Rotary positions in packed rows")."""
 
     def __init__(self, hidden_size, num_heads, qk_nope_head_dim,
@@ -389,16 +391,15 @@ class LatentAttention(Layer):
                     with jax.named_scope('mla.rope'):
                         at = jnp.arange(T, dtype=jnp.int32)[None, :] \
                             - doc_starts(seg)
-                        q = jnp.concatenate([
-                            q[..., :nope],
-                            rotate_pairs(q[..., nope:], at, theta)
-                            .astype(q.dtype)], axis=-1)
+                        q = rotary_pairs(q, at, theta, rope)
                         k_pe = rotate_pairs(k_pe, at, theta)
                 k_pe = jnp.broadcast_to(k_pe, (B, T, H, rope))
                 k = jnp.concatenate([kv[..., :nope], k_pe.astype(kv.dtype)],
                                     axis=-1)
-                q, k, v = (jnp.swapaxes(t, 1, 2)
-                           for t in (q, k, kv[..., nope:]))
+                v = kv[..., nope:]
+                if theta is None:
+                    q = jnp.swapaxes(q, 1, 2)
+                k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
                 o = flash_attention_bhld(
                     q, k, v, causal=True,
                     scale=1.0 / math.sqrt(nope + rope),

@@ -49,7 +49,7 @@ from .decoder_block import (STEP_COUNTER_NAMES, STEP_COUNTER_SUMS,
 # the rotation inside `mla.attention`, and everything the prediction module
 # runs: a scope is a path component, so the module's own attention counts
 # under `mtp`, `mla.attention` and `mla.rope` alike
-_costs.register_scopes('mla.rope', 'mtp')
+_costs.register_scopes('mla.rope', 'mtp', 'rotary.pallas')
 
 __all__ = ['JoyAIFlashConfig', 'JoyAIFlashForCausalLM']
 

@@ -47,7 +47,7 @@ from .decoder_block import (STEP_COUNTER_NAMES, STEP_COUNTER_SUMS,
 # the window layers' scope and the rotation inside either kind (`attn.full`
 # is the dense hybrid's too; a scope is registered once whoever names it)
 _costs.register_scopes('attn.full', 'attn.window', 'attn.rope',
-                       'flash_attention.pallas')
+                       'flash_attention.pallas', 'rotary.pallas')
 
 __all__ = ['MellumConfig', 'MellumBlock', 'MellumForCausalLM']
 

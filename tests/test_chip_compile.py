@@ -29,6 +29,7 @@ from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import fused_dropout_norm as fdn
 from paddle_tpu.kernels import fused_norm as fn
 from paddle_tpu.kernels import grouped_matmul as gm
+from paddle_tpu.kernels import rotary
 from paddle_tpu.kernels import row_permute as rp
 from paddle_tpu.kernels import short_conv as sc
 from paddle_tpu.kernels import ssd as ssd_kernels
@@ -366,6 +367,39 @@ def test_row_permute_compiles_fwd_bwd_at_the_cells_shapes(
     # gather and combine forward, then each one's backward
     assert len(calls) == 4, calls
     assert not re.search(r' (scatter|gather)\(', text)
+
+
+@pytest.mark.parametrize('heads,d,turned', [
+    (32, 128, None), (4, 128, None), (32, 192, 64)],
+    ids=['mellum2-q', 'mellum2-k', 'joyai-llm-flash-q'])
+def test_rotary_compiles_fwd_bwd_at_the_cells_shapes(one_chip, heads, d,
+                                                     turned):
+    """The rotation of two packed rows of 8192 at the two rotary cells'
+    published head shapes, bfloat16: the half turn over heads of 128 (32
+    query heads, 4 K/V heads) and the pair turn over the last 64 lanes of
+    heads of 128 + 64, whose block is two heads (384 lanes) and whose second
+    head starts mid-register. One custom call forward and one backward, the
+    same kernel with its BlockSpecs exchanged, and no transpose left beside
+    them."""
+    from paddle_tpu.nn.layer.linear_attention import rope_inv_freq
+
+    def loss(x, at):
+        if turned is None:
+            y = rotary.rotary_halves(
+                x, at, rope_inv_freq(500000, d).astype(np.float32), 1.25)
+        else:
+            y = rotary.rotary_pairs(x, at, 32e6, turned)
+        assert y.shape == (2, heads, 8192, d)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, 'default_backend', lambda: 'tpu')
+        text = _compile(jax.grad(loss), one_chip,
+                        ((2, 8192, heads, d), jnp.bfloat16),
+                        ((2, 8192), jnp.int32))
+    calls = [c for c in _CUSTOM_CALL.findall(text)
+             if 'rotary.pallas' in c]
+    assert len(calls) == 2, calls
+    assert not re.search(r' transpose\(', text)
 
 
 def test_fused_layer_norm_compiles_fwd_bwd(one_chip):
@@ -771,6 +805,17 @@ def test_rotary_decoder_step_holds_its_scopes(topo, monkeypatch):
     both = [n for n, scopes in under.items()
             if {'mtp', 'mla.rope'} <= set(scopes)]
     assert both and all('mla.attention' in under[n] for n in both)
+    # the queries' rotation is the kernel, under `mla.rope` in the forward
+    # pass, the recomputation and the backward pass of each block (else
+    # `mla.rope_ms` would fall because the work left the scope)
+    turned = [c for c in calls if c.startswith('rotary.pallas')]
+    assert len(turned) == 9 and 'rotary.xla' not in text, turned
+    assert all({'mla.attention', 'mla.rope'} <= set(under[c])
+               for c in turned)
+    assert sum('mtp' in under[c] for c in turned) == 3
+    phases = costs.instruction_phases(text)
+    assert sorted(phases[c] for c in turned) == \
+        ['backward'] * 6 + ['forward'] * 3
     assert any({'mtp', 'lm_head'} <= set(scopes) for scopes in under.values())
 
 
@@ -898,6 +943,15 @@ def test_grouped_query_decoder_step_holds_its_kernels_and_its_scopes(
     assert any('attn.window' in s for s in turned)
     assert any('attn.full' in s for s in turned)
     assert all(s & {'attn.window', 'attn.full'} for s in turned)
+    # and is the kernel, for q and for k, in the forward pass, the
+    # recomputation and the backward pass of each block, every call under
+    # `attn.rope` (else `attn.rope_ms` would fall because the work left it)
+    rope = [c for c in calls if c.startswith('rotary.pallas')]
+    assert len(rope) == 24, rope
+    assert all('attn.rope' in under[c] for c in rope)
+    phases = costs.instruction_phases(text)
+    assert sorted(phases[c] for c in rope) == \
+        ['backward'] * 16 + ['forward'] * 8
     # operands and results of the flash kernels by their leading size: 2 rows
     # x 8 query heads = 16 (forward q, o; backward q, o, dO, dQ) and 2 rows x
     # 2 K/V heads = 4 (forward k, v; backward k, v, dK, dV)

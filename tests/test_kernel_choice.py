@@ -3,21 +3,23 @@ program is the one it was.
 
 The rule that picks a Pallas kernel or its XLA form lives with the kernel
 (`kernels/flash_attention.py`, `fused_norm.py`, `fused_dropout_norm.py`,
-`short_conv.py`): backend, the shape's tiling, what the kernel can express,
-and two size rules (sequence length 512 for attention, 4096 rows for
-dropout + add + norm).
+`short_conv.py`, `rotary.py`): backend, the shape's tiling, what the kernel
+can express, whether the step is sharded over a mesh (the rotation), and two
+size rules (sequence length 512 for attention, 4096 rows for dropout + add +
+norm).
 Every outcome runs under `_common.took`, which bumps
 `kernels.<kernel>.<path>` and names the scope `<kernel>.<path>`: a trace of
 a step proves which one it ran. Here the sites are traced through
 `nn.functional` (the short convolution, which `nn.KimiDeltaAttention` calls
-on values, through its entry) with `jax.default_backend` patched; nothing is
-lowered.
+on values, through its entry; the rotation, which the attention layers call
+on values, likewise) with `jax.default_backend` patched; nothing is lowered.
 """
 import hashlib
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from paddle_tpu import observability as obs
@@ -95,6 +97,32 @@ def short_conv(y_shape, head_dim=None, taps=4, dtype=BF16):
         return entry(y, w, seg, head_dim)
     return site, [(y_shape, dtype), ((taps, y_shape[-1]), F32),
                   (y_shape[:2], jnp.int32)]
+
+
+def rotary(x_shape, turned=None, dtype=BF16, sharded=False):
+    """The attention layers' call: a projection's output seen by head
+    (B, T, H, d) and the packed row's positions; the half turn over the whole
+    head (`nn.GroupedQueryAttention`), or with `turned` the pair turn over a
+    head's last channels (`nn.LatentAttention`). `sharded`: traced inside
+    `kernel_mesh`, as a step whose operands are split over a mesh is."""
+    from paddle_tpu.kernels import rotary as entry
+    from paddle_tpu.kernels._common import kernel_mesh
+    from paddle_tpu.nn.layer.linear_attention import rope_inv_freq
+    d = x_shape[-1]
+
+    def turn(x, at):
+        if turned is None:
+            return entry.rotary_halves(
+                x, at, rope_inv_freq(500000, d).astype(np.float32), 1.25)
+        return entry.rotary_pairs(x, at, 32e6, turned)
+
+    def site(key, x, at):
+        if not sharded:
+            return turn(x, at)
+        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ('data',))
+        with kernel_mesh(mesh, ('data',)):
+            return turn(x, at)
+    return site, [(x_shape, dtype), (x_shape[:2], jnp.int32)]
 
 
 def _structs(shapes):
@@ -188,6 +216,28 @@ _CHOICES = [
      short_conv((1, 8192, 4096), head_dim=128, taps=10), 'xla'),
     ('short-conv-off-the-tpu', 'short_conv', 'cpu',
      short_conv((1, 8192, 4096), head_dim=128), 'xla'),
+    # the rotation: Mellum2's q and k (half turn, heads of 128), JoyAI's q
+    # (pair turn over the last 64 of 128 + 64)
+    ('rotary-mellum2-cell-q', 'rotary', 'tpu',
+     rotary((2, 8192, 32, 128)), 'pallas'),
+    ('rotary-mellum2-cell-k', 'rotary', 'tpu',
+     rotary((2, 8192, 4, 128)), 'pallas'),
+    ('rotary-joyai-cell-q', 'rotary', 'tpu',
+     rotary((2, 8192, 32, 192), turned=64), 'pallas'),
+    ('rotary-float32-rows-of-48', 'rotary', 'tpu',
+     rotary((2, 48, 2, 128), dtype=F32), 'pallas'),
+    ('rotary-rows-do-not-tile', 'rotary', 'tpu',
+     rotary((2, 8200, 32, 128)), 'xla'),
+    ('rotary-half-turn-of-half-a-register', 'rotary', 'tpu',
+     rotary((2, 8192, 32, 64)), 'xla'),
+    ('rotary-pair-turn-of-half-a-register', 'rotary', 'tpu',
+     rotary((2, 8192, 32, 64), turned=64), 'pallas'),
+    ('rotary-three-heads-of-192-fill-no-registers', 'rotary', 'tpu',
+     rotary((2, 8192, 3, 192), turned=64), 'xla'),
+    ('rotary-in-a-sharded-step', 'rotary', 'tpu',
+     rotary((2, 8192, 32, 128), sharded=True), 'xla'),
+    ('rotary-off-the-tpu', 'rotary', 'cpu',
+     rotary((2, 8192, 32, 128)), 'xla'),
 ]
 
 
@@ -227,7 +277,8 @@ def test_the_site_takes(monkeypatch, telemetry, kernel, backend, site, path):
 
 # Forward-and-gradient jaxprs of commit 31e439e at the cells' own shapes,
 # source locations taken out (name stacks are not printed): the programs
-# the benchmark's steps hold.
+# the benchmark's steps hold. The rotation's two are PR 44's, which brought
+# the kernel: Mellum2's q and k, JoyAI's q.
 _PROGRAMS = [
     ('attention-seq128', [attention((64, 128, 16, 64), (64, 1, 1, 128), 0.1)],
      '9728593572e4a028a0a00d326eb749ea0c1af8b8d46acba637181253fb2b6c6e'),
@@ -247,6 +298,11 @@ _PROGRAMS = [
     ('rms-norm-2304', [rms_norm((2, 8192, 2304)),
                        rms_norm((2, 8192, 2304), dtype=F32)],
      '7cf430396d2a57a067805e8d4458460c4c93f9aeda0258dca33baf34bdd80c67'),
+    ('rotary-halves-mellum2', [rotary((2, 8192, 32, 128)),
+                               rotary((2, 8192, 4, 128))],
+     'e42ea1f50fb1d7d01ba638c6a6a3c5414e64589c8c8f60f02dc86db61d25f087'),
+    ('rotary-pairs-joyai', [rotary((2, 8192, 32, 192), turned=64)],
+     '54acccbb58419b2bb8fce5b10b69cd4ea09cab9296d5866e6ccc452f6e71d60c'),
 ]
 
 
@@ -255,8 +311,9 @@ def program_digest(sites):
     for fn, shapes in sites:
         def loss(key, *args):
             return jnp.sum(fn(key, *args).astype(F32))
-        texts.append(str(jax.make_jaxpr(
-            jax.grad(loss, argnums=tuple(range(1, len(shapes) + 1))))(
+        floats = tuple(i + 1 for i, (_, dt) in enumerate(shapes)
+                       if jnp.issubdtype(dt, jnp.floating))
+        texts.append(str(jax.make_jaxpr(jax.grad(loss, argnums=floats))(
             _key(), *_structs(shapes))))
     text = re.sub(r'/[\w/.\-]+\.py:\d+', 'SRC', '\n'.join(texts))
     text = re.sub(r'at SRC|SRC', '', text)
@@ -268,3 +325,58 @@ def program_digest(sites):
 def test_the_cells_programs_are_unchanged(monkeypatch, sites, digest):
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
     assert program_digest(sites) == digest
+
+
+# The attention layers that rotate nothing (Kimi's `LatentAttention` with no
+# `rope_theta`, Nemotron's `GroupedQueryAttention` with no table) share their
+# code with the two that do: forward and gradient under bf16 autocast with the
+# block's norm in front and recomputation, they trace to the jaxprs of commit
+# 89fd2c6, the parent of the rotary kernel's PR, on the kernels' path.
+_LAYERS_THAT_DO_NOT_ROTATE = [
+    ('grouped-query-no-table',
+     lambda nn: nn.GroupedQueryAttention(256, 8, 2, 128, None),
+     'f1c43132cdefc18ca44a30cb684032591c3bb7adf7db04687f942d23bf8779b6'),
+    ('grouped-query-no-table-window',
+     lambda nn: nn.GroupedQueryAttention(256, 8, 2, 128, None, window=256),
+     '3642ff6f77ed9ebc4f2e694786da403e89974d98a8c058a47e6b55bf23f5c0c7'),
+    ('latent-no-theta',
+     lambda nn: nn.LatentAttention(256, 2, 128, 64, 128, 128),
+     '71e829e68cbd547617311b47196f3961e9fc70e130b56248425d0036e823d39c'),
+    ('latent-low-rank-no-theta',
+     lambda nn: nn.LatentAttention(256, 2, 128, 64, 128, 128,
+                                   q_lora_rank=64),
+     '560662a017a54059784143db7ddeb79c58e7580c413bba246f9db8d546adf19c'),
+]
+
+
+def layer_digest(make):
+    import paddle_tpu as paddle
+    from paddle_tpu import amp, nn
+    from paddle_tpu.nn.layer_base import functional_call
+    paddle.seed(0)
+    layer = make(nn)
+    names = [n for n, _ in layer.named_parameters()]
+    weights = [p._value for _, p in layer.named_parameters()]
+    norm = nn.RMSNorm(256, epsilon=1e-5)
+
+    def loss(x, seg, *ws):
+        with amp.auto_cast(dtype='bfloat16'):
+            y, _ = functional_call(layer, dict(zip(names, ws)), Tensor(x),
+                                   Tensor(seg), norm, True)
+        return jnp.sum(y._value.astype(F32))
+    text = str(jax.make_jaxpr(jax.grad(
+        loss, argnums=(0,) + tuple(range(2, 2 + len(weights)))))(
+            jnp.zeros((2, 1024, 256), F32), jnp.zeros((2, 1024), jnp.int32),
+            *weights))
+    text = re.sub(r'/[\w/.\-]+\.py:\d+', 'SRC', text)
+    text = re.sub(r'at SRC|SRC', '', text)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize('make,digest',
+                         [c[1:] for c in _LAYERS_THAT_DO_NOT_ROTATE],
+                         ids=[c[0] for c in _LAYERS_THAT_DO_NOT_ROTATE])
+def test_layers_that_do_not_rotate_trace_to_the_parents_program(
+        monkeypatch, make, digest):
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    assert layer_digest(make) == digest

@@ -5,11 +5,12 @@ scope split by op family and by instruction (needs the chip).
         --seconds 36 --trace 1
 
 The arguments behind the scope are `benchmark/run.py`'s, and so is
-everything printed before the last two lines. `moe.experts_ms` and its like
-say what a scope costs a step; this says what it is made of: the leaf ops of
-the whole traced steps whose instruction the program's map puts under the
-scope (`observability.costs.scopes`), summed by `harness/trace.op_family`
-and, for the heaviest, one by one with the head of the instruction's text
+everything printed before the last lines. Several scopes, separated by
+commas (`attn.rope,attn.window`), are split from the one run, a line each.
+`moe.experts_ms` and its like say what a scope costs a step; this says what
+it is made of: the leaf ops of the whole traced steps whose instruction the
+program's map puts under the scope (`observability.costs.scopes`), summed by
+`harness/trace.op_family` and, for the heaviest, one by one with the head of the instruction's text
 (its shape says which gather, product or scatter it is). Last lines:
 `{"phase": "scope_split", ...}` and the step counters of the run
 (`{"phase": "step_counters", ...}`, as `benchmark/tests/counters_on_chip.py`
@@ -74,10 +75,12 @@ def main(argv):
         return code or 1
     maps = {e['program']: obs.costs.scopes(e['program'])
             for e in obs.costs.ledger()}
-    found = split(trace_mod.read_xplane(path),
-                  {k: v for k, v in maps.items() if v}, scope, trace_mod,
-                  phases)
-    print(json.dumps({'phase': 'scope_split', **(found or {})}), flush=True)
+    trace = trace_mod.read_xplane(path)
+    maps = {k: v for k, v in maps.items() if v}
+    for one in scope.split(','):
+        found = split(trace, maps, one, trace_mod, phases)
+        print(json.dumps({'phase': 'scope_split', **(found or {})}),
+              flush=True)
     counters = obs.step_counters
     counters.drain(wait=True)
     seen = [ev['args'] for ev in obs.trace_events()
