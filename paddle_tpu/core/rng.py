@@ -12,6 +12,11 @@ the hardware RNG. This is scoped to OUR keys via PRNGKey(impl=...); the
 process-global jax default and the host application's own jax.random calls
 are untouched. Override with PADDLE_TPU_PRNG=threefry2x32 if counter-based
 reproducibility across backends matters more than speed.
+
+XLA's partitioner cannot split an 'rbg' draw: at a shape that is sharded over
+a mesh it is made WHOLE on every device and sliced. The dropout masks that
+XLA ops draw therefore go through ``kernels._common.keep_mask``, which draws
+each device's part on that device.
 """
 import contextlib
 import os

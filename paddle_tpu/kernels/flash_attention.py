@@ -61,8 +61,9 @@ sequence tiles, and ``attention_blhd`` for ``nn.functional``'s
 (B, L, H, D) call with a free-form mask adds what that call needs: a mask
 the kernels can express and a sequence long enough for them to win. Off
 their rule each takes plain-XLA attention with identical semantics (dropout
-there uses jax.random — same distribution, different stream); which path a
-trace took is marked in the HLO (``_common.took``).
+there uses jax.random — same distribution, different stream, and in a step
+sharded over a mesh each device's own draw: ``_common.keep_mask``); which
+path a trace took is marked in the HLO (``_common.took``).
 """
 import functools
 import math
@@ -72,7 +73,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import (pallas_runs, spmd_kernel,
+from ._common import (keep_mask, pallas_runs, spmd_kernel,
                       tile_keep_scale as _tile_keep_scale, took)
 
 NEG_INF = -1e30
@@ -109,7 +110,8 @@ def _attn_reference(q, k, v, causal, scale, kpad_bias=None, dropout_p=0.0,
         scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     if dropout_p > 0.0 and dropout_key is not None:
-        keep = jax.random.bernoulli(dropout_key, 1.0 - dropout_p, probs.shape)
+        keep = keep_mask(dropout_key, 1.0 - dropout_p, probs.shape,
+                         _BHLD[:2] + (None, None))
         probs = jnp.where(keep, probs / (1.0 - dropout_p),
                           jnp.zeros_like(probs))
     return jnp.einsum('bhlm,bhmd->bhld', probs, v)
@@ -866,8 +868,8 @@ def attention_blhd(q, k, v, mask=None, causal=False, dropout_p=0.0,
             scores = jnp.where(visible, scores, NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1)
         if dropout_p > 0.0:
-            keep = jax.random.bernoulli(dropout_key, 1.0 - dropout_p,
-                                        probs.shape)
+            keep = keep_mask(dropout_key, 1.0 - dropout_p, probs.shape,
+                             _BHLD[:2] + (None, None))
             probs = jnp.where(keep, probs / (1.0 - dropout_p),
                               jnp.zeros_like(probs))
         out = jnp.einsum('bhlm,bhmd->bhld', probs, v)

@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._common import (pallas_runs, row_block as _row_block, spmd_kernel,
+from ._common import (keep_mask, pallas_runs, row_block as _row_block,
+                      rows_first, spmd_kernel,
                       tile_keep_scale as _keep_scale, took)
 from .fused_norm import fused_layer_norm
 
@@ -225,7 +226,7 @@ def _xla_reference(x, residual, weight, bias, p, epsilon, dropout_seed):
             key = jax.random.fold_in(
                 jax.random.PRNGKey(0),
                 dropout_seed.reshape(()).astype(jnp.uint32))
-            keep = jax.random.bernoulli(key, 1.0 - p, x.shape)
+            keep = keep_mask(key, 1.0 - p, x.shape, rows_first(x.ndim))
             xx = jnp.where(keep, x / (1.0 - p), jnp.zeros_like(x))
         yin = residual + xx
         mean = jnp.mean(yin.astype(jnp.float32), axis=-1, keepdims=True)
@@ -265,6 +266,6 @@ def dropout_add_layer_norm(x, residual, weight=None, bias=None, dropout_p=0.0,
         if p >= 1.0:
             y = jnp.zeros_like(x)
         elif p > 0.0:
-            keep = jax.random.bernoulli(dropout_key, 1.0 - p, x.shape)
+            keep = keep_mask(dropout_key, 1.0 - p, x.shape, rows_first(x.ndim))
             y = jnp.where(keep, x / (1.0 - p), jnp.zeros_like(x))
         return fused_layer_norm(y + residual, weight, bias, epsilon)

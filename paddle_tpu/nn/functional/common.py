@@ -11,6 +11,7 @@ from jax import lax
 from ...core.tensor import Tensor, apply_op
 from ...core import rng as _rng
 from ...core.dtypes import convert_dtype
+from ...kernels._common import keep_mask, rows_first
 from ...tensor._helpers import _t
 
 __all__ = ['linear', 'embedding', 'one_hot', 'label_smooth', 'dropout',
@@ -85,10 +86,12 @@ def dropout(x, p=0.5, axis=None, training=True, mode="upscale_in_train", name=No
         axes = [axis] if isinstance(axis, numbers.Integral) else list(axis)
     def fn(v):
         if axes is None:
-            shape = v.shape
+            # the leading dim is the batch: in a sharded step each device
+            # draws the mask of its own rows
+            keep = keep_mask(key, 1.0 - p, v.shape, rows_first(v.ndim))
         else:
             shape = tuple(v.shape[i] if i in axes else 1 for i in range(v.ndim))
-        keep = jax.random.bernoulli(key, 1.0 - p, shape)
+            keep = jax.random.bernoulli(key, 1.0 - p, shape)
         if mode == "upscale_in_train":
             return jnp.where(keep, v / (1.0 - p), jnp.zeros_like(v))
         return jnp.where(keep, v, jnp.zeros_like(v))

@@ -327,6 +327,57 @@ def test_the_cells_programs_are_unchanged(monkeypatch, sites, digest):
     assert program_digest(sites) == digest
 
 
+def functional_dropout(x_shape, p=0.1, dtype=BF16):
+    def site(key, x):
+        return _call(F.dropout, (x,), key, p=p, training=True)
+    return site, [(x_shape, dtype)]
+
+
+# The XLA-path masks go through `kernels._common.keep_mask` since PR 45 so
+# that a SHARDED step draws them per device. On one device, outside
+# `kernel_mesh` or inside one that splits nothing, the trace is what
+# `jax.random.bernoulli` wrote in the site's own lines before.
+_MASK_SITES = [
+    ('attention-seq128-cell', 'tpu',
+     attention((64, 128, 16, 64), (64, 1, 1, 128), 0.1)),
+    ('attention-seq512-off-the-tpu-reference', 'cpu',
+     attention((2, 512, 4, 64), (2, 1, 1, 512), 0.1)),
+    ('dropout-add-norm-4095-rows', 'tpu', dropout_add_norm((4095, 1024))),
+    ('dropout-add-norm-off-the-tpu-reference', 'cpu',
+     dropout_add_norm((8192, 1024))),
+    ('functional-dropout-embeddings', 'tpu',
+     functional_dropout((64, 128, 1024))),
+]
+
+
+@pytest.mark.parametrize('scope', ['no-scope', 'one-device-mesh'])
+@pytest.mark.parametrize('backend,site', [c[1:] for c in _MASK_SITES],
+                         ids=[c[0] for c in _MASK_SITES])
+def test_the_one_device_mask_draw_is_jax_random_bernoulli(monkeypatch, backend,
+                                                          site, scope):
+    import contextlib
+    from paddle_tpu.kernels import (_common, flash_attention,
+                                    fused_dropout_norm)
+    from paddle_tpu.nn.functional import common
+    monkeypatch.setattr(jax, 'default_backend', lambda: backend)
+    fn, shapes = site
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ('data',))
+
+    def text():
+        with _common.kernel_mesh(mesh, ('data',)) \
+                if scope == 'one-device-mesh' else contextlib.nullcontext():
+            return str(jax.make_jaxpr(fn)(_key(), *_structs(shapes)))
+
+    got = text()
+    for module in (flash_attention, fused_dropout_norm, common):
+        monkeypatch.setattr(
+            module, 'keep_mask',
+            lambda key, keep_prob, shape, dims: jax.random.bernoulli(
+                key, keep_prob, shape))
+    assert 'random_bits' in got and 'shard_map' not in got
+    assert got == text()
+
+
 # The attention layers that rotate nothing (Kimi's `LatentAttention` with no
 # `rope_theta`, Nemotron's `GroupedQueryAttention` with no table) share their
 # code with the two that do: forward and gradient under bf16 autocast with the
