@@ -45,7 +45,7 @@ a user calls:
                 register(layer=, example=, bucket_spec=)``: requests of mixed
                 lengths through ``submit``, all ``ok``, no compile after
                 warm-up, outputs equal to a direct forward.
-- ``generate``  ``register(generative=, kv_cache='paged')`` ->
+- ``generate``  ``register(generative=, page_size=16)`` ->
                 ``PagedGenerativeRunner`` with ``TinyCausalLM`` at embed 1024
                 / 16 heads / vocab 30522 / max_seq 512: prefill + decode,
                 tokens equal to ``reference_decode``. That spec is one
@@ -532,7 +532,7 @@ def phase_generate(size, seed):
     from paddle_tpu import serving
     lm = serving.TinyCausalLM.random(seed=seed, **size['lm'])
     eng = serving.ServingEngine()
-    ep = eng.register('lm', generative=lm, kv_cache='paged', page_size=16)
+    ep = eng.register('lm', generative=lm, page_size=16)
     s0 = Compiles.seconds()
     t0 = time.perf_counter()
     eng.warmup()

@@ -325,9 +325,8 @@ def check_rotary(rows=2, seq=8192, q_heads=36, latent_heads=32,
     Both sides turn in float32 and round once, so they may differ by one
     bfloat16 rounding and no more. Returns the largest difference over the
     largest entry, per result; raises past 2^-7."""
-    from ..nn.layer.linear_attention import (rope_inv_freq, rotate_halves,
-                                             rotate_pairs)
-    from .rotary import rotary_halves, rotary_pairs
+    from .rotary import (rope_inv_freq, rotary_halves, rotary_pairs,
+                         rotate_halves, rotate_pairs)
     at = jnp.arange(seq, dtype=jnp.int32)[None] \
         - jnp.asarray(packed_doc_starts(rows, seq, 0, median=seq // 8))
     inv_freq = rope_inv_freq(500000.0, 128).astype(np.float32)

@@ -19,7 +19,8 @@ import numpy as np
 from ...core.tensor import apply_op
 from ...kernels.delta_rule import delta_rule
 from ...kernels.flash_attention import attention_blhd
-from ...kernels.rotary import rotary_halves, rotary_pairs
+from ...kernels.rotary import (rope_inv_freq, rotary_halves, rotary_pairs,
+                               rotate_halves, rotate_pairs, yarn_inv_freq)
 from ...kernels.short_conv import short_conv
 from ..initializer import Constant, Normal, ParamAttr
 from ..layer_base import Layer
@@ -187,59 +188,6 @@ class KimiDeltaAttention(Layer):
                              self.gate_a, self.gate_b, self.o_norm,
                              self.o_proj)
                         + ((pre_norm.weight,) if pre_norm is not None else ()))
-
-
-def rotate_pairs(x, positions, theta):
-    """Rotary position encoding of the last axis of x (B, T, ..., d): each
-    adjacent pair (2j, 2j + 1) turned by the angle p * theta^(-2j / d), p the
-    position `positions` (B, T) gives, in float32 -> float32."""
-    d = x.shape[-1]
-    x = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
-    rate = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angle = positions.astype(jnp.float32)[..., None] * rate      # (B, T, d/2)
-    angle = angle.reshape(angle.shape[:2] + (1,) * (x.ndim - 4)
-                          + angle.shape[2:])
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    even, odd = x[..., 0], x[..., 1]
-    return jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
-                     axis=-1).reshape(x.shape[:-2] + (d,))
-
-
-def rope_inv_freq(theta, dim):
-    """(dim / 2,) float64: the plain rotary table's inverse frequencies,
-    theta^(-2j / dim)."""
-    return theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-
-
-def yarn_inv_freq(theta, dim, factor, original_max_position_embeddings,
-                  beta_fast=32.0, beta_slow=1.0):
-    """-> ((dim / 2,) float64 inverse frequencies, low, high): YaRN
-    (arXiv:2309.00071 section 3.2, as the public `rope_type: yarn`
-    computes it). Dimension j turns c(b) = dim ln(original / (2 pi b)) /
-    (2 ln theta) at b rotations over the original context; the dimensions
-    under low = floor(c(beta_fast)) keep their rate, those over high =
-    ceil(c(beta_slow)) are slowed `factor` times, a linear ramp between."""
-    def turns(b):
-        return dim * math.log(original_max_position_embeddings
-                              / (2 * math.pi * b)) / (2 * math.log(theta))
-    low = max(math.floor(turns(beta_fast)), 0)
-    high = min(math.ceil(turns(beta_slow)), dim - 1)
-    plain = rope_inv_freq(theta, dim)
-    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
-                   / max(high - low, 1e-3), 0.0, 1.0)
-    return plain / factor * ramp + plain * (1.0 - ramp), low, high
-
-
-def rotate_halves(x, cos, sin):
-    """Rotary position encoding of the last axis of x (B, T, H, d) in the
-    half-split form: x * cos + [-x2, x1] * sin with x = [x1, x2] the two
-    halves; cos, sin (B, T, 1, d) float32, each of its d / 2 angles repeated
-    over the halves. x keeps its layout (the half turn is a roll of the
-    lanes, not a reshape to pairs); float32 -> float32."""
-    half = x.shape[-1] // 2
-    x = x.astype(jnp.float32)
-    sign = jnp.where(jnp.arange(x.shape[-1]) < half, -1.0, 1.0)
-    return x * cos + jnp.roll(x, half, axis=-1) * (sin * sign)
 
 
 class GroupedQueryAttention(Layer):

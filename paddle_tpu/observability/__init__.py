@@ -36,7 +36,7 @@ from . import events as _events
 from . import interpose, registry, spans, state, timing  # noqa: F401
 from . import aggregate, doctor, endpoint, flush  # noqa: F401  mission ctl
 from . import costs, flight, slo  # noqa: F401  cost explorer + black box
-from . import baseline, timeseries  # noqa: F401  time series + sentinel
+from . import timeseries  # noqa: F401  in-run time series
 from . import step_counters  # noqa: F401  values of the compiled step
 from .state import enable, disable, enabled, log_dir, sync_every
 from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
@@ -79,8 +79,8 @@ __all__ = [
     'diagnose', 'run_doctor',
     # cost explorer + SLO tracker + flight recorder
     'costs', 'slo', 'flight',
-    # time series + cross-run regression sentinel
-    'baseline', 'timeseries',
+    # in-run time series
+    'timeseries',
     # counters that are values of the compiled train step
     'step_counters',
 ]

@@ -112,11 +112,6 @@ cluster snapshot, via ``aggregate.merged_timeseries``; quiet without them):
                       ``retrace_storm`` (which needs compiles/steps to
                       already look bad in aggregate; this fires on the
                       inflection).
-- ``perf_regression`` the latest run in the cross-run registry
-                      (``runs.jsonl``, see ``baseline.py`` /
-                      ``tools/perfwatch.py``) regressed vs the rolling
-                      median + MAD of prior runs, direction-aware (qps
-                      down = bad, latency/stall up = bad).
 
 Ranked output: ``critical`` > ``warning`` > ``info``. Standalone on
 purpose — stdlib-only, importable by path — so ``tools/doctor.py`` works
@@ -996,64 +991,6 @@ def detect_compile_creep(events=None, snapshot=None, cluster=None,
             n_samples=len(vals))
 
 
-def _load_baseline():
-    """The cross-run baseline module, package-relative or by path (this
-    module is loaded standalone by tools/doctor.py)."""
-    if __package__:
-        from . import baseline
-        return baseline
-    import importlib.util
-    import os
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        'baseline.py')
-    try:
-        spec = importlib.util.spec_from_file_location(
-            'paddle_tpu_baseline_standalone', path)
-        if spec is None or spec.loader is None:
-            return None
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-    except (OSError, ImportError):
-        return None
-
-
-def detect_perf_regression(events=None, snapshot=None, cluster=None,
-                           runs_path=None, perf_min_samples=None, **_):
-    """The latest run in the cross-run registry regressed vs the rolling
-    median + MAD of prior runs (``baseline.detect_regressions`` — robust,
-    direction-aware). Points at the registry via ``runs_path`` or
-    ``PADDLE_TPU_RUNS_REGISTRY``; quiet without one."""
-    import os
-    path = runs_path or os.environ.get('PADDLE_TPU_RUNS_REGISTRY')
-    if not path or not os.path.isfile(path):
-        return
-    baseline = _load_baseline()
-    if baseline is None:
-        return
-    kw = {} if perf_min_samples is None else \
-        {'min_samples': int(perf_min_samples)}
-    runs = baseline.load_runs(path)
-    for reg in baseline.detect_regressions(runs, **kw):
-        severity = ('critical' if abs(reg.get('rel_change', 0)) >= 0.5
-                    else 'warning')
-        yield _diag(
-            'perf_regression', severity,
-            f"{reg['metric']}: last run {reg['value']:g} vs rolling median "
-            f"{reg['median']:g} of {reg['n_baseline']} prior run(s) "
-            f"({reg['direction']} {100 * abs(reg['rel_change']):.0f}%, "
-            f"bad direction: {reg['bad_direction']})",
-            "tools/perfwatch.py history --metric <name> shows the trend; "
-            "bisect the runs between the last healthy record and this one "
-            "(each record carries its config fingerprint) — if the change "
-            "is intentional, land a new baseline by letting healthy runs "
-            "accumulate past the window",
-            metric=reg['metric'], value=reg['value'],
-            median=reg['median'], mad=reg.get('mad', 0),
-            rel_change=reg['rel_change'], direction=reg['direction'],
-            n_baseline=reg['n_baseline'])
-
-
 def detect_cold_compile_storm(events=None, snapshot=None, cluster=None,
                               cold_storm_compiles=COLD_STORM_COMPILES,
                               cold_storm_hit_rate=COLD_STORM_HIT_RATE,
@@ -1289,7 +1226,6 @@ DETECTORS = {
     'latency_creep': detect_latency_creep,
     'qps_collapse': detect_qps_collapse,
     'compile_creep': detect_compile_creep,
-    'perf_regression': detect_perf_regression,
 }
 
 

@@ -31,9 +31,11 @@ Mission-control knobs (docs/OBSERVABILITY.md, "Mission control"):
 - ``PADDLE_TPU_TELEMETRY_RUN_DIR`` cluster run dir for per-rank telemetry
                                    files (default: the supervisor's run
                                    dir, passed via heartbeat env)
+- ``PADDLE_TPU_HEARTBEAT_DIR``     that default: ``distributed/launch.py``
+                                   sets it for every supervised rank
 
 Time-series knobs (owned by ``timeseries.py``, docs/OBSERVABILITY.md,
-"Time series + regression sentinel"):
+"Time series"):
 
 - ``PADDLE_TPU_TELEMETRY_SAMPLE_EVERY``
                                    ring-sampler cadence in seconds for the
@@ -44,9 +46,6 @@ Time-series knobs (owned by ``timeseries.py``, docs/OBSERVABILITY.md,
                                    ring capacity in samples (default 512 —
                                    ~8.5 min at the default cadence; memory
                                    stays O(cap) over arbitrarily long runs)
-- ``PADDLE_TPU_RUNS_REGISTRY``     cross-run baseline registry path
-                                   (``runs.jsonl``; see ``baseline.py`` /
-                                   ``tools/perfwatch.py``)
 
 Cost explorer / SLO / flight-recorder knobs (owned by ``costs.py`` /
 ``slo.py`` / ``flight.py``, catalogued here so one file documents the env

@@ -8,12 +8,12 @@ compiled-program caches and served under load with
   so steady-state traffic never traces or compiles (``jax.compiles`` flat
   after ``warmup()``; graftlint GL013 lints for violations statically);
 - **iteration-level continuous batching** (``runners``) — one-shot models
-  re-pack the queue every batch; generative models join/leave the KV
-  cache per decode step: by default a **paged** cache (``paged_kv`` /
-  ``paged_runner``: block tables over a refcounted page pool, prefix
-  sharing of identical prompt prefixes, chunked prefill for long
-  prompts, speculative decoding via a draft spec), with the fixed-slot
-  cache (``kv_cache``) retained as the memory baseline;
+  re-pack the queue every batch; generative models join/leave the
+  **paged** KV cache per decode step (``paged_kv`` / ``paged_runner``:
+  block tables over a refcounted page pool, prefix sharing of identical
+  prompt prefixes, chunked prefill for long prompts, speculative
+  decoding via a draft spec; ``kv_cache`` holds the contract a model
+  implements);
 - **production edges** (``scheduler``) — bounded admission queues with
   429-style shedding, per-request deadlines (expired work is dropped, not
   run), watchdog-bounded client waits;
@@ -50,7 +50,7 @@ from .paged_runner import PagedGenerativeRunner
 from .router import (CircuitBreaker, FleetOverloadError, FleetPending,
                      FleetRouter, NoHealthyReplicaError, ReplicaError,
                      ReplicaHandle, RouterPolicy)
-from .runners import BatchRunner, GenerativeRunner
+from .runners import BatchRunner
 from .scheduler import (AdmissionQueue, PendingRequest, QueueFullError,
                         Request, Response, STATUS_CANCELLED,
                         STATUS_DEADLINE, STATUS_ERROR, STATUS_OK)
@@ -68,7 +68,7 @@ __all__ = [
     'BucketSpec', 'DEFAULT_BATCH_BUCKETS', 'select_bucket', 'pad_to_bucket',
     'stack_examples',
     'GenerativeSpec', 'TinyCausalLM',
-    'BatchRunner', 'GenerativeRunner', 'PagedGenerativeRunner',
+    'BatchRunner', 'PagedGenerativeRunner',
     'PageAllocator', 'PagesExhaustedError', 'PrefixCache', 'chain_hashes',
     'AdmissionQueue', 'PendingRequest', 'QueueFullError', 'Request',
     'Response', 'STATUS_OK', 'STATUS_DEADLINE', 'STATUS_ERROR',

@@ -20,8 +20,7 @@ Registration adapters (all funnel into the two runner shapes):
 - ``predictor=`` — an ``inference.Predictor`` (portable export);
 - ``generative=`` — a ``kv_cache.GenerativeSpec`` for continuous-batching
   decode over the **paged KV cache** (block tables + free-list allocator,
-  prefix sharing, chunked prefill, speculative decoding via ``draft=``;
-  ``kv_cache='slot'`` retains the PR-6 fixed-slot baseline).
+  prefix sharing, chunked prefill, speculative decoding via ``draft=``).
 
 Drive it either with ``start()`` (background worker thread; clients block
 on ``Endpoint.predict``) or synchronously with ``pump()`` /
@@ -35,7 +34,7 @@ from .. import observability as _obs
 from ..resilience.watchdog import join_thread
 from .admission import WeightedFairQueue, record_shed
 from .paged_runner import PagedGenerativeRunner
-from .runners import BatchRunner, GenerativeRunner, _count
+from .runners import BatchRunner, _count
 from .scheduler import (AdmissionQueue, PendingRequest, QueueFullError,
                         Request)
 
@@ -114,25 +113,23 @@ class ServingEngine:
                  example=None, bucket_spec=None, quantize=None,
                  calib_data=None, default_max_new_tokens=32,
                  queue_capacity=None, jit_compile=True,
-                 kv_cache='paged', page_size=16, num_pages=None,
-                 max_concurrency=None, draft=None, draft_k=4,
-                 prefix_cache=True, slo_ms=None, slo_objective=0.99,
-                 artifact_dir=None):
+                 page_size=16, num_pages=None, max_concurrency=None,
+                 draft=None, draft_k=4, prefix_cache=True, slo_ms=None,
+                 slo_objective=0.99, artifact_dir=None):
         """Register one model under ``name``. Exactly one of
         ``predict_fn``/``layer``/``program``/``predictor``/``generative``
         must be given; one-shot kinds also need ``example`` (one request's
         inputs, no batch axis) to pin the closed shape set.
 
-        Generative models decode over a **paged KV cache** by default
-        (``kv_cache='paged'``; docs/SERVING.md "Paged KV cache"):
+        Generative models decode over a **paged KV cache**
+        (docs/SERVING.md "Paged KV cache"):
         ``page_size`` tokens per page, ``num_pages`` total (default:
         worst case — size it below that to realize the memory win),
         ``max_concurrency`` block-table rows (default
         ``spec.max_batch``), ``prefix_cache=`` hash-consed shared-prompt
         pages, and ``draft=``/``draft_k=`` speculative decoding (a small
         ``GenerativeSpec`` proposing ``draft_k`` tokens per verify
-        step). ``kv_cache='slot'`` keeps the PR-6 fixed-slot cache (the
-        memory baseline).
+        step).
 
         ``slo_ms=`` declares this model's latency objective for the SLO
         tracker: ``slo_objective`` (default 0.99) of requests must
@@ -175,20 +172,6 @@ class ServingEngine:
                     f"register({name!r}): {bad} do not apply to "
                     "generative= models — prompt buckets and batch size "
                     "come from the GenerativeSpec itself")
-            if kv_cache not in ('paged', 'slot'):
-                raise ValueError(
-                    f"register({name!r}): kv_cache must be 'paged' or "
-                    f"'slot', got {kv_cache!r}")
-            if kv_cache == 'slot':
-                paged_only = [k for k, v in (
-                    ('num_pages', num_pages), ('draft', draft),
-                    ('max_concurrency', max_concurrency)) if v is not None]
-                if paged_only:
-                    raise ValueError(
-                        f"register({name!r}): {paged_only} need the paged "
-                        "KV cache — drop kv_cache='slot' (paged is the "
-                        "default) to use pages, prefix sharing, and "
-                        "speculative decoding")
         else:
             paged_given = [k for k, v in (
                 ('num_pages', num_pages), ('draft', draft),
@@ -211,16 +194,11 @@ class ServingEngine:
         else:
             queue = AdmissionQueue(name, capacity)
         if generative is not None:
-            if kv_cache == 'paged':
-                runner = PagedGenerativeRunner(
-                    name, queue, generative, page_size=page_size,
-                    num_pages=num_pages, max_concurrency=max_concurrency,
-                    draft=draft, draft_k=draft_k, prefix_cache=prefix_cache,
-                    default_max_new_tokens=default_max_new_tokens)
-            else:
-                runner = GenerativeRunner(
-                    name, queue, generative,
-                    default_max_new_tokens=default_max_new_tokens)
+            runner = PagedGenerativeRunner(
+                name, queue, generative, page_size=page_size,
+                num_pages=num_pages, max_concurrency=max_concurrency,
+                draft=draft, draft_k=draft_k, prefix_cache=prefix_cache,
+                default_max_new_tokens=default_max_new_tokens)
         else:
             if example is None:
                 raise ValueError(

@@ -1,17 +1,14 @@
-"""Slot-based KV cache for iteration-level (continuous) batch decoding.
+"""The generative contract: what a model gives the serving engine to decode.
 
 The decode hot path of a text model is one token per step per sequence;
 recomputing attention over the whole prefix each step is O(S^2) per token.
-The KV cache stores every layer's keys/values at fixed ``[max_batch,
-max_seq]`` slots so one decode step is O(S) — and, crucially for the
-serving engine, the cache shapes are **static**: requests join by writing
-their prefill K/V into a free slot and leave by freeing it, while the
-jitted decode step always runs at ``[max_batch]``. No shape ever changes,
-so nothing ever recompiles (the Orca/vLLM iteration-level scheduling
-idea, restricted to fixed slots). The fixed-slot layout is the MEMORY
-BASELINE: every sequence pays ``max_seq`` rows; ``paged_kv.py`` replaces
-the slots with block-table pages (the serving default) and this module's
-``GenerativeSpec`` carries both contracts.
+A KV cache stores every layer's keys/values so one decode step is O(S) —
+and, crucially for the serving engine, the cache shapes are **static**:
+requests join and leave by taking and freeing pages of one pool
+(``paged_kv.py``) while the jitted decode step always runs at
+``[max_batch]``. No shape ever changes, so nothing ever recompiles (the
+Orca/vLLM iteration-level scheduling idea). ``GenerativeSpec`` is the
+contract ``paged_runner.PagedGenerativeRunner`` schedules.
 
 Everything here is pure ``jnp`` — safe inside ``jax.jit``; the cache is a
 plain dict pytree threaded through the jitted prefill/decode calls.
@@ -25,59 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-__all__ = ['create_cache', 'write_prompt', 'write_token', 'attend',
-           'attend_prompt', 'GenerativeSpec', 'TinyCausalLM']
-
-
-def create_cache(num_layers, max_batch, max_seq, num_heads, head_dim,
-                 dtype=jnp.float32):
-    """Zeroed cache pytree: ``{'k','v'}`` of ``[L, B, S, H, D]``."""
-    shape = (int(num_layers), int(max_batch), int(max_seq),
-             int(num_heads), int(head_dim))
-    # host-built zeros: device transfer only, no tiny fill-program compile
-    # (keeps an AOT cold boot at jax.compiles == 0 — see compilecache)
-    z = np.zeros(shape, np.dtype(dtype))
-    return {'k': jnp.asarray(z), 'v': jnp.asarray(z)}
-
-
-def write_prompt(cache, layer, slot, k, v):
-    """Write one sequence's prefill K/V (``[Lp, H, D]``) into ``slot`` at
-    positions ``0..Lp-1``. ``Lp`` is the (static) prompt bucket length;
-    rows beyond the real length hold padding garbage that ``attend`` masks
-    out by position. ``slot`` may be a traced scalar — joining a different
-    slot is not a recompile."""
-    k = jnp.asarray(k)[None]           # [1, Lp, H, D]
-    v = jnp.asarray(v)[None]
-    start = (layer, slot, 0, 0, 0)
-    return {
-        'k': jax.lax.dynamic_update_slice(cache['k'], k[None], start),
-        'v': jax.lax.dynamic_update_slice(cache['v'], v[None], start),
-    }
-
-
-def write_token(cache, layer, k, v, positions):
-    """Write one decode step's K/V (``[B, H, D]``) at per-slot
-    ``positions`` (``[B]`` int). Inactive slots write at position 0 —
-    harmless garbage that the next prefill into that slot overwrites."""
-    b = jnp.arange(cache['k'].shape[1])
-    return {
-        'k': cache['k'].at[layer, b, positions].set(k),
-        'v': cache['v'].at[layer, b, positions].set(v),
-    }
-
-
-def attend(cache, layer, q, lengths):
-    """Masked attention read over the cache: ``q`` ``[B, H, D]``,
-    ``lengths`` ``[B]`` = number of valid positions per slot (the current
-    token's K/V already written). Returns ``[B, H, D]``."""
-    k = cache['k'][layer]              # [B, S, H, D]
-    v = cache['v'][layer]
-    d = q.shape[-1]
-    scores = jnp.einsum('bhd,bshd->bhs', q, k) / jnp.sqrt(float(d))
-    mask = jnp.arange(k.shape[1])[None, None, :] < lengths[:, None, None]
-    scores = jnp.where(mask, scores, -1e30)
-    w = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum('bhs,bshd->bhd', w, v)
+__all__ = ['attend_prompt', 'GenerativeSpec', 'TinyCausalLM']
 
 
 def attend_prompt(q, k, v):
@@ -96,22 +41,10 @@ def attend_prompt(q, k, v):
 class GenerativeSpec:
     """What a model must provide to decode under continuous batching.
 
-    Subclasses implement three pure functions (all jitted by the runner,
-    so bodies must be trace-safe — no Python branching on traced values):
-
-    - ``init_cache() -> pytree`` of ``[.., max_batch, max_seq, ..]`` arrays
-    - ``prefill(cache, tokens[Lp], length, slot) -> (cache, logits[V])``
-      — process one padded prompt into ``slot``, return the next-token
-      logits at the last real position. ``length``/``slot`` are traced
-      scalars; ``Lp`` is one of ``prompt_buckets`` (static).
-    - ``decode(cache, tokens[B], positions[B]) -> (cache, logits[B, V])``
-      — one token step for every slot at once, ``B == max_batch`` fixed.
-
-    **Paged contract** (the default serving path — ``paged_kv.py`` has the
-    primitives, ``paged_runner.py`` the scheduler): four more pure
-    functions over a paged cache + block tables instead of slots. The
-    slot contract above is retained as the memory-baseline comparison
-    (``register(..., kv_cache='slot')``).
+    Subclasses implement pure functions over a paged cache + block tables
+    (``paged_kv.py`` has the primitives, ``paged_runner.py`` the
+    scheduler). All are jitted by the runner, so bodies must be trace-safe
+    — no Python branching on traced values:
 
     - ``init_paged_cache(num_pages, page_size) -> pytree`` of
       ``[.., P, page_size, ..]`` arrays
@@ -132,16 +65,6 @@ class GenerativeSpec:
     eos_id = None                      # None: stop only on max_new_tokens
     prompt_buckets = (16, 32, 64)
 
-    def init_cache(self):
-        raise NotImplementedError
-
-    def prefill(self, cache, tokens, length, slot):
-        raise NotImplementedError
-
-    def decode(self, cache, tokens, positions):
-        raise NotImplementedError
-
-    # -- paged contract (kv_cache='paged', the default) -----------------
     def init_paged_cache(self, num_pages, page_size):
         raise NotImplementedError
 
@@ -211,29 +134,7 @@ class TinyCausalLM(GenerativeSpec):
     def _head(self, y):
         return y @ self.p['emb'].T
 
-    def init_cache(self):
-        return create_cache(1, self.max_batch, self.max_seq,
-                            self.num_heads, self.head_dim)
-
-    def prefill(self, cache, tokens, length, slot):
-        lp = tokens.shape[0]
-        x = self.p['emb'][tokens] + self.p['pos'][:lp]      # [Lp, E]
-        q, k, v = self._qkv(x)                              # [Lp, H, D]
-        out = attend_prompt(q, k, v)
-        y = x + out.reshape(lp, -1) @ self.p['wo']
-        cache = write_prompt(cache, 0, slot, k, v)
-        logits = self._head(y)                              # [Lp, V]
-        return cache, logits[length - 1]
-
-    def decode(self, cache, tokens, positions):
-        x = self.p['emb'][tokens] + self.p['pos'][positions]  # [B, E]
-        q, k, v = self._qkv(x)                                # [B, H, D]
-        cache = write_token(cache, 0, k, v, positions)
-        out = attend(cache, 0, q, lengths=positions + 1)
-        y = x + out.reshape(x.shape[0], -1) @ self.p['wo']
-        return cache, self._head(y)
-
-    # -- paged contract (see paged_kv.py) -------------------------------
+    # -- the contract (primitives in paged_kv.py) ------------------------
     def init_paged_cache(self, num_pages, page_size):
         from . import paged_kv
         return paged_kv.create_paged_cache(

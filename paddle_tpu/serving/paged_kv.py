@@ -1,7 +1,7 @@
 """Paged KV cache: block-table attention for continuous-batching decode.
 
-The fixed-slot cache (``kv_cache.py``) reserves ``max_seq`` rows per
-sequence, so slot count — and therefore serving concurrency — is capped at
+A cache of fixed ``[L, B, S, H, D]`` rows reserves ``max_seq`` rows per
+sequence, so row count — and therefore serving concurrency — is capped at
 ``HBM / (L*S*H*D)`` even though most sequences are far shorter than
 ``max_seq``. The paged cache (vLLM's PagedAttention idea, sized for this
 runtime) stores K/V in fixed-size **pages** ``[L, P, page_size, H, D]``

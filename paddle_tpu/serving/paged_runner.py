@@ -1,10 +1,9 @@
 """PagedGenerativeRunner: continuous batching over the paged KV cache.
 
-The successor to ``runners.GenerativeRunner`` (which is retained as the
-fixed-slot memory baseline): sequences own **block tables** over a shared
-page pool instead of max-length slots, so the same KV memory sustains
-several times the concurrency — admission is gated on **free pages**, not
-free slots. Three capabilities ride the page structure:
+Sequences own **block tables** over a shared page pool instead of
+max-length rows, so the same KV memory sustains several times the
+concurrency — admission is gated on **free pages**, not free rows. Three
+capabilities ride the page structure:
 
 - **prefix caching** — full prompt pages are hash-consed by content-chain
   digest (``paged_kv.PrefixCache``); a request whose prompt prefix was

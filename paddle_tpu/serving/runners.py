@@ -9,36 +9,24 @@ Two execution shapes cover the inference surface:
   here (Layer / function models) or an ``Executor.run`` closure, in which
   case the Executor **program cache** is the warm-program store and its
   hit/miss counters are the cache telemetry.
-- ``GenerativeRunner`` — iteration-level continuous batching for decode
-  (Orca-style): every step admits waiting requests into free KV-cache
-  slots (bucketed prefill), then runs ONE fixed-shape decode step for all
-  active slots; finished sequences leave their slot immediately, so a
-  short request never waits for a long one to finish. Greedy decode; the
-  jitted step set is closed (one prefill per prompt bucket + one decode),
-  so steady-state traffic compiles nothing. This is the FIXED-SLOT
-  baseline (``register(..., kv_cache='slot')``): every sequence reserves
-  ``max_seq`` rows. The default generative path is
-  ``paged_runner.PagedGenerativeRunner`` — same scheduling contract over
-  a paged cache (several times the concurrency at equal memory, prefix
-  sharing, chunked prefill, speculative decoding).
+- ``paged_runner.PagedGenerativeRunner`` — iteration-level continuous
+  batching for decode over the paged KV cache (its own module; it shares
+  ``_Stats``, ``_count`` and ``finish_request`` with this one).
 
 Runners never block: ``step()`` does at most one batch / one decode
 iteration and returns whether it did work; the engine's worker loop (or a
 test's manual pump) drives it.
 """
-import collections
-
 import numpy as np
 import jax
 import jax.numpy as jnp
 
 from .. import compilecache as _cc
 from .. import observability as _obs
-from .bucketing import (BucketSpec, pad_to_bucket, select_bucket,
-                        stack_examples)
+from .bucketing import BucketSpec, stack_examples
 from .scheduler import STATUS_OK, STATUS_DEADLINE, STATUS_ERROR
 
-__all__ = ['BatchRunner', 'GenerativeRunner', 'finish_request']
+__all__ = ['BatchRunner', 'finish_request']
 
 
 def _count(name, n=1):
@@ -232,233 +220,3 @@ class BatchRunner:
                 _count('serving.deadline_expired')
             finish_request(r, status, out)
         return True
-
-
-class GenerativeRunner:
-    """Continuous batching: per-iteration join/leave over KV-cache slots.
-
-    ``spec`` is a ``kv_cache.GenerativeSpec``. The runner owns the cache
-    pytree and the slot table; requests are greedy-decoded. The compiled
-    set is exactly ``len(spec.prompt_buckets)`` prefill programs plus one
-    decode program — all fixed shapes, compiled at warmup.
-    """
-
-    kind = 'generative'
-
-    def __init__(self, name, queue, spec, default_max_new_tokens=32):
-        self.name = name
-        self.queue = queue
-        self.spec = spec
-        self.default_max_new_tokens = int(default_max_new_tokens)
-        self.cache = spec.init_cache()
-        self.slots = [None] * spec.max_batch
-        self.stats = _Stats()
-        self.step_no = 0
-        # join/leave journal for tests/debugging: (event, request_id, step)
-        self.journal = collections.deque(maxlen=1024)
-
-        def _prefill(cache, toks, length, slot):
-            cache, logits = spec.prefill(cache, toks, length, slot)
-            return cache, jnp.argmax(logits)
-
-        def _decode(cache, toks, pos):
-            cache, logits = spec.decode(cache, toks, pos)
-            return cache, jnp.argmax(logits, axis=-1)
-
-        self._prefill = _cc.CachedJit(_prefill)
-        self._decode = _cc.CachedJit(_decode)
-
-    def validate(self, req):
-        toks = np.asarray(req.inputs.get('tokens', ()))
-        if toks.size == 0:
-            raise ValueError(
-                f"serving[{self.name}]: generative request needs a "
-                "non-empty 'tokens' input")
-        if toks.ravel().shape[0] > self.spec.prompt_buckets[-1]:
-            raise ValueError(
-                f"serving[{self.name}]: prompt of {toks.ravel().shape[0]} "
-                f"tokens exceeds the largest prompt bucket "
-                f"{self.spec.prompt_buckets[-1]}")
-
-    def has_work(self):
-        return len(self.queue) > 0 or any(s is not None for s in self.slots)
-
-    def evict_in_flight(self):
-        """Vacate every occupied KV slot (engine shutdown): returns
-        ``[(request, partial_outputs)]`` so the engine can complete them
-        with their tokens-so-far instead of stranding the clients."""
-        out = []
-        for slot, s in enumerate(self.slots):
-            if s is None:
-                continue
-            self.slots[slot] = None
-            self.stats.leaves += 1
-            _count('serving.leaves')
-            self.journal.append(('leave', s['req'].id, self.step_no))
-            out.append((s['req'],
-                        {'tokens': np.asarray(s['tokens'], np.int32)}))
-        return out
-
-    def warmup(self):
-        """Ready every prefill bucket + the decode step: deserialize from
-        a bound compilecache artifact dir (zero compiles) or compile once.
-        Uses slot 0 with dummy tokens; a real join later overwrites the
-        slot's cache. With telemetry on, each program lands in the cost
-        ledger either way."""
-        n = 0
-        for lb in self.spec.prompt_buckets:
-            toks = jnp.asarray(np.zeros((lb,), np.int32))
-            # length/slot must be int32 ARRAYS exactly like the real calls:
-            # a python int here traces a weak-typed variant and the first
-            # real request would recompile the bucket
-            args = (self.cache, toks, jnp.asarray(1, jnp.int32),
-                    jnp.asarray(0, jnp.int32))
-            self.cache, _ = self._prefill.warm(
-                f'serving.{self.name}.prefill{lb}', *args,
-                kind='serving.prefill',
-                meta={'model': self.name, 'bucket': lb})
-            n += 1
-        b = self.spec.max_batch
-        dargs = (self.cache, jnp.asarray(np.zeros((b,), np.int32)),
-                 jnp.asarray(np.zeros((b,), np.int32)))
-        self.cache, _ = self._decode.warm(
-            f'serving.{self.name}.decode', *dargs, kind='serving.decode',
-            meta={'model': self.name, 'batch': b})
-        return n + 1
-
-    # -- one scheduler iteration ---------------------------------------
-    def step(self):
-        self.step_no += 1
-        did = self._admit()
-        did = self._decode_step() or did
-        return did
-
-    def _admit(self):
-        free = [i for i, s in enumerate(self.slots) if s is None]
-        if not free:
-            # still reap already-dead requests so they don't rot in queue
-            expired = self.queue.reap_expired()
-            for r in expired:
-                self._expire(r)
-            return bool(expired)
-        ready, expired = self.queue.pop_ready(len(free))
-        for r in expired:
-            self._expire(r)
-        did = bool(expired)
-        for r in ready:
-            did = True
-            slot = free.pop(0)
-            prompt = np.asarray(r.inputs['tokens'], np.int32).ravel()
-            lb = select_bucket(len(prompt), self.spec.prompt_buckets)
-            padded = pad_to_bucket(prompt, lb)
-            try:
-                with _obs.timer('serving.prefill', model=self.name,
-                                bucket=lb) as t:
-                    self.cache, nxt = self._prefill(
-                        self.cache, jnp.asarray(padded),
-                        jnp.asarray(len(prompt), jnp.int32),
-                        jnp.asarray(slot, jnp.int32))
-                r.add_phase_ms('prefill', t.elapsed_ms)
-            except Exception as e:                   # model bug: fail the
-                self.stats.errors += 1               # request, not the
-                free.insert(0, slot)                 # engine worker
-                finish_request(r, STATUS_ERROR, error=e)
-                continue
-            first = int(np.asarray(nxt))
-            self.stats.joins += 1
-            self.stats.prefill_tokens += len(prompt)
-            _count('serving.joins')
-            _count('serving.prefill_tokens', len(prompt))
-            self.journal.append(('join', r.id, self.step_no))
-            if _obs.enabled():
-                _obs.event('serving.join', model=self.name, request=r.id,
-                           slot=slot, prompt_len=len(prompt))
-                _obs.async_instant('prefill', r.id, cat='serving.request',
-                                   slot=slot, bucket=lb,
-                                   prompt_len=len(prompt))
-            max_new = int(self.default_max_new_tokens
-                          if r.max_new_tokens is None else r.max_new_tokens)
-            state = {'req': r, 'tokens': [first], 'last': first,
-                     'pos': len(prompt), 'max_new': max_new}
-            self.slots[slot] = state
-            self._maybe_finish(slot)
-        return did
-
-    def _decode_step(self):
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
-            return False
-        b = self.spec.max_batch
-        toks = np.zeros((b,), np.int32)
-        pos = np.zeros((b,), np.int32)
-        for i in active:
-            toks[i] = self.slots[i]['last']
-            pos[i] = self.slots[i]['pos']
-        self.stats.batches += 1
-        _count('serving.decode_steps')
-        self.stats.occupancy(len(active) / b)
-        try:
-            with _obs.timer('serving.decode', model=self.name,
-                            active=len(active)) as t:
-                self.cache, nxt = self._decode(self.cache, jnp.asarray(toks),
-                                               jnp.asarray(pos))
-        except Exception as e:                       # model bug: fail the
-            for i in active:                         # co-batched requests,
-                s = self.slots[i]                    # not the engine worker
-                self.slots[i] = None
-                self.stats.errors += 1
-                self.stats.leaves += 1
-                _count('serving.leaves')
-                self.journal.append(('leave', s['req'].id, self.step_no))
-                finish_request(s['req'], STATUS_ERROR,
-                               {'tokens': np.asarray(s['tokens'], np.int32)},
-                               error=e)
-            return True
-        nxt = np.asarray(nxt)
-        telemetry = _obs.enabled()
-        for i in active:
-            s = self.slots[i]
-            s['pos'] += 1
-            tok = int(nxt[i])
-            s['tokens'].append(tok)
-            s['last'] = tok
-            s['req'].add_phase_ms('decode', t.elapsed_ms)
-            self.stats.decode_tokens += 1
-            _count('serving.decode_tokens')
-            if telemetry:
-                _obs.async_instant('decode', s['req'].id,
-                                   cat='serving.request',
-                                   tokens=len(s['tokens']))
-            self._maybe_finish(i)
-        return True
-
-    # -- slot lifecycle -------------------------------------------------
-    def _maybe_finish(self, slot):
-        s = self.slots[slot]
-        r = s['req']
-        eos = self.spec.eos_id
-        done = (len(s['tokens']) >= s['max_new'] or
-                s['pos'] + 1 >= self.spec.max_seq or
-                (eos is not None and s['last'] == eos))
-        status = STATUS_OK
-        if r.expired():
-            done, status = True, STATUS_DEADLINE
-            self.stats.expired += 1
-            _count('serving.deadline_expired')
-        if not done:
-            return
-        self.slots[slot] = None
-        self.stats.leaves += 1
-        self.stats.completed += 1
-        _count('serving.leaves')
-        self.journal.append(('leave', r.id, self.step_no))
-        if _obs.enabled():
-            _obs.event('serving.leave', model=self.name, request=r.id,
-                       slot=slot, tokens=len(s['tokens']), status=status)
-        finish_request(r, status,
-                       {'tokens': np.asarray(s['tokens'], np.int32)})
-
-    def _expire(self, req):
-        self.stats.expired += 1
-        _count('serving.deadline_expired')
-        finish_request(req, STATUS_DEADLINE)

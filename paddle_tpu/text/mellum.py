@@ -15,7 +15,7 @@ the start of t's document):
   'sliding_attention': the plain table theta^(-2j / head_dim), and a query
   sees the last `sliding_window` keys of its document, itself among them;
   'full_attention': every key of its document, the YaRN table
-  (`nn.layer.linear_attention.yarn_inv_freq`) and cos and sin times
+  (`kernels.rotary.yarn_inv_freq`) and cos and sin times
   `attention_factor`.
 - Experts (`nn.SparseMoE`, `router='softmax'`): s = softmax(x W_r) over all
   `num_experts` in float32, the `num_experts_per_token` largest, weights

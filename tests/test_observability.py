@@ -9,9 +9,11 @@ Executor / optimizer / resilience / collective narrow waists, the
 ``tools/telemetry_dump.py`` CLI, and the telemetry-on-vs-off overhead
 smoke test (acceptance: within 5% on the CPU tier-1 run).
 """
+import ast
 import importlib.util
 import json
 import os
+import re
 import threading
 
 import numpy as np
@@ -529,6 +531,51 @@ def test_telemetry_dump_table_and_chrome(tmp_path, capsys):
 def test_telemetry_dump_missing_file(tmp_path, capsys):
     tool = _load_dump_tool()
     assert tool.main([str(tmp_path / 'nope.jsonl')]) == 2
+
+
+# ---------------------------------------------------------------------------
+# the environment surface: documented == read
+# ---------------------------------------------------------------------------
+
+def _option_names_read(path):
+    """The whole-string `PADDLE_TPU_*` constants of a file that are not (in)
+    a docstring: the names the code hands to `os.environ`."""
+    tree = ast.parse(open(path).read())
+    docstrings = {
+        id(node.body[0].value) for node in ast.walk(tree)
+        if isinstance(node, (ast.Module, ast.ClassDef, ast.FunctionDef,
+                             ast.AsyncFunctionDef))
+        and node.body and isinstance(node.body[0], ast.Expr)
+        and isinstance(node.body[0].value, ast.Constant)}
+    return {node.value for node in ast.walk(tree)
+            if isinstance(node, ast.Constant) and isinstance(node.value, str)
+            and id(node) not in docstrings
+            and re.fullmatch(r'PADDLE_TPU_[A-Z0-9_]+', node.value)}
+
+
+def test_documented_options_are_the_ones_read():
+    """`observability/state.py`'s docstring is the one file that documents
+    the package's environment surface ("catalogued here so one file
+    documents the env surface"): every option a module of the package reads
+    has a row there, and every row names an option something under
+    `paddle_tpu/` still reads. A deleted or added option cannot leave its
+    row behind, nor go without one."""
+    package = os.path.dirname(obs.__file__)
+    doc = ast.get_docstring(ast.parse(
+        open(os.path.join(package, 'state.py')).read()))
+    assert 'one file documents the env surface' in ' '.join(doc.split())
+    rows = {name for line in doc.splitlines() if line.startswith('- ``')
+            for name in re.findall(r'PADDLE_TPU_[A-Z0-9_]+', line)}
+    read_here = set().union(*(
+        _option_names_read(os.path.join(package, f))
+        for f in os.listdir(package) if f.endswith('.py')))
+    assert read_here - rows == set(), 'read in observability/, no row'
+    read_anywhere = set().union(*(
+        _option_names_read(os.path.join(d, f))
+        for d, _, files in os.walk(os.path.dirname(package))
+        for f in files if f.endswith('.py')))
+    assert rows - read_anywhere == set(), 'a row, and nothing reads it'
+    assert 'PADDLE_TPU_TELEMETRY' in rows & read_here      # the walk works
 
 
 # ---------------------------------------------------------------------------

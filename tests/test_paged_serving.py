@@ -189,7 +189,7 @@ class TestPageGatedAdmission:
 
 
 # ---------------------------------------------------------------------------
-# token-exactness: paged vs slot vs the no-cache oracle
+# token-exactness: paged vs the no-cache oracle
 # ---------------------------------------------------------------------------
 
 class TestPagedExactness:
@@ -201,7 +201,7 @@ class TestPagedExactness:
         eng.run_until_idle()
         return eng, [f.result(10) for f in futs]
 
-    def test_paged_matches_slot_and_reference_interleaved(self):
+    def test_paged_matches_reference_interleaved(self):
         lm = _lm(max_batch=2)
         prompts = [np.array([1, 2, 3], np.int32),
                    np.array([5, 6], np.int32),
@@ -209,12 +209,10 @@ class TestPagedExactness:
                    np.array([4], np.int32)]
         lens = (6, 3, 4, 8)                 # mixed: forces join/leave churn
         _, paged = self._serve(lm, prompts, lens, page_size=4)
-        _, slot = self._serve(lm, prompts, lens, kv_cache='slot')
-        for p, n, rp, rs in zip(prompts, lens, paged, slot):
+        for p, n, rp in zip(prompts, lens, paged):
             ref = _ref(lm, p, n)
             assert _tokens(rp) == ref, (p, _tokens(rp), ref)
-            assert _tokens(rs) == ref
-        assert all(r.ok for r in paged + slot)
+        assert all(r.ok for r in paged)
 
     def test_page_reuse_after_free_stays_exact(self):
         # pool sized so the second wave MUST reuse the first wave's freed
@@ -667,10 +665,6 @@ class TestPagedLifecycle:
     def test_register_validates_paged_knobs(self):
         eng = ServingEngine()
         lm = _lm()
-        with pytest.raises(ValueError, match="kv_cache must be"):
-            eng.register('a', generative=lm, kv_cache='magnetic-tape')
-        with pytest.raises(ValueError, match='paged'):
-            eng.register('b', generative=lm, kv_cache='slot', draft=_lm())
         with pytest.raises(ValueError, match='only to'):
             eng.register('c', predict_fn=lambda f: f['x'],
                          example={'x': np.zeros((4,), np.float32)},
@@ -680,6 +674,14 @@ class TestPagedLifecycle:
                          draft=_lm(max_seq=lm.max_seq // 2))
         with pytest.raises(ValueError, match='draft_k'):
             eng.register('e', generative=lm, draft=_lm(), draft_k=0)
+
+    @pytest.mark.parametrize('kind', ['slot', 'paged'])
+    def test_register_rejects_kv_cache(self, kind):
+        # one cache, so no option names it: Python's own TypeError
+        eng = ServingEngine()
+        with pytest.raises(TypeError, match='kv_cache'):
+            eng.register('lm', generative=_lm(), kv_cache=kind)
+        assert 'lm' not in eng._models
 
     def test_kv_telemetry_and_dump_columns(self, tmp_path):
         obs.enable()

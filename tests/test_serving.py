@@ -283,6 +283,32 @@ class TestContinuousBatching:
             # joined/left at different iterations
             assert got == ref, (p, got, ref)
 
+    def test_spec_with_only_the_paged_contract_serves(self):
+        lm = self._lm()
+
+        class PagedOnly(serving.GenerativeSpec):
+            """The four methods of the contract and nothing else."""
+            max_batch, max_seq = lm.max_batch, lm.max_seq
+            prompt_buckets = lm.prompt_buckets
+
+            init_paged_cache = staticmethod(lm.init_paged_cache)
+            prefill_chunk = staticmethod(lm.prefill_chunk)
+            decode_paged = staticmethod(lm.decode_paged)
+            verify_tokens = staticmethod(lm.verify_tokens)
+
+        for gone in ('init_cache', 'prefill', 'decode'):
+            assert not hasattr(serving.GenerativeSpec, gone), gone
+        eng = ServingEngine()
+        ep = eng.register('lm', generative=PagedOnly())
+        prompts = [np.array([1, 2, 3], np.int32), np.array([5, 6], np.int32)]
+        lens = (6, 3)                 # the short one leaves mid-flight
+        futs = [ep.submit({'tokens': p}, max_new_tokens=n)
+                for p, n in zip(prompts, lens)]
+        eng.run_until_idle()
+        for p, n, f in zip(prompts, lens, futs):
+            assert list(f.result(10).outputs['tokens']) == \
+                list(lm.reference_decode(p, n))
+
     def test_eos_stops_decode_early(self):
         lm = self._lm()
         prompt = np.array([1, 2, 3], np.int32)
@@ -324,11 +350,6 @@ class TestContinuousBatching:
         assert f.result(10).ok
         with pytest.raises(ValueError, match='max_seq'):
             ep.submit({'tokens': np.arange(32, dtype=np.int32)})
-        # the slot-cache baseline keeps the PR-6 bucket cap
-        ep_slot = eng.register('lm_slot', generative=self._lm(),
-                               kv_cache='slot')
-        with pytest.raises(ValueError, match='largest prompt bucket'):
-            ep_slot.submit({'tokens': np.arange(9, dtype=np.int32)})
 
 
 # ---------------------------------------------------------------------------

@@ -4,9 +4,9 @@ The two halves of ROADMAP item 2's robustness story, tested end to end:
 
 - **Admission isolation**: a ``faultinject.tenant_storm`` flooding one
   tenant of a shared engine sheds as ``'quota'`` at the front door when
-  per-tenant ``TenantPolicy`` quotas are on, and the victim tenant's
-  p99 stays within 1.5x its no-storm solo baseline — while quotas OFF
-  the same storm degrades the victim without bound. DRR pop order under
+  per-tenant ``TenantPolicy`` quotas are on, and every victim request is
+  served within a pump of its no-storm solo wait — while quotas OFF the
+  same storm queues the victim behind the backlog. DRR pop order under
   ``pump()`` is exactly deterministic, weights honored across pops.
 - **Elastic replica count**: the ``FleetAutoscaler`` grows on sustained
   SLO burn (``faultinject.burn_ramp`` through the real signal path),
@@ -21,8 +21,6 @@ The two halves of ROADMAP item 2's robustness story, tested end to end:
 Everything is manual-drive (``pump()``) on a virtual arbiter clock —
 queue interleavings are pinned by the pump cadence, not wall-clock.
 """
-import time
-
 import numpy as np
 import pytest
 
@@ -41,14 +39,6 @@ from paddle_tpu.serving import admission
 pytestmark = pytest.mark.serving
 
 
-def _mlp_fn(w, work_ms=0.0):
-    def predict(feeds):
-        if work_ms:
-            time.sleep(work_ms / 1000.0)   # deterministic latency floor
-        return feeds['x'] @ w
-    return predict
-
-
 def _example():
     return {'x': np.zeros((8,), np.float32)}
 
@@ -57,18 +47,13 @@ def _one():
     return {'x': np.ones((8,), np.float32)}
 
 
-def _engine(tenants=None, buckets=(1, 2, 4), jit=False, capacity=64,
-            work_ms=0.0):
+def _engine(tenants=None, buckets=(1, 2, 4), jit=False, capacity=64):
     eng = ServingEngine(queue_capacity=capacity, tenants=tenants)
-    eng.register('m', predict_fn=_mlp_fn(np.eye(8, dtype=np.float32),
-                                         work_ms),
+    w = np.eye(8, dtype=np.float32)
+    eng.register('m', predict_fn=lambda feeds: feeds['x'] @ w,
                  example=_example(), bucket_spec=BucketSpec(buckets),
                  jit_compile=jit)
     return eng   # manual drive: pump cadence IS the clock
-
-
-def _p99(lat):
-    return sorted(lat)[int(0.99 * (len(lat) - 1))] if lat else 0.0
 
 
 def _compiles():
@@ -152,11 +137,12 @@ class TestWeightedFairQueue:
 # tenant storm: quota isolation
 # ---------------------------------------------------------------------------
 
-def _storm_round(quotas, storm=True, ticks=10, qps=6.0, work_ms=5.0,
-                 seed=0):
+def _storm_round(quotas, storm=True, ticks=10, qps=6.0, seed=0):
     """One manual-drive round: per tick one virtual-clock storm burst +
-    one victim request + one pump. Returns victim tail, per-reason storm
-    sheds (as seen by the injector) and the admission ledger."""
+    one victim request + one pump. Returns how many pumps each victim
+    request waited from its submit to its completion (what the virtual
+    clock and the queue decide: no wall time), per-reason storm sheds (as
+    seen by the injector) and the admission ledger."""
     admission.reset_tenant_stats()
     clock = [0.0]
     arb = None
@@ -165,8 +151,17 @@ def _storm_round(quotas, storm=True, ticks=10, qps=6.0, work_ms=5.0,
         arb.set_policy(TenantPolicy('storm', weight=1.0, rate=0.5,
                                     burst=1))
         arb.set_policy(TenantPolicy('victim', weight=4.0, rate=1000.0))
-    eng = _engine(tenants=arb, work_ms=work_ms)
+    eng = _engine(tenants=arb)
     pend, shed = [], {}
+    pumps, submitted_at, waits = [0], {}, []
+
+    def pump():
+        worked = eng.pump()
+        pumps[0] += 1
+        for p in [p for p in submitted_at if p.done()]:
+            waits.append(pumps[0] - submitted_at.pop(p))
+        return worked
+
     for t in range(ticks):
         clock[0] = float(t)
         if storm:
@@ -177,19 +172,16 @@ def _storm_round(quotas, storm=True, ticks=10, qps=6.0, work_ms=5.0,
                 shed[r] = shed.get(r, 0) + n
         try:
             pend.append(eng.submit('m', _one(), tenant='victim'))
+            submitted_at[pend[-1]] = pumps[0]
         except QueueFullError:
             pass
-        eng.pump()
-    while eng.pump():
+        pump()
+    while pump():
         pass
-    lats = []
-    for p in pend:
-        r = p.result(timeout=10)
-        if r.ok:
-            lats.append(r.latency_ms)
+    completed = sum(p.result(timeout=10).ok for p in pend)
     ledger = admission.tenant_stats()
     eng.stop()
-    return {'p99': _p99(lats), 'completed': len(lats), 'offered': ticks,
+    return {'waits': waits, 'completed': completed, 'offered': ticks,
             'shed': shed, 'ledger': ledger}
 
 
@@ -216,14 +208,16 @@ class TestTenantIsolation:
         obs.enable()
         on = _storm_round(quotas=True)
         snap = obs.snapshot()
-        base = max(solo['p99'], 1.0)
-        # quotas ON: the victim's tail barely moves off its solo
-        # baseline, and every victim request completes
-        assert on['p99'] <= 1.5 * base, (on['p99'], solo['p99'])
-        assert on['completed'] == on['offered']
+        # alone, a victim request is served by the pump after its submit
+        base = max(solo['waits'])
+        assert base == 1 and len(solo['waits']) == solo['offered']
+        # quotas ON: every victim request completes, none more than one
+        # pump (the slack) later than it would alone
+        assert on['completed'] == on['offered'] == len(on['waits'])
+        assert max(on['waits']) <= base + 1, (on['waits'], solo['waits'])
         # quotas OFF: the same storm queues the victim behind the whole
         # backlog — degradation, not isolation
-        assert off['p99'] >= 2.0 * base, (off['p99'], solo['p99'])
+        assert max(off['waits']) >= 2 * base, (off['waits'], solo['waits'])
         # the storm was shed at the front door as 'quota', nothing else
         assert set(on['shed']) == {'quota'} and sum(on['shed'].values()) > 0
         assert 'quota' not in off['shed']
@@ -456,7 +450,7 @@ class TestDoctor:
 
     def test_noisy_neighbor_fires_on_storm_quiet_on_balanced(self):
         obs.enable()
-        _storm_round(quotas=True, work_ms=0.0)
+        _storm_round(quotas=True)
         hits = list(doc.detect_noisy_neighbor(events=obs.event_log(),
                                               snapshot=obs.snapshot()))
         assert len(hits) == 1
