@@ -161,6 +161,25 @@ layout_digest = _rows.layout_digest
 
 
 # ------------------------------------------------------------- operations
+def kernel_shapes(cfg):
+    """As `kimi_linear.kernel_shapes` says. Here: rotary latent attention at
+    192 / 128 in every block, the prediction module's among them; the
+    rotation turns the last 64 channels of every query head and the one
+    shared 64 of the keys; no convolution and no delta rule."""
+    heads, rope = cfg['num_attention_heads'], cfg['qk_rope_head_dim']
+    blocks = _blocks(cfg)
+    return {
+        'attention': [
+            {'window': None, 'heads': heads, 'kv_heads': heads,
+             'qk_dim': cfg['qk_nope_head_dim'] + rope,
+             'v_dim': cfg['v_head_dim']} for _ in blocks],
+        'rotary': [(heads + 1) * rope for _ in blocks],
+        'experts': {'layers': sum(sparse for _, sparse in blocks),
+                    'hidden': cfg['hidden_size'],
+                    'width': cfg['moe_intermediate_size'],
+                    'held': cfg['n_routed_experts'], 'products': 3}}
+
+
 
 def flops_per_sample(cfg, traffic):
     """Operations one packed row's forward and backward passes REQUIRE: 2 per

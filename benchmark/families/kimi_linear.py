@@ -222,6 +222,52 @@ def augment(traffic, batch, rs):
     return batch
 
 
+# ---------------------------------------------------------------- kernels
+
+def kernel_shapes(cfg):
+    """What one step asks of each kernel family, at the published sizes and
+    in no kernel's own terms: what the `*_roofline` readers under
+    `layer_metrics/` count operations and bytes from. Keys, each present
+    only where the model has the mechanism:
+
+    `attention`   one entry a softmax-attention layer the step runs:
+                  `window` (keys a query sees, None: all of its document),
+                  `heads`, `kv_heads`, `qk_dim`, `v_dim`
+    `rotary`      one entry a layer that turns q and k: the channels turned
+                  a token, q's and k's together
+    `short_conv`  one entry a call: `channels`, `taps`, `bias`
+    `experts`     `layers`, `hidden`, `width`, `held`, `products` (3 gated:
+                  gate, up, down; 2 ungated)
+    `delta_rule`  `layers`, `heads`, `key_dim`, `value_dim`, `decays` (a
+                  token and head: `key_dim` where every channel has its own)
+
+    Here: the NoPE latent layers at 192 / 128 on every head (the kernels
+    are handed k with the shared 64 broadcast); three convolutions a KDA
+    layer over all 4096 channels; the delta rule with a decay a channel."""
+    lin = _lin(cfg)
+    kinds = [_kinds(cfg, i) for i in range(cfg['num_hidden_layers'])]
+    kda = sum(a == 'kda' for a, _ in kinds)
+    heads = cfg['num_attention_heads']
+    inner = lin['num_heads'] * lin['head_dim']
+    return {
+        'attention': [
+            {'window': None, 'heads': heads, 'kv_heads': heads,
+             'qk_dim': cfg['qk_nope_head_dim'] + cfg['qk_rope_head_dim'],
+             'v_dim': cfg['v_head_dim']}
+            for a, _ in kinds if a == 'mla'],
+        'short_conv': [
+            {'channels': inner, 'taps': lin['short_conv_kernel_size'],
+             'bias': False} for _ in range(3 * kda)],
+        'experts': {'layers': sum(f == 'moe' for _, f in kinds),
+                    'hidden': cfg['hidden_size'],
+                    'width': cfg['moe_intermediate_size'],
+                    'held': cfg['num_experts'], 'products': 3},
+        'delta_rule': {'layers': kda, 'heads': lin['num_heads'],
+                       'key_dim': lin['head_dim'],
+                       'value_dim': lin['head_dim'],
+                       'decays': lin['head_dim']}}
+
+
 # ------------------------------------------------------------- operations
 
 def flops_per_sample(cfg, traffic):

@@ -110,6 +110,25 @@ layout_digest = _rows.layout_digest
 
 
 # ------------------------------------------------------------- operations
+def kernel_shapes(cfg):
+    """As `kimi_linear.kernel_shapes` says. Here: grouped-query attention in
+    every layer, a window where `layer_types` says so; the rotation turns
+    the whole head of every query and K/V head; every layer sparse."""
+    heads, kv, d = (cfg['num_attention_heads'], cfg['num_key_value_heads'],
+                    cfg['head_dim'])
+    kinds = cfg['layer_types'][:cfg['num_hidden_layers']]
+    return {
+        'attention': [
+            {'window': cfg['sliding_window']
+             if kind == 'sliding_attention' else None,
+             'heads': heads, 'kv_heads': kv, 'qk_dim': d, 'v_dim': d}
+            for kind in kinds],
+        'rotary': [(heads + kv) * d for _ in kinds],
+        'experts': {'layers': len(kinds), 'hidden': cfg['hidden_size'],
+                    'width': cfg['moe_intermediate_size'],
+                    'held': cfg['num_experts'], 'products': 3}}
+
+
 
 def flops_per_sample(cfg, traffic):
     """Operations one packed row's forward and backward passes REQUIRE of

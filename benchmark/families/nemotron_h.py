@@ -157,6 +157,32 @@ layout_digest = _rows.layout_digest
 
 
 # ------------------------------------------------------------- operations
+def kernel_shapes(cfg):
+    """As `kimi_linear.kernel_shapes` says. Here: 32 query heads on 2 K/V
+    heads in a `*` layer, no rotation; an `M` layer's convolution with its
+    bias over x, B and C (the program makes three calls of it, 4096 + 1024
+    + 1024 channels); ungated experts (up, down) at the published 1856; the
+    state-space rule is `ssd.scan_roofline`'s, from the configuration's own
+    keys."""
+    inner, bc, _ = _widths(cfg)
+    letters = _letters(cfg)
+    return {
+        'attention': [
+            {'window': None, 'heads': cfg['num_attention_heads'],
+             'kv_heads': cfg['num_key_value_heads'],
+             'qk_dim': cfg['head_dim'], 'v_dim': cfg['head_dim']}
+            for letter in letters if letter == '*'],
+        'short_conv': [
+            {'channels': width, 'taps': cfg['conv_kernel'],
+             'bias': cfg['use_conv_bias']}
+            for letter in letters if letter == 'M'
+            for width in (inner, bc, bc)],
+        'experts': {'layers': letters.count('E'),
+                    'hidden': cfg['hidden_size'],
+                    'width': cfg['moe_intermediate_size'],
+                    'held': cfg['n_routed_experts'], 'products': 2}}
+
+
 
 def flops_per_sample(cfg, traffic):
     """Operations one packed row's forward and backward passes REQUIRE of

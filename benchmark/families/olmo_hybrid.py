@@ -135,6 +135,28 @@ layout_digest = _rows.layout_digest
 
 
 # ------------------------------------------------------------- operations
+def kernel_shapes(cfg):
+    """As `kimi_linear.kernel_shapes` says, of THIS CHIP'S SHARE. Here: the
+    held heads of plain multi-head attention in the full layers, no
+    rotation; three convolutions a linear layer (q and k at 96 a head, v at
+    192); the delta rule with one decay a head (`gdn.scan_roofline` counts
+    it from the configuration's own keys); no experts."""
+    heads = _held(cfg)[1]
+    K, Vd = cfg['linear_key_head_dim'], cfg['linear_value_head_dim']
+    D, taps = cfg['assumed_values']['head_dim'], cfg['linear_conv_kernel_dim']
+    kinds = [_kind(cfg, i) for i in range(cfg['num_hidden_layers'])]
+    linear = kinds.count('linear_attention')
+    return {
+        'attention': [
+            {'window': None, 'heads': heads, 'kv_heads': heads, 'qk_dim': D,
+             'v_dim': D} for kind in kinds if kind == 'full_attention'],
+        'short_conv': [
+            {'channels': heads * width, 'taps': taps, 'bias': False}
+            for _ in range(linear) for width in (K, K, Vd)],
+        'delta_rule': {'layers': linear, 'heads': heads, 'key_dim': K,
+                       'value_dim': Vd, 'decays': 1}}
+
+
 
 def flops_per_sample(cfg, traffic):
     """Operations one packed row's forward and backward passes REQUIRE of

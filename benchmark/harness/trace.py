@@ -23,9 +23,20 @@ import sys
 DEVICE_PLANE = re.compile(r'^/device:TPU:(\d+)$')
 OPS_LINE = 'XLA Ops'
 MODULES_LINE = 'XLA Modules'
+# (`async-collective-start` / `-done`: the fusions that issue and await a
+# sharded parameter's use-time gather, named so by the compiler)
 COLLECTIVE = re.compile(
     r'^(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute|'
-    r'collective-broadcast)')
+    r'collective-broadcast|async-collective-(start|done))')
+# a collective the compiler runs as a FUSION on the compute stream, named
+# like any other (`%fusion.14 = f32[30522,256] fusion(f32[30522,1024]),
+# kind=kCustom, calls=%all-reduce-scatter.2`): only its called computation
+# says what it is. A sharded step's gradient reduction is 86 of them, ~10.9
+# ms a step of the four-chip cell, which the instruction's NAME never showed
+# (PERF.md, Findings PR 45 and PR 47)
+FUSED_COLLECTIVE = re.compile(
+    r'\bcalls=%?(all-gather|all-reduce|reduce-scatter|all-to-all|'
+    r'collective-permute|collective-broadcast)')
 
 
 def read_xplane(path, span_names=()):
@@ -161,6 +172,13 @@ def op_family(name):
     return '%s %s' % (head, kind.group(1)) if kind else head
 
 
+def is_collective(name):
+    """Whether an op event is a collective: by its instruction's own name,
+    or by the computation a fusion calls."""
+    return bool(COLLECTIVE.match(op_head(name))
+                or FUSED_COLLECTIVE.search(name))
+
+
 def reduce(trace, scopes=()):
     """Per chip: window, busy time, time under each scope, exposed time of
     collectives, operations by time, idle gaps by host span."""
@@ -169,10 +187,8 @@ def reduce(trace, scopes=()):
         lo, hi, steps = steady_window(dev)
         ops = leaves(clip(dev['ops'], lo, hi))
         busy = union((o[1], o[2]) for o in ops)
-        coll = union((o[1], o[2]) for o in ops
-                     if COLLECTIVE.match(op_head(o[0])))
-        compute = union((o[1], o[2]) for o in ops
-                        if not COLLECTIVE.match(op_head(o[0])))
+        coll = union((o[1], o[2]) for o in ops if is_collective(o[0]))
+        compute = union((o[1], o[2]) for o in ops if not is_collective(o[0]))
         by_scope = {}
         for scope in scopes:
             hit = [o for o in ops if scope in op_head(o[0])]

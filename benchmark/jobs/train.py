@@ -521,11 +521,20 @@ def run(*, cell, config, traffic, limits, family, reference, seed, seconds,
     # trace: a pool batch's intervals (a late notice repaired), their mean
     batch_ms = {b: float(np.mean([ms for ms, bb in zip(repaired, batch_of)
                                   if bb == b])) for b in set(batch_of)}
+    # the calls made under the trace, each from the start of its
+    # `input.wait` to the end of its `step.dispatch`: what the program
+    # recorded inside them (a get, the step's counters) is theirs
+    behind = spans.records[window_mark:]
+    calls_ns = [(w[1], d[2]) for w, d in zip(
+        (r for r in behind if r[0] == 'input.wait'),
+        (r for r in behind if r[0] == 'step.dispatch'))]
     context = {'spans': spans, 'window': (mark, window_mark),
                'traced_step_ms': [batch_ms[order[k]] for k in under_trace],
+               'traced_calls_ns': calls_ns[-len(under_trace):],
                'compiles_in_window': compiles_in_window, 'trace': reduced,
                'config': config, 'traffic': traffic, 'rows': rows,
-               'chips': chips, 'peaks': peak}
+               'chips': chips, 'peaks': peak, 'family': family,
+               'flops_per_sample': flops}
     metrics = {}
     for m in wanted:
         value = readers[m['name']].read(context)
@@ -540,8 +549,9 @@ def run(*, cell, config, traffic, limits, family, reference, seed, seconds,
         worst = max(per_chip, key=lambda c: c['window_s'] - c['busy_s'])
         result['breakdown'] = {'device_ops': worst['device_ops'],
                                'idle_gaps': worst['idle_gaps']}
-        say(phase='trace', per_chip={str(k): {
-            kk: vv for kk, vv in v.items()
-            if kk not in ('device_ops', 'idle_gaps')}
-            for k, v in reduced.items()})
+        say(phase='trace', traced_step_ms=context['traced_step_ms'],
+            per_chip={str(k): {
+                kk: vv for kk, vv in v.items()
+                if kk not in ('device_ops', 'idle_gaps')}
+                for k, v in reduced.items()})
     return result

@@ -1,21 +1,27 @@
 """Kernels: the flash-attention kernels' share of their roofline on PACKED
-rows with grouped-query heads and window layers, forward and backward
-together, from the device trace.
+rows, whatever the layers' heads (grouped-query, latent at 192 / 128, a
+share by heads) and masks (causal, a window), forward and backward
+together, from the device trace (`harness/roofline.py` says what the time
+is: the recomputation's forward kernel is in it, the share is of what the
+step pays).
 
-Time: the union of the events under the scope `flash_attention.pallas`, per
-step, on the slowest chip (the recomputation's forward kernel is in it: the
-share is of what the step pays). Operations and bytes one step REQUIRES of
-attention, whatever implements it: the (query, key) pairs a layer's mask
-admits, counted from the pool's LAYOUT (the traffic's `layout_seed`, as
+Operations and bytes one step REQUIRES of attention, whatever implements
+it, over the layers the cell's family lists (`kernel_shapes`' `attention`:
+one entry a layer the step runs, a prediction module's among them, with its
+window, its query and K/V heads and the sizes of a query/key head and a
+value head; since PR 47: until then the reader read Mellum's own keys and
+served that cell alone). The (query, key) pairs a layer's mask admits are
+counted from the pool's LAYOUT (the traffic's `layout_seed`, as
 `families/kimi_linear.make_pool` draws the rows' documents): a full layer
 the causal pairs inside documents, n (n + 1) / 2 a document of n; a window
 layer those inside the window too, w (w + 1) / 2 + (n - w) w where n > w.
-Per pair and query head: forward QK^T and PV (2 products of 2 d), backward
-dV, dP, dQ, dK (4 of them), 12 d operations; the backward's recomputed
-scores do not count. Bytes in the configuration's compute type: q read and o
-written at the QUERY heads, k and v read at the K/V heads forward; q, o, dO
-read and dQ written at the query heads, k, v read and dK, dV written at the
-K/V heads backward.
+Per pair and query head: forward QK^T (2 d_qk) and PV (2 d_v), backward dV
+and dP (2 d_v each), dQ and dK (2 d_qk each): 6 d_qk + 6 d_v operations, 12
+d where the two are one size; the backward's recomputed scores do not count.
+Bytes in the configuration's compute type: q read and o written at the
+QUERY heads, k and v read at the K/V heads forward; q, o, dO read and dQ
+written at the query heads, k, v read and dK, dV written at the K/V heads
+backward.
 
 The pairs are the MEAN over the pool's batches, the time that of the five
 or six steps the trace caught: a reading swings a few per cent with which
@@ -26,9 +32,9 @@ and reports nothing."""
 import numpy as np
 
 from families import kimi_linear
+from harness import roofline
 
 SCOPES = ('flash_attention.pallas',)
-BYTES_PER_ELEMENT = 2
 
 
 def pairs_per_row(traffic, rows, window):
@@ -44,28 +50,20 @@ def pairs_per_row(traffic, rows, window):
 
 
 def required(ctx):
-    cfg, traffic = ctx['config'], ctx['traffic']
+    traffic = ctx['traffic']
     rows = ctx['rows'] // ctx['chips']          # per chip
-    L, d = traffic['seq_len'], cfg['head_dim']
-    heads, kv = cfg['num_attention_heads'], cfg['num_key_value_heads']
-    kinds = cfg['layer_types'][:cfg['num_hidden_layers']]
-    pairs = {'full_attention': pairs_per_row(traffic, rows, None),
-             'sliding_attention': pairs_per_row(traffic, rows,
-                                                cfg['sliding_window'])}
-    flops = rows * heads * 12 * d * sum(pairs[kind] for kind in kinds)
-    bytes_ = len(kinds) * rows * L * d * (6 * heads + 6 * kv) \
-        * BYTES_PER_ELEMENT
-    return flops, bytes_
+    item = roofline.ITEM[ctx['config']['compute_dtype']]
+    layers = roofline.shapes(ctx, 'attention')
+    pairs = {w: pairs_per_row(traffic, rows, w)
+             for w in {layer['window'] for layer in layers}}
+    flops = elements = 0
+    for layer in layers:
+        sizes = layer['qk_dim'] + layer['v_dim']
+        flops += rows * layer['heads'] * pairs[layer['window']] * 6 * sizes
+        elements += rows * traffic['seq_len'] * 3 * sizes \
+            * (layer['heads'] + layer['kv_heads'])
+    return flops, elements * item
 
 
 def read(ctx):
-    chips = [c for c in ctx['trace'].values()
-             if c['steps'] and c['scopes'][SCOPES[0]]['events']]
-    if not chips:
-        return None
-    seconds = max(c['scopes'][SCOPES[0]]['seconds'] / c['steps']
-                  for c in chips)
-    flops, bytes_ = required(ctx)
-    least = max(flops / ctx['peaks']['bf16_flops_per_s'],
-                bytes_ / ctx['peaks']['hbm_bytes_per_s'])
-    return 100.0 * least / seconds
+    return roofline.read(ctx, SCOPES[0], required, 'attention')

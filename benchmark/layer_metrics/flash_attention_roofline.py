@@ -12,6 +12,8 @@ in the configuration's compute type; at seq 512 the operations bound it
 where the compiler holds operands in on-chip memory. A step whose attention
 took the XLA path has no such event and reports nothing."""
 
+from harness import roofline
+
 SCOPES = ('flash_attention.pallas',)
 
 
@@ -26,13 +28,4 @@ def required(ctx):
 
 
 def read(ctx):
-    chips = [c for c in ctx['trace'].values()
-             if c['steps'] and c['scopes'][SCOPES[0]]['events']]
-    if not chips:
-        return None
-    seconds = max(c['scopes'][SCOPES[0]]['seconds'] / c['steps']
-                  for c in chips)
-    flops, bytes_ = required(ctx)
-    least = max(flops / ctx['peaks']['bf16_flops_per_s'],
-                bytes_ / ctx['peaks']['hbm_bytes_per_s'])
-    return 100.0 * least / seconds
+    return roofline.read(ctx, SCOPES[0], required)

@@ -15,6 +15,8 @@ operations), so the share says how far the kernels are from streaming their
 operands once. A step whose rule took the XLA form has no such event and
 reports nothing."""
 
+from harness import roofline
+
 SCOPES = ('delta_rule.pallas',)
 BYTES_PER_ELEMENT = 4
 
@@ -33,13 +35,4 @@ def required(ctx):
 
 
 def read(ctx):
-    chips = [c for c in ctx['trace'].values()
-             if c['steps'] and c['scopes'][SCOPES[0]]['events']]
-    if not chips:
-        return None
-    seconds = max(c['scopes'][SCOPES[0]]['seconds'] / c['steps']
-                  for c in chips)
-    flops, bytes_ = required(ctx)
-    least = max(flops / ctx['peaks']['bf16_flops_per_s'],
-                bytes_ / ctx['peaks']['hbm_bytes_per_s'])
-    return 100.0 * least / seconds
+    return roofline.read(ctx, SCOPES[0], required)

@@ -15,6 +15,8 @@ against 9.4 MFLOP, 131 ns against 48 ns), so the share says how far the
 kernels are from streaming their operands once. A step whose rule took the
 XLA form has no such event and reports nothing."""
 
+from harness import roofline
+
 SCOPES = ('ssd.pallas',)
 BYTES_PER_ELEMENT = 4
 
@@ -34,13 +36,4 @@ def required(ctx):
 
 
 def read(ctx):
-    chips = [c for c in ctx['trace'].values()
-             if c['steps'] and c['scopes'][SCOPES[0]]['events']]
-    if not chips:
-        return None
-    seconds = max(c['scopes'][SCOPES[0]]['seconds'] / c['steps']
-                  for c in chips)
-    flops, bytes_ = required(ctx)
-    least = max(flops / ctx['peaks']['bf16_flops_per_s'],
-                bytes_ / ctx['peaks']['hbm_bytes_per_s'])
-    return 100.0 * least / seconds
+    return roofline.read(ctx, SCOPES[0], required)

@@ -49,6 +49,20 @@ def test_reduce_synthetic():
         'step.dispatch': pytest.approx(10e-9),
         'no benchmark span': pytest.approx(10e-9)}
     assert out['device_ops'][0] == ['fusion kOutput', pytest.approx(130e-9)]
+    # a reduce-scatter the compiler runs as a fusion on the compute stream
+    # (the four-chip cell's gradient reduction) is a collective by what it
+    # calls, and nothing overlaps it
+    dev['ops'].append(('%fusion.14 = f32[2] fusion(f32[8] %p), kind=kCustom, '
+                       'calls=%all-reduce-scatter.2', 190, 196))
+    fused = trace.reduce({'devices': {0: dev}, 'host': []})[0]
+    assert fused['collective_s'] == pytest.approx(26e-9)
+    assert fused['exposed_collective_s'] == pytest.approx(16e-9)
+    assert not trace.is_collective(
+        '%fusion.9 = f32[8] fusion(f32[8] %all-gather.3), kind=kLoop, '
+        'calls=%fused_computation.9')
+    assert trace.is_collective(
+        '%async-collective-start = (f32[256,1024], f32[1024,1024]) fusion('
+        '%custom-call.175), kind=kCustom, calls=%fused_computation.1080')
 
 
 def test_reduce_recorded_trace():
@@ -136,7 +150,8 @@ def test_manifest_names_files_that_exist():
         assert callable(reader.read)
 
 
-def test_idle_share_takes_its_period_from_the_untraced_window():
+def test_idle_share_of_a_stretched_trace_takes_the_untraced_windows_period(
+        capsys):
     """ResNet-50's traced steps on the chip (my chip run, PR 24): 6 whole
     steps, 0.7317 s busy in a traced window of 2.863 s that the profiler's
     slow host stretched; the untraced window's steps came every 122.0055 ms,
@@ -146,25 +161,55 @@ def test_idle_share_takes_its_period_from_the_untraced_window():
            'trace': {0: {'steps': 6, 'busy_s': 0.731716132,
                          'window_s': 2.863382462}}}
     assert reader.read(ctx) == pytest.approx(0.043, abs=0.001)
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line['per_chip']['0']['form'] == 'window'
     assert reader.read({'traced_step_ms': [1.0] * 7, 'trace': {}}) is None
 
 
-def test_idle_share_takes_the_period_of_the_batches_that_were_traced():
-    """A packed cell: the window's steps run 715-950 ms by their batch
-    (median 822) and the six whole traced steps are the pool's longest,
-    the device busy 99.94% of each. Over six MEDIAN periods that read -11;
-    over the window's readings of the same batches it is what the trace
-    says. A trace that caught five whole steps takes the five before its
-    last."""
+def test_idle_share_of_a_trace_that_kept_up_is_the_traces_own(capsys):
+    """A routed cell (Kimi, ledger PR 46): the traced steps took 692.7 ms
+    each under the routing of the window's END, the device busy 99.92% of
+    them; the window read the same pool batches at 682 ms, tens of steps
+    earlier under a lighter routing, and over that the share read -1.5.
+    A trace that caught five whole steps takes its own span all the same;
+    a chip the host left waiting falls back to the window's reading."""
     reader = _tiny.harness_run.load_module('layer_metrics', 'device.idle_pct')
-    ms = [905.0, 949.0, 921.0, 934.0, 940.0, 917.0, 715.0]
-    chip = {'steps': 6, 'busy_s': 0.9994 * sum(ms[:6]) / 1e3}
+    ms = [682.0] * 7
+    chip = {'steps': 6, 'window_s': 6 * 0.6927, 'busy_s': 0.9992 * 6 * 0.6927}
+    assert 100.0 * (1.0 - chip['busy_s'] / (6 * 0.682)) < -1.4
     got = reader.read({'traced_step_ms': ms, 'trace': {0: chip}})
-    assert got == pytest.approx(0.06, abs=1e-6)
-    assert 100.0 * (1.0 - chip['busy_s'] / (6 * 0.822)) < -10
-    chip = {'steps': 5, 'busy_s': 0.9994 * sum(ms[1:6]) / 1e3}
-    assert reader.read({'traced_step_ms': ms, 'trace': {0: chip}}) \
-        == pytest.approx(0.06, abs=1e-6)
+    assert got == pytest.approx(0.08, abs=1e-6)
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line['per_chip']['0'] == {
+        'form': 'trace', 'steps': 6, 'idle_pct': pytest.approx(0.08),
+        'period_ms': pytest.approx(692.7)}
+    slow = {'steps': 5, 'window_s': 5 * 0.800, 'busy_s': 0.9 * 5 * 0.700}
+    got = reader.read({'traced_step_ms': [700.0] * 7,
+                       'trace': {0: chip, 1: slow}})
+    assert got == pytest.approx(10.0)
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert [line['per_chip'][k]['form'] for k in '01'] == ['trace', 'window']
+
+
+def test_a_starved_traced_get_sends_the_period_back_to_the_window(
+        monkeypatch):
+    """The host's own record decides before the busy share does: a get of
+    the whole traced steps that found the queue empty."""
+    from harness import period, program
+    gets = [{'name': 'prefetch.get_wait', 'ph': 'X', 't0_ns': t, 't1_ns': t + 1,
+             'args': {'gets': i, 'starved': 3 + (i >= 4), 'depth': 1}}
+            for i, t in enumerate(range(5, 75, 10))]
+    monkeypatch.setattr(program, 'enable', lambda: type('o', (), {
+        'trace_events': staticmethod(lambda: gets)}))
+    calls = [(t, t + 8) for t in range(0, 70, 10)]
+    chip = {'steps': 3, 'window_s': 3.0, 'busy_s': 2.99}
+    ctx = {'traced_step_ms': [900.0] * 7, 'traced_calls_ns': calls}
+    assert program.traced_calls(ctx, 3) == calls[3:6]
+    assert period.starved(ctx, 3)              # get 4 lies in call 4
+    assert period.read(ctx, chip) == (pytest.approx(2.7), 'window')
+    assert not period.starved(ctx, 2)          # calls 4 and 5: 4 and 4
+    assert period.read(ctx, dict(chip, steps=2, window_s=2.0, busy_s=1.99)) \
+        == (2.0, 'trace')
 
 
 # ------------------------------------------------- the packed rows' layout
@@ -232,6 +277,206 @@ def test_layout_holds_the_pairs_the_operation_count_expects(two_pools):
     # the layout keeps its spread: not sorted, balanced or padded
     each = [pairs(b[0][1]) for b in pool]
     assert max(each) > 3 * min(each) and each != sorted(each)
+
+
+# ------------------------------------------- the kernels' required counts
+
+def kernel_ctx(config_name):
+    """A reader's context at a test size: two rows of 64 tokens a step on
+    one chip (128 tokens), the family's own description of its kernels."""
+    run = _tiny.harness_run
+    config = _tiny.load(config_name)
+    return {'config': config, 'traffic': _tiny.load('train-pack-tiny'),
+            'rows': 2, 'chips': 1,
+            'family': run.load_module('families', config['family'])}
+
+
+def causal_pairs(traffic, window=None):
+    """Mean pairs a row of the pool, from the pool's own segment ids."""
+    family = _tiny.harness_run.load_module('families', 'kimi_linear')
+    pool = family.make_pool({'vocab_size': 64}, traffic, 3,
+                            traffic['pool_batches'], 2)
+    total = 0
+    for row in np.concatenate([b[0][1] for b in pool]):
+        for n in np.bincount(row):
+            w = n if window is None else min(n, window)
+            total += w * (w + 1) // 2 + (n - w) * w
+    return total / (2 * traffic['pool_batches'])
+
+
+# (reader, test configuration, held rows or None, operations, bytes): each
+# count written out by hand from the configuration's numbers; float32 cells,
+# so an element of the compute type is 4 bytes
+REQUIRED = [
+    # 2 KDA layers x 2 heads x 128 tokens; 18 x 16 x 16 operations; q, k, v,
+    # 16 decays and beta are 65 elements, o 16: (81 + 81 + 65) x 4 bytes
+    ('kda.scan_roofline', 'kimi-linear-tiny', None,
+     512 * 18 * 16 * 16, 512 * 227 * 4),
+    # 2 KDA layers x 3 calls of 32 channels, 4 taps: 24 operations and 20
+    # bytes an element, 3 x 4 taps x 32 x 4 bytes of taps a call
+    ('short_conv_roofline', 'kimi-linear-tiny', None,
+     6 * 128 * 32 * 24, 6 * (128 * 32 * 20 + 1536)),
+    # 3 M layers x (256 + 32 + 32) channels with a bias (5 rows of taps)
+    ('short_conv_roofline', 'nemotron-h-tiny', None,
+     3 * 128 * 320 * 24, 3 * (128 * 320 * 20 + 3 * 5 * 320 * 4)),
+    # 6 linear layers x 3 heads x (96 + 96 + 192) channels
+    ('short_conv_roofline', 'olmo-hybrid-tiny', None,
+     6 * 128 * 1152 * 24, 6 * (128 * 1152 * 20 + 3 * 4 * 1152 * 4)),
+    # 100 held rows, gate + up + down x 3 passes, 32 x 16; 2 expert layers
+    # x 4 held experts of 32 x 16 a matrix; 32 + 16 elements a row and pass
+    ('moe.experts_roofline', 'kimi-linear-tiny', 100,
+     100 * 9 * 2 * 32 * 16, 9 * (2 * 4 * 32 * 16 + 100 * 48) * 4),
+    # ungated: up + down x 3 passes at the odd width 13; 3 E layers
+    ('moe.experts_roofline', 'nemotron-h-tiny', 100,
+     100 * 6 * 2 * 32 * 13, 6 * (3 * 4 * 32 * 13 + 100 * 45) * 4),
+    # 100 rows of 32: read and written by four moves, 4 + 4 + 4 + 4 bytes
+    # each way; a multiply-add each way in the two weighted moves
+    ('moe.permute_roofline', 'kimi-linear-tiny', 100,
+     100 * 32 * 4, 100 * 32 * 2 * 16),
+    # 8 layers x (4 + 2) heads of 8, all turned; 12 operations, 4 x 4 bytes
+    ('rope_roofline', 'mellum-tiny', None,
+     8 * 48 * 128 * 12, 8 * 48 * 128 * 16),
+    # 3 blocks (2 layers and the module) x (2 heads + the shared key) x 8
+    ('rope_roofline', 'joyai-flash-tiny', None,
+     3 * 24 * 128 * 12, 3 * 24 * 128 * 16),
+]
+
+
+@pytest.mark.parametrize('name,config,held,flops,bytes_', REQUIRED)
+def test_a_kernels_required_count(name, config, held, flops, bytes_):
+    reader = _tiny.harness_run.load_module('layer_metrics', name)
+    ctx = kernel_ctx(config)
+    got = reader.required(ctx) if held is None \
+        else reader.required(ctx, held)
+    assert got == (flops, bytes_)
+
+
+@pytest.mark.parametrize('config,layers', [
+    # 3 latent blocks: 2 heads on 2, 24 / 16
+    ('joyai-flash-tiny', [(None, 2, 2, 24, 16)] * 3),
+    # a window of 6 keys, 4 query heads on 2 K/V heads of 8
+    ('mellum-tiny', [(6, 4, 2, 8, 8)] * 3 + [(None, 4, 2, 8, 8)]
+     + [(6, 4, 2, 8, 8)] * 3 + [(None, 4, 2, 8, 8)]),
+    # a share by heads: 3 of 6, in the two full layers of eight
+    ('olmo-hybrid-tiny', [(None, 3, 3, 128, 128)] * 2),
+    # 4 query heads on 2 K/V heads in the two * layers
+    ('nemotron-h-tiny', [(None, 4, 2, 8, 8)] * 2),
+    # the one latent layer of three
+    ('kimi-linear-tiny', [(None, 2, 2, 24, 16)]),
+])
+def test_packed_attentions_required_count(config, layers):
+    reader = _tiny.harness_run.load_module(
+        'layer_metrics', 'flash_attention_packed_roofline')
+    ctx = kernel_ctx(config)
+    flops = elements = 0
+    for window, heads, kv, qk, v in layers:
+        pairs = causal_pairs(ctx['traffic'], window)
+        flops += 2 * heads * pairs * (6 * qk + 6 * v)
+        elements += 128 * (heads + kv) * (3 * qk + 3 * v)
+    got = reader.required(ctx)
+    assert got == (pytest.approx(flops, rel=1e-12), elements * 4)
+
+
+def roofline_ctx(config, scope, seconds, **more):
+    chip = {'steps': 5, 'busy_s': 1.0, 'window_s': 1.0,
+            'scopes': {scope: {'seconds': seconds, 'events': 10}}}
+    return dict(kernel_ctx(config), trace={0: chip}, peaks={
+        'bf16_flops_per_s': 1e12, 'hbm_bytes_per_s': 1e11}, **more)
+
+
+def test_a_share_is_the_least_time_over_the_time_paid(monkeypatch):
+    """5 whole steps, 50 us under the scope: 10 us a step; the count above
+    needs 464896 bytes at 1e11 a second, 4.65 us, more than its operations
+    at 1e12: 46.5%. On the slowest chip; nothing where no event ran, or
+    where the family has no such mechanism."""
+    reader = _tiny.harness_run.load_module('layer_metrics',
+                                           'kda.scan_roofline')
+    ctx = roofline_ctx('kimi-linear-tiny', 'delta_rule.pallas', 50e-6)
+    assert reader.read(ctx) == pytest.approx(46.4896)
+    slower = {'steps': 5, 'scopes': {'delta_rule.pallas': {
+        'seconds': 100e-6, 'events': 10}}}
+    ctx['trace'][1] = slower
+    assert reader.read(ctx) == pytest.approx(46.4896 / 2)
+    ctx['trace'] = {0: {'steps': 5, 'scopes': {'delta_rule.pallas': {
+        'seconds': 0.0, 'events': 0}}}}
+    assert reader.read(ctx) is None
+    assert reader.read(roofline_ctx('mellum-tiny', 'delta_rule.pallas',
+                                    50e-6)) is None
+
+
+def test_a_routed_share_counts_the_traced_steps_own_rows(monkeypatch):
+    """The held assignments are those of the WHOLE TRACED steps (calls 2 to
+    6 of the 7 under the trace), not the window's: 100 a step there, 40 in
+    the window before. The bytes bound the count above at the test's peaks:
+    320256 over 1e11 a second, 3.2 us of the 10 us a step paid."""
+    from harness import program
+    calls = [(t, t + 8) for t in range(100, 170, 10)]
+    records = [{'name': 'engine.step_counters', 'ph': 'X', 't0_ns': t,
+                't1_ns': t, 'args': {'moe.assignments_held': v}}
+               for t, v in [(15, 40.0), (25, 40.0), (105, 70.0)]
+               + [(t + 4, 100.0) for t, _ in calls[1:6]] + [(164, 130.0)]]
+    fake = type('o', (), {
+        'trace_events': staticmethod(lambda: records),
+        'step_counters': type('c', (), {
+            'SPAN': 'engine.step_counters',
+            'drain': staticmethod(lambda wait=False: 0)})})
+    monkeypatch.setattr(program, 'enable', lambda: fake)
+    ctx = roofline_ctx('kimi-linear-tiny', 'grouped_matmul.pallas', 50e-6,
+                       traced_calls_ns=calls)
+    assert program.traced_mean(ctx, 5, 'moe.assignments_held') == 100.0
+    reader = _tiny.harness_run.load_module('layer_metrics',
+                                           'moe.experts_roofline')
+    assert reader.read(ctx) == pytest.approx(32.0256)
+    ctx['trace'][0]['scopes']['row_permute.pallas'] = {'seconds': 50e-6,
+                                                       'events': 10}
+    assert _tiny.harness_run.load_module(
+        'layer_metrics', 'moe.permute_roofline').read(ctx) \
+        == pytest.approx(10.24)
+    monkeypatch.setattr(program, 'enable', lambda: None)    # no program
+    assert reader.read(ctx) is None
+
+
+def test_step_mfu_is_the_rows_operations_over_the_traced_period():
+    reader = _tiny.harness_run.load_module('layer_metrics', 'step_mfu')
+    chip = {'steps': 5, 'busy_s': 0.99, 'window_s': 1.0}
+    ctx = {'trace': {0: chip}, 'traced_step_ms': [300.0] * 7, 'rows': 4,
+           'chips': 2, 'flops_per_sample': 1e9,
+           'peaks': {'bf16_flops_per_s': 1e11}}
+    assert reader.read(ctx) == pytest.approx(100 * 4e9 / (2e11 * 0.2))
+    chip['busy_s'] = 0.5        # the host did not keep up: the window's
+    assert reader.read(ctx) == pytest.approx(100 * 4e9 / (2e11 * 0.3))
+    assert reader.read(dict(ctx, trace={})) is None
+
+
+def test_a_scopes_shared_part_is_said():
+    """Two steps: a kernel's custom call under a layer's scope and its own
+    (they nest: nothing shared), a fusion that holds the norm's backward
+    AND the next layer's weight gradient (shared by both), a fusion of the
+    norm alone."""
+    from harness import scopes
+    names = {'k': '%k.pallas.1 = f32[8] custom-call(f32[8] %p)',
+             'mix': '%fusion.2 = f32[8] fusion(f32[8] %p), kind=kOutput, '
+                    'calls=%fused_computation.2',
+             'own': '%fusion.3 = f32[8] fusion(f32[8] %p), kind=kLoop, '
+                    'calls=%fused_computation.3'}
+    step = [('k', 0, 30), ('mix', 30, 50), ('own', 50, 60)]
+    dev = {'modules': [('jit_step(1)', 100 * i, 100 * i + 60)
+                       for i in range(4)],
+           'ops': [(names[n], 100 * i + s, 100 * i + e)
+                   for i in range(4) for n, s, e in step]}
+    maps = {'engine.train_step0': {
+        'k.pallas.1': ('k.pallas', 'layer.scan'),
+        'fusion.2': ('layer.proj', 'norm.pallas'),
+        'fusion.3': ('norm.pallas',)}}
+    out = scopes.reduce({'devices': {0: dev}, 'host': []}, maps)
+    assert out['per_step_ms'] == {
+        'k.pallas': pytest.approx(30e-6), 'layer.scan': pytest.approx(30e-6),
+        'layer.proj': pytest.approx(20e-6),
+        'norm.pallas': pytest.approx(30e-6)}
+    assert out['shared_ms'] == {
+        'k.pallas': 0.0, 'layer.scan': 0.0,
+        'layer.proj': pytest.approx(20e-6),
+        'norm.pallas': pytest.approx(20e-6)}
 
 
 # ------------------------------------------------------- the window's rule
