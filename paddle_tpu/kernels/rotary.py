@@ -12,7 +12,11 @@ with the sign of the sine folded into the table. Which lane the partner is
 comes from the model's mathematics and is static:
 
 - `halves` (`nn.GroupedQueryAttention`; `rotate_halves`): the lane d / 2
-  away, one roll of the head's lanes;
+  away, one roll of the head's lanes; or, where only the first `turned`
+  channels of a head turn (a `partial_rotary_factor`), the half-split form
+  INSIDE them: lane j < turned / 2 pairs with j + turned / 2, rolls by
+  turned / 2 either way and a select on the lane index. No table of the
+  whole head says this: the pairing differs, not the angle;
 - `pairs` (`nn.LatentAttention`; `rotate_pairs`): the other lane of the pair
   (2j, 2j + 1), rolls by one lane either way and a select on lane parity.
 
@@ -101,23 +105,36 @@ def yarn_inv_freq(theta, dim, factor, original_max_position_embeddings,
     return plain / factor * ramp + plain * (1.0 - ramp), low, high
 
 
-def rotate_halves(x, cos, sin):
+def rotate_halves(x, cos, sin, turned=None):
     """Rotary position encoding of the last axis of x (B, T, H, d) in the
     half-split form: x * cos + [-x2, x1] * sin with x = [x1, x2] the two
     halves; cos, sin (B, T, 1, d) float32, each of its d / 2 angles repeated
     over the halves. x keeps its layout (the half turn is a roll of the
-    lanes, not a reshape to pairs); float32 -> float32."""
-    half = x.shape[-1] // 2
+    lanes, not a reshape to pairs); float32 -> float32. With `turned` < d
+    the two halves are those of the first `turned` channels (x1 = x[:turned
+    / 2], x2 = x[turned / 2:turned]) and the channels behind them, whose
+    cos is 1 and sin 0, pass."""
+    d = x.shape[-1]
+    half = (turned or d) // 2
     x = x.astype(jnp.float32)
-    sign = jnp.where(jnp.arange(x.shape[-1]) < half, -1.0, 1.0)
-    return x * cos + jnp.roll(x, half, axis=-1) * (sin * sign)
+    lane = jnp.arange(d)
+    sign = jnp.where(lane < half, -1.0, 1.0)
+    partner = jnp.roll(x, half, axis=-1)
+    if 2 * half != d:
+        partner = jnp.where(lane < half, jnp.roll(x, -half, axis=-1), partner)
+    return x * cos + partner * (sin * sign)
 
 
-def _turned(x, cos, sin, form):
+def _turned(x, cos, sin, form, d, turned):
     """One period (tt, P) float32, turned."""
     lanes = x.shape[1]
-    if form == 'halves':
+    if form == 'halves' and turned == d:
         partner = pltpu.roll(x, lanes // 2, 1)
+    elif form == 'halves':
+        low = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) % d \
+            < turned // 2
+        partner = jnp.where(low, pltpu.roll(x, lanes - turned // 2, 1),
+                            pltpu.roll(x, turned // 2, 1))
     else:
         even = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) % 2 == 0
         partner = jnp.where(even, pltpu.roll(x, lanes - 1, 1),
@@ -125,7 +142,7 @@ def _turned(x, cos, sin, form):
     return x * cos + partner * sin
 
 
-def _kernel(x_ref, cos_ref, sin_ref, o_ref, *, form, d):
+def _kernel(x_ref, cos_ref, sin_ref, o_ref, *, form, d, turned):
     """One of the two refs is a tile of tokens by whole periods (tt, n * P),
     the other the same heads one by one (n * P / d, tt, d); either may be
     the input."""
@@ -139,7 +156,8 @@ def _kernel(x_ref, cos_ref, sin_ref, o_ref, *, form, d):
             x = jnp.concatenate([x_ref[h] for h in heads], axis=1)
         else:
             x = x_ref[:, j * P:(j + 1) * P]
-        y = _turned(x.astype(_F32), cos, sin, form).astype(o_ref.dtype)
+        y = _turned(x.astype(_F32), cos, sin, form, d,
+                    turned).astype(o_ref.dtype)
         if by_head:
             o_ref[:, j * P:(j + 1) * P] = y
         else:
@@ -169,10 +187,12 @@ def _tiles(T, width, d):
 # step's trace is traced and lowered once for all of them (as
 # `kernels/short_conv.py`'s are). The scope is entered again inside: the
 # compiler names a custom call after its innermost scope.
-@functools.partial(jax.jit, static_argnames=('form', 'heads', 'interpret'))
-def _call(x, cos, sin, *, form, heads, interpret):
+@functools.partial(jax.jit, static_argnames=('form', 'heads', 'interpret',
+                                             'turned'))
+def _call(x, cos, sin, *, form, heads, interpret, turned=None):
     """x (B, T, heads * d) -> (B, heads, T, d), or back where x has four
-    axes."""
+    axes. `turned`: the channels in front of a head that the `halves` form
+    turns (None: the whole head)."""
     to_heads = x.ndim == 3
     if to_heads:
         B, T, width = x.shape
@@ -188,7 +208,7 @@ def _call(x, cos, sin, *, form, heads, interpret):
     table = pl.BlockSpec((None, tt, P), lambda b, t, j: (b, t, 0))
     with jax.named_scope('rotary.pallas'):
         return pl.pallas_call(
-            functools.partial(_kernel, form=form, d=d),
+            functools.partial(_kernel, form=form, d=d, turned=turned or d),
             grid=(B, T // tt, width // (n * P)),
             in_specs=[flat if to_heads else by_head, table, table],
             out_specs=by_head if to_heads else flat,
@@ -217,10 +237,11 @@ def _rotary_bwd(static, tables, dy):
 _rotary.defvjp(_rotary_fwd, _rotary_bwd)
 
 
-def _site(x, form, tables, xla, interpret):
+def _site(x, form, tables, xla, interpret, turned=None):
     """The one decision, for x (B, T, H, d): the kernel on `tables()`'s cos
     and sin of one head (B, T, d), the sign folded into the sine here, or
-    `xla(x)` and a `swapaxes`."""
+    `xla(x)` and a `swapaxes`. `turned`: the channels in front of a head
+    that the `halves` form turns."""
     B, T, heads, d = x.shape
     # (a half turn rolls a head's own lanes: heads of whole registers)
     if not (pallas_runs(interpret) and not sharded_step()
@@ -231,28 +252,42 @@ def _site(x, form, tables, xla, interpret):
     with took('rotary', 'pallas'):
         cos, sin = tables()
         lane = jnp.arange(d)
-        sin = jnp.where(lane < d // 2 if form == 'halves' else lane % 2 == 0,
-                        -sin, sin)
+        sin = jnp.where(lane < turned // 2 if form == 'halves'
+                        else lane % 2 == 0, -sin, sin)
         cos, sin = (jnp.tile(t, (1, 1, _period(d) // d)) for t in (cos, sin))
         return _rotary(x.reshape(B, T, heads * d), cos, sin, (
-            ('form', form), ('heads', heads), ('interpret', interpret)))
+            ('form', form), ('heads', heads), ('interpret', interpret),
+            ('turned', turned)))
 
 
 def rotary_halves(x, positions, inv_freq, factor=1.0, interpret=False):
-    """`rotate_halves` over the whole head of x (B, T, H, d), at the
-    positions (B, T) and the table `inv_freq` (d / 2,), cos and sin times
-    `factor` -> (B, H, T, d) in x's dtype, the turn in float32."""
+    """`rotate_halves` of x (B, T, H, d) at the positions (B, T) and the
+    table `inv_freq`, cos and sin times `factor` -> (B, H, T, d) in x's
+    dtype, the turn in float32. A table of d / 2 rates turns the whole head;
+    a shorter one, of n, the head's first 2 n channels in the half-split
+    form inside them, and the channels behind pass (no factor on them)."""
+    d = x.shape[-1]
+    turned = 2 * len(inv_freq)
+    if turned > d:
+        raise ValueError('a table of %d rates turns no %d-wide head'
+                         % (len(inv_freq), d))
+
     def tables():
         angle = positions.astype(_F32)[..., None] * inv_freq
         angle = jnp.concatenate([angle, angle], -1)
-        return factor * jnp.cos(angle), factor * jnp.sin(angle)
+        cos, sin = factor * jnp.cos(angle), factor * jnp.sin(angle)
+        if turned == d:
+            return cos, sin
+        behind = [(0, 0), (0, 0), (0, d - turned)]
+        return (jnp.pad(cos, behind, constant_values=1.0),
+                jnp.pad(sin, behind))
 
     def xla(x):
         cos, sin = tables()
-        return rotate_halves(x, cos[:, :, None], sin[:, :, None]) \
-            .astype(x.dtype)
+        return rotate_halves(x, cos[:, :, None], sin[:, :, None],
+                             turned).astype(x.dtype)
 
-    return _site(x, 'halves', tables, xla, interpret)
+    return _site(x, 'halves', tables, xla, interpret, turned)
 
 
 def rotary_pairs(x, positions, theta, turned, interpret=False):

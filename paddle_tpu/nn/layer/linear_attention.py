@@ -6,7 +6,8 @@ section 2.1); Gated DeltaNet (a decay per head, arXiv:2412.06464) and plain
 multi-head causal attention with normed queries and keys (the OLMo 2/3
 block's), either of which may hold a share of its heads
 (docs/HEAD_SHARE.md); grouped-query attention with a rotary table over the
-whole head and, where asked, an attention window (docs/ATTENTION.md). All
+whole head or its first channels and, where asked, an attention window and
+an output gate a head (docs/ATTENTION.md). All
 take a packed row's document numbers: state, convolution, scores and
 positions stop at document boundaries.
 """
@@ -193,35 +194,49 @@ class KimiDeltaAttention(Layer):
 class GroupedQueryAttention(Layer):
     """Causal attention of `num_heads` query heads over `num_kv_heads` key /
     value heads of `head_dim` (query head h reads K/V head h // group), no
-    bias: q = x W_q, k = x W_k, v = x W_v; q and k rotated over the whole
-    head in the half-split form (`rotate_halves`, under the scope
-    `attn.rope`; on the TPU one pass of `kernels/rotary.py`, which also
-    writes the flash kernels' layout) by a position that restarts at each
-    document of a packed row; softmax(q k^T / sqrt(head_dim)) over the keys
-    of the query's document, the last `window` of them where a window is
-    given; W_o.
+    bias: q = x W_q, k = x W_k, v = x W_v; q and k rotated in the half-split
+    form (`rotate_halves`, under the scope `attn.rope`; on the TPU one pass
+    of `kernels/rotary.py`, which also writes the flash kernels' layout) by
+    a position that restarts at each document of a packed row;
+    softmax(q k^T / sqrt(head_dim)) over the keys of the query's document,
+    the last `window` of them where a window is given; W_o.
 
-    The rotary table is DATA: `inv_freq` (head_dim / 2,) and `rope_factor`,
-    which multiplies cos and sin (YaRN's attention factor; the scores take
-    its square). A plain table and a YaRN table are one code path; with
-    `inv_freq=None` nothing is rotated and no `attn.rope` scope is entered
-    (a decoder whose state-space layers carry the order). The layer
-    runs under the scope `attn.window` with a window, else `attn.full`; k
-    and v go to the flash kernels at their own head count."""
+    The rotary table is DATA: `inv_freq` and `rope_factor`, which multiplies
+    cos and sin (YaRN's attention factor; the scores take its square). A
+    plain table and a YaRN table are one code path. The table has
+    `rotary_dim` / 2 rates (None: `head_dim`, the whole head turns); with a
+    smaller `rotary_dim` the head's first `rotary_dim` channels turn, in the
+    half-split form inside them, and the rest pass. With `inv_freq=None`
+    nothing is rotated and no `attn.rope` scope is entered (a decoder whose
+    state-space layers carry the order).
+
+    `gate='per_head'`: one scalar a query head and token on the heads' way
+    into W_o, o_h <- sigmoid(x W_g)_h o_h with `g_proj` (hidden, heads) and
+    x the layer's (normed) input, the sigmoid in float32, under the scope
+    `attn.gate` (the head-wise form of arXiv:2505.06708); None: no gate, no
+    parameter, no scope.
+
+    The layer runs under the scope `attn.window` with a window, else
+    `attn.full`; k and v go to the flash kernels at their own head count."""
 
     def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
                  inv_freq, rope_factor=1.0, window=None,
-                 initializer_range=0.02):
+                 initializer_range=0.02, rotary_dim=None, gate=None):
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError('%d query heads do not group over %d K/V heads'
                              % (num_heads, num_kv_heads))
+        if gate not in (None, 'per_head'):
+            raise ValueError('no output gate %r' % (gate,))
         self.heads = (num_heads, num_kv_heads, head_dim)
         self.inv_freq = None if inv_freq is None \
             else np.asarray(inv_freq, np.float32)
-        if inv_freq is not None and self.inv_freq.shape != (head_dim // 2,):
-            raise ValueError('inv_freq %r is no table of a %d-wide head'
-                             % (self.inv_freq.shape, head_dim))
+        turned = head_dim if rotary_dim is None else rotary_dim
+        if inv_freq is not None and (self.inv_freq.shape != (turned // 2,)
+                                     or not 0 < turned <= head_dim):
+            raise ValueError('inv_freq %r is no table of %d channels of a '
+                             '%d-wide head' % (self.inv_freq.shape, turned,
+                                               head_dim))
         self.rope_factor, self.window = float(rope_factor), window
 
         def weight(*shape):
@@ -231,14 +246,16 @@ class GroupedQueryAttention(Layer):
         self.k_proj = weight(hidden_size, num_kv_heads * head_dim)
         self.v_proj = weight(hidden_size, num_kv_heads * head_dim)
         self.o_proj = weight(num_heads * head_dim, hidden_size)
+        self.g_proj = weight(hidden_size, num_heads) if gate else None
 
     def forward(self, x, segment_ids, pre_norm=None, recompute=False):
         H, HK, D = self.heads
         inv_freq, factor, window = self.inv_freq, self.rope_factor, \
             self.window
+        gated = self.g_proj is not None
         dtype = compute_dtype()
 
-        def fn(x, seg, wq, wk, wv, wo):
+        def fn(x, seg, wq, wk, wv, wo, *wg):
             from ...kernels.flash_attention import flash_attention_bhld
             B, T, _ = x.shape
             with jax.named_scope('attn.full' if window is None
@@ -257,12 +274,20 @@ class GroupedQueryAttention(Layer):
                 v = jnp.swapaxes(v, 1, 2)
                 o = flash_attention_bhld(q, k, v, causal=True,
                                          doc_start=start, window=window)
-                return _mm(jnp.swapaxes(o, 1, 2).reshape(B, T, H * D), wo,
-                           dtype)
+                o = jnp.swapaxes(o, 1, 2)
+                if gated:
+                    with jax.named_scope('attn.gate'):
+                        xx, ww = (x, wg[0]) if dtype is None else (
+                            x.astype(dtype), wg[0].astype(dtype))
+                        g = jax.nn.sigmoid(jnp.matmul(
+                            xx, ww, preferred_element_type=jnp.float32))
+                        o = (o * g[..., None]).astype(o.dtype)
+                return _mm(o.reshape(B, T, H * D), wo, dtype)
 
         run, front = pre_normed(fn, pre_norm, recompute)
         return apply_op(run, (x,) + front + (
-            segment_ids, self.q_proj, self.k_proj, self.v_proj, self.o_proj))
+            segment_ids, self.q_proj, self.k_proj, self.v_proj, self.o_proj)
+            + ((self.g_proj,) if gated else ()))
 
 
 class LatentAttention(Layer):

@@ -10,6 +10,7 @@ from .kimi_linear import (KimiLinearConfig, KimiLinearBlock,
 from .joyai_flash import JoyAIFlashConfig, JoyAIFlashForCausalLM
 from .olmo_hybrid import OlmoHybridConfig, OlmoHybridForCausalLM
 from .mellum import MellumConfig, MellumForCausalLM
+from .laguna import LagunaConfig, LagunaForCausalLM
 from .nemotron_h import NemotronHConfig, NemotronHForCausalLM
 from .seq2seq import Seq2SeqTransformer
 from .word2vec import SkipGram, Word2Vec

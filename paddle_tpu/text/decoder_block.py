@@ -21,7 +21,8 @@ from ..observability import costs as _costs
 # step keeps which instructions lie under each (observability.costs.scopes),
 # for the device time a layer takes
 _costs.register_scopes('mla.attention', 'moe.route', 'moe.experts',
-                       'moe.shared', 'lm_head', 'fused_rms_norm.pallas',
+                       'moe.shared', 'ffn.dense', 'lm_head',
+                       'fused_rms_norm.pallas',
                        'grouped_matmul.pallas', 'row_permute.pallas',
                        'update')
 
@@ -47,11 +48,13 @@ class SparseDecoderBlock(nn.Layer):
     """`x += attention(RMSNorm(x)); x += FFN(RMSNorm(x))` -> (x, expert
     counters). `attention(x, segment_ids, pre_norm, recompute)` is any layer
     of `nn.layer.linear_attention`; the feed-forward is the expert layer
-    where `sparse`, else SwiGLU. `config` names the sizes (`hidden_size`,
-    `intermediate_size`, `moe_intermediate_size`, `num_experts`,
-    `num_experts_per_token`, `num_shared_experts`, `routed_scaling_factor`,
-    `experts_held`, `moe_block`, `rms_norm_eps`, `initializer_range`; and
-    `router`, 'sigmoid' where the configuration names none) and
+    where `sparse`, else SwiGLU under the scope `ffn.dense`. `config` names
+    the sizes (`hidden_size`, `intermediate_size`, `moe_intermediate_size`,
+    `num_experts`, `num_experts_per_token`, `num_shared_experts`,
+    `routed_scaling_factor`, `experts_held`, `moe_block`, `rms_norm_eps`,
+    `initializer_range`; `router`, 'sigmoid' where the configuration names
+    none; `shared_expert_intermediate_size`, the shared expert's own width,
+    `moe_intermediate_size * num_shared_experts` where it names none) and
     `recompute`: each half norms inside its own traced function, which is
     then re-run in the backward pass, so the block keeps its two inputs."""
 
@@ -65,16 +68,19 @@ class SparseDecoderBlock(nn.Layer):
         self.sparse = sparse
         self.recompute = c.recompute
         if self.sparse:
+            shared = getattr(c, 'shared_expert_intermediate_size', None)
+            if shared is None:
+                shared = c.moe_intermediate_size * c.num_shared_experts
             self.mlp = nn.SparseMoE(
                 c.hidden_size, c.moe_intermediate_size, c.num_experts,
                 c.num_experts_per_token, experts_held=c.experts_held,
-                shared_size=c.moe_intermediate_size * c.num_shared_experts,
+                shared_size=shared,
                 scaling=c.routed_scaling_factor, block=c.moe_block,
                 initializer_range=c.initializer_range,
                 router=getattr(c, 'router', 'sigmoid'))
         else:
             self.mlp = nn.SwiGLU(c.hidden_size, c.intermediate_size,
-                                 c.initializer_range)
+                                 c.initializer_range, scope='ffn.dense')
 
     def forward(self, x, segment_ids, selected=None):
         again = self.recompute

@@ -31,6 +31,15 @@ them), and those a WINDOW layer's visits (`flash.window_tiles_swept`).
 `experts_held`, `recompute` and `moe_block` are `text/kimi_linear.py`'s: a
 chip may hold a share of each expert layer (docs/EXPERT_LAYER.md), and a
 block keeps its two inputs and re-runs each half in the backward pass.
+
+The decoder is also that of every `config.json` of this layout
+(`layer_types`, `mlp_layer_types`, `rope_parameters` by layer kind,
+`sliding_window`): what such a model adds to Mellum 2 the configuration
+names and `MellumBlock` reads, each None or Mellum's value here: the query
+heads by layer (`num_attention_heads_per_layer`), a dense SwiGLU where
+`mlp_layer_types[i]` is 'dense', an output gate a head (`gate`), a layer
+kind's `partial_rotary_factor`, a shared expert of its own width and the
+sigmoid router with its factor (`text/laguna.py`).
 """
 import jax.numpy as jnp
 
@@ -57,7 +66,10 @@ WINDOW_COUNTER = 'flash.window_tiles_swept'
 class MellumConfig:
     # what `SparseDecoderBlock` reads and this model has none of
     num_shared_experts, routed_scaling_factor, router = 0, 1.0, 'softmax'
-    intermediate_size = None        # every layer is sparse
+    shared_expert_intermediate_size = None
+    intermediate_size = mlp_layer_types = None      # every layer is sparse
+    # what `MellumBlock` reads and this model has none of
+    num_attention_heads_per_layer = gate = None
 
     def __init__(self, vocab_size=98304, hidden_size=2304,
                  num_hidden_layers=28, num_attention_heads=32,
@@ -84,16 +96,16 @@ class MellumConfig:
             {k: v for k, v in locals().items() if k != 'self'})
 
 
-def rotary_table(parameters, head_dim):
-    """One layer kind's `rope_parameters` -> (inverse frequencies
-    (head_dim / 2,), the factor on cos and sin)."""
+def rotary_table(parameters, dim):
+    """One layer kind's `rope_parameters` -> (inverse frequencies (dim / 2,),
+    the factor on cos and sin), `dim` the channels of a head that turn."""
     kind, theta = parameters['rope_type'], parameters['rope_theta']
     if kind == 'default':
-        return rope_inv_freq(theta, head_dim), 1.0
+        return rope_inv_freq(theta, dim), 1.0
     if kind != 'yarn':
         raise ValueError('no rotary table of type %r' % (kind,))
     table, _, _ = yarn_inv_freq(
-        theta, head_dim, parameters['factor'],
+        theta, dim, parameters['factor'],
         parameters['original_max_position_embeddings'],
         parameters['beta_fast'], parameters['beta_slow'])
     return table, parameters['attention_factor']
@@ -108,12 +120,19 @@ class MellumBlock(SparseDecoderBlock):
         if kind not in ('sliding_attention', 'full_attention'):
             raise ValueError('layer %d is of no known type: %r'
                              % (index, kind))
-        inv_freq, factor = rotary_table(c.rope_parameters[kind], c.head_dim)
+        rope = c.rope_parameters[kind]
+        turned = int(c.head_dim * rope.get('partial_rotary_factor', 1))
+        inv_freq, factor = rotary_table(rope, turned)
         window = c.sliding_window if kind == 'sliding_attention' else None
+        heads = c.num_attention_heads if not c.num_attention_heads_per_layer \
+            else c.num_attention_heads_per_layer[index]
         super().__init__(c, nn.GroupedQueryAttention(
-            c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
-            c.head_dim, inv_freq, rope_factor=factor, window=window,
-            initializer_range=c.initializer_range), sparse=True)
+            c.hidden_size, heads, c.num_key_value_heads, c.head_dim,
+            inv_freq, rope_factor=factor, window=window,
+            initializer_range=c.initializer_range, rotary_dim=turned,
+            gate=c.gate),
+            sparse=not c.mlp_layer_types
+            or c.mlp_layer_types[index] == 'sparse')
 
 
 class MellumForCausalLM(nn.Layer):
@@ -121,10 +140,11 @@ class MellumForCausalLM(nn.Layer):
     # step, which `engine.TrainStep` records under these names
     step_counter_names = STEP_COUNTER_NAMES + (WINDOW_COUNTER,)
     step_counter_sums = STEP_COUNTER_SUMS + (WINDOW_COUNTER,)
+    config_class = MellumConfig
 
     def __init__(self, config=None, **kwargs):
         super().__init__()
-        config = config or MellumConfig(**kwargs)
+        config = config or self.config_class(**kwargs)
         self.config = config
         init = nn.ParamAttr(initializer=nn.initializer.Normal(
             0., config.initializer_range))
@@ -145,7 +165,8 @@ class MellumForCausalLM(nn.Layer):
         counted = []
         for block in self.layers:
             x, counters = block(x, segment_ids, selected)
-            counted.append(counters)
+            if block.sparse:
+                counted.append(counters)
         window = self.config.sliding_window
         counters = apply_op(
             lambda c, seg: jnp.concatenate([c, doc_tile_counts(

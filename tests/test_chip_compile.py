@@ -177,6 +177,28 @@ def test_flash_grouped_query_shapes_compile_fwd_bwd(one_chip):
     assert 'f32[4,8192,128]' in text            # dK, dV: 2 rows x 2 heads
 
 
+@pytest.mark.parametrize('heads,window', [(48, None), (64, 512)],
+                         ids=['full-48-on-8', 'window-512-64-on-8'])
+def test_flash_two_head_counts_compile_fwd_bwd(one_chip, heads, window):
+    """Laguna's two kinds of layer at their real shapes: rows of 8192, 48
+    query heads (a group of 6) without a window and 64 (a group of 8, q 8192
+    wide) under a window of 512, ONE tile, on 8 K/V heads of 128, packed
+    documents. dK and dV come back at the K/V heads."""
+    def fn(q, k, v, doc_start):
+        start = fa.row_starts(doc_start, window)
+        doc = (start,) + fa.doc_tile_bounds(start, 512, 512)
+        out = fa._flash(q, k, v, None, jnp.zeros((1, 1), jnp.int32), doc,
+                        True, 128 ** -0.5, 512, 512, 0.0, False)
+        return jnp.sum(out.astype(jnp.float32))
+    text = _compile(jax.grad(fn, argnums=(0, 1, 2)), one_chip,
+                    ((2, heads, 8192, 128), jnp.bfloat16),
+                    ((2, 8, 8192, 128), jnp.bfloat16),
+                    ((2, 8, 8192, 128), jnp.bfloat16),
+                    ((2, 8192), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert 'f32[16,8192,128]' in text           # dK, dV: 2 rows x 8 heads
+
+
 def test_delta_rule_compiles_fwd_bwd_at_the_cells_shape(one_chip,
                                                         monkeypatch):
     """One row of Kimi-Linear's delta rule as the cell runs it: 8192
@@ -369,24 +391,29 @@ def test_row_permute_compiles_fwd_bwd_at_the_cells_shapes(
     assert not re.search(r' (scatter|gather)\(', text)
 
 
-@pytest.mark.parametrize('heads,d,turned', [
-    (32, 128, None), (4, 128, None), (32, 192, 64)],
-    ids=['mellum2-q', 'mellum2-k', 'joyai-llm-flash-q'])
+@pytest.mark.parametrize('heads,d,form,turned', [
+    (32, 128, 'halves', 128), (4, 128, 'halves', 128),
+    (32, 192, 'pairs', 64), (48, 128, 'halves', 64), (8, 128, 'halves', 64),
+    (64, 128, 'halves', 128)],
+    ids=['mellum2-q', 'mellum2-k', 'joyai-llm-flash-q', 'laguna-full-q',
+         'laguna-full-k', 'laguna-window-q'])
 def test_rotary_compiles_fwd_bwd_at_the_cells_shapes(one_chip, heads, d,
-                                                     turned):
-    """The rotation of two packed rows of 8192 at the two rotary cells'
-    published head shapes, bfloat16: the half turn over heads of 128 (32
-    query heads, 4 K/V heads) and the pair turn over the last 64 lanes of
-    heads of 128 + 64, whose block is two heads (384 lanes) and whose second
-    head starts mid-register. One custom call forward and one backward, the
-    same kernel with its BlockSpecs exchanged, and no transpose left beside
-    them."""
+                                                     form, turned):
+    """The rotation of two packed rows of 8192 at the three rotary cells'
+    published head shapes, bfloat16: the half turn over heads of 128 (32 and
+    64 query heads, 4 K/V heads), the half turn INSIDE the first 64 lanes of
+    heads of 128 (48 query heads, 8 K/V heads: two rolls and a select), and
+    the pair turn over the last 64 lanes of heads of 128 + 64, whose block
+    is two heads (384 lanes) and whose second head starts mid-register. One
+    custom call forward and one backward, the same kernel with its
+    BlockSpecs exchanged, and no transpose left beside them."""
     from paddle_tpu.nn.layer.linear_attention import rope_inv_freq
 
     def loss(x, at):
-        if turned is None:
+        if form == 'halves':
             y = rotary.rotary_halves(
-                x, at, rope_inv_freq(500000, d).astype(np.float32), 1.25)
+                x, at, rope_inv_freq(500000, turned).astype(np.float32),
+                1.25)
         else:
             y = rotary.rotary_pairs(x, at, 32e6, turned)
         assert y.shape == (2, heads, 8192, d)
@@ -964,6 +991,85 @@ def test_grouped_query_decoder_step_holds_its_kernels_and_its_scopes(
             seen.append(sorted(sizes.count(n) for n in ('16', '4')))
             assert set(sizes) == {'16', '4'}, line[:300]
     assert sorted(seen) == [[2, 2]] * 8 + [[4, 4]] * 4, seen
+
+
+def test_gated_decoder_step_holds_its_kernels_and_its_scopes(topo,
+                                                             monkeypatch):
+    """Laguna at a small width (heads of the real size, 128, of which a full
+    layer turns 64; 6 query heads in the full layers and 8 in the window
+    layers on ONE K/V head; rows of 1024 under a window of 256) through
+    `engine.build_train_step` under bf16 autocast with per-half
+    recomputation, compiled for one described chip: the dense layer and a
+    whole period behind it. The gate, the dense layer and the shared expert
+    name instructions under their scopes, no site took its XLA form, the
+    rotation is the kernel in both kinds of layer, and the flash kernels
+    take q at each kind's own head count."""
+    import paddle_tpu as paddle
+    from paddle_tpu import amp, engine, optimizer
+    from paddle_tpu.nn.layer_base import buffer_values, param_values
+    from paddle_tpu.observability import costs
+    from paddle_tpu.text.laguna import LagunaConfig, LagunaForCausalLM
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    paddle.seed(0)
+    net = LagunaForCausalLM(LagunaConfig(
+        vocab_size=1024, hidden_size=256, num_hidden_layers=5,
+        num_attention_heads=6, num_attention_heads_per_layer=[6, 8, 8, 8, 6],
+        num_key_value_heads=1, sliding_window=256, intermediate_size=512,
+        moe_intermediate_size=128, shared_expert_intermediate_size=128,
+        num_experts=16, num_experts_per_token=4, experts_held=(4, 8),
+        recompute=True))
+    net.train()
+    assert len(buffer_values(net)) == 4     # the sparse layers' zero biases
+    step = engine.build_train_step(
+        net=net, loss=net.training_loss,
+        optimizer=optimizer.AdamW(learning_rate=1e-4, weight_decay=0.1))
+    one = SingleDeviceSharding(topo.devices[0])
+    state = jax.tree_util.tree_map(
+        lambda v: jax.ShapeDtypeStruct(np.shape(v), v.dtype, sharding=one),
+        step.init_state(param_values(net), buffer_values(net)))
+    feed = tuple(jax.ShapeDtypeStruct((2, 1024), jnp.int32, sharding=one)
+                 for _ in range(3))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
+    with amp.auto_cast(dtype='bfloat16'):
+        text = step._jit.lower(state, (feed, ()), key).compile().as_text()
+    calls = _CUSTOM_CALL.findall(text)
+    under = costs.instruction_scopes(text)
+    found = {scope for scopes in under.values() for scope in scopes}
+    assert found >= {'attn.window', 'attn.full', 'attn.rope', 'attn.gate',
+                     'ffn.dense', 'moe.route', 'moe.experts', 'moe.shared',
+                     'lm_head', 'flash_attention.pallas', 'rotary.pallas',
+                     'fused_rms_norm.pallas', 'grouped_matmul.pallas',
+                     'update'}
+    assert not re.findall(r'[\w.]+\.xla\b', text)
+    # the gate lies inside either kind of layer, never outside one
+    gated = [set(s) for s in under.values() if 'attn.gate' in s]
+    assert any('attn.window' in s for s in gated)
+    assert any('attn.full' in s for s in gated)
+    assert all(s & {'attn.window', 'attn.full'} for s in gated)
+    # five blocks: the forward kernel, the forward again in the
+    # recomputation and the one backward kernel; three of them window layers
+    flash = [c for c in calls if c.startswith('flash_attention.pallas')]
+    assert len(flash) == 15, flash
+    assert sum('attn.window' in under[c] for c in flash) == 9
+    assert sum('attn.full' in under[c] for c in flash) == 6
+    # the rotation is the kernel for q and k in the forward pass, the
+    # recomputation and the backward pass of each block, partial rule and
+    # whole head alike
+    rope = [c for c in calls if c.startswith('rotary.pallas')]
+    assert len(rope) == 30, rope
+    assert all('attn.rope' in under[c] for c in rope)
+    # q enters the flash kernels at 2 rows x 6 heads in a full layer and at
+    # 2 rows x 8 in a window layer; k and v at 2 rows x 1
+    seen = set()
+    for line in text.splitlines():
+        m = re.match(r'\s*%?(flash_attention\.pallas[\w.\-]*) = ', line)
+        if m and 'custom-call(' in line:
+            head = line.split('frontend_attributes')[0]
+            sizes = set(re.findall(r'(?:bf16|f32)\[(\d+),1024,128\]', head))
+            kind = 'attn.window' if 'attn.window' in under[m.group(1)] \
+                else 'attn.full'
+            seen.add((kind,) + tuple(sorted(sizes, key=int)))
+    assert seen == {('attn.full', '2', '12'), ('attn.window', '2', '16')}
 
 
 def test_state_space_decoder_step_holds_its_kernels_and_its_scopes(

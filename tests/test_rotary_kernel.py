@@ -2,7 +2,8 @@
 forms it replaces, `rotate_halves` and `rotate_pairs` with the `swapaxes`
 behind them: values and `jax.vjp`, both partner lanes, bfloat16 and float32,
 a YaRN table, positions that restart inside a packed row, a 128 + 64 head of
-which only the last 64 lanes turn, the `(B, H, T, d)` layout, and the
+which only the last 64 lanes turn, a head of 128 of which only the FIRST 64
+turn in the half-split form inside them, the `(B, H, T, d)` layout, and the
 property that makes the backward the same kernel: turning by the negated
 sine undoes the turn."""
 import jax
@@ -51,9 +52,24 @@ def pairs(theta, turned):
     return site
 
 
+def yarn_half_table():
+    """The YaRN table of 64 of a head's 128 channels (dimension 64, factor
+    64 over 4096, beta 64 / 1), and its factor."""
+    inv_freq, low, high = la.yarn_inv_freq(500000.0, 64, 64.0, 4096, 64, 1)
+    assert (low, high) == (5, 16) and inv_freq.shape == (32,)
+    return inv_freq.astype(np.float32), 0.1 * np.log(64.0) + 1.0
+
+
 _SITES = {
     'halves-plain-table': (halves(la.rope_inv_freq(
         500000.0, 128).astype(np.float32)), 4, 128),
+    'halves-first-64-of-128-48-query-heads': (halves(*yarn_half_table()),
+                                               48, 128),
+    'halves-first-64-of-128-8-kv-heads': (halves(*yarn_half_table()), 8, 128),
+    'halves-whole-head-64-query-heads': (halves(la.rope_inv_freq(
+        10000.0, 128).astype(np.float32)), 64, 128),
+    'halves-first-128-of-256': (halves(la.rope_inv_freq(
+        10000.0, 128).astype(np.float32), 0.5), 2, 256),
     'halves-yarn-table-and-factor': (halves(*yarn_table(128)), 4, 128),
     'halves-one-kv-head': (halves(la.rope_inv_freq(
         10000.0, 128).astype(np.float32)), 1, 128),
@@ -99,6 +115,7 @@ def test_the_kernel_equals_the_xla_form(name, dtype):
 
 
 @pytest.mark.parametrize('name', ['halves-yarn-table-and-factor',
+                                  'halves-first-64-of-128-8-kv-heads',
                                   'pairs-last-64-of-a-128+64-head'])
 def test_the_kernel_took_the_kernel_and_the_xla_form_did_not(name):
     site, heads, d = _SITES[name]
@@ -128,17 +145,45 @@ def test_lanes_that_are_not_turned_are_carried_as_they_are():
     assert np.abs(got[..., 128:] - want[..., 128:]).max() > 0.1
 
 
-@pytest.mark.parametrize('form,heads,d', [('halves', 4, 128),
-                                          ('pairs', 4, 192)])
+def test_the_first_lanes_turn_and_the_lanes_behind_are_carried():
+    """64 of 128: lanes 64-127 leave the kernel bit for bit (no factor on
+    them either), lane j < 32 is turned with lane j + 32 and NOT with lane
+    j + 64, and a position 0 scales the turned lanes by the factor alone."""
+    site, heads, d = _SITES['halves-first-64-of-128-8-kv-heads']
+    inv_freq, factor = yarn_half_table()
+    rs = np.random.default_rng(3)
+    x = jnp.asarray(rs.normal(size=(B, T, heads, d)), F32)
+    at = packed_positions()
+    got = np.asarray(site(x, at, True)).transpose(0, 2, 1, 3)
+    xs = np.asarray(x)
+    np.testing.assert_array_equal(got[..., 64:], xs[..., 64:])
+    angle = np.asarray(at)[..., None] * inv_freq                 # (B, T, 32)
+    z = factor * (xs[..., :32] + 1j * xs[..., 32:64]) \
+        * np.exp(1j * angle)[:, :, None, :]
+    np.testing.assert_allclose(got[..., :32], z.real, atol=1e-5)
+    np.testing.assert_allclose(got[..., 32:64], z.imag, atol=1e-5)
+    first = np.asarray(at) == 0
+    np.testing.assert_allclose(got[first][..., :64],
+                               factor * xs[first][..., :64], rtol=1e-6)
+
+
+@pytest.mark.parametrize('form,heads,d,turned', [
+    ('halves', 4, 128, None), ('halves', 4, 128, 64), ('pairs', 4, 192, None)])
 @pytest.mark.parametrize('dtype', [BF16, F32], ids=['bfloat16', 'float32'])
-def test_turning_back_by_the_negated_sine_gives_x(form, heads, d, dtype):
+def test_turning_back_by_the_negated_sine_gives_x(form, heads, d, turned,
+                                                  dtype):
     """`rotary(rotary(x, sin), -sin) == x` to rounding: the second call is
     the backward's (the same kernel, the BlockSpecs exchanged), fed the
     first one's result."""
     rs = np.random.default_rng(4)
     x = jnp.asarray(rs.normal(size=(B, T, heads * d)), dtype)
     angle = jnp.asarray(rs.uniform(0, 6.28, size=(B, T, d // 2)), F32)
-    if form == 'halves':
+    if form == 'halves' and turned:
+        angle = angle[..., :turned // 2]
+        angle = jnp.pad(jnp.concatenate([angle, angle], -1),
+                        [(0, 0), (0, 0), (0, d - turned)])
+        sign = jnp.where(jnp.arange(d) < turned // 2, -1.0, 1.0)
+    elif form == 'halves':
         angle = jnp.concatenate([angle, angle], -1)
         sign = jnp.where(jnp.arange(d) < d // 2, -1.0, 1.0)
     else:
@@ -147,7 +192,7 @@ def test_turning_back_by_the_negated_sine_gives_x(form, heads, d, dtype):
     reps = (1, 1, rotary._period(d) // d)
     cos = jnp.tile(jnp.cos(angle), reps)
     sin = jnp.tile(jnp.sin(angle) * sign, reps)
-    static = dict(form=form, heads=heads, interpret=True)
+    static = dict(form=form, heads=heads, interpret=True, turned=turned)
     there = rotary._call(x, cos, sin, **static)
     assert there.shape == (B, heads, T, d)
     back = rotary._call(there, cos, -sin, **static)
